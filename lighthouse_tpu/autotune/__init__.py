@@ -25,7 +25,7 @@ Import cost: this package and its submodules import only the stdlib and
 `utils.metrics`; jax / numpy / fixtures are imported lazily inside the
 functions that need them, so consulting the planner from hot paths
 (BeaconProcessorConfig defaults, HybridBackend construction) is cheap and
-can never block on a device tunnel.
+can never block on a device.
 """
 
 from . import planner, profile, profiler, runtime  # noqa: F401

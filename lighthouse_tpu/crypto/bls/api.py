@@ -124,7 +124,7 @@ def _load_hybrid_backend():
     """Host/device routing policy (crypto/bls/hybrid.py): urgent or tiny
     verifies ride the host path while the device is cold, absent, or over
     its latency budget — the serving story for a node started during a
-    tunnel outage (SURVEY §7 hard part (d))."""
+    device outage (SURVEY §7 hard part (d))."""
     from .hybrid import HybridBackend
 
     backend = HybridBackend()

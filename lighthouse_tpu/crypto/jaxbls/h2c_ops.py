@@ -317,11 +317,11 @@ def hash_to_g2_jacobian(us):
     """Device: (n, 2, 2, NL) STANDARD-form u-values -> batched Jacobian G2
     points (converts to Montgomery on device first).
 
-    On a single accelerator the whole map runs as a fused Pallas kernel
-    (pallas_ops.hash_to_g2_fused); plain XLA elsewhere."""
+    Plain XLA; the whole map as one fused Pallas kernel
+    (pallas_ops.hash_to_g2_fused) when pallas_ops.mode() asks."""
     from . import pallas_ops
 
-    m = pallas_ops.mode("h2c", n=us.shape[0])
+    m = pallas_ops.mode()
     if m is not None:
         return pallas_ops.hash_to_g2_fused(us, interpret=(m == "interpret"))
     us = lb.to_mont(us)

@@ -313,16 +313,12 @@ def pairing_product_is_one(p_aff, q_aff, valid_mask):
     """prod_{i valid} e(P_i, Q_i) == 1: shared-accumulator Miller loop
     (any pair count) + one final exponentiation.
 
-    On a single accelerator the Miller loop and the final-exp hard part run
-    as fused Pallas kernels (pallas_ops.py); the plain XLA path remains the
-    reference (and the mesh-sharded multi-chip path)."""
+    Plain XLA (the reference, and the mesh-sharded multi-chip path); when
+    pallas_ops.mode() asks, the Miller loop and the final-exp hard part
+    run as fused Pallas kernels (pallas_ops.py)."""
     from . import pallas_ops
 
-    # size-gate on the SET count: the backend appends one generator row to
-    # the pair axis, so shape[0] is n_sets + 1 — without the -1 a 64-set
-    # batch (the largest bucket the gate keeps fused) would gate this, the
-    # dominant stage, while every other stage ran fused
-    m = pallas_ops.mode("pairing", n=max(1, p_aff[0].shape[0] - 1))
+    m = pallas_ops.mode()
     if m is not None:
         return pallas_ops.pairing_product_is_one_fused(
             p_aff, q_aff, valid_mask, interpret=(m == "interpret")

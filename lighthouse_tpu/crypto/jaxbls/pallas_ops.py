@@ -37,13 +37,14 @@ Kernel design notes:
 Reference workload this accelerates: multi-set verification exactly as in
 /root/reference/crypto/bls/src/impls/blst.rs:35-117 (SURVEY.md §6 north star).
 
-Mode selection (LIGHTHOUSE_TPU_PALLAS):
-  "auto" (default) — fused kernels when running single-device on a TPU-like
-                     backend; plain XLA on CPU and under a multi-chip mesh
-                     (the pairing stage's set axis is sharded there).
-  "on"/"1"         — force fused kernels (compiled).
+Mode selection (LIGHTHOUSE_TPU_PALLAS) — never decided by a file, a
+platform string or a caught exception:
+  "auto" (default), "off"/"0" — the plain XLA staged programs.
+  "on"/"1"         — the fused kernels, compiled; whatever Mosaic raises
+                     is raised.
   "interpret"      — fused kernels in Pallas interpreter mode (CPU tests).
-  "off"/"0"        — force plain XLA.
+Whether a fused kernel earns a place on the default path is decided on a
+benchmark (ROADMAP D3), not here.
 """
 
 from __future__ import annotations
@@ -64,89 +65,21 @@ from . import pairing_ops as po
 _X_BITS_ARR = np.array([int(b) for b in bin(X_ABS)[3:]], np.int32)
 
 
-_STATUS_MEMO: list = []
-
-
-def _probed_ok(kernel: str | None = None) -> bool:
-    """The PALLAS_STATUS.json gate, shared by every auto-mode consumer:
-    fused kernels only after scripts/probe_pallas.py has validated Mosaic
-    lowering on THIS platform (the record carries str(jax.devices()) so a
-    stale file from a different chip keeps auto on the XLA path).
-
-    With a kernel family name ("prepare"/"h2c"/"pairs"/"pairing") the
-    per-family verdict applies, so e.g. the SMEM-bits Miller/final-exp pair
-    can run fused while a scan-built stage stays on XLA."""
-    if not _STATUS_MEMO:
-        st = None
-        try:
-            import json
-
-            root = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                "..", "..", "..")
-            with open(os.path.join(root, "PALLAS_STATUS.json")) as f:
-                cand = json.load(f)
-            if cand.get("platform") == str(jax.devices()):
-                st = cand
-        except Exception:
-            st = None
-        _STATUS_MEMO.append(st or {})
-    st = _STATUS_MEMO[0]
-    if kernel is not None and isinstance(st.get("kernels"), dict):
-        return bool(st["kernels"].get(kernel))
-    return bool(st.get("ok"))
-
-
-def mode(
-    kernel: str | None = None,
-    n: int | None = None,
-    pk_width: int | None = None,
-) -> str | None:
+def mode() -> str | None:
     """Resolve the Pallas routing mode. Returns "compile", "interpret" or
-    None (use the plain XLA path). `kernel` names the fused-kernel family
-    asking (see _probed_ok) — auto mode enables each independently.
-
-    `n` is the caller's batch extent (sets / pairs): auto mode keeps the
-    fused kernels on the SMALL buckets — the urgent/latency-bound path,
-    where one kernel launch replaces dozens of dispatch round trips — and
-    leaves wide firehose buckets on the proven XLA path, whose per-op
-    dispatch overhead already amortizes over huge vectors and whose
-    compile cost is far lower (Mosaic compile of the fused stages grows
-    steeply with lane width; the v5e probe measured minutes per stage at
-    toy shapes). Explicit "on"/"interpret" bypass the size gate."""
+    None (use the plain XLA path). The decision reads the environment
+    switch alone, so every stage and shape takes the same path, and an
+    unknown value raises instead of picking one."""
     env = os.environ.get("LIGHTHOUSE_TPU_PALLAS", "auto").lower()
-    if env in ("off", "0", "no"):
+    if env in ("auto", "off", "0", "no"):
         return None
     if env == "interpret":
         return "interpret"
     if env in ("on", "1", "yes", "force"):
         return "compile"
-    # auto: only on a real accelerator, only when the set axis is not
-    # sharded over a multi-device mesh (mesh mode keeps the XLA collectives
-    # path — parallel/mesh.py), and only once the on-chip probe has
-    # validated Mosaic lowering here (an unproven kernel costs minutes of
-    # doomed client-side lowering before any fallback can engage).
-    # Knob parses live OUTSIDE the try: a malformed value must raise, not
-    # silently disable every fused kernel via the probe catch-all.
-    max_n = int(os.environ.get("LIGHTHOUSE_TPU_PALLAS_AUTO_MAX", "64"))
-    max_pks = int(os.environ.get("LIGHTHOUSE_TPU_PALLAS_AUTO_MAX_PKS", "8"))
-    if n is not None and n > max_n:
-        return None
-    # the prepare kernel's body grows with the pubkey axis (log2(m)
-    # unrolled jac_add tree levels): Mosaic compile at m=128 ran well
-    # over an hour on the v5e vs 340 s at the probe's m=2 — auto mode
-    # keeps fused prepare to narrow buckets only
-    if pk_width is not None and pk_width > max_pks:
-        return None
-    try:
-        if jax.default_backend() == "cpu":
-            return None
-        from ...parallel.mesh import get_mesh
-
-        if get_mesh() is not None:
-            return None
-        return "compile" if _probed_ok(kernel) else None
-    except Exception:
-        return None
+    raise ValueError(
+        f"LIGHTHOUSE_TPU_PALLAS={env!r}: expected auto, off, on or interpret"
+    )
 
 
 def _pl():

@@ -7,7 +7,7 @@ pure function of its own row plus a handful of epoch scalars. This module
 expresses them ONCE over an abstract array namespace `xp` — the same
 shared-schedule trick as ssz/sha256_batch.compress — so the host lane
 (numpy uint64) and the device lane (jnp uint64 under a scoped
-`jax.experimental.enable_x64`; jaxbls' uint32 limb kernels are untouched
+`jax.enable_x64`; jaxbls' uint32 limb kernels are untouched
 by the scope) trace identical integer arithmetic, and both are pinned
 bit-exact against the pure-Python spec path in tests/test_jaxhash.py.
 
@@ -303,12 +303,12 @@ def _device_altair_deltas(n, eff, part_masks, eligible_mask, target_part,
         "epoch", lane="batch", bucket=None, est_cost=None
     )
     try:
-        from jax.experimental import enable_x64
+        import jax
 
         nb = next_pow2(n)
         interval.bucket = nb
         interval.start()
-        with enable_x64():
+        with jax.enable_x64():
             kernel = _device_epoch_kernel(nb)
             part = np.stack([_pad(m, nb) for m in part_masks])
             rew, pen, inact = kernel(

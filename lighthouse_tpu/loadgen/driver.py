@@ -209,7 +209,7 @@ def _write_matrix_rows(name, reports_by_point, *, smoke, bench_root,
                        stderr) -> dict:
     """Snapshot measured sets/s + p50 into the BENCH_MATRIX schema with a
     `source: loadtest` tag (observability/perf.write_loadtest_rows) — the
-    tunnel-proof bench seam: any soak through `bn loadtest` doubles as a
+    device-free bench seam: any soak through `bn loadtest` doubles as a
     bench round, and the trend gate reads the rows as fresh."""
     import time as _time
 

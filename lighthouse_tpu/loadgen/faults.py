@@ -4,7 +4,7 @@
 verifies instantly (fake-crypto semantics — loadgen measures the QoS
 machinery, not pairings) until `stall()` is called, after which every
 verify blocks for a bounded `wait_secs` and then raises `DeviceStallError`
-— the shape of a wedged remote-TPU tunnel as seen by a caller with a
+— the shape of a wedged device as seen by a caller with a
 timeout. Async handles block in `result()` the same way, so the processor's
 in-flight resolution path is exercised too. `release()` restores instant
 service.

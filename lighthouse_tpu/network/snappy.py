@@ -50,22 +50,13 @@ def _load_native():
     _native_tried = True
     try:
         import ctypes
-        import os
-        import subprocess
         from pathlib import Path
 
-        src = Path(__file__).parent / "native" / "snappy.cc"
-        lib_path = Path(__file__).parent / "native" / "libltsnappy.so"
-        if not lib_path.exists() or lib_path.stat().st_mtime < src.stat().st_mtime:
-            # build to a per-pid temp path + atomic rename: concurrent
-            # processes must never CDLL a half-written library
-            tmp = lib_path.with_suffix(f".tmp.{os.getpid()}")
-            subprocess.run(
-                ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-                 str(src), "-o", str(tmp)],
-                check=True, capture_output=True,
-            )
-            os.replace(tmp, lib_path)
+        from ..utils.native_build import build_native
+
+        lib_path = build_native(
+            Path(__file__).parent / "native" / "snappy.cc", "libltsnappy.so"
+        )
         lib = ctypes.CDLL(str(lib_path))
         lib.snp_uncompressed_length.restype = ctypes.c_int
         lib.snp_uncompressed_length.argtypes = [

@@ -3,7 +3,7 @@
 PR 1 taught the node to size batches per device and PR 2 made every stage
 observable; this package is the layer that *protects* the pipeline when the
 measured numbers go bad. The reference client treats overload as a design
-concern — a priority-ordered work taxonomy, oldest-first shedding on the
+concern — a priority order of work kinds, oldest-first shedding on the
 batchable gossip queues (LIFO-queue semantics in
 beacon_processor/src/lib.rs:301-372), and explicit backfill rate limiting —
 and this package gives the TPU port the same spine:

@@ -1,6 +1,6 @@
 """Admission control: priority classes, slot deadlines, shed accounting.
 
-Parity surface: the reference's Work taxonomy orders every work kind
+Parity surface: the reference's Work enum orders every work kind
 explicitly (beacon_processor/src/lib.rs:955-1090) and bounds each queue;
 what it does NOT do is refuse work early — a flooded queue sheds on push.
 Here the `AdmissionController` sits in front of `BeaconProcessor.submit`

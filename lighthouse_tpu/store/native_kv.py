@@ -1,6 +1,7 @@
 """ctypes binding for the native C++ log-structured KV store.
 
-Builds lib on first use with g++ (cached beside the source); exposes the
+Builds the library on first use with g++ (into <repo>/.native_cache/,
+utils/native_build.py — never into the package); exposes the
 KeyValueStore interface so HotColdDB can run on either MemoryStore (tests)
 or NativeKVStore (production), mirroring how the reference picks
 LevelDB vs MemoryStore behind its KeyValueStore trait.
@@ -19,7 +20,6 @@ from __future__ import annotations
 import ctypes
 import os
 import struct
-import subprocess
 import threading
 import zlib
 from pathlib import Path
@@ -127,42 +127,6 @@ def iter_record_ops(payload: bytes):
 
 
 _SRC = Path(__file__).parent / "native" / "kv_store.cc"
-_LIB = Path(__file__).parent / "native" / "libltkv.so"
-_build_lock = threading.Lock()
-
-
-def _cache_lib() -> Path:
-    """Per-user rebuild target: the tracked .so must never be overwritten
-    at runtime (a host-toolchain binary would dirty every checkout and
-    could land in a commit)."""
-    base = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache"
-    )
-    d = Path(base) / "lighthouse_tpu_native"
-    d.mkdir(parents=True, exist_ok=True)
-    return d / "libltkv.so"
-
-
-def _build(dst: Path) -> Path:
-    cmd = [
-        "g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-        str(_SRC), "-o", str(dst),
-    ]
-    subprocess.run(cmd, check=True, capture_output=True)
-    return dst
-
-
-def _ensure_built() -> Path:
-    with _build_lock:
-        if _LIB.exists() and _LIB.stat().st_mtime >= _SRC.stat().st_mtime:
-            return _LIB
-        # tracked lib absent or stale vs source: build into the cache, not
-        # over the tracked artifact
-        cached = _cache_lib()
-        if cached.exists() and cached.stat().st_mtime >= _SRC.stat().st_mtime:
-            return cached
-        return _build(cached)
-
 
 _lib = None
 
@@ -171,17 +135,9 @@ def _load():
     global _lib
     if _lib is not None:
         return _lib
-    path = _ensure_built()
-    try:
-        lib = ctypes.CDLL(str(path))
-    except OSError:
-        # the prebuilt .so can be unloadable on THIS host (e.g. it requires
-        # a GLIBCXX newer than the system libstdc++): recompiling from
-        # source links against the local toolchain, so try that once before
-        # the caller degrades to the pure-Python engine
-        with _build_lock:
-            path = _build(_cache_lib())
-        lib = ctypes.CDLL(str(path))
+    from ..utils.native_build import build_native
+
+    lib = ctypes.CDLL(str(build_native(_SRC, "libltkv.so")))
     lib.kvs_open.restype = ctypes.c_void_p
     lib.kvs_open.argtypes = [ctypes.c_char_p]
     lib.kvs_close.argtypes = [ctypes.c_void_p]
@@ -201,21 +157,11 @@ def _load():
     lib.kvs_count.argtypes = [ctypes.c_void_p]
     lib.kvs_compact.restype = ctypes.c_int
     lib.kvs_compact.argtypes = [ctypes.c_void_p]
-    # durability controls — absent from pre-fsync builds of the library
-    # (e.g. a stale tracked .so whose checkout mtime beat the source's);
-    # degrade to fflush-only rather than refusing to open the DB
-    try:
-        lib.kvs_set_fsync.restype = ctypes.c_int
-        lib.kvs_set_fsync.argtypes = [ctypes.c_void_p, ctypes.c_int]
-        lib.kvs_flush.restype = ctypes.c_int
-        lib.kvs_flush.argtypes = [ctypes.c_void_p]
-        lib._has_fsync = True
-    except AttributeError:
-        lib._has_fsync = False
-        get_logger("store").warn(
-            "native kv library predates fsync support; durability policy "
-            "degraded to OS page cache (rebuild with g++ to fix)"
-        )
+    # durability controls
+    lib.kvs_set_fsync.restype = ctypes.c_int
+    lib.kvs_set_fsync.argtypes = [ctypes.c_void_p, ctypes.c_int]
+    lib.kvs_flush.restype = ctypes.c_int
+    lib.kvs_flush.argtypes = [ctypes.c_void_p]
     _ITER_CB = ctypes.CFUNCTYPE(None, ctypes.c_void_p,
                                 ctypes.POINTER(ctypes.c_uint8), ctypes.c_uint32,
                                 ctypes.POINTER(ctypes.c_uint8), ctypes.c_uint32)
@@ -429,10 +375,9 @@ class NativeKVStore(KeyValueStore):
         self._h = lib.kvs_open(os.fspath(path).encode())
         if not self._h:
             raise OSError(f"cannot open native kv store at {path}")
-        if lib._has_fsync:
-            lib.kvs_set_fsync(
-                self._h, {"never": 0, "batch": 1, "always": 2}[self._fsync]
-            )
+        lib.kvs_set_fsync(
+            self._h, {"never": 0, "batch": 1, "always": 2}[self._fsync]
+        )
 
     def get(self, column: Column, key: bytes) -> bytes | None:
         k = _ckey(column, key)
@@ -482,7 +427,7 @@ class NativeKVStore(KeyValueStore):
             raise OSError(f"kvs_compact failed: {rc}")
 
     def flush(self) -> None:
-        if self._h and self._lib._has_fsync:
+        if self._h:
             rc = self._lib.kvs_flush(self._h)
             if rc != 0:
                 raise OSError(f"kvs_flush failed: {rc}")

@@ -11,7 +11,7 @@ startup warmup plan (lighthouse_tpu/autotune/).
 
     # CPU smoke: tiny fixtures, pure-python measurement backend, output to
     # a gitignored path (./autotune_profile_smoke.json) — never touches a
-    # tunnel, never clobbers an on-device profile:
+    # device, never clobbers an on-device profile:
     python scripts/autotune_calibrate.py --smoke
 
 All logic lives in lighthouse_tpu.autotune.calibrate (shared with the

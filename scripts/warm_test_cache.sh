@@ -1,5 +1,6 @@
 #!/bin/bash
-# Populate the per-platform jax compile cache for the test suite.
+# Populate the jax compile cache for the test suite ($JAX_COMPILATION_CACHE_DIR
+# when set, else <repo>/.jax_cache — lighthouse_tpu/utils/jaxcfg.py).
 #
 # pytest runs are cache-READ-ONLY by default (see tests/conftest.py: the
 # XLA:CPU executable serializer can segfault when writing entries late in a

@@ -481,7 +481,7 @@ def test_warmup_plan_fallback_and_ordering():
 
 def test_warmup_failure_never_propagates():
     def boom(n, m):
-        raise RuntimeError("tunnel died")
+        raise RuntimeError("device lost")
 
     t = runtime.start_warmup(buckets=((4, 1),), warm_fn=boom)
     t.join(timeout=10)  # the thread swallows the failure and exits
@@ -779,3 +779,40 @@ def test_plan_tree_hash_warmup_derivation():
     # and the installed plan surfaces it to consumers
     runtime.install_profile(p)
     assert runtime.active_plan().tree_hash_warmup == capped
+
+
+# ------------------------------------------------- compile cache placement
+
+
+@pytest.mark.parametrize("placed", [True, False], ids=["env", "default"])
+def test_compile_cache_dir_is_placed_from_outside(tmp_path, placed):
+    """utils/jaxcfg.py: with JAX_COMPILATION_CACHE_DIR set JAX's own
+    setting stands (the module sets no directory); unset, the cache is
+    <repo>/.jax_cache. The autotune profile dir follows whichever is in
+    force. A fresh process: the variable is read when jax is imported."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR",
+                        "LIGHTHOUSE_TPU_AUTOTUNE_DIR")}
+    env["JAX_PLATFORMS"] = "cpu"
+    want = os.path.join(repo, ".jax_cache")
+    if placed:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "placed")
+    code = (
+        "import jax\n"
+        "from lighthouse_tpu.utils import jaxcfg\n"
+        "from lighthouse_tpu.autotune.profile import profile_dir\n"
+        "jaxcfg.setup_compilation_cache()\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "print(jaxcfg.cache_base_dir())\n"
+        "print(profile_dir())\n"
+    )
+    r = subprocess.run([sys.executable, "-c", code], cwd=repo, env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.split() == [want, want, os.path.join(want, "autotune")]
+

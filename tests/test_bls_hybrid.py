@@ -2,8 +2,8 @@
 or over budget (SURVEY §7 hard part (d); reference escape hatch:
 /root/reference/beacon_node/beacon_chain/src/attestation_verification/batch.rs:116-120).
 
-These tests drive the policy with a stub device so no jax dispatch (or
-tunnel) is involved; the real device path is covered by the jaxbls suites.
+These tests drive the policy with a stub device so no jax dispatch is
+involved; the real device path is covered by the jaxbls suites.
 """
 
 import threading

@@ -1,0 +1,183 @@
+"""The main path's device programs, compiled for a DESCRIBED TPU v5e.
+
+No chip is attached here: the TPU compiler that ships with the image
+compiles for a topology that is described, not present
+(`jax.experimental.topologies`). What it refuses here the chip refuses too
+— a kernel slice off the tiling, a program over the chip's 16 GB — so
+these cases guard every later PR at no chip time. A compile that passes is
+not a chip run: `chip_smoke.py` is.
+
+The topology is described inside a module-scoped fixture of THIS file
+(never at import, never in conftest, never autouse) because the process
+that loads the TPU library holds its lock until it exits; the compiles run
+in the test's own process, with the persistent cache off around them (an
+entry written for a described chip cannot be read back without one).
+
+Unmarked: the compiles of a few seconds. `slow`: the minute-long stage
+compiles at the served 64x128 bucket (prepare ~1 min, hash-to-G2 ~3 min,
+pairing ~2.5 min on eight host cores).
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from lighthouse_tpu.crypto.jaxbls import backend as be
+from lighthouse_tpu.crypto.jaxbls import h2c_ops as h2
+from lighthouse_tpu.crypto.jaxbls import limbs as lb
+
+V5E_HBM_BYTES = 16 * 1024**3
+N_SETS, N_PKS = 64, 128   # the served gossip bucket (chip_smoke.py)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _stage_args(n: int, m: int, sharding) -> dict:
+    """Argument shapes of the four staged programs at bucket (n, m), as
+    the marshal and the previous stages produce them."""
+    NL = lb.NL
+
+    def u32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=sharding)
+
+    g1 = tuple(u32(n, NL) for _ in range(3))          # z_pk, jacobian G1
+    g2 = tuple(u32(n, 2, NL) for _ in range(3))       # h_jac, jacobian G2
+    acc = tuple(u32(2, NL) for _ in range(3))         # sig_acc, one G2 point
+    mask = jax.ShapeDtypeStruct((n + 1,), jnp.bool_, sharding=sharding)
+    return {
+        "prepare": (u32(n, m, NL), u32(n, m, NL), u32(n, m),
+                    u32(n, 2, NL), u32(n, 2, NL), u32(n, be.Z_DIGITS), u32(n)),
+        "h2c": (u32(n, 2, 2, NL),),
+        "pairs": (g1, g2, acc, u32(n)),
+        "pairing": (u32(n + 1, NL), u32(n + 1, NL),
+                    u32(n + 1, 2, NL), u32(n + 1, 2, NL), mask),
+    }
+
+
+_STAGE_FNS = {
+    "prepare": be._stage_prepare,
+    "h2c": h2.hash_to_g2_jacobian,
+    "pairs": be._stage_pairs,
+    "pairing": be._stage_pairing,
+}
+
+
+def _compile_stage(stage: str, n: int, m: int, sharding):
+    """The stage as the TPU node jits it: the XLA path, donation on."""
+    be._init_consts()
+    fn = jax.jit(_STAGE_FNS[stage],
+                 donate_argnums=be.STAGE_DONATE_ARGNUMS[stage])
+    return fn.lower(*_stage_args(n, m, sharding)[stage]).compile()
+
+
+def _assert_fits_hbm(compiled):
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
+    assert total < V5E_HBM_BYTES, mem
+
+
+def _shapes(tree):
+    return jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), tree)
+
+
+@pytest.mark.parametrize("stage", [
+    "pairs",
+    pytest.param("prepare", marks=pytest.mark.slow),
+    pytest.param("h2c", marks=pytest.mark.slow),
+    pytest.param("pairing", marks=pytest.mark.slow),
+])
+def test_xla_stage_compiles_for_v5e(stage, one_chip, no_persistent_cache,
+                                    monkeypatch):
+    monkeypatch.setenv("LIGHTHOUSE_TPU_PALLAS", "off")
+    compiled = _compile_stage(stage, N_SETS, N_PKS, one_chip)
+    _assert_fits_hbm(compiled)
+    assert "tpu_custom_call" not in compiled.as_text()
+    # the next stage's argument shapes in _stage_args are this stage's
+    # outputs: a drifted hand-written shape must fail here, not broadcast
+    args = _stage_args(N_SETS, N_PKS, one_chip)
+    out = _shapes(jax.eval_shape(_STAGE_FNS[stage], *args[stage]))
+    if stage == "prepare":
+        assert out[:2] == _shapes((args["pairs"][0], args["pairs"][2]))
+    elif stage == "h2c":
+        assert out == _shapes(args["pairs"][1])
+    elif stage == "pairs":
+        assert out == _shapes(args["pairing"])
+
+
+def test_fused_pairs_kernel_compiles_for_v5e(one_chip, no_persistent_cache):
+    """LIGHTHOUSE_TPU_PALLAS=on at the urgent lane's 4-set bucket: Mosaic
+    accepts the fused pair-assembly kernel."""
+    from lighthouse_tpu.crypto.jaxbls import pallas_ops
+
+    args = _stage_args(4, 1, one_chip)["pairs"]
+    compiled = jax.jit(pallas_ops.stage_pairs_fused).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _shapes(jax.eval_shape(pallas_ops.stage_pairs_fused, *args)) == (
+        _shapes(_stage_args(4, 1, one_chip)["pairing"])
+    )
+
+
+def test_tree_hash_ladder_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The jaxhash ladder at its 1,048,576-leaf bucket fits the chip."""
+    from lighthouse_tpu.jaxhash import engine
+
+    n = 1 << 20
+    ladder = engine._make_ladder(n, 1, True, None)
+    words = jax.ShapeDtypeStruct((n, 8), np.uint32, sharding=one_chip)
+    _assert_fits_hbm(ladder.lower(words).compile())
+
+
+@pytest.mark.parametrize("value,want", [
+    (None, None), ("auto", None), ("off", None), ("0", None),
+    ("on", "compile"), ("1", "compile"), ("interpret", "interpret"),
+])
+def test_pallas_mode_reads_the_switch_alone(monkeypatch, value, want):
+    """Which path a stage takes is the environment switch and nothing
+    else: no recorded probe file, no platform string, no size gate, no
+    caught exception. `auto` is the XLA staged programs."""
+    from lighthouse_tpu.crypto.jaxbls import pallas_ops
+
+    if value is None:
+        monkeypatch.delenv("LIGHTHOUSE_TPU_PALLAS", raising=False)
+    else:
+        monkeypatch.setenv("LIGHTHOUSE_TPU_PALLAS", value)
+    assert pallas_ops.mode() == want
+
+
+def test_pallas_mode_refuses_an_unknown_value(monkeypatch):
+    from lighthouse_tpu.crypto.jaxbls import pallas_ops
+
+    monkeypatch.setenv("LIGHTHOUSE_TPU_PALLAS", "maybe")
+    with pytest.raises(ValueError, match="LIGHTHOUSE_TPU_PALLAS"):
+        pallas_ops.mode()

@@ -285,6 +285,35 @@ def test_altair_deltas_host_lane_bit_exact(monkeypatch):
         assert (got[f][0], got[f][1]) == want[f], f
 
 
+def test_device_epoch_lane_itself_serves(monkeypatch):
+    """The DEVICE lane returns a result and reports success to the shared
+    breaker. `altair_deltas` answers from the host vector lane whenever
+    the device leg returns None, so a JAX name removed by an upgrade (the
+    `enable_x64` import, jax 0.9.0) hid behind bit-exact host answers in
+    every other test here; this one wedges the host lane's way out."""
+    from lighthouse_tpu.state_transition import epoch as ep
+    from lighthouse_tpu.types.spec import ForkName
+
+    spec, _types, state = _epoch_state(seed=5)
+    eligible = ep._eligible_validator_indices(state, spec)
+    monkeypatch.setenv("LIGHTHOUSE_TPU_EPOCH_VEC_MIN", "1")
+    set_hash_backend("device")
+    served, recorded = [], []
+    device_lane = ev._device_altair_deltas
+
+    def spy(*a, **k):
+        served.append(device_lane(*a, **k))
+        return served[-1]
+
+    monkeypatch.setattr(ev, "_device_altair_deltas", spy)
+    monkeypatch.setattr(ROUTER, "record_device", recorded.append)
+    assert ev.altair_deltas(state, spec, ForkName.deneb, eligible) is not None
+    assert len(served) == 1 and served[0] is not None, (
+        "the device epoch lane returned None: the host vector lane served"
+    )
+    assert recorded == [True]
+
+
 def test_epoch_vectors_honor_shared_breaker(monkeypatch):
     """In hybrid mode an OPEN tree-hash breaker refuses the epoch-vector
     device path O(1) (pure-Python serves) — the router.py contract holds

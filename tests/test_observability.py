@@ -309,7 +309,7 @@ def test_processor_device_failure_counted_and_logged():
 
     class BoomHandle:
         def result(self):
-            raise RuntimeError("tunnel dropped")
+            raise RuntimeError("device lost")
 
     proc = BeaconProcessor()
     errors0 = _ERRORS.labels("device").value
@@ -323,7 +323,7 @@ def test_processor_device_failure_counted_and_logged():
     assert _ERRORS.labels("device").value == errors0 + 1
     rec = [r for r in RECENT if r[2] == "beacon_processor"][-1]
     assert rec[1] == "ERROR" and "device batch failed" in rec[3]
-    assert "tunnel dropped" in rec[4]["error"]
+    assert "device lost" in rec[4]["error"]
 
     # continuation failures are tracked under their own stage label
     cont0 = _ERRORS.labels("continuation").value
