@@ -7,7 +7,9 @@ anywhere; the chip itself is reached through chip_smoke.py (README
 "Running").
 """
 
+import faulthandler
 import os
+import signal
 
 import pytest
 
@@ -37,7 +39,63 @@ if os.environ.get("LIGHTHOUSE_TPU_CACHE_WRITE") != "1":
 
 
 def pytest_configure(config):
-    config.addinivalue_line("markers", "slow: long multi-node simulations")
+    config.addinivalue_line(
+        "markers", "slow: long multi-node simulations, and compiles of many minutes"
+    )
+    # xdist's loadfile scheduler hands files out by their number of tests,
+    # most first, unless told otherwise — which puts the compile-heavy
+    # files (5-7 tests each) at the very end of the run. Hand out in
+    # collection order instead: _longest_first below decides it.
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+# Files that need the most seconds on their worker, longest first, with the
+# seconds of the six-worker tier-1 run on the 8-core sandbox (PR 25: 941 s
+# in all) beside each. Their items move to the front of the collection so
+# `--dist loadfile` hands them out at t = 0 and the ~1000 light tests fill
+# in behind: the run is then bounded by about max(longest file, total /
+# workers). A file that needs more than 300 s on its worker belongs here.
+# Past the first six the order is not by seconds: xdist hands a worker its
+# next file while the last two tests of its current one are still pending,
+# so the workers of the two- and the one-test file among the first six take
+# the seventh and the eighth file on at t = 0 and run them after minutes of
+# compiling, and the one-test file in eighth place takes a successor along
+# too. This order is the one that ended soonest in a replay of that rule
+# over the seconds of an earlier run, each varied by 15 % (the replay gave
+# 1,102 s for the order of that run, which took 1,104 s, and 1,013 s for
+# this one).
+_LONGEST_FIRST = (
+    "test_ef_vectors.py",               # 738
+    "test_jaxbls_pallas.py",            # 610 (2 tests: takes the 7th along)
+    "test_jaxbls_backend.py",           # 622
+    "test_multichip.py",                # 568
+    "test_jaxbls_pairing.py",           # 369
+    "test_multichip_2d.py",             # 380 (1 test: takes the 8th along)
+    "test_fleet.py",                    # 183
+    "test_jaxbls_pallas_final_exp.py",  # 360 (1 test)
+    "test_beacon_chain.py",             # 250
+    "test_jaxbls_h2c.py",               # 167
+    "test_jaxbls_pallas_stages.py",     # 303
+    "test_kzg.py",                      # 154
+    "test_jaxbls_msm.py",               # 123
+)
+
+
+def _longest_first(items, names=_LONGEST_FIRST):
+    """`items` with those of the files in `names` first, in the order of
+    `names`; order inside a file, and of every other item, is kept."""
+    rank = {name: i for i, name in enumerate(names)}
+
+    def key(item):
+        file_name = item.nodeid.split("::", 1)[0].rsplit("/", 1)[-1]
+        return rank.get(file_name, len(rank))
+
+    return sorted(items, key=key)  # stable
+
+
+def pytest_collection_modifyitems(config, items):
+    items[:] = _longest_first(items)
 
 
 # Every XLA:CPU executable a process keeps costs memory mappings, and the
@@ -67,13 +125,42 @@ def _release_executables_between_files():
     _release_executables()
 
 
+def _n_memory_mappings() -> int:
+    try:
+        with open("/proc/self/maps") as f:
+            return sum(1 for _ in f)
+    except OSError:  # no procfs: nothing to measure
+        return 0
+
+
 @pytest.fixture(autouse=True)
 def _release_executables_near_map_limit():
     yield
-    try:
-        with open("/proc/self/maps") as f:
-            n_maps = sum(1 for _ in f)
-    except OSError:  # no procfs: nothing to measure
-        return
-    if n_maps > _MAP_COUNT_HIGH_MARK:
+    if _n_memory_mappings() > _MAP_COUNT_HIGH_MARK:
         _release_executables()
+
+
+# No test of tier-1 needs minutes once its module's programs are compiled,
+# so a test that is still running after this long is waiting on something
+# that will not come; without a limit it keeps its worker until the
+# driver's outer clock cuts the whole run. One limit, no marker raises it.
+_TEST_LIMIT_S = 600
+
+
+@pytest.fixture(autouse=True)
+def _per_test_time_limit(request):
+    def _on_alarm(signum, frame):
+        pytest.fail(
+            f"{request.node.nodeid} ran past {_TEST_LIMIT_S} s", pytrace=False
+        )
+
+    prev = signal.signal(signal.SIGALRM, _on_alarm)
+    # every thread's stack, a moment before the failure is raised
+    faulthandler.dump_traceback_later(_TEST_LIMIT_S - 1, exit=False)
+    signal.setitimer(signal.ITIMER_REAL, _TEST_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        faulthandler.cancel_dump_traceback_later()
+        signal.signal(signal.SIGALRM, prev)

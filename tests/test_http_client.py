@@ -128,12 +128,12 @@ def test_read_timeout_classified(chain=None):
         _read_request(sock)
         import time
 
-        time.sleep(2.0)
+        time.sleep(0.5)
         sock.close()
 
     srv = RawServer(never_respond)
     base = HTTP_CLIENT_TIMEOUTS.labels("read").value
-    c = BeaconNodeHttpClient(f"http://127.0.0.1:{srv.port}", timeout=0.3)
+    c = BeaconNodeHttpClient(f"http://127.0.0.1:{srv.port}", timeout=0.1)
     try:
         with pytest.raises(NodeTimeout, match="response timed out"):
             c._get("/eth/v1/node/version")
@@ -149,12 +149,12 @@ def test_stalled_body_timeout_classified():
         sock.sendall(b"HTTP/1.1 200 OK\r\nContent-Length: 4096\r\n\r\nab")
         import time
 
-        time.sleep(2.0)
+        time.sleep(0.5)
         sock.close()
 
     srv = RawServer(stall_body)
     base = HTTP_CLIENT_TIMEOUTS.labels("body").value
-    c = BeaconNodeHttpClient(f"http://127.0.0.1:{srv.port}", timeout=0.3)
+    c = BeaconNodeHttpClient(f"http://127.0.0.1:{srv.port}", timeout=0.1)
     try:
         with pytest.raises(NodeTimeout, match="body stalled"):
             c._get("/eth/v1/node/version")
@@ -186,13 +186,15 @@ def test_stale_pooled_socket_retries_once():
 
     def one_then_close(sock):
         _read_request(sock)
+        # counted before the response goes out: the client asserts on the
+        # count as soon as it has the response, in another thread
+        served.append(1)
         body = b'{"data": {"version": "raw/1"}}'
         sock.sendall(
             b"HTTP/1.1 200 OK\r\nContent-Type: application/json\r\n"
             b"Content-Length: " + str(len(body)).encode() + b"\r\n\r\n"
             + body
         )
-        served.append(1)
         # keep-alive implied (HTTP/1.1, no Connection: close), but the
         # server hangs up right after — the pooled socket goes stale
         sock.close()

@@ -1,6 +1,12 @@
 """End-to-end parity: the jax TPU backend vs the pure-Python backend on the
 generic BLS API — the same dual-backend strategy the reference uses for
-blst vs fake_crypto (/root/reference/crypto/bls/tests/tests.rs)."""
+blst vs fake_crypto (/root/reference/crypto/bls/tests/tests.rs).
+
+This is one of the two modules that drive the real JaxBackend through its
+four staged programs (the other is test_multichip.py). Each compiles its
+programs once, in one module-scoped warm-up (tests/jaxbls_warm.py): a new
+test of the staged backend joins one of the two instead of opening a
+file, and keeps to the builds and key-count buckets its module warms."""
 
 import random
 
@@ -28,58 +34,26 @@ def _mk_set(n_pks: int, msg: bytes, valid=True):
 
 @pytest.fixture(scope="module", autouse=True)
 def _warm_stages_parallel():
-    """Cold-compile the four stage programs in PARALLEL THREADS at the test
-    bucket shapes (n=4 sets, m in {1,2,4,8}) before the tests run — XLA
-    releases the GIL while compiling, so the wall-clock cost of a cold
-    suite is max(stage) instead of sum(stages)."""
-    import threading
+    """Cold-compile, in PARALLEL THREADS, the two builds of the four stage
+    programs that the tests below dispatch, before they run — XLA releases
+    the GIL while compiling, so the wall-clock cost of a cold module is
+    about one build's instead of the sum of every stage.
 
-    import numpy as np
+    conftest gives the process 8 virtual devices, so the backend's batch
+    lane dispatches over the 8-device `sets` mesh (sets padded to 8) and
+    only its urgent lane and the host-side callers (verify, the h2c and
+    pairing of aggregate_verify) run the unsharded 4-set programs. Key
+    counts below are 1 (single / attribution / aggregate_verify / the
+    dispatcher test) and 2-4 (the batches, the aggregate): two key-count
+    buckets on the mesh, m = 1 and m = 4, and m = 1 on the urgent lane."""
+    from jaxbls_warm import warm_builds
 
-    from lighthouse_tpu.crypto.jaxbls import backend as be, h2c_ops as h2, limbs as lb
+    from lighthouse_tpu import parallel
 
-    prepare, h2c_stage, pairs_stage, pairing_stage = be._get_stages()
-    rng_ = np.random.default_rng(0)
-
-    def rl(shape):
-        a = rng_.integers(0, 1 << 16, size=shape + (lb.NL,), dtype=np.uint32)
-        a[..., -1] = 0
-        return a
-
-    import jax
-
-    n = be.MIN_SETS
-
-    def w_prepare():
-        for m in (1, 2, 4, 8):
-            jax.block_until_ready(
-                prepare(
-                    rl((n, m)), rl((n, m)), np.ones((n, m), np.uint32),
-                    rl((n, 2)), rl((n, 2)),
-                    np.ones((n, be.Z_DIGITS), np.uint32), np.ones((n,), np.uint32),
-                )
-            )
-
-    def w_h2c():
-        jax.block_until_ready(h2c_stage(rl((n, 2, 2))))
-
-    def w_pairs_pairing():
-        z_pk = (rl((n,)), rl((n,)), rl((n,)))                 # (n,) G1 jac
-        h_jac = (rl((n, 2)), rl((n, 2)), rl((n, 2)))          # (n,) G2 jac
-        sig_acc = (rl((2,)), rl((2,)), rl((2,)))              # single G2 jac
-        out = pairs_stage(z_pk, h_jac, sig_acc, np.ones((n,), np.uint32))
-        jax.block_until_ready(out)
-        jax.block_until_ready(pairing_stage(*out))
-
-    threads = [
-        threading.Thread(target=f)
-        for f in (w_prepare, w_h2c, w_pairs_pairing)
-    ]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    yield
+    parallel.reset_mesh_cache()
+    live = parallel.get_mesh()
+    assert live is not None and int(live.devices.size) == 8
+    warm_builds((8, (1, 4), live), (4, (1,), None))
 
 
 @pytest.fixture(autouse=True)
@@ -90,7 +64,7 @@ def _restore_backend():
 
 def test_verify_signature_sets_parity():
     backend = bls_api.set_backend("jax")
-    sets = [_mk_set(3, b"\x11" * 32), _mk_set(1, b"\x22" * 32), _mk_set(5, b"\x33" * 32)]
+    sets = [_mk_set(3, b"\x11" * 32), _mk_set(1, b"\x22" * 32), _mk_set(4, b"\x33" * 32)]
     rands = [1, 0xDEADBEEF12345677, 0x42]
     assert backend.verify_signature_sets(sets, rands)
 
@@ -168,3 +142,113 @@ def test_aggregate_verify_distinct_messages_parity():
     pks = [sk.public_key() for sk in sks]
     assert bls_api.aggregate_verify(pks, msgs, agg)
     assert not bls_api.aggregate_verify(pks, list(reversed(msgs)), agg)
+
+
+# ----------------------------------------------------- e2e sharded dispatch
+# (from test_mesh.py, which compiles nothing: these need the builds above)
+
+
+def _mk_set_from(rng, n_pks, msg, valid=True):
+    sks = [rng.randrange(1, R) for _ in range(n_pks)]
+    pks = [bls.PublicKey(cv.g1_mul(cv.G1_GEN, sk)) for sk in sks]
+    h = bls_api.hash_to_g2_point(msg)
+    agg = sum(sks) % R
+    if not valid:
+        agg = (agg + 1) % R
+    return bls.SignatureSet(bls.Signature(cv.g2_mul(h, agg)), pks, msg)
+
+
+def test_e2e_sharded_dispatch_through_pipelined_dispatcher():
+    """The tier-1 multichip acceptance: the REAL JaxBackend over the REAL
+    8-virtual-device mesh, batches riding the REAL PipelinedDispatcher —
+    FIFO resolution, the urgent single-chip bypass, correct verdicts, and
+    the mesh dispatch-lane accounting all survive sharding. Stage shapes
+    ((8,1) sharded, (4,1) single-chip) are among those this module's
+    warm-up compiles, so here it is seconds; alone in a cold process it
+    is the cold compile of both builds (tier-1 never reads a compile
+    cache), which cost it 623-757 s while it lived in test_mesh.py."""
+    from lighthouse_tpu import parallel
+    from lighthouse_tpu.parallel.mesh import MESH_DISPATCH
+
+    mesh = parallel.get_mesh()
+    assert mesh is not None and int(mesh.devices.size) == 8
+
+    backend = bls_api.set_backend("jax")
+    try:
+        rng = random.Random(0xE2E)
+        batches = [
+            [_mk_set_from(rng, 1, bytes([b * 8 + i]) * 32) for i in range(8)]
+            for b in range(3)
+        ]
+        sharded0 = MESH_DISPATCH.labels("sharded").value
+        urgent0 = MESH_DISPATCH.labels("urgent").value
+
+        tickets = [
+            backend.verify_signature_sets_async(sets, [1] * 8)
+            for sets in batches
+        ]
+        assert backend.dispatcher.inflight() >= 1
+        # the urgent bypass: resolves without draining the batch window
+        urgent_set = _mk_set_from(rng, 1, b"\xfe" * 32)
+        assert backend.verify_signature_sets_urgent([urgent_set], [1]) is True
+        # FIFO: resolving the LAST ticket first drains earlier ones first
+        assert tickets[-1].result() is True
+        assert all(t.done for t in tickets)
+        assert all(t.result() is True for t in tickets)
+        assert backend.dispatcher.inflight() == 0
+
+        # a tampered sharded batch still rejects through the collectives
+        bad = [_mk_set_from(rng, 1, bytes([0x40 + i]) * 32) for i in range(7)]
+        bad.append(_mk_set_from(rng, 1, b"\x66" * 32, valid=False))
+        assert backend.verify_signature_sets(bad, [1] * 8) is False
+
+        # lane accounting: 4 sharded batches, 1 urgent bypass
+        assert MESH_DISPATCH.labels("sharded").value == sharded0 + 4
+        assert MESH_DISPATCH.labels("urgent").value == urgent0 + 1
+    finally:
+        bls_api.set_backend("python")
+
+
+@pytest.mark.slow
+def test_shard_map_pairing_fallback_real_collective():
+    """The REAL shard_map pair product: force the explicit-sharding jit to
+    fail and verify valid/tampered batches through the all_gather + Fq12
+    partial-product collective. Slow: the fallback pairing program is a
+    fresh XLA compile (~minutes cold on CPU)."""
+    from lighthouse_tpu import parallel
+    from lighthouse_tpu.crypto.jaxbls import backend as be
+
+    mesh = parallel.get_mesh()
+    backend = bls_api.set_backend("jax")
+    try:
+        stages = be._get_stages(mesh=mesh)
+        pd = stages[3]
+        assert isinstance(pd, be._PairingDispatch)
+        old = (pd._jit, pd._use_fallback, pd._fallback)
+
+        class _Boom:
+            def __call__(self, *a):
+                raise RuntimeError("forced propagation failure")
+
+        pd._jit, pd._use_fallback, pd._fallback = _Boom(), False, None
+        try:
+            rng = random.Random(0x5AFE)
+            sets = [_mk_set_from(rng, 1, bytes([i]) * 32) for i in range(8)]
+            assert backend.verify_signature_sets(sets, [1] * 8) is True
+            assert pd._use_fallback is True
+            bad = sets[:-1] + [_mk_set_from(rng, 1, b"\x99" * 32, valid=False)]
+            assert backend.verify_signature_sets(bad, [1] * 8) is False
+        finally:
+            pd._jit, pd._use_fallback, pd._fallback = old
+    finally:
+        bls_api.set_backend("python")
+
+
+def test_module_stays_under_the_mapping_mark():
+    """Last on purpose: with every build of this module compiled and kept,
+    the process must be under conftest's mark — past it conftest drops the
+    executables between tests and each later test recompiles for minutes.
+    A module that outgrows the mark is split, not left to thrash."""
+    from conftest import _MAP_COUNT_HIGH_MARK, _n_memory_mappings
+
+    assert _n_memory_mappings() < _MAP_COUNT_HIGH_MARK

@@ -3,13 +3,12 @@
 Runs on the forced-host-device harness (tests/conftest.py pins
 XLA_FLAGS=--xla_force_host_platform_device_count=8): mesh resolution
 seams, the 2-D acceptance/rejection matrix, placement, mesh-keyed
-padding, and an end-to-end 8-virtual-device dispatch through the REAL
-`PipelinedDispatcher` asserting FIFO/urgent/donation semantics survive
-sharding. The heavyweight shard_map-fallback compile lives behind the
-`slow` marker; tier-1 covers the fallback's flip mechanism with a stub.
+padding, the stage-cache keying and the shard_map fallback's flip
+mechanism (with a stub). Nothing here compiles a staged program: the
+end-to-end 8-virtual-device dispatch through the REAL
+`PipelinedDispatcher`, and the `slow`-marked real shard_map collective,
+live in test_jaxbls_backend.py, beside the programs they need.
 """
-
-import random
 
 import numpy as np
 import pytest
@@ -298,105 +297,3 @@ def test_pairing_dispatch_flips_to_fallback_once(monkeypatch):
     assert pd._use_fallback is True
     assert pd(1, 2, 3, 4, 5) == "fallback-result"
     assert calls == ["built"]  # built once, flip is sticky
-
-
-# ----------------------------------------------------- e2e sharded dispatch
-
-
-def _mk_set(rng, n_pks, msg, valid=True):
-    from lighthouse_tpu.crypto import bls
-    from lighthouse_tpu.crypto.bls import api as bls_api
-    from lighthouse_tpu.crypto.bls381 import curve as cv
-    from lighthouse_tpu.crypto.bls381.constants import R
-
-    sks = [rng.randrange(1, R) for _ in range(n_pks)]
-    pks = [bls.PublicKey(cv.g1_mul(cv.G1_GEN, sk)) for sk in sks]
-    h = bls_api.hash_to_g2_point(msg)
-    agg = sum(sks) % R
-    if not valid:
-        agg = (agg + 1) % R
-    return bls.SignatureSet(bls.Signature(cv.g2_mul(h, agg)), pks, msg)
-
-
-def test_e2e_sharded_dispatch_through_pipelined_dispatcher():
-    """The tier-1 multichip acceptance: the REAL JaxBackend over the REAL
-    8-virtual-device mesh, batches riding the REAL PipelinedDispatcher —
-    FIFO resolution, the urgent single-chip bypass, correct verdicts, and
-    the mesh dispatch-lane accounting all survive sharding. Stage shapes
-    ((8,1) sharded, (4,1) single-chip) are exactly the ones earlier test
-    files already compiled, so this is seconds, not a cold compile."""
-    from lighthouse_tpu.crypto.bls import api as bls_api
-    from lighthouse_tpu.parallel.mesh import MESH_DISPATCH
-
-    mesh = parallel.get_mesh()
-    assert mesh is not None and int(mesh.devices.size) == 8
-
-    backend = bls_api.set_backend("jax")
-    try:
-        rng = random.Random(0xE2E)
-        batches = [
-            [_mk_set(rng, 1, bytes([b * 8 + i]) * 32) for i in range(8)]
-            for b in range(3)
-        ]
-        sharded0 = MESH_DISPATCH.labels("sharded").value
-        urgent0 = MESH_DISPATCH.labels("urgent").value
-
-        tickets = [
-            backend.verify_signature_sets_async(sets, [1] * 8)
-            for sets in batches
-        ]
-        assert backend.dispatcher.inflight() >= 1
-        # the urgent bypass: resolves without draining the batch window
-        urgent_set = _mk_set(rng, 1, b"\xfe" * 32)
-        assert backend.verify_signature_sets_urgent([urgent_set], [1]) is True
-        # FIFO: resolving the LAST ticket first drains earlier ones first
-        assert tickets[-1].result() is True
-        assert all(t.done for t in tickets)
-        assert all(t.result() is True for t in tickets)
-        assert backend.dispatcher.inflight() == 0
-
-        # a tampered sharded batch still rejects through the collectives
-        bad = [_mk_set(rng, 1, bytes([0x40 + i]) * 32) for i in range(7)]
-        bad.append(_mk_set(rng, 1, b"\x66" * 32, valid=False))
-        assert backend.verify_signature_sets(bad, [1] * 8) is False
-
-        # lane accounting: 4 sharded batches, 1 urgent bypass
-        assert MESH_DISPATCH.labels("sharded").value == sharded0 + 4
-        assert MESH_DISPATCH.labels("urgent").value == urgent0 + 1
-    finally:
-        bls_api.set_backend("python")
-
-
-@pytest.mark.slow
-def test_shard_map_pairing_fallback_real_collective():
-    """The REAL shard_map pair product: force the explicit-sharding jit to
-    fail and verify valid/tampered batches through the all_gather + Fq12
-    partial-product collective. Slow: the fallback pairing program is a
-    fresh XLA compile (~minutes cold on CPU)."""
-    from lighthouse_tpu.crypto.bls import api as bls_api
-    from lighthouse_tpu.crypto.jaxbls import backend as be
-
-    mesh = parallel.get_mesh()
-    backend = bls_api.set_backend("jax")
-    try:
-        stages = be._get_stages(mesh=mesh)
-        pd = stages[3]
-        assert isinstance(pd, be._PairingDispatch)
-        old = (pd._jit, pd._use_fallback, pd._fallback)
-
-        class _Boom:
-            def __call__(self, *a):
-                raise RuntimeError("forced propagation failure")
-
-        pd._jit, pd._use_fallback, pd._fallback = _Boom(), False, None
-        try:
-            rng = random.Random(0x5AFE)
-            sets = [_mk_set(rng, 1, bytes([i]) * 32) for i in range(8)]
-            assert backend.verify_signature_sets(sets, [1] * 8) is True
-            assert pd._use_fallback is True
-            bad = sets[:-1] + [_mk_set(rng, 1, b"\x99" * 32, valid=False)]
-            assert backend.verify_signature_sets(bad, [1] * 8) is False
-        finally:
-            pd._jit, pd._use_fallback, pd._fallback = old
-    finally:
-        bls_api.set_backend("python")

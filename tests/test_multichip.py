@@ -6,6 +6,14 @@ signature tree-sum become XLA collectives. These tests prove the sharded
 program (a) compiles and runs over 8 devices, (b) agrees bit-for-bit with
 the unsharded single-device program, and (c) agrees with the pure-Python
 backend on valid AND invalid batches.
+
+This is one of the two modules that drive the real JaxBackend through its
+four staged programs (the other is test_jaxbls_backend.py). Each compiles
+its programs once, in one module-scoped warm-up (tests/jaxbls_warm.py): a
+new test of the staged backend joins one of the two instead of opening a
+file, and keeps to the builds and key-count buckets its module warms. The
+2-D (sets, pks) mesh shares no program with the 1-D one and has
+test_multichip_2d.py to itself.
 """
 
 import random
@@ -92,6 +100,22 @@ def jax_backend():
     return bls_api.set_backend("jax")
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _warm_stages_parallel():
+    """Cold-compile the module's two builds side by side before the tests
+    run (tests/jaxbls_warm.py): 8 sets of 2 keys (one key-count bucket,
+    m = 2) over the 8-device mesh, and the same unsharded — what the
+    sharded result is compared with. Every test after it is seconds."""
+    from jaxbls_warm import warm_builds
+
+    from lighthouse_tpu import parallel
+
+    parallel.reset_mesh_cache()
+    live = parallel.get_mesh()
+    assert live is not None and int(live.devices.size) == N_DEV
+    warm_builds((8, (2,), live), (8, (2,), None))
+
+
 def _run_staged(args, mesh=None):
     """The production staged pipeline; with a mesh, every input is sharded
     along the sets axis (collectives cross shards in the reductions)."""
@@ -169,45 +193,6 @@ def test_backend_dispatch_uses_mesh(jax_backend):
     assert h.result() is True
 
 
-def test_backend_2d_mesh_wide_aggregation(jax_backend, monkeypatch):
-    """2-D (sets, pks) mesh: WITHIN-SET parallelism — the pubkey axis of a
-    wide aggregation (the 512-pk sync-committee shape, scaled down) is
-    sharded too, so the per-set point tree spreads across chips and its
-    reduction lowers to collectives over the pks axis (SURVEY §5's
-    bucket-parallel-within-a-set requirement). This lane owns the 2-D
-    coverage: the driver's dryrun_multichip gate runs the 1-D production
-    path only (the 2-D re-trace doubled cold-compile wall and timed out
-    the r4 gate)."""
-    from lighthouse_tpu import parallel
-
-    monkeypatch.setenv("LIGHTHOUSE_TPU_PK_SHARDS", "2")
-    parallel.reset_mesh_cache()
-    try:
-        mesh2 = parallel.get_mesh()
-        assert mesh2 is not None and parallel.mesh.PK_AXIS in mesh2.axis_names
-        assert dict(mesh2.shape) == {"sets": N_DEV // 2, "pks": 2}
-
-        rng = random.Random(0x2D)
-        big_sks = [rng.randrange(1, R) for _ in range(8)]
-        big_pks = [bls.PublicKey(cv.g1_mul(cv.G1_GEN, sk)) for sk in big_sks]
-        msg = b"\x2d" * 32
-        h = bls_api.hash_to_g2_point(msg)
-        big_sig = bls.Signature(cv.g2_mul(h, sum(big_sks) % R))
-        small_sets, rands = _build_sets(3, 2, seed=0x57)
-        big_sets = [bls.SignatureSet(big_sig, big_pks, msg)] + small_sets
-        big_rands = [1] + rands
-        assert jax_backend.verify_signature_sets(big_sets, big_rands) is True
-        # a tampered wide set must reject through the same 2-D path
-        wrong = bls.Signature(cv.g2_mul(h, (sum(big_sks) + 1) % R))
-        bad_sets = [bls.SignatureSet(wrong, big_pks, msg)] + small_sets
-        assert jax_backend.verify_signature_sets(bad_sets, big_rands) is False
-        py = bls_api._BACKENDS["python"]
-        assert py.verify_signature_sets(big_sets, big_rands) is True
-        assert py.verify_signature_sets(bad_sets, big_rands) is False
-    finally:
-        parallel.reset_mesh_cache()
-
-
 def test_backend_mesh_agrees_with_single_device(jax_backend, monkeypatch):
     from lighthouse_tpu import parallel
 
@@ -222,3 +207,13 @@ def test_backend_mesh_agrees_with_single_device(jax_backend, monkeypatch):
     meshed = jax_backend.verify_signature_sets(sets, rands)
     parallel.reset_mesh_cache()
     assert single == meshed == True  # noqa: E712
+
+
+def test_module_stays_under_the_mapping_mark():
+    """Last on purpose: with every build of this module compiled and kept,
+    the process must be under conftest's mark — past it conftest drops the
+    executables between tests and each later test recompiles for minutes.
+    A module that outgrows the mark is split, not left to thrash."""
+    from conftest import _MAP_COUNT_HIGH_MARK, _n_memory_mappings
+
+    assert _n_memory_mappings() < _MAP_COUNT_HIGH_MARK

@@ -181,10 +181,12 @@ def test_counter_samples_export_as_counter_events(tmp_path):
 def test_processor_samples_queue_depth_counters():
     """Every batch formation samples the per-WorkKind queue-depth gauges
     into the tracer's counter ring."""
-    before = len(TRACER.snapshot_counters())
+    before = TRACER.snapshot_counters()
     _drain_probe()
     samples = TRACER.snapshot_counters()
-    assert len(samples) > before
+    # the ring is bounded, and full in a worker that ran many files before
+    # this one: a new sample shows as a newer last entry, not as more entries
+    assert samples and (not before or samples[-1][0] > before[-1][0])
     t, name, values = samples[-1]
     assert name == "queue_depth"
     assert "gossip_attestation" in values
