@@ -18,6 +18,12 @@ calls). Small trees are pinned single-chip (`LIGHTHOUSE_TPU_HASH_MESH_MIN`
 leaves, default 8192): below that, mesh padding and resharding would cost
 more than the hash work.
 
+Leaves upload in the HOST'S NATIVE byte order: a full-bucket C-contiguous
+uint8 plane goes up as a zero-copy `view(np.uint32)` of the caller's own
+memory (no packing, no temporary), anything else through one zero-filled
+buffer, and the ladder's first operation swaps the words to SHA-256's
+big-endian order on the device (`be_words`; skipped on a big-endian host).
+
 The compression schedule itself is ssz/sha256_batch.compress — the ONE
 definition shared with the numpy host lane, traced here over jnp uint32
 lanes. Bit-exactness vs hashlib is pinned for both lanes in
@@ -26,6 +32,8 @@ in tests/test_jaxhash.py.
 """
 
 from __future__ import annotations
+
+import sys
 
 import numpy as np
 
@@ -41,6 +49,7 @@ from ..ssz.sha256_batch import (
     sha256_pairs,
     words_from_bytes,
 )
+from ..observability.device import annotation_scope
 from ..utils.metrics import REGISTRY
 
 # ------------------------------------------------------------------ metrics
@@ -68,6 +77,14 @@ _MARSHALLED = REGISTRY.counter_vec(
     "bytes packed for device upload by the tree-hash engine, by array "
     "family",
     ("array",),
+)
+_LEAF_MARSHAL = REGISTRY.counter_vec(
+    "jaxhash_leaf_marshal_total",
+    "tree-hash leaf uploads by host path: `view` (a full-bucket contiguous "
+    "uint8 plane uploaded as a zero-copy uint32 view of the caller's "
+    "memory) or `copy` (ragged, strided or non-uint8 leaves copied once "
+    "into a zero-filled bucket buffer)",
+    ("path",),
 )
 
 #: smallest compile bucket (leaf axis) — below the router threshold the
@@ -156,9 +173,41 @@ def compress_rolled(state, w16, k):
     return jnp.stack(v) + state
 
 
+def be_words(native):
+    """uint32 words read from bytes in the host's native order -> the
+    big-endian words SHA-256 is defined over (== words_from_bytes of the
+    same bytes). Operators only, so numpy and traced jnp lanes both take
+    it; the host's byte order is a fact of the process, decided here."""
+    if sys.byteorder == "big":
+        return native
+    return (
+        ((native & 0xFF) << 24)
+        | ((native & 0xFF00) << 8)
+        | ((native >> 8) & 0xFF00)
+        | (native >> 24)
+    )
+
+
+def _native_words(leaves: np.ndarray, nb: int):
+    """((nb, 8) uint32 words of `leaves` in native byte order, path): the
+    caller's own memory as a `view` when it already is the whole bucket,
+    else one zero-filled buffer that receives the leaves by a single
+    slice assignment (`copy`). A view needs no writable flag."""
+    if (
+        leaves.shape == (nb, 32)
+        and leaves.dtype == np.uint8
+        and leaves.flags.c_contiguous
+    ):
+        return leaves.view(np.uint32), "view"
+    words = np.zeros((nb, 8), np.uint32)
+    words.view(np.uint8)[: leaves.shape[0]] = leaves
+    return words, "copy"
+
+
 def _make_ladder(n_bucket: int, stop: int, donate: bool, mesh):
-    """Jitted level ladder for one bucket: (n_bucket, 8) uint32 digest
-    words -> tuple of level word arrays (n/2, 8) ... (stop, 8). Levels
+    """Jitted level ladder for one bucket: (n_bucket, 8) uint32 leaf words
+    IN NATIVE BYTE ORDER (swapped to big-endian here, fused into level
+    0) -> tuple of level word arrays (n/2, 8) ... (stop, 8). Levels
     are unrolled in the trace (their shapes halve — static per level),
     the compression inside each is rolled; the whole ladder is one
     program per bucket and intermediates never leave the device."""
@@ -185,7 +234,7 @@ def _make_ladder(n_bucket: int, stop: int, donate: bool, mesh):
 
     def ladder(words):
         out = []
-        cur = words
+        cur = be_words(words)
         for _ in range(n_levels):
             cur = hash_pairs(cur)
             out.append(cur)
@@ -270,6 +319,13 @@ def device_build_levels(leaves: np.ndarray, depth: int,
     The device computes the padded pow2 ladder (zero-chunk padding IS the
     SSZ zero-hash folding, so trimmed prefixes match the host builder
     exactly); the mesh-stop tail and the virtual depth finish on host.
+
+    Leaves upload in native byte order and the ladder swaps them on the
+    device: a C-contiguous uint8 plane that fills its bucket goes up as a
+    uint32 view of the caller's memory (read-only is fine; nothing is
+    written through it), anything else is copied once into a zero-filled
+    bucket buffer. The call returns after the root is on the host, so the
+    caller may reuse its leaves as soon as it has the result.
     Raises on device failure — the router owns the fallback."""
     import time
 
@@ -288,19 +344,23 @@ def device_build_levels(leaves: np.ndarray, depth: int,
         )
     t0 = time.perf_counter()
     ladder, stop = _get_ladder(nb, mesh)
-    words = np.zeros((nb, 8), np.uint32)
-    words[:n_real] = words_from_bytes(np.ascontiguousarray(leaves))
+    with annotation_scope("jaxhash:marshal"):
+        words, path = _native_words(leaves, nb)
+    _LEAF_MARSHAL.labels(path).inc()
     _MARSHALLED.labels("leaves").inc(words.nbytes)
     JAXHASH_DISPATCH.labels(
         "sharded" if mesh is not None else "single_device"
     ).inc()
     put = put_single if mesh is None else (lambda a: put_sets(a, mesh=mesh))
-    placed = put(words)
-
-    dev_levels = _get_dispatcher().submit(
-        lambda: _LevelsHandle(ladder(placed), last_only=root_only,
-                              first=min_level)
-    ).result()
+    with annotation_scope("jaxhash:upload"):
+        placed = put(words)
+    with annotation_scope("jaxhash:ladder"):
+        ticket = _get_dispatcher().submit(
+            lambda: _LevelsHandle(ladder(placed), last_only=root_only,
+                                  first=min_level)
+        )
+    with annotation_scope("jaxhash:readback"):
+        dev_levels = ticket.result()
 
     import hashlib
 

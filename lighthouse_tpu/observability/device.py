@@ -42,6 +42,7 @@ report` and the metrics lint run with no device attached.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 from time import perf_counter
@@ -153,21 +154,22 @@ def _annotation():
     return _trace_annotation
 
 
+def annotation_scope(name: str):
+    """`with annotation_scope("jaxhash:upload"):` — a named host scope in
+    the profiler's own trace (nanoseconds when no profiler session is
+    active); a no-op context where TraceAnnotation is unavailable."""
+    ta = _annotation()
+    return contextlib.nullcontext() if ta is False else ta(name)
+
+
 def run_stage(attr: DispatchAttribution | None, stage: str, fn, *args):
     """Dispatch one jit stage under a named annotation scope; with an
     attribution handle, also event-time the resolve and record it."""
-    ta = _annotation()
-    if attr is None:
-        if ta is False:
-            return fn(*args)
-        with ta(f"jaxbls:{stage}"):
-            return fn(*args)
     t0 = perf_counter()
-    if ta is False:
+    with annotation_scope(f"jaxbls:{stage}"):
         out = fn(*args)
-    else:
-        with ta(f"jaxbls:{stage}"):
-            out = fn(*args)
+    if attr is None:
+        return out
     try:
         import jax
 

@@ -61,11 +61,130 @@ def test_device_levels_match_host_builder(n, depth):
         assert np.array_equal(a, b)
 
 
-def test_device_levels_mesh_sharded(monkeypatch):
+def _aligned_leaves(n, seed):
+    """A plane whose first byte sits on a 64-byte boundary: the CPU
+    backend's device_put aliases such memory instead of copying it."""
+    raw = np.zeros(n * 32 + 64, np.uint8)
+    off = (-raw.ctypes.data) % 64
+    plane = raw[off:off + n * 32].reshape(n, 32)
+    plane[:] = _rand_leaves(n, seed)
+    return plane
+
+
+def _readonly_leaves(n, seed):
+    return np.frombuffer(_rand_leaves(n, seed).tobytes(), np.uint8).reshape(n, 32)
+
+
+#: kind -> (maker of the caller's array, the host path it must take); all
+#: land in the 2**10 bucket so two ladders (donation off/on) serve every case
+_LEAF_KINDS = {
+    "full": (lambda: _rand_leaves(1024, 1), "view"),
+    "aligned": (lambda: _aligned_leaves(1024, 2), "view"),
+    "readonly": (lambda: _readonly_leaves(1024, 3), "view"),
+    "ragged": (lambda: _rand_leaves(700, 4), "copy"),
+    "strided": (lambda: _rand_leaves(2048, 5)[::2], "copy"),
+    "int64": (lambda: _rand_leaves(1024, 6).astype(np.int64), "copy"),
+}
+
+
+@pytest.mark.parametrize("donate", ["0", "1"], ids=["keep", "donate"])
+@pytest.mark.parametrize("root_only", [False, True], ids=["levels", "root"])
+@pytest.mark.parametrize("kind", list(_LEAF_KINDS))
+def test_device_build_levels_leaf_paths(monkeypatch, kind, root_only, donate):
+    """Every kind of caller array gives the host builder's levels and
+    root, takes the expected host path exactly once, counts the bucket's
+    bytes, and is byte-identical afterwards — with the ladder's input
+    donated or not (a viewed plane is the CALLER'S memory)."""
+    monkeypatch.setenv("LIGHTHOUSE_TPU_DONATE", donate)
+    make, want_path = _LEAF_KINDS[kind]
+    leaves = make()
+    keep = leaves.tobytes()
+    assert leaves.flags.writeable == (kind != "readonly")
+    depth = 12
+    lv_h, root_h = tc._build(np.ascontiguousarray(leaves, np.uint8), depth)
+
+    def counted():
+        return np.array(
+            [engine._LEAF_MARSHAL.labels(p).value for p in ("view", "copy")]
+            + [engine._MARSHALLED.labels("leaves").value]
+        )
+
+    before = counted()
+    lv_d, root_d = engine.device_build_levels(leaves, depth,
+                                              root_only=root_only)
+    assert list(counted() - before) == [
+        want_path == "view", want_path == "copy", 1024 * 32
+    ]
+
+    assert root_d == root_h
+    if root_only:
+        assert lv_d is None
+    else:
+        assert len(lv_d) == len(lv_h) == depth
+        for a, b in zip(lv_d, lv_h):
+            assert np.array_equal(a, b)
+    assert leaves.tobytes() == keep
+
+
+def test_be_words_on_device_equals_words_from_bytes():
+    """The swap the ladder starts with, traced over jnp, applied to the
+    native uint32 view of random bytes == the host's big-endian packing."""
+    import jax
+
+    from lighthouse_tpu.ssz.sha256_batch import words_from_bytes
+
+    data = np.random.default_rng(26).integers(0, 256, (4096, 64), np.uint8)
+    got = np.asarray(jax.jit(engine.be_words)(data.view(np.uint32)))
+    assert got.dtype == np.uint32
+    assert np.array_equal(got, words_from_bytes(data))
+    assert np.array_equal(engine.be_words(data.view(np.uint32)), got)
+
+
+@pytest.mark.parametrize(
+    "n,path,limit", [(1 << 16, "view", 0.25), ((1 << 16) - 3, "copy", 1.25)]
+)
+def test_leaf_marshal_allocates_no_temporaries(monkeypatch, n, path, limit):
+    """Guard against the packing temporaries coming back unseen: the host
+    memory allocated inside device_build_levels up to the device_put
+    stays under a quarter of the leaves' bytes on the view path (the old
+    packing peaked at eight times them) and at the one bucket buffer on
+    the copy path."""
+    import tracemalloc
+
+    import lighthouse_tpu.parallel as par
+
+    monkeypatch.setenv("LIGHTHOUSE_TPU_HASH_MESH_MIN", str(1 << 20))
+    leaves = _rand_leaves(n, seed=n)
+    engine.device_build_levels(leaves, 16, root_only=True)  # compile
+    peaks = []
+    real_put = par.put_single
+
+    def put_and_note(a):
+        peaks.append(tracemalloc.get_traced_memory()[1])
+        return real_put(a)
+
+    monkeypatch.setattr(par, "put_single", put_and_note)
+    before = engine._LEAF_MARSHAL.labels(path).value
+    tracemalloc.start()
+    try:
+        base = tracemalloc.get_traced_memory()[0]
+        tracemalloc.reset_peak()
+        _, root = engine.device_build_levels(leaves, 16, root_only=True)
+    finally:
+        tracemalloc.stop()
+    assert engine._LEAF_MARSHAL.labels(path).value - before == 1
+    assert root == tc._build(leaves, 16)[1]
+    assert len(peaks) == 1
+    assert peaks[0] - base < limit * (1 << 16) * 32
+
+
+@pytest.mark.parametrize("n", [200, 256], ids=["ragged", "full_view"])
+def test_device_levels_mesh_sharded(monkeypatch, n):
     """With the single-chip pin threshold lowered, the ladder shards the
     leaf axis over the virtual 8-device mesh (each chip reduces its local
     subtree; host finishes the top) — output still bit-identical, and the
-    dispatch is counted on the `sharded` lane."""
+    dispatch is counted on the `sharded` lane. A full bucket goes up as a
+    view of the caller's plane, split over the chips as it lies."""
     from lighthouse_tpu.parallel import get_mesh, reset_mesh_cache
 
     monkeypatch.delenv("LIGHTHOUSE_TPU_MESH", raising=False)
@@ -78,10 +197,12 @@ def test_device_levels_mesh_sharded(monkeypatch):
         before = {
             k: c.value for k, c in engine.JAXHASH_DISPATCH.children()
         }
-        leaves = _rand_leaves(200, seed=8)
+        leaves = _rand_leaves(n, seed=8)
+        keep = leaves.copy()
         lv_d, root_d = engine.device_build_levels(leaves, 12)
         lv_h, root_h = tc._build(leaves, 12)
         assert root_d == root_h
+        assert np.array_equal(leaves, keep)
         for a, b in zip(lv_d, lv_h):
             assert np.array_equal(a, b)
         sharded = {
