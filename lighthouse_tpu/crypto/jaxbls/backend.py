@@ -84,6 +84,13 @@ _PK_CACHE = REGISTRY.counter_vec(
     "device-resident pubkey marshalling cache outcomes",
     ("result",),
 )
+_BUCKET_SLOTS = REGISTRY.counter_vec(
+    "jaxbls_bucket_slots_total",
+    "slots of the padding bucket per dispatch, by axis (sets: n; keys: "
+    "n*m) and kind: real = what the caller sent, padded = what the bucket "
+    "holds; real over padded is the bucket's fill",
+    ("axis", "kind"),
+)
 _seen_exec_buckets: set = set()  # buckets that have resolved at least once
 
 MIN_SETS = 4          # smallest bucket (pairs axis = sets + 1 rounded up)
@@ -799,6 +806,11 @@ class JaxBackend:
             "urgent" if urgent else ("sharded" if mesh is not None
                                      else "single_device")
         ).inc()
+        real_keys = sum(len(s.signing_keys) for s in sets)
+        _BUCKET_SLOTS.labels("sets", "real").inc(n_real)
+        _BUCKET_SLOTS.labels("sets", "padded").inc(n)
+        _BUCKET_SLOTS.labels("keys", "real").inc(real_keys)
+        _BUCKET_SLOTS.labels("keys", "padded").inc(n * m)
 
         pk_x, pk_y, pk_mask = self._marshal_pubkeys(
             sets, n, m, single_chip=single_chip
@@ -844,7 +856,8 @@ class JaxBackend:
         _MARSHAL_SECONDS.observe(t_marshalled - t_marshal)
         tr = _obs.current_trace()
         if tr is not None:
-            tr.annotate(bucket=f"{n}x{m}", real_sets=n_real)
+            tr.annotate(bucket=f"{n}x{m}", real_sets=n_real,
+                        real_keys=real_keys)
 
         def dispatch():
             # each stage dispatch runs under a named annotation scope;

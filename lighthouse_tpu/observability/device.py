@@ -154,12 +154,13 @@ def _annotation():
     return _trace_annotation
 
 
-def annotation_scope(name: str):
+def annotation_scope(name: str, **args):
     """`with annotation_scope("jaxhash:upload"):` — a named host scope in
     the profiler's own trace (nanoseconds when no profiler session is
-    active); a no-op context where TraceAnnotation is unavailable."""
+    active), `args` shown with it; a no-op context where TraceAnnotation
+    is unavailable."""
     ta = _annotation()
-    return contextlib.nullcontext() if ta is False else ta(name)
+    return contextlib.nullcontext() if ta is False else ta(name, **args)
 
 
 def run_stage(attr: DispatchAttribution | None, stage: str, fn, *args):

@@ -209,6 +209,58 @@ def test_e2e_sharded_dispatch_through_pipelined_dispatcher():
         bls_api.set_backend("python")
 
 
+# ------------------------------------------------------ a block-shaped batch
+# A block's sets as one ragged batch: two one-key sets (proposal, RANDAO),
+# four two-key "attestations", one four-key "sync aggregate" — 7 sets, 14
+# keys, in the (8, 4) bucket this module warms (8 set slots, 32 key slots).
+
+_BLOCK_WIDTHS = (1, 1, 2, 2, 2, 2, 4)
+
+
+@pytest.mark.parametrize(
+    "damaged", [None, 0, 3, 6], ids=["valid", "one_key", "two_key", "widest"]
+)
+def test_block_shaped_batch_through_signature_batch_parity(damaged):
+    """`SignatureBatch.verify()` on the jax backend against the pure-Python
+    backend on the same operands: a valid block is True on both, one bad
+    set in any width class makes it False on both, and the bucket-fill
+    counters move by exactly what was sent over what the bucket holds."""
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+    from lighthouse_tpu.state_transition.block import SignatureBatch
+
+    rng = random.Random(0xB10C)
+    sets = [
+        _mk_set_from(rng, w, bytes([0xA0 + i]) * 32, valid=(i != damaged))
+        for i, w in enumerate(_BLOCK_WIDTHS)
+    ]
+    batch = SignatureBatch()
+    batch.add(sets[0])
+    batch.add(sets[1])
+    batch.add(sets[2:6])          # a list, as process_operations hands them
+    batch.add(None)               # an absent set is skipped
+    batch.add(sets[6])
+    assert batch.sets == sets
+
+    slots = {
+        (axis, kind): be._BUCKET_SLOTS.labels(axis, kind)
+        for axis in ("sets", "keys") for kind in ("real", "padded")
+    }
+    before = {k: c.value for k, c in slots.items()}
+    bls_api.set_backend("jax")
+    on_jax = batch.verify()
+    moved = {k: c.value - before[k] for k, c in slots.items()}
+    bls_api.set_backend("python")
+    on_python = batch.verify()
+
+    assert on_python is (damaged is None)
+    assert on_jax is on_python
+    assert be.padding_bucket(7, 4) == (8, 4)
+    assert moved == {("sets", "real"): 7, ("sets", "padded"): 8,
+                     ("keys", "real"): 14, ("keys", "padded"): 32}
+    # the pure-Python verify went nowhere near the device counters
+    assert {k: c.value - before[k] for k, c in slots.items()} == moved
+
+
 @pytest.mark.slow
 def test_shard_map_pairing_fallback_real_collective():
     """The REAL shard_map pair product: force the explicit-sharding jit to

@@ -250,6 +250,50 @@ def _drain_probe():
     return pipeline.run_probe(n_items=8)
 
 
+def test_signature_batch_records_span_and_block_families():
+    """SignatureBatch.verify() on the pure-Python backend: one histogram
+    observation of real seconds, the set counter by the batch's size, and
+    a `block:signature_batch` span on the carried trace with the set count
+    and the widest key count. An empty batch records nothing."""
+    from lighthouse_tpu.crypto import bls
+    from lighthouse_tpu.observability import trace as obstrace
+    from lighthouse_tpu.state_transition import block as blk
+    from lighthouse_tpu.utils.metrics import REGISTRY
+
+    bls.set_backend("python")
+    msgs = [bytes([0xC0 + i]) * 32 for i in range(2)]
+    pairs = [bls.interop_keypair(i) for i in range(3)]
+    one = bls.SignatureSet(bls.sign(pairs[0].sk, msgs[0]), [pairs[0].pk],
+                           msgs[0])
+    two = bls.SignatureSet(
+        bls.AggregateSignature.aggregate(
+            [bls.sign(kp.sk, msgs[1]) for kp in pairs[1:]]),
+        [kp.pk for kp in pairs[1:]], msgs[1])
+    batch = blk.SignatureBatch()
+    batch.add([one, two])
+
+    n0, total0 = blk._BATCH_SECONDS.n, blk._BATCH_SECONDS.total
+    sets0 = blk._BATCH_SETS.value
+    tr = Trace("gossip_block", 1)
+    obstrace.set_current_trace(tr)
+    try:
+        assert blk.SignatureBatch().verify() is True     # empty: no record
+        assert batch.verify() is True
+    finally:
+        obstrace.set_current_trace(None)
+
+    assert blk._BATCH_SECONDS.n == n0 + 1
+    assert blk._BATCH_SETS.value == sets0 + 2
+    (span,) = [sp for sp in tr.spans if sp[0] == blk.BATCH_SPAN]
+    name, t0, t1, args = span
+    assert name == "block:signature_batch"
+    assert args == {"sets": 2, "widest_keys": 2}
+    assert 0 < t1 - t0 == blk._BATCH_SECONDS.total - total0
+    names = {m.name for m in REGISTRY.all_metrics()}
+    assert {"block_signature_batch_seconds",
+            "block_signature_batch_sets_total"} <= names
+
+
 def test_processor_traces_every_stage():
     """A batch through a real BeaconProcessor produces one trace holding
     every canonical pipeline stage, and feeds the labeled stage family."""
