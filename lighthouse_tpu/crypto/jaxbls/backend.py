@@ -91,6 +91,14 @@ _BUCKET_SLOTS = REGISTRY.counter_vec(
     "holds; real over padded is the bucket's fill",
     ("axis", "kind"),
 )
+_TREE_SUM_LANE_ADDS = REGISTRY.counter_vec(
+    "jaxbls_tree_sum_lane_additions_total",
+    "point additions of the key-axis sum in prepare, per dispatch: done = "
+    "what curve_ops.tree_sum_plan gives for the bucket's (m, n) key grid, "
+    "needed = real keys - real sets; done over needed is what padding and "
+    "the reduction's shape still cost",
+    ("kind",),
+)
 _seen_exec_buckets: set = set()  # buckets that have resolved at least once
 
 MIN_SETS = 4          # smallest bucket (pairs axis = sets + 1 rounded up)
@@ -226,9 +234,11 @@ def _stage_prepare(pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits, set_mask):
     sig_x = _to_mont_dev(sig_x)
     sig_y = _to_mont_dev(sig_y)
 
-    # aggregate pubkeys per set: (n, m) -> (n,) — fixed-shape tree_sum
-    # compiles ONE add instance for all log2(m) rounds (m=128 in the
-    # firehose bucket; the unrolled form was the compile whale here)
+    # aggregate pubkeys per set: (n, m) -> (n,). tree_sum folds the key
+    # axis down to about co.TREE_SUM_L0 lanes and halves the rest: two add
+    # instances whatever m is (the unrolled tree was the compile whale
+    # here), about m*n lane-additions instead of m*n*log2(m) — which on the
+    # v5e was 1.46 s of a 2.56 s block at 256x512 (PERF.md S6, PR 27-28)
     pk_jac = co.affine_to_jac(co.FQ_OPS, (pk_x, pk_y), inf_mask=jnp.logical_not(pk_mask))
     pk_jac_t = tuple(jnp.moveaxis(c, 1, 0) for c in pk_jac)
     aggpk = co.tree_sum(pk_jac_t, co.FQ_OPS)               # (n,) jacobian G1
@@ -811,6 +821,8 @@ class JaxBackend:
         _BUCKET_SLOTS.labels("sets", "padded").inc(n)
         _BUCKET_SLOTS.labels("keys", "real").inc(real_keys)
         _BUCKET_SLOTS.labels("keys", "padded").inc(n * m)
+        _TREE_SUM_LANE_ADDS.labels("done").inc(co.tree_sum_plan(m, n)[3])
+        _TREE_SUM_LANE_ADDS.labels("needed").inc(real_keys - n_real)
 
         pk_x, pk_y, pk_mask = self._marshal_pubkeys(
             sets, n, m, single_chip=single_chip

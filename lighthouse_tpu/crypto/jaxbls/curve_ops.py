@@ -384,23 +384,108 @@ def scalars_to_bits(zs, nbits: int) -> np.ndarray:
     return out
 
 
+# Lanes (entries kept x lanes behind axis 0) that tree_sum folds down to
+# before it starts halving. Measured on a v5e (PR 28, one G1 jac_add of L
+# flat lanes inside a fori_loop, scripts/measure_tree_sum_l0.py), us a lane:
+# 1.07 at 256, 0.99 at 512, 0.91 at 1,024, 0.855 at 2,048, 0.852 at 4,096,
+# 0.98 at 8,192, 1.16 at 16,384, 1.33 at 32,768, 1.59 at 131,072. The add
+# never becomes bound by its count of small operations in that range (0.27 ms
+# at 256 lanes); 2,048 is the smallest width at the lowest cost a lane. With
+# it the (512, 256) key grid sums in 112 ms (107-123 for L0 from 256 to
+# 4,096; 1,766 unfolded) and the (128, 64) grid in 14.7 ms (8.1 at 256, 26.1
+# at 4,096; 56.6 unfolded). One constant for every caller, no knob: a smaller
+# value would buy the gossip bucket ~6 ms of a 590 ms batch and route the
+# narrow urgent bucket (128 x 4) through the fold as well.
+TREE_SUM_L0 = 2048
+
+
+def tree_sum_plan(m: int, rest: int) -> tuple:
+    """What tree_sum does with m entries on axis 0 and `rest` lanes behind
+    each: (c, fold_steps, finish_rounds, lane_additions). Pure, no jit —
+    tree_sum follows it, the backend's lane-addition counter reads it.
+
+    c is the smallest power of two with c * rest >= TREE_SUM_L0, capped at
+    m: the fold adds m/c - 1 chunks of c entries into the first, the finish
+    halves the c survivors in log2(c) fixed-shape rounds. c == m is the
+    fixed-shape loop alone; m <= 4 is the unrolled halving (m - 1 adds)."""
+    assert m >= 1 and m & (m - 1) == 0, "tree_sum needs power-of-two length"
+    if m <= 4:
+        return m, 0, m.bit_length() - 1, (m - 1) * rest
+    c = 1
+    while c < m and c * rest < TREE_SUM_L0:
+        c *= 2
+    fold_steps = m // c - 1
+    finish_rounds = c.bit_length() - 1
+    return c, fold_steps, finish_rounds, (fold_steps + finish_rounds) * c * rest
+
+
+def _halving_rounds(p_jac, ops, view, axis, rounds):
+    """`rounds` fixed-shape halving rounds over axis `axis` of `view`, the
+    batch dims of p_jac seen as that shape: round r adds the entry
+    half-a-stride away (dynamic roll) and keeps the sum in the low entries
+    via select. One jac_add instance for all rounds; after log2(view[axis])
+    rounds entry 0 holds the sum."""
+    batch = np.shape(ops.is_zero(p_jac[2]))
+    # select conds index ALL batch dims (everything but the field-element
+    # dims): the entry index over the full batch, not just the one axis
+    entry = jax.lax.broadcasted_iota(jnp.int32, view, axis).reshape(batch)
+
+    def body(r, acc):
+        half = jnp.int32(view[axis]) >> (r + 1)
+        shifted = jax.tree_util.tree_map(
+            lambda x: jnp.roll(
+                x.reshape(view + x.shape[len(batch):]), -half, axis=axis
+            ).reshape(x.shape),
+            acc,
+        )
+        added = jac_add(acc, shifted, ops)
+        # entries >= half hold garbage sums; keep previous values there
+        # (only entries < the next round's stride are ever read again)
+        return pt_select(ops, entry < half, added, acc)
+
+    return jax.lax.fori_loop(0, rounds, body, p_jac)
+
+
 def tree_sum(p_jac, ops):
-    """Sum points along the FIRST batch axis by halving tree reduction.
+    """Sum points along the FIRST batch axis.
 
     Input axis length must be a power of two (pad with identity).
 
-    Two lowerings, bit-identical results:
-      * fori_loop (default): a FIXED-SHAPE body — round r adds the lane
-        half-a-stride away (dynamic roll) and keeps the sum in the low
-        lanes via select. One jac_add instance compiles for all log2(n)
-        rounds; the unrolled form instantiated log2(n) separate adds,
-        which dominated the prepare-stage XLA compile (the r4 multichip
-        gate timed out in exactly that compile). Runtime trades n-1 adds
-        for n*log2(n) lanes of batched adds — noise next to the 64-bit
-        scalar-mul scans.
-      * unrolled halving: kept for Pallas kernel bodies (Mosaic has no
-        dynamic roll) and for tiny n where the loop machinery outweighs
-        two adds."""
+    tree_sum_plan gives the sizes from the shape (m entries, `rest` lanes
+    behind each):
+      * c == m: the fixed-shape loop alone (_halving_rounds over axis 0),
+        m lanes of add in each of log2(m) rounds — what this function was
+        before it folded, and still is for every narrow caller.
+      * c < m, fold: axis 0 viewed as (m/c, c); a fori_loop adds chunk j
+        into chunk 0 for j = 1 .. m/c - 1 on ONE lane axis of rest * c
+        (lane i*c + k = lane i of entry k). Flat because the chip's vector
+        unit is 128 lanes wide and XLA puts one batch dim on them: a
+        (32, 64) batch ran the same add 3.7x slower than 2,048 flat lanes
+        (6.5 against 1.75 ms). Rest-major because that is the order the
+        caller's (rest, m) grid lies in, and because a sharded `rest` (the
+        mesh's set axis) stays contiguous in it: the fold and its finish
+        need no collective.
+      * finish: _halving_rounds over the c survivors of every lane group.
+    Two jac_add instances compile whatever m is (one when c == m). An
+    unrolled halving tree reaches a few lane-additions fewer but
+    instantiates log2(m) separate adds, which dominated the prepare-stage
+    XLA compile (the r4 multichip gate timed out in exactly that compile).
+
+    Why fold: the loop alone does m * log2(m) lane-additions where m - 1
+    are needed, and on the v5e that is not noise: a G1 jac_add costs in
+    proportion to its lanes from 256 lanes up (0.85-1.07 us a lane to
+    8,192, 1.6 us at 131,072), so the key-axis sum of the 256x512 block
+    bucket, 9 rounds x 131,072 lanes, was 1.46 s of a 2.56 s block and is
+    0.11 s folded (PERF.md S6, PR 28; scripts/measure_tree_sum_l0.py).
+    TREE_SUM_L0 = 2,048 is where the per-lane cost is lowest.
+
+    The association order differs from a halving tree's; jac_add is
+    complete (identity lanes, P + P, P - P by its selects), so the result
+    is another Jacobian representative of the same point.
+
+    Unrolled halving is kept for Pallas kernel bodies (Mosaic has no
+    dynamic roll) and for m <= 4, where the loop machinery outweighs two
+    adds."""
     n = jax.tree_util.tree_leaves(p_jac)[0].shape[0]
     assert n & (n - 1) == 0, "tree_sum needs power-of-two length"
     if lb._pallas_tracing() or n <= 4:
@@ -412,25 +497,33 @@ def tree_sum(p_jac, ops):
             n = half
         return jax.tree_util.tree_map(lambda x: x[0], p_jac)
 
-    rounds = n.bit_length() - 1
-    # select conds index ALL batch dims (everything but the field-element
-    # dims): shape the lane index over the full batch, not just axis 0
     batch = np.shape(ops.is_zero(p_jac[2]))
-    lane = jnp.arange(n).reshape((n,) + (1,) * (len(batch) - 1))
+    rest = int(np.prod(batch[1:]))
+    c, fold_steps, rounds, _ = tree_sum_plan(n, rest)
+    if not fold_steps:
+        acc = _halving_rounds(p_jac, ops, batch, 0, rounds)
+        return jax.tree_util.tree_map(lambda x: x[0], acc)
 
-    def body(r, acc):
-        half = jnp.int32(n) >> (r + 1)
-        shifted = jax.tree_util.tree_map(
-            lambda x: jnp.roll(x, -half, axis=0), acc
-        )
-        added = jac_add(acc, shifted, ops)
-        # lanes >= half hold garbage sums; keep previous values there (only
-        # lanes < the next round's stride are ever read again)
-        keep = jnp.broadcast_to(lane < half, batch)
-        return pt_select(ops, keep, added, acc)
+    def chunk(j):
+        def lanes(x):
+            field = x.shape[len(batch):]
+            ch = jax.lax.dynamic_index_in_dim(
+                x.reshape((n // c, c, rest) + field), j, 0, keepdims=False
+            )
+            return jnp.swapaxes(ch, 0, 1).reshape((rest * c,) + field)
 
-    acc = jax.lax.fori_loop(0, rounds, body, p_jac)
-    return jax.tree_util.tree_map(lambda x: x[0], acc)
+        return jax.tree_util.tree_map(lanes, p_jac)
+
+    acc = jax.lax.fori_loop(
+        1, n // c, lambda j, a: jac_add(a, chunk(j), ops), chunk(0)
+    )
+    if rounds:
+        acc = _halving_rounds(acc, ops, (rest, c), 1, rounds)
+    return jax.tree_util.tree_map(
+        lambda x, x0: x.reshape((rest, c) + x0.shape[len(batch):])[:, 0]
+        .reshape(x0.shape[1:]),
+        acc, p_jac,
+    )
 
 
 def masked_tree_sum(p_jac, mask, ops):

@@ -223,8 +223,9 @@ _BLOCK_WIDTHS = (1, 1, 2, 2, 2, 2, 4)
 def test_block_shaped_batch_through_signature_batch_parity(damaged):
     """`SignatureBatch.verify()` on the jax backend against the pure-Python
     backend on the same operands: a valid block is True on both, one bad
-    set in any width class makes it False on both, and the bucket-fill
-    counters move by exactly what was sent over what the bucket holds."""
+    set in any width class makes it False on both, and the bucket-fill and
+    lane-addition counters move by exactly what was sent over what the
+    bucket holds and what tree_sum_plan says the key-axis sum does."""
     import lighthouse_tpu.crypto.jaxbls.backend as be
     from lighthouse_tpu.state_transition.block import SignatureBatch
 
@@ -245,6 +246,11 @@ def test_block_shaped_batch_through_signature_batch_parity(damaged):
         (axis, kind): be._BUCKET_SLOTS.labels(axis, kind)
         for axis in ("sets", "keys") for kind in ("real", "padded")
     }
+    # the key-axis sum's lane-additions, done (the plan's) against needed
+    slots.update(
+        {("adds", kind): be._TREE_SUM_LANE_ADDS.labels(kind)
+         for kind in ("done", "needed")}
+    )
     before = {k: c.value for k, c in slots.items()}
     bls_api.set_backend("jax")
     on_jax = batch.verify()
@@ -255,8 +261,12 @@ def test_block_shaped_batch_through_signature_batch_parity(damaged):
     assert on_python is (damaged is None)
     assert on_jax is on_python
     assert be.padding_bucket(7, 4) == (8, 4)
+    # (8, 4): four keys a set sum unrolled, 3 adds on each of 8 set lanes;
+    # 14 real keys in 7 sets need 7
+    assert be.co.tree_sum_plan(4, 8) == (4, 0, 2, 24)
     assert moved == {("sets", "real"): 7, ("sets", "padded"): 8,
-                     ("keys", "real"): 14, ("keys", "padded"): 32}
+                     ("keys", "real"): 14, ("keys", "padded"): 32,
+                     ("adds", "done"): 24, ("adds", "needed"): 7}
     # the pure-Python verify went nowhere near the device counters
     assert {k: c.value - before[k] for k, c in slots.items()} == moved
 

@@ -7,8 +7,9 @@ import numpy as np
 import pytest
 
 from lighthouse_tpu.crypto.bls381 import curve as pc
-from lighthouse_tpu.crypto.bls381.constants import R
+from lighthouse_tpu.crypto.bls381.constants import P, R
 from lighthouse_tpu.crypto.jaxbls import curve_ops as co
+from lighthouse_tpu.crypto.jaxbls import tower as tw
 
 rng = random.Random(0xC1)
 
@@ -86,12 +87,109 @@ def test_tree_sum_masked():
     assert co.g1_from_device(s) == expected
 
 
+# (m entries, rest lanes) -> the (c, fold_steps, finish_rounds) it must plan
+_TREE_SUM_SHAPES = {
+    "fold_and_finish": ((16, co.TREE_SUM_L0 // 4), (4, 3, 2)),
+    "fold_alone": ((8, co.TREE_SUM_L0), (1, 7, 0)),
+    "loop_alone": ((8, 2), (8, 0, 3)),       # c = m: the fixed-shape loop
+    "unrolled": ((4, 2), (4, 0, 2)),         # m <= 4
+}
+
+
+def _same_point(jac, affine):
+    """Host: Jacobian (X, Y, Z) bigints against affine (x, y) or None —
+    X = x Z^2, Y = y Z^3, identity = Z 0. No inversion on 2,048 lanes."""
+    X, Y, Z = jac
+    if affine is None or Z == 0:
+        return affine is None and Z == 0
+    return X == affine[0] * Z * Z % P and Y == affine[1] * Z * Z * Z % P
+
+
+@pytest.mark.parametrize("branch", list(_TREE_SUM_SHAPES))
+def test_tree_sum_matches_host_sum(branch):
+    """tree_sum over axis 0 of an (m, rest) grid against the bigint sum of
+    every column, with identity lanes, P + P and P - P meeting inside the
+    fold (neighbouring entries) and inside the finish (entries half the
+    axis apart), and an all-identity column."""
+    (m, rest), plan = _TREE_SUM_SHAPES[branch]
+    assert co.tree_sum_plan(m, rest)[:3] == plan
+
+    r = random.Random(0x5EED + m * rest)
+    pool = [None, None] + [pc.g1_mul(pc.G1_GEN, r.randrange(1, R)) for _ in range(5)]
+    grid = [[r.choice(pool) for _ in range(rest)] for _ in range(m)]
+    p, q = pool[2], pool[3]
+    col0 = [p, p] + [None] * (m - 2)                      # P + P, first add
+    col1 = [q, pc.g1_neg(q), p] + [None] * (m - 3)        # P - P, then 0 + P
+    for j in range(m):
+        grid[j][0], grid[j][1] = col0[j], col1[j]
+    if rest >= 8:
+        for j in range(m):
+            grid[j][2] = grid[j][3] = grid[j][4] = None   # [4] stays identity
+        grid[0][2], grid[m // 2][2] = p, p                # P + P half apart
+        grid[0][3], grid[m // 2][3] = q, pc.g1_neg(q)     # P - P half apart
+
+    flat = co.g1_batch_to_device([pt for row in grid for pt in row])
+    dev = jax.tree_util.tree_map(
+        lambda x: x.reshape((m, rest) + x.shape[1:]), flat
+    )
+    got = zip(*(
+        tw.fq_batch_from_device(v)
+        for v in jax.jit(lambda pts: co.tree_sum(pts, co.FQ_OPS))(dev)
+    ))
+    want = [None] * rest
+    for row in grid:
+        want = [pc.g1_add(w, pt) for w, pt in zip(want, row)]
+    wrong = [k for k, (g, w) in enumerate(zip(got, want)) if not _same_point(g, w)]
+    assert not wrong, (branch, wrong[:8])
+    assert want[0] == pc.g1_add(p, p) and want[1] == p
+    if rest >= 8:
+        assert want[2:5] == [pc.g1_add(p, p), None, None]
+
+
+@pytest.mark.parametrize(
+    "m, rest, plan",
+    [
+        (512, 256, (8, 63, 3, 135_168)),     # block pubkeys, bucket 256x512
+        (128, 64, (32, 3, 5, 16_384)),       # gossip pubkeys, bucket 64x128
+        (128, 4, (128, 0, 7, 3_584)),        # urgent pubkeys: c = m
+        (256, 1, (256, 0, 8, 2_048)),        # sig_acc over 256 sets: c = m
+        (64, 1, (64, 0, 6, 384)),
+        (8, 4_096, (1, 7, 0, 28_672)),       # rest >= L0: the fold alone
+        (4, 8, (4, 0, 2, 24)),               # unrolled: m - 1 adds a lane
+        (1, 8, (1, 0, 0, 0)),
+    ],
+)
+def test_tree_sum_plan_table(m, rest, plan):
+    """No jit: (c, fold_steps, finish_rounds, lane_additions) by shape, at
+    the measured TREE_SUM_L0; c * rest reaches L0 or c is capped at m."""
+    assert co.TREE_SUM_L0 == 2048
+    assert co.tree_sum_plan(m, rest) == plan
+    c = plan[0]
+    assert c == m or (c * rest >= co.TREE_SUM_L0 > (c // 2) * rest)
+
+
+@pytest.mark.parametrize("m", [8, 128, 512, 4096])
+def test_tree_sum_add_instances_do_not_grow_with_m(m, monkeypatch):
+    """Tracing only: tree_sum calls jac_add at most twice whatever m is
+    (fold + finish), once where the plan is the loop alone — the unrolled
+    tree's log2(m) instances were the prepare-stage compile whale."""
+    calls = []
+    real = co.jac_add
+    monkeypatch.setattr(
+        co, "jac_add", lambda a, b, ops: calls.append(1) or real(a, b, ops)
+    )
+    for rest, shape in ((256, (m, 256, 24)), (1, (m, 24))):
+        _, fold_steps, rounds, _ = co.tree_sum_plan(m, rest)
+        fq = jax.ShapeDtypeStruct(shape, np.uint32)
+        calls.clear()
+        jax.eval_shape(lambda pts: co.tree_sum(pts, co.FQ_OPS), (fq, fq, fq))
+        assert len(calls) == (fold_steps > 0) + (rounds > 0) <= 2
+
+
 def test_batch_affine_roundtrip():
     pts = [rand_g1() for _ in range(3)] + [None]
     dp = co.g1_batch_to_device(pts)
     x, y, inf = jax.jit(lambda p: co.jac_to_affine(p, co.FQ_OPS))(dp)
-    from lighthouse_tpu.crypto.jaxbls import tower as tw
-
     xs = tw.fq_batch_from_device(x)
     ys = tw.fq_batch_from_device(y)
     infs = np.asarray(inf)
