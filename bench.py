@@ -473,12 +473,6 @@ def main():
         _DEVICE_KEY.update(current_device_key())
     except Exception as e:
         log(f"autotune device key capture failed: {e}")
-    # every stage takes the path LIGHTHOUSE_TPU_PALLAS names (auto = the
-    # XLA staged programs; pallas_ops.mode()) — record it with the numbers
-    from lighthouse_tpu.crypto.jaxbls import pallas_ops as _plo
-
-    _MATRIX["pallas"] = _plo.mode() or "off"
-
     from lighthouse_tpu.crypto.bls import api as bls_api
 
     # capture compiled-program cost/memory analytics for every bucket the

@@ -186,7 +186,6 @@ def phase_bls(devices, chips: int, log: CompileLog) -> None:
     )
     from lighthouse_tpu.crypto import bls
     from lighthouse_tpu.crypto.jaxbls import backend as jb
-    from lighthouse_tpu.crypto.jaxbls import pallas_ops
     from lighthouse_tpu.observability import device as obs_device
     from lighthouse_tpu.parallel import get_mesh
 
@@ -277,14 +276,13 @@ def phase_bls(devices, chips: int, log: CompileLog) -> None:
         "/".join(map(str, k)): {"n": c.n, "mean_secs": round(c.total / c.n, 4)}
         for k, c in later.children() if c.n
     }
-    path = "pallas" if pallas_ops.mode() else "xla"
     used = set().union(*placements)
     errors = family_values("beacon_processor_errors_total")
     hybrid = family_values("bls_hybrid_route_total")
     peak = (devices[0].memory_stats() or {}).get("peak_bytes_in_use")
     emit(phase="bls", step="resolved", verdicts=verdicts, widths=widths,
          inflight_at_submit=inflight_seen, wall_secs=round(wall, 2),
-         stage_path={s: path for s in obs_device.STAGES},
+         stage_path={s: "xla" for s in obs_device.STAGES},
          stage_first_resolve_secs={k: round(v, 3)
                                    for k, v in stage_first.items()},
          stage_later_resolves=stage_later,

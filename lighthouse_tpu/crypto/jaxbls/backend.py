@@ -176,31 +176,16 @@ def _batched_affine(z_pk, h_jac, sig_acc):
     n = Zp.shape[0]
 
     def embed(fq):             # (n, NL) -> (n, 2, NL)
-        return lb.kstack([fq, jnp.zeros_like(fq)], axis=-2)
+        return jnp.stack([fq, jnp.zeros_like(fq)], axis=-2)
 
-    if lb._pallas_tracing():
-        # equal-extent 3-stack (3, n, 2, NL): the ragged (2n+1) concat would
-        # unroll one select per slab in the kernel body; the sig Z broadcast
-        # to n lanes wastes n-1 inversion lanes but keeps the Fermat chain
-        # single and the assembly three selects
-        zs = lb.kstack(
-            [embed(Zp), Zh, jnp.broadcast_to(Zs[None], Zh.shape)], axis=0
-        )
-        zinv = tw.fq2_inv(zs)
-        zinv2 = tw.fq2_sqr(zinv)
-        zinv3 = tw.fq2_mul(zinv2, zinv)
-        pk_i2, pk_i3 = zinv2[0, :, 0, :], zinv3[0, :, 0, :]     # Fq lanes
-        h_i2, h_i3 = zinv2[1], zinv3[1]
-        s_i2, s_i3 = zinv2[2, 0], zinv3[2, 0]
-    else:
-        zs = jnp.concatenate([embed(Zp), Zh, Zs[None]], axis=0)  # (2n+1, 2, NL)
-        zinv = tw.fq2_inv(zs)
-        zinv2 = tw.fq2_sqr(zinv)
-        zinv3 = tw.fq2_mul(zinv2, zinv)
+    zs = jnp.concatenate([embed(Zp), Zh, Zs[None]], axis=0)  # (2n+1, 2, NL)
+    zinv = tw.fq2_inv(zs)
+    zinv2 = tw.fq2_sqr(zinv)
+    zinv3 = tw.fq2_mul(zinv2, zinv)
 
-        pk_i2, pk_i3 = zinv2[:n, 0, :], zinv3[:n, 0, :]         # Fq lanes
-        h_i2, h_i3 = zinv2[n : 2 * n], zinv3[n : 2 * n]
-        s_i2, s_i3 = zinv2[2 * n], zinv3[2 * n]
+    pk_i2, pk_i3 = zinv2[:n, 0, :], zinv3[:n, 0, :]         # Fq lanes
+    h_i2, h_i3 = zinv2[n : 2 * n], zinv3[n : 2 * n]
+    s_i2, s_i3 = zinv2[2 * n], zinv3[2 * n]
 
     px = lb.mont_mul(Xp, pk_i2)
     py = lb.mont_mul(Yp, pk_i3)
@@ -216,17 +201,7 @@ def _batched_affine(z_pk, h_jac, sig_acc):
 
 def _stage_prepare(pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits, set_mask):
     """Stage 1: mont conversion, pubkey tree-aggregation, z-scaling of
-    aggregate pubkeys and signatures, signature tree-sum.
-
-    Plain XLA; one fused Pallas kernel when pallas_ops.mode() asks."""
-    from . import pallas_ops
-
-    m = pallas_ops.mode()
-    if m is not None:
-        return pallas_ops.stage_prepare_fused(
-            pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits, set_mask,
-            interpret=(m == "interpret"),
-        )
+    aggregate pubkeys and signatures, signature tree-sum."""
     import jax.numpy as jnp
 
     pk_x = _to_mont_dev(pk_x)
@@ -263,16 +238,7 @@ def _stage_prepare(pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits, set_mask):
 
 
 def _stage_pairs(z_pk, h_jac, sig_acc, set_mask):
-    """Stage 3: batched affine conversion + pair-array assembly.
-
-    Plain XLA; one fused Pallas kernel when pallas_ops.mode() asks."""
-    from . import pallas_ops
-
-    m = pallas_ops.mode()
-    if m is not None:
-        return pallas_ops.stage_pairs_fused(
-            z_pk, h_jac, sig_acc, set_mask, interpret=(m == "interpret")
-        )
+    """Stage 3: batched affine conversion + pair-array assembly."""
     import jax.numpy as jnp
 
     (p1x, p1y, p1inf), (qx, qy, qinf), (sx, sy, sinf) = _batched_affine(
@@ -541,18 +507,6 @@ def _get_stages(mesh=None):
                 ),
             )
     return _kernel_cache[key]
-
-
-def _get_kernel():
-    import jax
-
-    _init_consts()
-    if "k" not in _kernel_cache:
-        from ...utils.jaxcfg import setup_compilation_cache
-
-        setup_compilation_cache()
-        _kernel_cache["k"] = jax.jit(_verify_kernel)
-    return _kernel_cache["k"]
 
 
 def warm_stages(n_sets: int, n_pks: int, single_chip: bool = False) -> None:

@@ -24,21 +24,19 @@ from . import tower as tw
 class _Ops:
     """Field-generic namespace so G1 (Fq) and G2 (Fq2) share point formulas.
 
-    `zero`/`one` are PROPERTIES: zero materializes fresh (a broadcast of the
-    scalar 0 — never a captured array), one routes through
-    limbs.kernel_const so Pallas kernel bodies read it from a real input
-    instead of closing over a module-level device constant."""
+    `zero`/`one` are PROPERTIES: each use materializes them fresh from a
+    shape and a host array, never from a module-level device constant (see
+    the constants note in limbs.py)."""
 
     __slots__ = (
         "add", "sub", "mul", "sqr", "neg", "small", "select", "inv",
-        "is_zero", "eq", "_zero_shape", "_one_name", "_one_np",
+        "is_zero", "eq", "_zero_shape", "_one_np",
     )
 
-    def __init__(self, *, zero_shape, one_name, one_np, **kw):
+    def __init__(self, *, zero_shape, one_np, **kw):
         for k, v in kw.items():
             setattr(self, k, v)
         self._zero_shape = zero_shape
-        self._one_name = one_name
         self._one_np = one_np
 
     @property
@@ -47,7 +45,7 @@ class _Ops:
 
     @property
     def one(self):
-        return lb.kernel_const(self._one_name, self._one_np)
+        return jnp.asarray(self._one_np)
 
 
 def _fq_select(cond, a, b):
@@ -59,14 +57,14 @@ FQ_OPS = _Ops(
     add=lb.add_mod, sub=lb.sub_mod, mul=lb.mont_mul, sqr=lb.mont_sqr,
     neg=lb.neg_mod, small=lb.mul_small, select=_fq_select, inv=lb.mont_inv,
     is_zero=lb.is_zero, eq=lb.eq,
-    zero_shape=(lb.NL,), one_name="FQ_ONE", one_np=tw._mont_const(1),
+    zero_shape=(lb.NL,), one_np=tw._mont_const(1),
 )
 
 FQ2_OPS = _Ops(
     add=lb.add_mod, sub=lb.sub_mod, mul=tw.fq2_mul, sqr=tw.fq2_sqr,
     neg=lb.neg_mod, small=lb.mul_small, select=tw.fq2_select, inv=tw.fq2_inv,
     is_zero=tw.fq2_is_zero, eq=tw.fq2_eq,
-    zero_shape=(2, lb.NL), one_name="FQ2_ONE", one_np=tw._FQ2_ONE_NP,
+    zero_shape=(2, lb.NL), one_np=tw.FQ2_ONE,
 )
 
 
@@ -93,12 +91,12 @@ def _stk(ops, *els):
     uses for fq6/fq12)."""
     axis = -1 if ops is FQ_OPS else -2
     axis -= 1
-    return lb.kstack(els, axis=axis)
+    return jnp.stack(els, axis=axis)
 
 
 def _lanes(ops, stacked, k):
-    # static integer indexing (a squeeze-slice) instead of jnp.take: take
-    # lowers through gather, which Mosaic cannot ingest in kernel bodies
+    # static integer indexing (a squeeze-slice) instead of jnp.take, which
+    # lowers through gather
     tail = (slice(None),) * (1 if ops is FQ_OPS else 2)
     return tuple(stacked[(Ellipsis, i) + tail] for i in range(k))
 
@@ -236,9 +234,6 @@ def scalar_mul_static(p_jac, k: int, ops):
         X, Y, Z = p_jac
         p_jac = (X, ops.neg(Y), Z)
         k = -k
-    impl = lb.kernel_impl(("scalar_mul_static", k))
-    if impl is not None:
-        return impl(p_jac, ops)
     bits = jnp.asarray(np.array([int(b) for b in bin(k)[2:]], np.uint32))
 
     def body(acc, bit):
@@ -332,8 +327,8 @@ def _psi_consts():
         _PSI_CONSTS["cx"] = np.asarray(tw._fq2_const_np(pc.PSI_CX))
         _PSI_CONSTS["cy"] = np.asarray(tw._fq2_const_np(pc.PSI_CY))
     return (
-        lb.kernel_const("PSI_CX", _PSI_CONSTS["cx"]),
-        lb.kernel_const("PSI_CY", _PSI_CONSTS["cy"]),
+        jnp.asarray(_PSI_CONSTS["cx"]),
+        jnp.asarray(_PSI_CONSTS["cy"]),
     )
 
 
@@ -483,12 +478,11 @@ def tree_sum(p_jac, ops):
     complete (identity lanes, P + P, P - P by its selects), so the result
     is another Jacobian representative of the same point.
 
-    Unrolled halving is kept for Pallas kernel bodies (Mosaic has no
-    dynamic roll) and for m <= 4, where the loop machinery outweighs two
-    adds."""
+    Unrolled halving is kept for m <= 4, where the loop machinery
+    outweighs two adds."""
     n = jax.tree_util.tree_leaves(p_jac)[0].shape[0]
     assert n & (n - 1) == 0, "tree_sum needs power-of-two length"
-    if lb._pallas_tracing() or n <= 4:
+    if n <= 4:
         while n > 1:
             half = n // 2
             a = jax.tree_util.tree_map(lambda x: x[:half], p_jac)

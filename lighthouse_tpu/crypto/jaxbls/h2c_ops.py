@@ -30,35 +30,14 @@ from . import curve_ops as co
 Q = P * P  # order of Fq2
 
 # ------------------------------------------------------------ constants
-# Host np masters + kernel_const accessors: Pallas kernel bodies receive
-# these as real inputs (limbs.kernel_const), the XLA path materializes them
-# as ordinary device constants.
+# Host np arrays (see the constants note in limbs.py); a trace embeds them
+# as ordinary constants.
 
 _ISO_A_NP = np.asarray(tw._fq2_const_np(ph2c.ISO_A))
 _ISO_B_NP = np.asarray(tw._fq2_const_np(ph2c.ISO_B))
 _ISO_Z_NP = np.asarray(tw._fq2_const_np(ph2c.ISO_Z))
 _NEG_A_NP = np.asarray(tw._fq2_const_np(pyf.fq2_neg(ph2c.ISO_A)))
 _ZA_NP = np.asarray(tw._fq2_const_np(pyf.fq2_mul(ph2c.ISO_Z, ph2c.ISO_A)))
-
-
-def ISO_A_c():
-    return lb.kernel_const("ISO_A", _ISO_A_NP)
-
-
-def ISO_B_c():
-    return lb.kernel_const("ISO_B", _ISO_B_NP)
-
-
-def ISO_Z_c():
-    return lb.kernel_const("ISO_Z", _ISO_Z_NP)
-
-
-def _NEG_A_c():
-    return lb.kernel_const("ISO_NEG_A", _NEG_A_NP)
-
-
-def _ZA_c():
-    return lb.kernel_const("ISO_ZA", _ZA_NP)
 
 # sqrt_ratio exponent: s = u * v^7 * (u * v^15)^E with E = (q-9)/16 gives
 # s^2 = omega * u/v for an 8th root of unity omega.
@@ -90,10 +69,6 @@ for w in _NQR_OMEGAS:
     _CANDS.append(c)
 _CAND_CONSTS_NP = np.stack([np.asarray(tw._fq2_const_np(c)) for c in _CANDS])
 
-
-def CAND_CONSTS_c():
-    return lb.kernel_const("H2C_CANDS", _CAND_CONSTS_NP)
-
 # Isogeny coefficient matrix: 4 polynomials x 4 coefficients (padded), in the
 # shared monomial basis [xd^3, xn*xd^2, xn^2*xd, xn^3].
 def _poly4(coeffs):
@@ -109,10 +84,6 @@ _ISO_K_NP = np.stack(
         _poly4(ph2c.Y_DEN),
     ]
 )  # (4 polys, 4 coeffs, 2, NL)
-
-
-def ISO_K_c():
-    return lb.kernel_const("ISO_K", _ISO_K_NP)
 
 
 # ------------------------------------------------------------ device pieces
@@ -171,16 +142,6 @@ def fq2_sgn0(a):
     return s0 | (lb.b2u(z0) & s1)
 
 
-def _pow_e(a):
-    """a^E with E = (q-9)/16 — the one 761-bit exponentiation in SSWU.
-    Pallas kernel bodies plant a ref-reading loop ("POW_E"); the XLA path
-    uses the windowed static form."""
-    impl = lb.kernel_impl("POW_E")
-    if impl is not None:
-        return impl(a)
-    return fq2_pow_static(a, _E_BITS)
-
-
 def fq2_sqrt_ratio(u, v):
     """RFC 9380-style sqrt_ratio for Fq2 (q = p^2 ≡ 9 mod 16).
 
@@ -192,20 +153,21 @@ def fq2_sqrt_ratio(u, v):
     v7 = tw.fq2_mul(v4, tw.fq2_mul(v2, v))
     v15 = tw.fq2_mul(v8, v7)
     uv15 = tw.fq2_mul(u, v15)
-    s = tw.fq2_mul(tw.fq2_mul(u, v7), _pow_e(uv15))
+    s = tw.fq2_mul(tw.fq2_mul(u, v7), fq2_pow_static(uv15, _E_BITS))
 
-    ys = tw.fq2_mul(s[..., None, :, :], CAND_CONSTS_c())      # (..., 8, 2, NL)
+    ys = tw.fq2_mul(                                          # (..., 8, 2, NL)
+        s[..., None, :, :], jnp.asarray(_CAND_CONSTS_NP)
+    )
     checks = tw.fq2_mul(tw.fq2_sqr(ys), v[..., None, :, :])   # y^2 * v
-    zu = tw.fq2_mul(jnp.broadcast_to(ISO_Z_c(), u.shape), u)
+    zu = tw.fq2_mul(jnp.broadcast_to(jnp.asarray(_ISO_Z_NP), u.shape), u)
     ok_qr = tw.fq2_eq(checks[..., :4, :, :], u[..., None, :, :])
     ok_nqr = tw.fq2_eq(checks[..., 4:, :, :], zu[..., None, :, :])
     is_qr = jnp.any(ok_qr, axis=-1)
 
     # first matching candidate via 8 unrolled masked selects (argmax +
-    # take_along_axis lowered to a gather, which Mosaic rejects in kernels);
-    # the candidate flags concat as u32 — an i1 vector concat is a vreg
-    # re-layout the chip compiler refuses
-    ok = lb.kconcat([lb.b2u(ok_qr), lb.b2u(ok_nqr)], axis=-1)  # (..., 8)
+    # take_along_axis lowers to a gather); the candidate flags concat as
+    # u32, the form the served program contains
+    ok = jnp.concatenate([lb.b2u(ok_qr), lb.b2u(ok_nqr)], axis=-1)  # (..., 8)
     y = jnp.zeros_like(u)
     found = jnp.zeros(ok.shape[:-1], bool)
     for i in range(8):
@@ -221,16 +183,19 @@ def sswu_projective(u):
 
     Returns (xn, xd, y): affine x = xn/xd on E2', y affine."""
     shape = u.shape
-    Z = jnp.broadcast_to(ISO_Z_c(), shape)
-    A = jnp.broadcast_to(ISO_A_c(), shape)
-    B = jnp.broadcast_to(ISO_B_c(), shape)
+    Z = jnp.broadcast_to(jnp.asarray(_ISO_Z_NP), shape)
+    A = jnp.broadcast_to(jnp.asarray(_ISO_A_NP), shape)
+    B = jnp.broadcast_to(jnp.asarray(_ISO_B_NP), shape)
 
     u2 = tw.fq2_sqr(u)
     tv1 = tw.fq2_mul(Z, u2)
     tv2 = tw.fq2_add(tw.fq2_sqr(tv1), tv1)
-    x1n = tw.fq2_mul(B, tw.fq2_add(tv2, jnp.broadcast_to(tw.fq2_one(), shape)))
-    xd = tw.fq2_mul(jnp.broadcast_to(_NEG_A_c(), shape), tv2)
-    xd = tw.fq2_select(tw.fq2_is_zero(xd), jnp.broadcast_to(_ZA_c(), shape), xd)
+    one = jnp.broadcast_to(jnp.asarray(tw.FQ2_ONE), shape)
+    x1n = tw.fq2_mul(B, tw.fq2_add(tv2, one))
+    xd = tw.fq2_mul(jnp.broadcast_to(jnp.asarray(_NEG_A_NP), shape), tv2)
+    xd = tw.fq2_select(
+        tw.fq2_is_zero(xd), jnp.broadcast_to(jnp.asarray(_ZA_NP), shape), xd
+    )
 
     xd2 = tw.fq2_sqr(xd)
     xd3 = tw.fq2_mul(xd2, xd)
@@ -257,7 +222,7 @@ def iso_map_jacobian(xn, xd, y):
     the shared monomial vector [xd^3, xn*xd^2, xn^2*xd, xn^3]."""
     xd2 = tw.fq2_sqr(xd)
     xn2 = tw.fq2_sqr(xn)
-    m = lb.kstack(
+    m = jnp.stack(
         [
             tw.fq2_mul(xd2, xd),
             tw.fq2_mul(xn, xd2),
@@ -266,7 +231,7 @@ def iso_map_jacobian(xn, xd, y):
         ],
         axis=-3,
     )  # (..., 4, 2, NL)
-    terms = tw.fq2_mul(ISO_K_c(), m[..., None, :, :, :])      # (..., 4, 4, 2, NL)
+    terms = tw.fq2_mul(jnp.asarray(_ISO_K_NP), m[..., None, :, :, :])  # (..., 4, 4, 2, NL)
     sums = lb.add_mod(
         lb.add_mod(terms[..., 0, :, :], terms[..., 1, :, :]),
         lb.add_mod(terms[..., 2, :, :], terms[..., 3, :, :]),
@@ -286,7 +251,7 @@ def iso_map_jacobian(xn, xd, y):
 def map_to_g2(u0, u1):
     """Device: two field elements per message -> Jacobian point in G2
     (SSWU + isogeny on both, add, clear cofactor). u0/u1: (..., 2, NL)."""
-    us = lb.kstack([u0, u1], axis=0)          # map both in one batched pass
+    us = jnp.stack([u0, u1], axis=0)          # map both in one batched pass
     xn, xd, y = sswu_projective(us)
     q = iso_map_jacobian(xn, xd, y)
     q0 = jax.tree_util.tree_map(lambda c: c[0], q)
@@ -315,14 +280,6 @@ def hash_to_field_batch(messages, dst: bytes) -> np.ndarray:
 
 def hash_to_g2_jacobian(us):
     """Device: (n, 2, 2, NL) STANDARD-form u-values -> batched Jacobian G2
-    points (converts to Montgomery on device first).
-
-    Plain XLA; the whole map as one fused Pallas kernel
-    (pallas_ops.hash_to_g2_fused) when pallas_ops.mode() asks."""
-    from . import pallas_ops
-
-    m = pallas_ops.mode()
-    if m is not None:
-        return pallas_ops.hash_to_g2_fused(us, interpret=(m == "interpret"))
+    points (converts to Montgomery on device first)."""
     us = lb.to_mont(us)
     return map_to_g2(us[:, 0], us[:, 1])

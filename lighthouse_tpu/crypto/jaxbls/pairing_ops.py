@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..bls381.constants import X_ABS
-from . import limbs as lb
 from . import tower as tw
 from . import curve_ops as co
 
@@ -93,9 +92,9 @@ def _add_step(r, q_aff, xp, yp):
 def _line_to_fq12(line):
     l0, l1, l2 = line
     z = jnp.zeros_like(l0)
-    c0 = lb.kstack([l0, l1, z], axis=-3)
-    c1 = lb.kstack([z, l2, z], axis=-3)
-    return lb.kstack([c0, c1], axis=-4)
+    c0 = jnp.stack([l0, l1, z], axis=-3)
+    c1 = jnp.stack([z, l2, z], axis=-3)
+    return jnp.stack([c0, c1], axis=-4)
 
 
 def _mul_by_line(f, line):
@@ -110,11 +109,11 @@ def _line_mul_line(la, lb_):
     6 Fq2 products (one batched fq2_mul) via Karatsuba cross terms."""
     l0, l1, l2 = la
     m0, m1, m2 = lb_
-    A = lb.kstack(
+    A = jnp.stack(
         [l0, l1, l2, tw.fq2_add(l0, l1), tw.fq2_add(l0, l2), tw.fq2_add(l1, l2)],
         axis=-3,
     )
-    B = lb.kstack(
+    B = jnp.stack(
         [m0, m1, m2, tw.fq2_add(m0, m1), tw.fq2_add(m0, m2), tw.fq2_add(m1, m2)],
         axis=-3,
     )
@@ -127,19 +126,19 @@ def _line_mul_line(la, lb_):
     c10 = jnp.zeros_like(p00)
     c11 = tw.fq2_sub(tw.fq2_sub(s02, p00), p22)
     c12 = tw.fq2_sub(tw.fq2_sub(s12, p11), p22)
-    lo = lb.kstack([c00, c01, c02], axis=-3)
-    hi = lb.kstack([c10, c11, c12], axis=-3)
-    return lb.kstack([lo, hi], axis=-4)
+    lo = jnp.stack([c00, c01, c02], axis=-3)
+    hi = jnp.stack([c10, c11, c12], axis=-3)
+    return jnp.stack([lo, hi], axis=-4)
 
 
 def _set_lane0(fs, folded):
     """fs with lane 0 replaced by `folded` (unit leading axis).
 
     Keeps tree reductions concat-free: instead of carrying an odd leftover
-    lane to the next level (a leading-axis concatenate Mosaic cannot
-    re-layout), the straggler is multiplied into lane 0 and planted via an
-    iota select. Field products are exact mod P, so the association change
-    is bit-invisible."""
+    lane to the next level through a leading-axis concatenate, the
+    straggler is multiplied into lane 0 and planted via an iota select.
+    Field products are exact mod P, so the association change is
+    bit-invisible."""
     idx = lax.broadcasted_iota(jnp.uint32, fs.shape, 0)
     return jnp.where(idx == 0, folded, fs)
 
@@ -162,7 +161,7 @@ def _mask_lines(line, valid_mask):
     """Replace invalid lanes with the identity line (1, 0, 0)."""
     l0, l1, l2 = line
     m = jnp.asarray(valid_mask, bool)
-    one = jnp.broadcast_to(tw.fq2_one(), l0.shape)
+    one = jnp.broadcast_to(jnp.asarray(tw.FQ2_ONE), l0.shape)
     zero = jnp.zeros_like(l0)
     return (
         tw.fq2_select(m, l0, one),
@@ -185,7 +184,7 @@ def _combine_lines(line, valid_mask):
     )
     if n % 2:
         # odd straggler: sparse-fold its line into lane 0 (cheaper than the
-        # old identity-line pad, and concat-free for Mosaic)
+        # old identity-line pad, and concat-free)
         folded = tw.fq12_mul_by_014(
             fs[0:1], l0[n - 1 : n], l1[n - 1 : n], l2[n - 1 : n]
         )
@@ -311,18 +310,7 @@ def final_exponentiation(m):
 
 def pairing_product_is_one(p_aff, q_aff, valid_mask):
     """prod_{i valid} e(P_i, Q_i) == 1: shared-accumulator Miller loop
-    (any pair count) + one final exponentiation.
-
-    Plain XLA (the reference, and the mesh-sharded multi-chip path); when
-    pallas_ops.mode() asks, the Miller loop and the final-exp hard part
-    run as fused Pallas kernels (pallas_ops.py)."""
-    from . import pallas_ops
-
-    m = pallas_ops.mode()
-    if m is not None:
-        return pallas_ops.pairing_product_is_one_fused(
-            p_aff, q_aff, valid_mask, interpret=(m == "interpret")
-        )
+    (any pair count) + one final exponentiation."""
     f = miller_loop_product(p_aff, q_aff, valid_mask)
     f = final_exponentiation(f)
     return tw.fq12_eq_one(f)

@@ -34,29 +34,13 @@ def _mont_const(x: int) -> np.ndarray:
 FQ_ZERO = np.zeros((NL,), np.uint32)
 FQ_ONE = np.asarray(_mont_const(1))
 
-_FQ2_ONE_NP = np.stack([_mont_const(1), np.zeros(NL, np.uint32)])
-_FQ6_ONE_NP = np.stack(
-    [_FQ2_ONE_NP, np.zeros((2, NL), np.uint32), np.zeros((2, NL), np.uint32)]
-)
-_FQ12_ONE_NP = np.stack([_FQ6_ONE_NP, np.zeros((3, 2, NL), np.uint32)])
-
 FQ2_ZERO = np.zeros((2, NL), np.uint32)
-FQ2_ONE = np.asarray(_FQ2_ONE_NP)
+FQ2_ONE = np.stack([_mont_const(1), np.zeros(NL, np.uint32)])
 FQ6_ZERO = np.zeros((3, 2, NL), np.uint32)
-FQ6_ONE = np.asarray(_FQ6_ONE_NP)
+FQ6_ONE = np.stack([FQ2_ONE, FQ2_ZERO, FQ2_ZERO])
 # numpy, not jnp: module-level device arrays initialize the backend at
 # import (see limbs.py constants note)
-FQ12_ONE = np.asarray(_FQ12_ONE_NP)
-
-
-def fq2_one():
-    """FQ2 one as a kernel-safe constant (limbs.kernel_const)."""
-    return lb.kernel_const("FQ2_ONE", _FQ2_ONE_NP)
-
-
-def fq12_one():
-    """FQ12 one as a kernel-safe constant (limbs.kernel_const)."""
-    return lb.kernel_const("FQ12_ONE", _FQ12_ONE_NP)
+FQ12_ONE = np.stack([FQ6_ONE, FQ6_ZERO])
 
 
 # ----------------------------------------------------------------- Fq2
@@ -68,7 +52,7 @@ fq2_neg = lb.neg_mod
 
 
 def fq2_conj(a):
-    return lb.kstack([a[..., 0, :], lb.neg_mod(a[..., 1, :])], axis=-2)
+    return jnp.stack([a[..., 0, :], lb.neg_mod(a[..., 1, :])], axis=-2)
 
 
 def fq2_mul(a, b):
@@ -76,12 +60,12 @@ def fq2_mul(a, b):
     a0, a1 = a[..., 0, :], a[..., 1, :]
     b0, b1 = b[..., 0, :], b[..., 1, :]
     # One add for both operand sums (stacked), one mont_mul for all 3 products.
-    sums = lb.add_mod(lb.kstack([a0, b0], axis=-2), lb.kstack([a1, b1], axis=-2))
+    sums = lb.add_mod(jnp.stack([a0, b0], axis=-2), jnp.stack([a1, b1], axis=-2))
     sa, sb = sums[..., 0, :], sums[..., 1, :]
-    t = lb.mont_mul(lb.kstack([a0, a1, sa], axis=-2), lb.kstack([b0, b1, sb], axis=-2))
+    t = lb.mont_mul(jnp.stack([a0, a1, sa], axis=-2), jnp.stack([b0, b1, sb], axis=-2))
     t0, t1, t2 = t[..., 0, :], t[..., 1, :], t[..., 2, :]
     t01 = lb.add_mod(t0, t1)
-    res = lb.sub_mod(lb.kstack([t0, t2], axis=-2), lb.kstack([t1, t01], axis=-2))
+    res = lb.sub_mod(jnp.stack([t0, t2], axis=-2), jnp.stack([t1, t01], axis=-2))
     return res
 
 
@@ -89,10 +73,10 @@ def fq2_sqr(a):
     a0, a1 = a[..., 0, :], a[..., 1, :]
     s = lb.add_mod(a0, a1)
     d = lb.sub_mod(a0, a1)
-    t = lb.mont_mul(lb.kstack([s, a0], axis=-2), lb.kstack([d, a1], axis=-2))
+    t = lb.mont_mul(jnp.stack([s, a0], axis=-2), jnp.stack([d, a1], axis=-2))
     c0, t1 = t[..., 0, :], t[..., 1, :]
     c1 = lb.add_mod(t1, t1)
-    return lb.kstack([c0, c1], axis=-2)
+    return jnp.stack([c0, c1], axis=-2)
 
 
 def fq2_mul_fq(a, k):
@@ -106,7 +90,7 @@ def fq2_mul_small(a, k: int):
 
 def fq2_mul_by_xi(a):
     a0, a1 = a[..., 0, :], a[..., 1, :]
-    return lb.kstack([lb.sub_mod(a0, a1), lb.add_mod(a0, a1)], axis=-2)
+    return jnp.stack([lb.sub_mod(a0, a1), lb.add_mod(a0, a1)], axis=-2)
 
 
 def fq2_inv(a):
@@ -114,14 +98,14 @@ def fq2_inv(a):
     sq = lb.mont_mul(a, a)                      # (a0^2, a1^2) in one call
     norm = lb.add_mod(sq[..., 0, :], sq[..., 1, :])
     ninv = lb.mont_inv(norm)
-    out = lb.mont_mul(lb.kstack([a0, lb.neg_mod(a1)], axis=-2), ninv[..., None, :])
+    out = lb.mont_mul(jnp.stack([a0, lb.neg_mod(a1)], axis=-2), ninv[..., None, :])
     return out
 
 
 def fq2_is_zero(a):
-    # chained single-axis reductions: Mosaic's vector.multi_reduction over
-    # BOTH trailing dims is unimplemented unless the result keeps a unit
-    # trailing axis (observed compiling the fused h2c kernel on a v5e)
+    # chained single-axis reductions, not one all() over both trailing
+    # axes: this is the form the served programs contain; fusing the two is
+    # a change to them, to be measured
     return jnp.all(jnp.all(a == 0, axis=-1), axis=-1)
 
 
@@ -143,10 +127,9 @@ fq6_neg = lb.neg_mod
 
 
 def _sel3(x, i, j, k):
-    """Static permutation x[..., [i, j, k], :, :] as slices + stack — list
-    indexing creates an i32[3] gather, which Pallas kernels cannot capture
-    and Mosaic lowers poorly; the stacked-slice form is equivalent."""
-    return lb.kstack([x[..., i, :, :], x[..., j, :, :], x[..., k, :, :]], axis=-3)
+    """Static permutation x[..., [i, j, k], :, :] as slices + stack: list
+    indexing creates an i32[3] gather where three static slices do."""
+    return jnp.stack([x[..., i, :, :], x[..., j, :, :], x[..., k, :, :]], axis=-3)
 
 
 def fq6_mul(a, b):
@@ -154,24 +137,24 @@ def fq6_mul(a, b):
     a, b = jnp.broadcast_arrays(a, b)
     # Operand sums for the three cross terms, a and b together: one add.
     sums = lb.add_mod(
-        lb.kconcat([_sel3(a, 1, 0, 0), _sel3(b, 1, 0, 0)], axis=-3),
-        lb.kconcat([_sel3(a, 2, 1, 2), _sel3(b, 2, 1, 2)], axis=-3),
+        jnp.concatenate([_sel3(a, 1, 0, 0), _sel3(b, 1, 0, 0)], axis=-3),
+        jnp.concatenate([_sel3(a, 2, 1, 2), _sel3(b, 2, 1, 2)], axis=-3),
     )
-    A = lb.kconcat([a, sums[..., :3, :, :]], axis=-3)   # (..., 6, 2, NL)
-    B = lb.kconcat([b, sums[..., 3:, :, :]], axis=-3)
+    A = jnp.concatenate([a, sums[..., :3, :, :]], axis=-3)   # (..., 6, 2, NL)
+    B = jnp.concatenate([b, sums[..., 3:, :, :]], axis=-3)
     t = fq2_mul(A, B)                                        # ONE mont_mul, 18 lanes
     t0, t1, t2 = t[..., 0, :, :], t[..., 1, :, :], t[..., 2, :, :]
     m12, m01, m02 = t[..., 3, :, :], t[..., 4, :, :], t[..., 5, :, :]
 
     # pair sums (t1+t2, t0+t1, t0+t2) in one add, cross-minus in one sub
     ps = lb.add_mod(_sel3(t, 1, 0, 0), _sel3(t, 2, 1, 2))
-    um = lb.sub_mod(lb.kstack([m12, m01, m02], axis=-3), ps)
+    um = lb.sub_mod(jnp.stack([m12, m01, m02], axis=-3), ps)
     u, v, w = um[..., 0, :, :], um[..., 1, :, :], um[..., 2, :, :]
     # xi-mults for u and t2 in one stacked call
-    xis = fq2_mul_by_xi(lb.kstack([u, t2], axis=-3))
+    xis = fq2_mul_by_xi(jnp.stack([u, t2], axis=-3))
     c = lb.add_mod(
-        lb.kstack([t0, v, w], axis=-3),
-        lb.kstack([xis[..., 0, :, :], xis[..., 1, :, :], t1], axis=-3),
+        jnp.stack([t0, v, w], axis=-3),
+        jnp.stack([xis[..., 0, :, :], xis[..., 1, :, :], t1], axis=-3),
     )
     return c
 
@@ -181,7 +164,7 @@ def fq6_sqr(a):
 
 
 def fq6_mul_by_v(a):
-    return lb.kconcat([fq2_mul_by_xi(a[..., 2:3, :, :]), a[..., 0:2, :, :]], axis=-3)
+    return jnp.concatenate([fq2_mul_by_xi(a[..., 2:3, :, :]), a[..., 0:2, :, :]], axis=-3)
 
 
 def fq6_mul_fq2(a, k):
@@ -196,7 +179,7 @@ def fq6_inv(a):
     c0 = fq2_sub(sq[..., 0, :, :], fq2_mul_by_xi(pr[..., 1, :, :]))
     c1 = fq2_sub(fq2_mul_by_xi(sq[..., 2, :, :]), pr[..., 0, :, :])
     c2 = fq2_sub(sq[..., 1, :, :], pr[..., 2, :, :])
-    cs = lb.kstack([c0, c1, c2], axis=-3)
+    cs = jnp.stack([c0, c1, c2], axis=-3)
     # t = a0*c0 + xi*(a1*c2 + a2*c1)
     acs = fq2_mul(a, cs[..., [0, 2, 1], :, :])                # a0c0, a1c2, a2c1
     t = fq2_add(
@@ -214,14 +197,14 @@ def fq12_mul(a, b):
     a, b = jnp.broadcast_arrays(a, b)
     a0, a1 = a[..., 0, :, :, :], a[..., 1, :, :, :]
     b0, b1 = b[..., 0, :, :, :], b[..., 1, :, :, :]
-    sums = lb.add_mod(lb.kstack([a0, b0], axis=-4), lb.kstack([a1, b1], axis=-4))
-    A = lb.kconcat([a, sums[..., 0:1, :, :, :]], axis=-4)   # (..., 3, 3, 2, NL)
-    B = lb.kconcat([b, sums[..., 1:2, :, :, :]], axis=-4)
+    sums = lb.add_mod(jnp.stack([a0, b0], axis=-4), jnp.stack([a1, b1], axis=-4))
+    A = jnp.concatenate([a, sums[..., 0:1, :, :, :]], axis=-4)   # (..., 3, 3, 2, NL)
+    B = jnp.concatenate([b, sums[..., 1:2, :, :, :]], axis=-4)
     t = fq6_mul(A, B)                                            # ONE mont_mul, 54 lanes
     t0, t1, tx = t[..., 0, :, :, :], t[..., 1, :, :, :], t[..., 2, :, :, :]
     c0 = fq6_add(t0, fq6_mul_by_v(t1))
     c1 = fq6_sub(tx, fq6_add(t0, t1))
-    return lb.kstack([c0, c1], axis=-4)
+    return jnp.stack([c0, c1], axis=-4)
 
 
 def fq12_mul_by_014(a, l0, l1, l2):
@@ -246,8 +229,8 @@ def fq12_mul_by_014(a, l0, l1, l2):
     #  t-part: f0*l0, f1*l1, (f0+f1)*(l0+l1), f2*l0, f2*l1       (a0 * [l0,l1])
     #  q-part: g0*l2, g1*l2, g2*l2                               (a1 * [l2])
     #  r-part: c0*l0, c1*l12, (c0+c1)*(l0+l12), c2*l0, c2*l12    ((a0+a1)*[l0,l1+l2])
-    A = lb.kstack([f0, f1, f01, f2, f2, g0, g1, g2, c0, c1, c01, c2, c2], axis=-3)
-    B = lb.kstack(
+    A = jnp.stack([f0, f1, f01, f2, f2, g0, g1, g2, c0, c1, c01, c2, c2], axis=-3)
+    B = jnp.stack(
         [l0, l1, l01, l0, l1, l2, l2, l2, l0, l12, l0_12, l0, l12], axis=-3
     )
     t = fq2_mul(A, B)
@@ -269,7 +252,7 @@ def fq12_mul_by_014(a, l0, l1, l2):
     t2_2 = fq2_add(r2, r4)
 
     # out0 = t0 + v * t1 = (t0_0 + xi*t1_2, t0_1 + t1_0, t0_2 + t1_1)
-    out0 = lb.kstack(
+    out0 = jnp.stack(
         [
             fq2_add(t0_0, fq2_mul_by_xi(t1_2)),
             fq2_add(t0_1, t1_0),
@@ -278,7 +261,7 @@ def fq12_mul_by_014(a, l0, l1, l2):
         axis=-3,
     )
     # out1 = t2 - t0 - t1 componentwise
-    out1 = lb.kstack(
+    out1 = jnp.stack(
         [
             fq2_sub(fq2_sub(t2_0, t0_0), t1_0),
             fq2_sub(fq2_sub(t2_1, t0_1), t1_1),
@@ -286,7 +269,7 @@ def fq12_mul_by_014(a, l0, l1, l2):
         ],
         axis=-3,
     )
-    return lb.kstack([out0, out1], axis=-4)
+    return jnp.stack([out0, out1], axis=-4)
 
 
 def fq12_sqr(a):
@@ -295,23 +278,23 @@ def fq12_sqr(a):
     # c0 = s - t - v*t ; c1 = 2t.  The two fq6 muls share one call.
     s1 = fq6_add(a0, a1)
     s2 = fq6_add(a0, fq6_mul_by_v(a1))
-    t_pair = fq6_mul(lb.kstack([a0, s1], axis=-4), lb.kstack([a1, s2], axis=-4))
+    t_pair = fq6_mul(jnp.stack([a0, s1], axis=-4), jnp.stack([a1, s2], axis=-4))
     t, s = t_pair[..., 0, :, :, :], t_pair[..., 1, :, :, :]
     c0 = fq6_sub(fq6_sub(s, t), fq6_mul_by_v(t))
     c1 = fq6_add(t, t)
-    return lb.kstack([c0, c1], axis=-4)
+    return jnp.stack([c0, c1], axis=-4)
 
 
 def fq12_conj(a):
-    return lb.kstack([a[..., 0, :, :, :], fq6_neg(a[..., 1, :, :, :])], axis=-4)
+    return jnp.stack([a[..., 0, :, :, :], fq6_neg(a[..., 1, :, :, :])], axis=-4)
 
 
 def fq12_inv(a):
     a0, a1 = a[..., 0, :, :, :], a[..., 1, :, :, :]
-    sq = fq6_sqr(lb.kstack([a0, a1], axis=-4))
+    sq = fq6_sqr(jnp.stack([a0, a1], axis=-4))
     t = fq6_sub(sq[..., 0, :, :, :], fq6_mul_by_v(sq[..., 1, :, :, :]))
     tinv = fq6_inv(t)
-    out = fq6_mul(lb.kstack([a0, fq6_neg(a1)], axis=-4), tinv[..., None, :, :, :])
+    out = fq6_mul(jnp.stack([a0, fq6_neg(a1)], axis=-4), tinv[..., None, :, :, :])
     return out
 
 
@@ -340,10 +323,10 @@ def fq12_cyclotomic_sqr(a):
     g0, g1, g2 = a0[..., 0, :, :], a0[..., 1, :, :], a0[..., 2, :, :]
     g3, g4, g5 = a1[..., 0, :, :], a1[..., 1, :, :], a1[..., 2, :, :]
 
-    C0 = lb.kstack([g0, g3, g1], axis=-3)
-    C1 = lb.kstack([g4, g2, g5], axis=-3)
+    C0 = jnp.stack([g0, g3, g1], axis=-3)
+    C1 = jnp.stack([g4, g2, g5], axis=-3)
     # fq4_sqr batched: t0 = C0^2, t1 = C1^2, ts = (C0+C1)^2  — one fq2_sqr, 9 lanes
-    S = fq2_sqr(lb.kconcat([C0, C1, lb.add_mod(C0, C1)], axis=-3))
+    S = fq2_sqr(jnp.concatenate([C0, C1, lb.add_mod(C0, C1)], axis=-3))
     t0 = S[..., 0:3, :, :]
     t1 = S[..., 3:6, :, :]
     ts = S[..., 6:9, :, :]
@@ -355,17 +338,17 @@ def fq12_cyclotomic_sqr(a):
     #   a0' = (3cA0 - 2g0, 3cB0 - 2g1, 3cC0 - 2g2)
     #   a1' = (3*xi*cC1 + 2g3, 3cA1 + 2g4, 3cB1 + 2g5)
     cC1 = r1[..., 2, :, :]
-    lo_g = lb.kstack([g0, g1, g2], axis=-3)
+    lo_g = jnp.stack([g0, g1, g2], axis=-3)
     d = lb.sub_mod(r0, lo_g)
     lo = lb.add_mod(r0, lb.add_mod(d, d))
 
-    hi_t = lb.kconcat(
+    hi_t = jnp.concatenate(
         [fq2_mul_by_xi(cC1)[..., None, :, :], r1[..., 0:2, :, :]], axis=-3
     )
-    hi_g = lb.kstack([g3, g4, g5], axis=-3)
+    hi_g = jnp.stack([g3, g4, g5], axis=-3)
     s = lb.add_mod(hi_t, hi_g)
     hi = lb.add_mod(hi_t, lb.add_mod(s, s))
-    return lb.kstack([lo, hi], axis=-4)
+    return jnp.stack([lo, hi], axis=-4)
 
 
 # ------------------------------------------------ Frobenius
@@ -397,14 +380,14 @@ _FROB12_COEFF_NP: dict = {}
 
 def _frob12_coeff_np(power: int) -> np.ndarray:
     """(2, 3, 2, NL) Frobenius coefficient block for fq12_frobenius, cached
-    per power mod 12 (host np — becomes a kernel input in Pallas bodies)."""
+    per power mod 12 (host np, see the constants note in limbs.py)."""
     key = power % 12
     if key not in _FROB12_COEFF_NP:
         g = _FROB12_C1[key]
-        coeff0 = np.stack([_FQ2_ONE_NP, _FROB6_C1[key % 6], _FROB6_C2[key % 6]])
+        coeff0 = np.stack([FQ2_ONE, _FROB6_C1[key % 6], _FROB6_C2[key % 6]])
         coeff1 = np.stack(
             [
-                np.asarray(_fq2_mul_np(g, _FQ2_ONE_NP)),
+                np.asarray(_fq2_mul_np(g, FQ2_ONE)),
                 _fq2_mul_np(_FROB6_C1[key % 6], g),
                 _fq2_mul_np(_FROB6_C2[key % 6], g),
             ]
@@ -417,8 +400,8 @@ def fq12_frobenius(a, power=1):
     a0, a1 = a[..., 0, :, :, :], a[..., 1, :, :, :]
     conj0 = a0 if power % 2 == 0 else fq2_conj(a0)
     conj1 = a1 if power % 2 == 0 else fq2_conj(a1)
-    stacked = lb.kstack([conj0, conj1], axis=-4)
-    coeff = lb.kernel_const(f"FROB12C_{power % 12}", _frob12_coeff_np(power))
+    stacked = jnp.stack([conj0, conj1], axis=-4)
+    coeff = jnp.asarray(_frob12_coeff_np(power))
     return fq2_mul(stacked, coeff)
 
 

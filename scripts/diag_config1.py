@@ -17,8 +17,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-os.environ.setdefault("LIGHTHOUSE_TPU_PALLAS", "off")
-
 from lighthouse_tpu.utils.jaxcfg import setup_compilation_cache
 
 setup_compilation_cache()

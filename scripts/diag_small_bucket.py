@@ -17,8 +17,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-os.environ.setdefault("LIGHTHOUSE_TPU_PALLAS", "off")
-
 
 def run_stages():
     from lighthouse_tpu.utils.jaxcfg import setup_compilation_cache

@@ -11,12 +11,10 @@ the autotune profile snapshot, printing roofline utilization against the
 device's ESTIMATED peak.
 
 Usage: python scripts/profile_components.py [--sets N] [--pks M] [--reps R]
-       [--shift] [--msm] [--no-analytics]
+       [--msm] [--no-analytics]
 
---shift flips limbs._POLY_SHIFT to the shift-accumulate poly_mul form (vs
-the default banded-einsum form) for A/B comparison. --msm appends the
-variable-base vs fixed-base comb MSM comparison at KZG scale (the one
-measurement here that is not stage timing).
+--msm appends the variable-base vs fixed-base comb MSM comparison at KZG
+scale (the one measurement here that is not stage timing).
 """
 
 import argparse
@@ -67,7 +65,6 @@ def run_msm_comparison(reps: int) -> None:
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shift", action="store_true")
     ap.add_argument("--sets", type=int, default=64)
     ap.add_argument("--pks", type=int, default=128)
     ap.add_argument("--reps", type=int, default=3)
@@ -80,14 +77,6 @@ def main():
     from lighthouse_tpu.utils.jaxcfg import setup_compilation_cache
 
     setup_compilation_cache()
-
-    from lighthouse_tpu.crypto.jaxbls import limbs as lb
-
-    if args.shift:
-        lb._POLY_SHIFT = True
-        print("poly_mul: SHIFT-ACCUMULATE form", file=sys.stderr)
-    else:
-        print("poly_mul: BANDED-EINSUM form", file=sys.stderr)
 
     import jax
 

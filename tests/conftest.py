@@ -51,32 +51,24 @@ def pytest_configure(config):
 
 
 # Files that need the most seconds on their worker, longest first, with the
-# seconds of the six-worker tier-1 run on the 8-core sandbox (PR 25: 941 s
-# in all) beside each. Their items move to the front of the collection so
+# seconds of a six-worker tier-1 run on the 8-core sandbox (PR 25: 941 s in
+# all) beside each. Their items move to the front of the collection so
 # `--dist loadfile` hands them out at t = 0 and the ~1000 light tests fill
 # in behind: the run is then bounded by about max(longest file, total /
 # workers). A file that needs more than 300 s on its worker belongs here.
-# Past the first six the order is not by seconds: xdist hands a worker its
-# next file while the last two tests of its current one are still pending,
-# so the workers of the two- and the one-test file among the first six take
-# the seventh and the eighth file on at t = 0 and run them after minutes of
-# compiling, and the one-test file in eighth place takes a successor along
-# too. This order is the one that ended soonest in a replay of that rule
-# over the seconds of an earlier run, each varied by 15 % (the replay gave
-# 1,102 s for the order of that run, which took 1,104 s, and 1,013 s for
-# this one).
+# xdist hands a worker its next file while the last two tests of its current
+# one are still pending, so the worker of a one-test file among the first
+# six takes the seventh file on at t = 0 and runs it after minutes of
+# compiling: keep a short file in seventh place.
 _LONGEST_FIRST = (
     "test_ef_vectors.py",               # 738
-    "test_jaxbls_pallas.py",            # 610 (2 tests: takes the 7th along)
     "test_jaxbls_backend.py",           # 622
     "test_multichip.py",                # 568
+    "test_multichip_2d.py",             # 380 (1 test: takes the 7th along)
     "test_jaxbls_pairing.py",           # 369
-    "test_multichip_2d.py",             # 380 (1 test: takes the 8th along)
-    "test_fleet.py",                    # 183
-    "test_jaxbls_pallas_final_exp.py",  # 360 (1 test)
     "test_beacon_chain.py",             # 250
+    "test_fleet.py",                    # 183
     "test_jaxbls_h2c.py",               # 167
-    "test_jaxbls_pallas_stages.py",     # 303
     "test_kzg.py",                      # 154
     "test_jaxbls_msm.py",               # 123
 )

@@ -13,9 +13,10 @@ that loads the TPU library holds its lock until it exits; the compiles run
 in the test's own process, with the persistent cache off around them (an
 entry written for a described chip cannot be read back without one).
 
-Unmarked: the compiles of a few seconds. `slow`: the minute-long stage
-compiles at the served 64x128 bucket (prepare ~1 min, hash-to-G2 ~3 min,
-pairing ~2.5 min on eight host cores).
+Unmarked: the compiles of a few seconds (`pairs` at each served bucket's
+set count). `slow`: the minute-long stage compiles at the served 64x128
+bucket (prepare ~1 min, hash-to-G2 ~3 min, pairing ~2.5 min on eight host
+cores).
 """
 
 import numpy as np
@@ -31,6 +32,8 @@ from lighthouse_tpu.crypto.jaxbls import limbs as lb
 
 V5E_HBM_BYTES = 16 * 1024**3
 N_SETS, N_PKS = 64, 128   # the served gossip bucket (chip_smoke.py)
+#: the served buckets: urgent, gossip, block (BENCHMARK.json's BLS cells)
+SERVED_BUCKETS = ((4, 128), (64, 128), (256, 512))
 
 
 @pytest.fixture(scope="module")
@@ -93,7 +96,7 @@ _STAGE_FNS = {
 
 
 def _compile_stage(stage: str, n: int, m: int, sharding):
-    """The stage as the TPU node jits it: the XLA path, donation on."""
+    """The stage as the TPU node jits it: donation on."""
     be._init_consts()
     fn = jax.jit(_STAGE_FNS[stage],
                  donate_argnums=be.STAGE_DONATE_ARGNUMS[stage])
@@ -111,21 +114,20 @@ def _shapes(tree):
     return jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), tree)
 
 
-@pytest.mark.parametrize("stage", [
-    "pairs",
-    pytest.param("prepare", marks=pytest.mark.slow),
-    pytest.param("h2c", marks=pytest.mark.slow),
-    pytest.param("pairing", marks=pytest.mark.slow),
-])
-def test_xla_stage_compiles_for_v5e(stage, one_chip, no_persistent_cache,
-                                    monkeypatch):
-    monkeypatch.setenv("LIGHTHOUSE_TPU_PALLAS", "off")
-    compiled = _compile_stage(stage, N_SETS, N_PKS, one_chip)
+@pytest.mark.parametrize("stage,bucket", [
+    *[("pairs", b) for b in SERVED_BUCKETS],
+    pytest.param("prepare", (N_SETS, N_PKS), marks=pytest.mark.slow),
+    pytest.param("h2c", (N_SETS, N_PKS), marks=pytest.mark.slow),
+    pytest.param("pairing", (N_SETS, N_PKS), marks=pytest.mark.slow),
+], ids=lambda v: v if isinstance(v, str) else "%dx%d" % v)
+def test_stage_compiles_for_v5e(stage, bucket, one_chip,
+                                no_persistent_cache):
+    n, m = bucket
+    compiled = _compile_stage(stage, n, m, one_chip)
     _assert_fits_hbm(compiled)
-    assert "tpu_custom_call" not in compiled.as_text()
     # the next stage's argument shapes in _stage_args are this stage's
     # outputs: a drifted hand-written shape must fail here, not broadcast
-    args = _stage_args(N_SETS, N_PKS, one_chip)
+    args = _stage_args(n, m, one_chip)
     out = _shapes(jax.eval_shape(_STAGE_FNS[stage], *args[stage]))
     if stage == "prepare":
         assert out[:2] == _shapes((args["pairs"][0], args["pairs"][2]))
@@ -133,19 +135,6 @@ def test_xla_stage_compiles_for_v5e(stage, one_chip, no_persistent_cache,
         assert out == _shapes(args["pairs"][1])
     elif stage == "pairs":
         assert out == _shapes(args["pairing"])
-
-
-def test_fused_pairs_kernel_compiles_for_v5e(one_chip, no_persistent_cache):
-    """LIGHTHOUSE_TPU_PALLAS=on at the urgent lane's 4-set bucket: Mosaic
-    accepts the fused pair-assembly kernel."""
-    from lighthouse_tpu.crypto.jaxbls import pallas_ops
-
-    args = _stage_args(4, 1, one_chip)["pairs"]
-    compiled = jax.jit(pallas_ops.stage_pairs_fused).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    assert _shapes(jax.eval_shape(pallas_ops.stage_pairs_fused, *args)) == (
-        _shapes(_stage_args(4, 1, one_chip)["pairing"])
-    )
 
 
 def test_tree_hash_ladder_compiles_for_v5e(one_chip, no_persistent_cache):
@@ -156,28 +145,3 @@ def test_tree_hash_ladder_compiles_for_v5e(one_chip, no_persistent_cache):
     ladder = engine._make_ladder(n, 1, True, None)
     words = jax.ShapeDtypeStruct((n, 8), np.uint32, sharding=one_chip)
     _assert_fits_hbm(ladder.lower(words).compile())
-
-
-@pytest.mark.parametrize("value,want", [
-    (None, None), ("auto", None), ("off", None), ("0", None),
-    ("on", "compile"), ("1", "compile"), ("interpret", "interpret"),
-])
-def test_pallas_mode_reads_the_switch_alone(monkeypatch, value, want):
-    """Which path a stage takes is the environment switch and nothing
-    else: no recorded probe file, no platform string, no size gate, no
-    caught exception. `auto` is the XLA staged programs."""
-    from lighthouse_tpu.crypto.jaxbls import pallas_ops
-
-    if value is None:
-        monkeypatch.delenv("LIGHTHOUSE_TPU_PALLAS", raising=False)
-    else:
-        monkeypatch.setenv("LIGHTHOUSE_TPU_PALLAS", value)
-    assert pallas_ops.mode() == want
-
-
-def test_pallas_mode_refuses_an_unknown_value(monkeypatch):
-    from lighthouse_tpu.crypto.jaxbls import pallas_ops
-
-    monkeypatch.setenv("LIGHTHOUSE_TPU_PALLAS", "maybe")
-    with pytest.raises(ValueError, match="LIGHTHOUSE_TPU_PALLAS"):
-        pallas_ops.mode()
