@@ -99,6 +99,15 @@ _TREE_SUM_LANE_ADDS = REGISTRY.counter_vec(
     "the reduction's shape still cost",
     ("kind",),
 )
+_MILLER_PLAN = REGISTRY.counter_vec(
+    "jaxbls_miller_plan_total",
+    "what pairing_ops.miller_lane_plan gives the pairing stage, per "
+    "dispatch, for the pair lanes one Miller loop sees: dispatches, "
+    "accumulators = W carried through the loop, in_step_levels = dense "
+    "Fq12 tree levels left inside each of its steps; in_step_levels over "
+    "dispatches is 0 where the lines are narrowed only after the loop",
+    ("kind",),
+)
 _seen_exec_buckets: set = set()  # buckets that have resolved at least once
 
 MIN_SETS = 4          # smallest bucket (pairs axis = sets + 1 rounded up)
@@ -309,7 +318,8 @@ def _init_consts():
 def _build_shard_map_pairing(mesh):
     """Stage-4 pair product as an EXPLICIT collective (the fallback when
     sharding propagation through the jit build fails): each shard runs the
-    shared-accumulator Miller loop over its LOCAL pairs — partial products
+    shared-accumulator Miller loop over its LOCAL pairs, its accumulator
+    count read from that local width — partial products
     over disjoint pair subsets multiply to the full Miller value, and
     conjugation (x < 0) distributes over the product — then one all_gather
     over the sets axis, an Fq12 product of the per-shard partials, and a
@@ -415,6 +425,15 @@ class _PairingDispatch:
     def lower(self, *args):
         fn = self._get_fallback() if self._use_fallback else self._jit
         return fn.lower(*args)
+
+    def miller_pairs(self, n_pairs: int) -> int:
+        """Pair lanes ONE Miller loop of the serving build runs: all of
+        them in the jit build, a chip's share in the shard_map build."""
+        if not self._use_fallback:
+            return n_pairs
+        from ...parallel.mesh import SET_AXIS
+
+        return -(-n_pairs // int(self._mesh.shape[SET_AXIS]))
 
 
 def _get_stages(mesh=None):
@@ -777,6 +796,13 @@ class JaxBackend:
         _BUCKET_SLOTS.labels("keys", "padded").inc(n * m)
         _TREE_SUM_LANE_ADDS.labels("done").inc(co.tree_sum_plan(m, n)[3])
         _TREE_SUM_LANE_ADDS.labels("needed").inc(real_keys - n_real)
+        miller_pairs = n + 1
+        if isinstance(pairing_stage, _PairingDispatch):
+            miller_pairs = pairing_stage.miller_pairs(miller_pairs)
+        w, in_step_levels, _ = po.miller_lane_plan(miller_pairs)
+        _MILLER_PLAN.labels("dispatches").inc()
+        _MILLER_PLAN.labels("accumulators").inc(w)
+        _MILLER_PLAN.labels("in_step_levels").inc(in_step_levels)
 
         pk_x, pk_y, pk_mask = self._marshal_pubkeys(
             sets, n, m, single_chip=single_chip

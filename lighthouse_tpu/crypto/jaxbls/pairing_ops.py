@@ -1,28 +1,45 @@
 """Batched optimal ate pairing on TPU.
 
 Strategy (differs from the pure-Python ground truth only in schedule, not
-semantics): the Miller loop runs vmapped over the pair axis — each pair keeps
-its own running f_i — then the product over pairs is one tree reduction and a
-single shared final exponentiation checks prod_i e(P_i, Q_i) == 1. That keeps
-every step embarrassingly batch-parallel (the TPU win) while doing the one
-expensive final exp only once, the same trick blst's
-verify_multiple_aggregate_signatures uses on CPU
+semantics): ONE Miller loop serves all n pairs of a dispatch. It carries W
+running accumulators f[0..W), each the Miller value of a fixed, disjoint
+subset of the pairs. Each of the 63 doubling steps (and 5 addition steps)
+squares the W accumulators once, runs the point step over all pair lanes,
+and multiplies every pair's sparse line into its accumulator: directly
+(one line an accumulator), or as a sparse line-pair product, a dense Fq12,
+when an accumulator takes two lines or more. After the loop ONE tree
+product narrows the W accumulators to the Miller value of all pairs, and a
+single shared final exponentiation checks prod_i e(P_i, Q_i) == 1, the
+same trick blst's verify_multiple_aggregate_signatures uses on CPU
 (/root/reference/crypto/bls/src/impls/blst.rs:35-117).
+
+W is read from the pair count (`miller_lane_plan`). On this chip a field
+operation on exactly 128 pair lanes costs what it costs on one lane and
+less than on 2 to 64, so narrowing a step's lines to ONE value inside every
+step (log2(n/2) dense products, each on fewer lanes than the last) costs
+more than everything else in the step. From 33 pairs on the loop carries
+W = 128 accumulators, one full row of vector lanes, and pads the pair axis
+to whole rows with masked lanes; a few pairs keep W = 1, the whole product
+tree inside the step. Squaring distributes over a product and every field
+operation ends canonical (< P), so the product of the W finals is limb for
+limb the W = 1 value.
 
 Line evaluations use inversion-free Jacobian steps; every line is scaled by
 the Fq2 unit 2YZ^3 (doubling) or Z3 (addition), which the final
 exponentiation annihilates (its easy part contains the factor p^2 - 1).
-The static low-hamming-weight loop parameter X_ABS is walked with lax.scan
-over zero-runs + unrolled add steps, so the compiled graph stays small while
-doing no wasted conditional adds.
+The static low-hamming-weight loop parameter X_ABS is walked with ONE
+lax.scan over its bits; the (rare) addition step hides behind lax.cond with
+a scalar predicate, so the compiled graph holds one loop body and runs no
+wasted conditional adds.
 
 Like the ground truth (bls381/pairing.py) this computes the CUBED pairing —
 the HHT final-exp chain — which is still non-degenerate and bilinear, and
 all consensus uses only compare pairing products to 1.
 
-Padded/invalid lanes (identity points) run on garbage deterministically and
-are replaced by 1 before the product (mask select), mirroring how the Python
-miller_loop skips None pairs.
+Padded/invalid lanes (identity points) run on garbage deterministically;
+their lines are replaced by the identity line (mask select) before any
+product, mirroring how the Python miller_loop skips None pairs, so an
+accumulator whose pairs are all padding stays 1.
 """
 
 from __future__ import annotations
@@ -143,18 +160,24 @@ def _set_lane0(fs, folded):
     return jnp.where(idx == 0, folded, fs)
 
 
-def fq12_product_any(fs):
-    """Tree product over the first axis, any length >= 1 (odd stragglers are
-    folded into lane 0 — no shape-changing concat)."""
+def _fq12_product_to(fs, w: int):
+    """Tree product over the first axis, halved until `w` lanes remain (odd
+    stragglers are folded into lane 0 — no shape-changing concat). `w` is
+    on the halving ladder of the length: len >> k."""
     n = fs.shape[0]
-    while n > 1:
+    while n > w:
         half = n // 2
         prod = tw.fq12_mul(fs[:half], fs[half : 2 * half])
         if n % 2:
             prod = _set_lane0(prod, tw.fq12_mul(prod[0:1], fs[2 * half : n]))
         fs = prod
         n = half
-    return fs[0]
+    return fs
+
+
+def fq12_product_any(fs):
+    """Tree product over the first axis, any length >= 1."""
+    return _fq12_product_to(fs, 1)[0]
 
 
 def _mask_lines(line, valid_mask):
@@ -170,13 +193,15 @@ def _mask_lines(line, valid_mask):
     )
 
 
-def _combine_lines(line, valid_mask):
-    """All n masked lines -> ONE dense Fq12: pair the lines sparsely
-    (6 Fq2 muls per pair) then tree-reduce the halved batch."""
+def _combine_lines(line, valid_mask, w: int):
+    """All n masked lines -> `w` dense Fq12 lanes: pair the lines sparsely
+    (6 Fq2 muls per pair), then tree-reduce the halved batch down to w
+    (not at all when w == n // 2). Lane j holds the lines of a fixed subset
+    of the pairs, the same at every step of the loop."""
     l0, l1, l2 = _mask_lines(line, valid_mask)
     n = l0.shape[0]
     if n == 1:
-        return _line_to_fq12((l0, l1, l2))[0]
+        return _line_to_fq12((l0, l1, l2))
     half = n // 2
     fs = _line_mul_line(
         (l0[:half], l1[:half], l2[:half]),
@@ -189,36 +214,131 @@ def _combine_lines(line, valid_mask):
             fs[0:1], l0[n - 1 : n], l1[n - 1 : n], l2[n - 1 : n]
         )
         fs = _set_lane0(fs, folded)
-    return fq12_product_any(fs)
+    return _fq12_product_to(fs, w)
+
+
+# How many accumulators the Miller loop carries, read from the pair count.
+# Measured on one TPU v5e (scripts/measure_miller_lanes.py, PR 30), ms a call
+# inside a fori_loop, by pair lanes L (0 = no batch axis):
+#
+#   L                 0     1     2     4     8    16    32    64   128   256   257
+#   fq12_mul        .299  .301  .505  .884  .608  .679  .807  .839  .351  .647  .952
+#   fq12_sqr        .235  .234  .369  .600  .413  .469  .546  .562  .225  .399  .573
+#   fq12_mul_by_014 .257  .151  .204  .306  .305  .527  .834 1.249  .272  .495  .678
+#   _line_mul_line    -   .138  .188  .278  .239  .265  .310  .315  .167  .259  .353
+#   _dbl_step         -   .288  .370  .577  .503  .377  .532  .678  .263  .378  .500
+#
+# A call on exactly 128 lanes costs what it costs on ONE and less than on 2
+# to 64: the compiler lays the pair axis on the 128 vector lanes, and only
+# whole rows are cheap (256 = two rows, 257 pays for three). So the wide loop
+# always carries one full row of accumulators, padding the pair axis to it.
+# miller_loop_product, ms a call (W accumulators; every value limb for limb
+# W = 1's):
+#
+#   257 pairs   W=1 365.1   W=32 271.4   W=64 178.1   W=128  78.9
+#    65 pairs   W=1 270.5   W=16 175.5   W=32 140.4   W=64 157.0 (no padding)
+#                                                     W=128  42.2 (padded)
+#     5 pairs   W=1 110.9   W=2  118.1                W=128  42.0 (padded)
+#
+# MILLER_WIDE_FROM keeps the one-accumulator loop (the program PR 29 served,
+# byte for byte) for the few-pair shapes: the urgent bucket's 5 pairs, KZG's
+# 4, and the 3..17 pairs the CPU tests run, where 128 lanes cost 128 lanes.
+# On the chip the padded row wins there too (last line): lowering the
+# constant is the next step, once the CPU tests' cost of it is dealt with.
+MILLER_LANES = 128          # accumulators of the wide loop: one row of vector lanes
+MILLER_WIDE_FROM = 33       # fewer pair lanes than this keep ONE accumulator
+
+
+def _lines_per_accumulator(n_pairs: int, w: int) -> int:
+    """Lines one of w accumulators takes a step: the smallest power of two
+    g with w * g >= n_pairs - 1 (the one pair over — the signature pair of
+    a full bucket — is folded into lane 0 on its own)."""
+    g = 1
+    while w * g < n_pairs - 1:
+        g *= 2
+    return g
+
+
+def miller_lane_plan(n_pairs: int) -> tuple:
+    """What miller_loop_product does with n_pairs pair lanes:
+    (W, in_step_levels, after_loop_levels). Pure, no jit — the loop follows
+    it, the backend's plan counter reads it.
+
+    From MILLER_WIDE_FROM pair lanes on, W = MILLER_LANES accumulators: the
+    pair axis is padded with masked lanes to W * g (g a power of two, + the
+    one pair over), each accumulator takes g lines a step — one sparse
+    line (g = 1), a line pair (g = 2), or line pairs and in_step_levels =
+    log2(g) - 1 dense tree levels — and one product tree of
+    after_loop_levels levels narrows the W accumulators after the loop.
+    Below that, W = 1 and the whole tree over the n // 2 line pairs stays
+    in the step."""
+    assert n_pairs >= 1
+    if n_pairs < MILLER_WIDE_FROM:
+        return 1, max(n_pairs // 2, 1).bit_length() - 1, 0
+    w = MILLER_LANES
+    g = _lines_per_accumulator(n_pairs, w)
+    return w, max(g.bit_length() - 2, 0), w.bit_length() - 1
 
 
 def miller_loop_product(p_aff, q_aff, valid_mask):
-    """Multi-pairing Miller loop with ONE shared accumulator f.
+    """Multi-pairing Miller loop over W shared accumulators (W from
+    miller_lane_plan; W = 1 is one shared f).
 
-    Per bit: a single fq12_sqr (instead of one per pair), each pair's line
-    folded in through a sparse line-pair product tree. Returns the Miller
-    value prod_i f_i as one Fq12 (conjugated for x < 0)."""
+    Per bit: one fq12_sqr of the W accumulators (instead of one per pair),
+    each pair's line folded into its accumulator — sparsely, or through
+    the sparse line-pair product and the in-step levels of the product
+    tree. Returns the Miller value prod_i f_i as one Fq12 (conjugated for
+    x < 0)."""
     xp, yp = p_aff
     xq, yq = q_aff
-    r = co.affine_to_jac(co.FQ2_OPS, (xq, yq))
+    n = xp.shape[0]
+    w = miller_lane_plan(n)[0]
+    g = _lines_per_accumulator(n, w)
     f = tw.FQ12_ONE
+    if w > 1:
+        f = jnp.broadcast_to(f, (w,) + f.shape)
+        pad = max(w * g - n, 0)
+        if pad:
+            # masked lanes up to whole rows: they run on zeros, their lines
+            # are the identity line
+            def z(a):
+                return jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
+
+            xp, yp, xq, yq = z(xp), z(yp), z(xq), z(yq)
+            valid_mask = z(jnp.asarray(valid_mask, bool))
+    r = co.affine_to_jac(co.FQ2_OPS, (xq, yq))
     bits_arr = jnp.asarray(np.array([int(b) for b in _X_BITS], np.uint32))
+
+    def times_lines(f, line):
+        if w > 1 and g == 1:
+            # one sparse line an accumulator; the pair over goes into lane 0
+            l0, l1, l2 = _mask_lines(line, valid_mask)
+            f = tw.fq12_mul_by_014(f, l0[:w], l1[:w], l2[:w])
+            if l0.shape[0] > w:
+                f = _set_lane0(
+                    f, tw.fq12_mul_by_014(f[0:1], l0[w:], l1[w:], l2[w:])
+                )
+            return f
+        fs = _combine_lines(line, valid_mask, w)
+        return tw.fq12_mul(f, fs if w > 1 else fs[0])
 
     def step(carry, bit):
         f, r = carry
         f = tw.fq12_sqr(f)
         r, line = _dbl_step(r, xp, yp)
-        f = tw.fq12_mul(f, _combine_lines(line, valid_mask))
+        f = times_lines(f, line)
 
         def with_add(op):
             f_, r_ = op
             r2, line2 = _add_step(r_, (xq, yq), xp, yp)
-            return (tw.fq12_mul(f_, _combine_lines(line2, valid_mask)), r2)
+            return (times_lines(f_, line2), r2)
 
         f, r = lax.cond(bit == 1, with_add, lambda op: op, (f, r))
         return (f, r), None
 
     (f, r), _ = lax.scan(step, (f, r), bits_arr)
+    if w > 1:
+        f = fq12_product_any(f)
     return tw.fq12_conj(f)          # x < 0: conjugate the Miller value
 
 

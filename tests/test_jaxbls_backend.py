@@ -225,7 +225,8 @@ def test_block_shaped_batch_through_signature_batch_parity(damaged):
     backend on the same operands: a valid block is True on both, one bad
     set in any width class makes it False on both, and the bucket-fill and
     lane-addition counters move by exactly what was sent over what the
-    bucket holds and what tree_sum_plan says the key-axis sum does."""
+    bucket holds, what tree_sum_plan says the key-axis sum does and what
+    miller_lane_plan says the Miller loop carries."""
     import lighthouse_tpu.crypto.jaxbls.backend as be
     from lighthouse_tpu.state_transition.block import SignatureBatch
 
@@ -251,6 +252,11 @@ def test_block_shaped_batch_through_signature_batch_parity(damaged):
         {("adds", kind): be._TREE_SUM_LANE_ADDS.labels(kind)
          for kind in ("done", "needed")}
     )
+    # the Miller loop's plan for the bucket's 8 + 1 pair lanes
+    slots.update(
+        {("miller", kind): be._MILLER_PLAN.labels(kind)
+         for kind in ("dispatches", "accumulators", "in_step_levels")}
+    )
     before = {k: c.value for k, c in slots.items()}
     bls_api.set_backend("jax")
     on_jax = batch.verify()
@@ -264,9 +270,14 @@ def test_block_shaped_batch_through_signature_batch_parity(damaged):
     # (8, 4): four keys a set sum unrolled, 3 adds on each of 8 set lanes;
     # 14 real keys in 7 sets need 7
     assert be.co.tree_sum_plan(4, 8) == (4, 0, 2, 24)
+    # 9 pairs: one accumulator, both levels of the 4-lane tree in the step
+    assert be.po.miller_lane_plan(9) == (1, 2, 0)
     assert moved == {("sets", "real"): 7, ("sets", "padded"): 8,
                      ("keys", "real"): 14, ("keys", "padded"): 32,
-                     ("adds", "done"): 24, ("adds", "needed"): 7}
+                     ("adds", "done"): 24, ("adds", "needed"): 7,
+                     ("miller", "dispatches"): 1,
+                     ("miller", "accumulators"): 1,
+                     ("miller", "in_step_levels"): 2}
     # the pure-Python verify went nowhere near the device counters
     assert {k: c.value - before[k] for k, c in slots.items()} == moved
 

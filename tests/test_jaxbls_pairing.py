@@ -4,6 +4,7 @@ The device pairing uses unit-scaled lines, so raw Miller values differ from
 the ground truth by Fq2 units — equality is checked after final
 exponentiation (the only form consensus code ever uses)."""
 
+import functools
 import random
 
 import jax
@@ -40,6 +41,7 @@ _full_pairing = jax.jit(
     lambda p, q, m: po.final_exponentiation(po.fq12_product(po.miller_loop_batch(p, q, m)))
 )
 _product_check = jax.jit(po.pairing_product_is_one)
+_final_exp = jax.jit(po.final_exponentiation)
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -105,5 +107,122 @@ def test_final_exp_matches_python_on_random_miller_output():
     q = pc.g2_mul(pc.G2_GEN, rng.randrange(1, R))
     m = pp.miller_loop([(p, q)])
     dm = tw.fq12_to_device(m)
-    got = tw.fq12_from_device(jax.jit(po.final_exponentiation)(dm))
+    got = tw.fq12_from_device(_final_exp(dm))
     assert got == pp.final_exponentiation(m)
+
+
+# ------------------------------------------- the Miller loop's lane plan
+
+# (pair lanes, plan): W accumulators, dense tree levels left in each step,
+# levels of the one tree after the loop. 5 / 65 / 257 are the served
+# buckets (4x128, 64x128, 256x512); 17 and 65 are also a chip's share of
+# the gossip and block buckets' pairs (65 -> 68, 257 -> 260) on a four-chip
+# `sets` mesh.
+_PLAN_ROWS = [
+    (1, (1, 0, 0)), (2, (1, 0, 0)), (3, (1, 0, 0)), (5, (1, 1, 0)),
+    (9, (1, 2, 0)), (17, (1, 3, 0)), (33, (128, 0, 7)), (65, (128, 0, 7)),
+    (129, (128, 0, 7)), (257, (128, 0, 7)), (258, (128, 1, 7)),
+]
+
+
+@pytest.mark.parametrize("n_pairs,plan", _PLAN_ROWS,
+                         ids=[str(n) for n, _ in _PLAN_ROWS])
+def test_miller_lane_plan(n_pairs, plan):
+    w, in_step, after = po.miller_lane_plan(n_pairs)
+    assert (w, in_step, after) == plan
+    if w == 1:
+        # today's loop: the whole tree over the line pairs is in the step
+        assert n_pairs < po.MILLER_WIDE_FROM and after == 0
+        return
+    # a full row of accumulators, each taking g lines a step: g the
+    # smallest power of two that seats every pair but the one over
+    g = po._lines_per_accumulator(n_pairs, w)
+    assert w == po.MILLER_LANES and g & (g - 1) == 0
+    assert w * g >= n_pairs - 1 and (g == 1 or w * g // 2 < n_pairs - 1)
+    # a sparse line or a line pair needs no dense level; each doubling of
+    # g beyond that leaves one in the step; the rest is one tree, after
+    assert in_step == max(g.bit_length() - 2, 0)
+    assert after == w.bit_length() - 1
+
+
+@pytest.fixture(scope="module")
+def miller_loops():
+    """miller_loop_product at nine pair lanes under three plans: "w1" one
+    accumulator (today's loop), "pairs" four accumulators of a line pair
+    each + the pair over (the block bucket's form), "padded" sixteen
+    accumulators of one sparse line, seven of them padding (the gossip
+    bucket's form). The module's three Miller-loop compiles."""
+    shipped = po.MILLER_LANES, po.MILLER_WIDE_FROM
+    plans = {"w1": (128, 1 << 30, (1, 2, 0), 8),
+             "pairs": (4, 0, (4, 0, 2), 2),
+             "padded": (16, 0, (16, 0, 4), 1)}
+    fns = {}
+    try:
+        for name, (lanes, wide_from, plan, g) in plans.items():
+            po.MILLER_LANES, po.MILLER_WIDE_FROM = lanes, wide_from
+            assert po.miller_lane_plan(9) == plan
+            assert po._lines_per_accumulator(9, plan[0]) == g
+            fn = jax.jit(lambda p, q, m: po.miller_loop_product(p, q, m))
+            # trace and compile now, while the patched plan is in force
+            fns[name] = fn.lower(*_device_pairs([], 9)).compile()
+    finally:
+        po.MILLER_LANES, po.MILLER_WIDE_FROM = shipped
+    return fns
+
+
+@functools.cache
+def _eight_pairs():
+    """(a_i, b_i) and the pairs (a_i G1, b_i G2), i < 8."""
+    r = random.Random(0x9A1)
+    ab = [(r.randrange(1, R), r.randrange(1, R)) for _ in range(8)]
+    return ab, [(pc.g1_mul(pc.G1_GEN, a), pc.g2_mul(pc.G2_GEN, b))
+                for a, b in ab]
+
+
+def _nine_lanes(masked, tamper=False, filler=None):
+    """Device operands of nine pair lanes. Lanes 0-7 hold e(a_i G1, b_i G2);
+    those in `masked` are padding (zeroed as the backend pads, or holding
+    the real pair `filler`); lane 8 closes the product of the live ones to
+    1 with e(-sum(a_i b_i) G1, G2) — off by one G1 when `tamper`."""
+    ab, pairs = _eight_pairs()
+    total = sum(a * b for i, (a, b) in enumerate(ab) if i not in masked)
+    lanes = list(pairs)
+    lanes.append((pc.g1_neg(pc.g1_mul(pc.G1_GEN, (total + tamper) % R)),
+                  pc.G2_GEN))
+    for i in masked:
+        lanes[i] = filler or (None, None)
+    dp, dq, _ = _device_pairs(lanes, 9)
+    mask = np.ones(9, bool)
+    mask[list(masked)] = False
+    return dp, dq, jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("tamper", [False, True], ids=["valid", "tampered"])
+def test_wide_accumulators_give_the_one_accumulator_miller_value(
+        miller_loops, tamper):
+    """Nine pairs, two masked lanes (3 and 6): the Miller value of four
+    and of sixteen accumulators equals one accumulator's limb for limb,
+    BEFORE final exponentiation, and the verdict after it is the product's
+    truth."""
+    dp, dq, mask = _nine_lanes({3, 6}, tamper)
+    narrow = np.asarray(miller_loops["w1"](dp, dq, mask))
+    for name in ("pairs", "padded"):
+        wide = miller_loops[name](dp, dq, mask)
+        assert np.array_equal(np.asarray(wide), narrow), name
+        assert bool(tw.fq12_eq_one(_final_exp(wide))) is (not tamper)
+
+
+def test_all_masked_accumulator_lane_leaves_the_product_unchanged(
+        miller_loops):
+    """With four accumulators over nine lanes, accumulator 1 holds lanes 1
+    and 5. Masked both, it stays 1 whatever the padding holds: zeros and a
+    real pair give the same Miller value, which is one accumulator's and
+    still verifies."""
+    zeros = _nine_lanes({1, 5})
+    junk = _nine_lanes({1, 5}, filler=(pc.G1_GEN, pc.G2_GEN))
+    wide = miller_loops["pairs"](*zeros)
+    assert np.array_equal(np.asarray(wide),
+                          np.asarray(miller_loops["pairs"](*junk)))
+    assert np.array_equal(np.asarray(wide),
+                          np.asarray(miller_loops["w1"](*zeros)))
+    assert bool(tw.fq12_eq_one(_final_exp(wide)))
