@@ -37,6 +37,7 @@ from ..types.state_util import clone_state
 from ..types import helpers as h
 from ..types.spec import ChainSpec, DOMAIN_BEACON_ATTESTER
 from ..utils.slot_clock import SlotClock
+from .aggregate_batch import AggregateBatch
 from .pubkey_cache import ValidatorPubkeyCache
 
 # Validator-monitor attribution failures survived in place (the block is
@@ -1327,15 +1328,16 @@ class BeaconChain:
 
         return handle, continuation
 
-    def verify_aggregated_attestations(self, signed_aggregates) -> list:
-        """Batch gossip verification of SignedAggregateAndProof messages:
-        3 signature sets each (selection proof, aggregator signature,
-        indexed attestation) verified in ONE batch
-        (attestation_verification/batch.rs:31-135)."""
+    def _prepare_aggregate_batch(self, signed_aggregates):
+        """Host phase of aggregate gossip verification: drop observed
+        aggregators and unverifiable aggregates, build each remaining
+        SignedAggregateAndProof's 3 signature sets (selection proof,
+        aggregator signature, indexed attestation). Returns
+        ([(signed, attesting)], AggregateBatch), index for index."""
         spec = self.spec
         get_pubkey = self.pubkey_cache.pubkey_getter()
         prepared = []
-        sets = []
+        batch = AggregateBatch()
         for signed in signed_aggregates:
             msg = signed.message
             att = msg.aggregate
@@ -1361,30 +1363,63 @@ class BeaconChain:
                 attesting_indices=sorted(attesting), data=data, signature=att.signature
             )
             try:
-                trio = [
+                batch.add(
                     sigs.selection_proof_set(
                         state, spec, types, data.slot, msg.aggregator_index,
                         msg.selection_proof, get_pubkey,
                     ),
                     sigs.aggregate_and_proof_set(state, spec, types, signed, get_pubkey),
                     sigs.indexed_attestation_set(state, spec, types, indexed, get_pubkey),
-                ]
+                )
             except sigs.SignatureSetError:
                 continue
-            prepared.append((signed, attesting, trio))
-            sets.extend(trio)
-        if not sets:
-            return []
-        ok = bls.verify_signature_sets(sets)
+            prepared.append((signed, attesting))
+        return prepared, batch
+
+    def _complete_aggregate_batch(self, prepared, verdicts) -> list:
+        """Device-result phase: record the aggregators of the aggregates
+        that verified, return their (aggregate, attesting_indices)."""
         results = []
-        for signed, attesting, trio in prepared:
-            valid = ok or bls.verify_signature_sets(trio)
+        for (signed, attesting), valid in zip(prepared, verdicts):
             if valid:
                 self.observed_aggregators.add(
                     (signed.message.aggregate.data.target.epoch, signed.message.aggregator_index)
                 )
                 results.append((signed.message.aggregate, attesting))
         return results
+
+    def verify_aggregated_attestations(self, signed_aggregates) -> list:
+        """Batch gossip verification of SignedAggregateAndProof messages:
+        3 signature sets each, verified in ONE batch; a False batch is
+        re-verified trio by trio (attestation_verification/batch.rs:31-135,
+        chain/aggregate_batch.py). The synchronous form of
+        submit_aggregate_batch."""
+        prepared, batch = self._prepare_aggregate_batch(signed_aggregates)
+        if not prepared:
+            return []
+        return self._complete_aggregate_batch(prepared, batch.verify())
+
+    def submit_aggregate_batch(self, signed_aggregates, on_done=None):
+        """Pipelined form: prepare on host, submit async to the device, and
+        return (handle, continuation). The continuation — run when the
+        processor resolves the handle — gives every aggregate its verdict,
+        records the observed aggregators and returns the verified
+        (aggregate, attesting_indices), which it also hands to
+        on_done. Returns None (after on_done([])) if nothing verifiable."""
+        prepared, batch = self._prepare_aggregate_batch(signed_aggregates)
+        if not prepared:
+            if on_done is not None:
+                on_done([])
+            return None
+        handle, verdicts_of = batch.submit()
+
+        def continuation(ok: bool):
+            results = self._complete_aggregate_batch(prepared, verdicts_of(ok))
+            if on_done is not None:
+                on_done(results)
+            return results
+
+        return handle, continuation
 
     def verify_sync_committee_message(self, msg) -> bool:
         """Gossip verification of a single SyncCommitteeMessage

@@ -91,6 +91,14 @@ _BUCKET_SLOTS = REGISTRY.counter_vec(
     "holds; real over padded is the bucket's fill",
     ("axis", "kind"),
 )
+_DISPATCH_MESSAGES = REGISTRY.counter_vec(
+    "jaxbls_dispatch_messages_total",
+    "messages of the real sets per dispatch: sent = one a set, distinct = "
+    "different byte strings among them; each set is hashed to G2 and "
+    "paired on its own today, so distinct over sent is the share of that "
+    "work a dispatch with shared messages would still need",
+    ("kind",),
+)
 _TREE_SUM_LANE_ADDS = REGISTRY.counter_vec(
     "jaxbls_tree_sum_lane_additions_total",
     "point additions of the key-axis sum in prepare, per dispatch: done = "
@@ -794,6 +802,9 @@ class JaxBackend:
         _BUCKET_SLOTS.labels("sets", "padded").inc(n)
         _BUCKET_SLOTS.labels("keys", "real").inc(real_keys)
         _BUCKET_SLOTS.labels("keys", "padded").inc(n * m)
+        distinct_messages = len({s.message for s in sets})
+        _DISPATCH_MESSAGES.labels("sent").inc(n_real)
+        _DISPATCH_MESSAGES.labels("distinct").inc(distinct_messages)
         _TREE_SUM_LANE_ADDS.labels("done").inc(co.tree_sum_plan(m, n)[3])
         _TREE_SUM_LANE_ADDS.labels("needed").inc(real_keys - n_real)
         miller_pairs = n + 1
@@ -849,7 +860,8 @@ class JaxBackend:
         tr = _obs.current_trace()
         if tr is not None:
             tr.annotate(bucket=f"{n}x{m}", real_sets=n_real,
-                        real_keys=real_keys)
+                        real_keys=real_keys,
+                        distinct_messages=distinct_messages)
 
         def dispatch():
             # each stage dispatch runs under a named annotation scope;

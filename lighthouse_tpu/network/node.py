@@ -649,6 +649,20 @@ class NetworkNode:
 
         return resolve
 
+    def _locked_continuation(self, pair):
+        """A chain submission's (handle, continuation) with the
+        continuation's chain mutation under the same lock the inline
+        handlers use; None stays None."""
+        if pair is None:
+            return None
+        handle, cont = pair
+
+        def wrapped(ok: bool):
+            with self._lock:
+                return cont(ok)
+
+        return handle, wrapped
+
     def _run_attestation_batch(self, payloads):
         """Coalesced batch runner (pump thread): delegates the whole
         prepare -> ONE async device submission -> complete/fork-choice
@@ -686,16 +700,7 @@ class NetworkNode:
                 for _a, mid in payloads:
                     self.gossipsub.report_validation_result(mid, None)
                 return None
-        if pair is None:
-            return None
-        handle, cont = pair
-
-        def wrapped(ok: bool):
-            # chain mutation under the same lock the inline handlers use
-            with self._lock:
-                return cont(ok)
-
-        return handle, wrapped
+        return self._locked_continuation(pair)
 
     def _on_aggregate(self, msg):
         spec = self.chain.spec
@@ -738,31 +743,38 @@ class NetworkNode:
             return True if results else None
 
     def _run_aggregate_batch(self, payloads):
-        """Coalesced aggregate runner: one multi-set device verification for
-        the whole batch (3 sets per aggregate), then per-message gossip
-        resolution (process_gossip_aggregate_batch analog)."""
+        """Coalesced aggregate runner (pump thread): delegates the whole
+        prepare -> ONE async device submission (3 sets per aggregate) ->
+        one verdict an aggregate pipeline to chain.submit_aggregate_batch,
+        adding the fork-choice votes, the op pool and the per-message
+        gossip resolution (process_gossip_aggregate_batch analog)."""
         types = types_for_slot(self.chain.spec, self.chain.current_slot)
         signeds = [s for s, _mid in payloads]
-        with self._lock:
-            try:
-                results = self.chain.verify_aggregated_attestations(signeds)
-            except (AttestationError, BlockError):
-                results = []
+
+        def on_done(results):
+            # results are the verified (aggregate, indices); map back to
+            # the submitted containers by identity of the embedded
+            # aggregate. Anything else (duplicate aggregator, unverifiable,
+            # failed) is a terminal ignore, never a penalty
             valid_atts = set()
             for att, indices in results:
                 valid_atts.add(id(att))
                 self.chain.apply_attestation_to_fork_choice(att, indices)
                 if self.op_pool is not None:
                     self.op_pool.insert_attestation(att, indices, types)
-        # verify_aggregated_attestations returns the verified (aggregate,
-        # indices); map back to the submitted containers by identity of the
-        # embedded aggregate
-        for signed, mid in payloads:
-            self.gossipsub.report_validation_result(
-                mid,
-                True if id(signed.message.aggregate) in valid_atts else None,
-            )
-        return None
+            for signed, mid in payloads:
+                self.gossipsub.report_validation_result(
+                    mid,
+                    True if id(signed.message.aggregate) in valid_atts else None,
+                )
+
+        with self._lock:
+            try:
+                pair = self.chain.submit_aggregate_batch(signeds, on_done=on_done)
+            except (AttestationError, BlockError):
+                on_done([])
+                return None
+        return self._locked_continuation(pair)
 
     def _on_blob(self, msg):
         spec = self.chain.spec

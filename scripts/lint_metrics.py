@@ -41,6 +41,7 @@ METRIC_MODULES = (
     "lighthouse_tpu.network.sync",
     "lighthouse_tpu.observability.propagation",
     "lighthouse_tpu.chain.beacon_chain",
+    "lighthouse_tpu.chain.aggregate_batch",
     "lighthouse_tpu.state_transition.block",
     "lighthouse_tpu.loadgen.netfaults",
     "lighthouse_tpu.loadgen.meshsim",

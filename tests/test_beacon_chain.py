@@ -157,6 +157,117 @@ def test_attestation_gossip_batch(env):
     assert chain.verify_unaggregated_attestations(singles) == []
 
 
+def _signed_aggregates(harness, slot, atts):
+    """Two SignedAggregateAndProof a committee of `slot`: its first two
+    members each aggregate the committee's full attestation."""
+    from lighthouse_tpu.ssz.core import uint64
+    from lighthouse_tpu.state_transition import accessors as acc
+    from lighthouse_tpu.types import helpers as hlp
+    from lighthouse_tpu.types.spec import (
+        DOMAIN_AGGREGATE_AND_PROOF,
+        DOMAIN_SELECTION_PROOF,
+    )
+
+    spec = harness.spec
+    types = types_for_slot(spec, slot)
+    st = clone_state(harness.state, spec)
+    epoch = acc.get_current_epoch(st, spec)
+    cache = acc.build_committee_cache(st, spec, epoch)
+    proof_root = hlp.compute_signing_root(
+        uint64, slot, hlp.get_domain(st, spec, DOMAIN_SELECTION_PROOF, epoch)
+    )
+    domain = hlp.get_domain(st, spec, DOMAIN_AGGREGATE_AND_PROOF, epoch)
+    signeds = []
+    for index in range(cache.committees_per_slot):
+        for vi in cache.committee(slot, index)[:2]:
+            msg = types.AggregateAndProof.make(
+                aggregator_index=vi,
+                # its own container, as off the wire: the node resolves
+                # gossip messages by the identity of the aggregate
+                aggregate=types.Attestation.deserialize(
+                    types.Attestation.serialize(atts[index])
+                ),
+                selection_proof=bls.sign(harness.sk(vi), proof_root).serialize(),
+            )
+            root = hlp.compute_signing_root(types.AggregateAndProof, msg, domain)
+            signeds.append(types.SignedAggregateAndProof.make(
+                message=msg, signature=bls.sign(harness.sk(vi), root).serialize(),
+            ))
+    return signeds
+
+
+def test_aggregate_batch_pipelined_equals_synchronous(env):
+    """submit_aggregate_batch's continuation returns what
+    verify_aggregated_attestations returns for the same aggregates (one of
+    them with a bad aggregator signature: the batch is False and the trio
+    fallback drops exactly that one), a duplicate aggregator is dropped,
+    and NetworkNode._run_aggregate_batch hands the processor a
+    (handle, continuation) that resolves every gossip message."""
+    import threading
+    from types import SimpleNamespace
+
+    from lighthouse_tpu.chain import aggregate_batch as ab
+    from lighthouse_tpu.network.node import NetworkNode
+
+    harness, chain = env
+    slot = harness.state.slot
+    types = types_for_slot(harness.spec, slot)
+    atts = harness.build_attestations(
+        clone_state(harness.state, harness.spec), slot, chain.head_root
+    )
+    signeds = _signed_aggregates(harness, slot, atts)
+    assert len(signeds) >= 2
+    bad = signeds[-1]
+    signeds[-1] = types.SignedAggregateAndProof.make(
+        message=bad.message, signature=signeds[0].signature
+    )
+    observed = set(chain.observed_aggregators)
+
+    def shape(results):
+        return [(id(att), indices) for att, indices in results]
+
+    fallback0 = ab._BATCH_FALLBACK.value
+    want = chain.verify_aggregated_attestations(signeds)
+    assert shape(want) == [
+        (id(s.message.aggregate), want[i][1]) for i, s in enumerate(signeds[:-1])
+    ]
+    assert ab._BATCH_FALLBACK.value - fallback0 == len(signeds)
+    recorded = set(chain.observed_aggregators)
+    assert len(recorded - observed) == len(signeds) - 1
+
+    chain.observed_aggregators = set(observed)
+    done = []
+    handle, continuation = chain.submit_aggregate_batch(signeds, on_done=done.append)
+    assert done == [] and chain.observed_aggregators == observed
+    got = continuation(handle.result())
+    assert shape(got) == shape(want) and shape(done[0]) == shape(want)
+    assert chain.observed_aggregators == recorded
+    # every aggregator but the refused one is observed now: a duplicate is
+    # dropped at prepare, and with nothing left there is nothing to submit
+    assert chain.submit_aggregate_batch(signeds[:-1], on_done=done.append) is None
+    assert done[1] == []
+    assert chain.verify_aggregated_attestations(signeds[:-1]) == []
+
+    chain.observed_aggregators = set(observed)
+    reports = {}
+    # the runner's own code on a node that was never started: no sockets
+    node = NetworkNode.__new__(NetworkNode)
+    node.chain, node.op_pool, node._lock = chain, None, threading.RLock()
+    node.gossipsub = SimpleNamespace(
+        report_validation_result=lambda mid, res: reports.update({mid: res})
+    )
+    handle, continuation = node._run_aggregate_batch(
+        [(s, f"mid{i}") for i, s in enumerate(signeds)]
+    )
+    assert hasattr(handle, "result") and reports == {}
+    assert shape(continuation(handle.result())) == shape(want)
+    assert reports == {
+        f"mid{i}": (True if i < len(signeds) - 1 else None)
+        for i in range(len(signeds))
+    }
+    assert chain.observed_aggregators == recorded
+
+
 def test_fork_revert_drops_bad_branch(env):
     """revert_to_fork_boundary rebuilds fork choice without the bad branch
     (fork_revert.rs analog)."""

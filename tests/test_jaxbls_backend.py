@@ -282,6 +282,88 @@ def test_block_shaped_batch_through_signature_batch_parity(damaged):
     assert {k: c.value - before[k] for k, c in slots.items()} == moved
 
 
+# ------------------------------------------------ an aggregate-shaped batch
+# Two SignedAggregateAndProof of one four-member committee as one batch:
+# per aggregate a one-key selection proof (both on the slot's message), a
+# one-key aggregator signature (a message each) and the aggregate (both on
+# the committee's AttestationData root, four and three of its keys) — 6
+# sets, widths 1, 1, 4, 1, 1, 3, four distinct messages, in the (8, 4)
+# bucket this module warms; so is a trio verified alone (3 sets).
+
+
+def _signed_by(sks, msg, valid=True):
+    agg = sum(sks) % R
+    if not valid:
+        agg = (agg + 1) % R
+    pks = [bls.PublicKey(cv.g1_mul(cv.G1_GEN, sk)) for sk in sks]
+    sig = bls.Signature(cv.g2_mul(bls_api.hash_to_g2_point(msg), agg))
+    return bls.SignatureSet(sig, pks, msg)
+
+
+@pytest.mark.parametrize(
+    "victim,role", [(None, None), (1, 0), (0, 1), (1, 2)],
+    ids=["valid", "selection_proof", "aggregator_signature", "aggregate"],
+)
+def test_aggregate_shaped_batch_through_aggregate_batch_parity(victim, role):
+    """`AggregateBatch.submit()` on the jax backend against the pure-Python
+    backend on the same operands: two valid aggregates are [True, True] on
+    both; one bad set in any role makes the batch False, and the trio
+    fallback gives exactly that aggregate False and the other True on
+    both. The dispatch counts 6 messages sent and 4 distinct, and the span
+    and the batch's families are recorded."""
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+    from lighthouse_tpu.chain import aggregate_batch as ab
+    from lighthouse_tpu.observability import trace as obstrace
+
+    rng = random.Random(0xA66)
+    committee = [rng.randrange(1, R) for _ in range(4)]
+    slot_msg, att_msg = b"\x51" * 32, b"\x41" * 32
+    batch = ab.AggregateBatch()
+    for i, attesting in enumerate((committee, committee[:3])):
+        def sound(r):
+            return (i, r) != (victim, role)
+
+        batch.add(
+            _signed_by([committee[i]], slot_msg, sound(0)),
+            _signed_by([committee[i]], bytes([0x61 + i]) * 32, sound(1)),
+            _signed_by(attesting, att_msg, sound(2)),
+        )
+    want = [i != victim for i in range(2)]
+
+    sent = be._DISPATCH_MESSAGES.labels("sent")
+    distinct = be._DISPATCH_MESSAGES.labels("distinct")
+    fallback0 = ab._BATCH_FALLBACK.value
+    aggregates0, seconds0 = ab._BATCH_AGGREGATES.value, ab._BATCH_SECONDS.n
+    sent0, distinct0 = sent.value, distinct.value
+
+    bls_api.set_backend("jax")
+    assert be.padding_bucket(6, 4) == be.padding_bucket(3, 4) == (8, 4)
+    tr = obstrace.Trace("gossip_aggregate", 2)
+    obstrace.set_current_trace(tr)
+    try:
+        handle, continuation = batch.submit()
+    finally:
+        obstrace.set_current_trace(None)
+    ok = handle.result()
+    # the batch's own dispatch, before any trio goes down alone
+    assert (sent.value - sent0, distinct.value - distinct0) == (6, 4)
+    assert ok is (victim is None)
+    on_jax = continuation(ok)
+    # a False batch sends both trios down again, each alone
+    assert ab._BATCH_FALLBACK.value - fallback0 == (0 if victim is None else 2)
+    bls_api.set_backend("python")
+    on_python = batch.verify()
+
+    assert on_python == want
+    assert on_jax == on_python
+    assert ab._BATCH_FALLBACK.value - fallback0 == (0 if victim is None else 4)
+    assert ab._BATCH_AGGREGATES.value - aggregates0 == 4
+    assert ab._BATCH_SECONDS.n - seconds0 == 2
+    args = dict(aggregates=2, sets=6, distinct_messages=4, widest_keys=4)
+    assert [s[3] for s in tr.spans if s[0] == ab.BATCH_SPAN] == [args]
+    assert tr.meta["distinct_messages"] == 4 and tr.meta["bucket"] == "8x4"
+
+
 @pytest.mark.slow
 def test_shard_map_pairing_fallback_real_collective():
     """The REAL shard_map pair product: force the explicit-sharding jit to
