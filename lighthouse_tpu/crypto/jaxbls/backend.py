@@ -278,15 +278,32 @@ def _stage_pairs(z_pk, h_jac, sig_acc, set_mask):
 
 
 def _stage_pairing(px, py, qxx, qyy, pair_mask):
-    """Stage 4: shared-accumulator multi-Miller loop + final exponentiation."""
+    """Stage 4 as ONE program: shared-accumulator multi-Miller loop + final
+    exponentiation (_verify_kernel, the meshed jit build and one chip's
+    one-accumulator buckets; its wide buckets run the two programs below)."""
     return po.pairing_product_is_one((px, py), (qxx, qyy), pair_mask)
+
+
+def _stage_miller(px, py, qxx, qyy, pair_mask):
+    """Stage 4 of a wide bucket, first program: the shared-accumulator
+    multi-Miller loop, one Fq12 out. Compiled per bucket (n + 1 pairs)."""
+    return po.miller_loop_product((px, py), (qxx, qyy), pair_mask)
+
+
+def _stage_final_exp(f):
+    """Stage 4 of a wide bucket, second program: final exponentiation of
+    the Miller value and the comparison with one. No pair axis: one
+    program for every such bucket."""
+    return tw.fq12_eq_one(po.final_exponentiation(f))
 
 
 def _verify_kernel(pk_x, pk_y, pk_mask, sig_x, sig_y, us, z_digits, set_mask):
     """The full device program as ONE composition (kept for the sharding
-    tests and the multichip dryrun; the hot path runs the stages as
-    SEPARATE jit calls — smaller programs compile minutes faster and cache
-    independently, and intermediates stay device-resident between calls).
+    tests and the multichip dryrun; the hot path runs the four stages as
+    SEPARATE jit calls, five for a wide bucket on one chip — smaller
+    programs compile minutes faster and cache independently,
+    intermediates stay device-resident between calls, and stage 4's wide
+    Miller loop keeps the layout it has compiled alone: _PairingPrograms).
 
     Shapes:
       pk_x/pk_y: (n, m, NL)  padded pubkey affine coords, STANDARD form
@@ -313,6 +330,7 @@ _kernel_cache: dict = {}
 #: policy and its reasons: _get_stages' docstring)
 STAGE_DONATE_ARGNUMS = dict(
     prepare=(3, 4, 5), h2c=(0,), pairs=(0, 1, 2, 3), pairing=(0, 1, 2, 3, 4),
+    miller=(0, 1, 2, 3, 4), final_exp=(0,),
 )
 
 
@@ -376,6 +394,47 @@ def _build_shard_map_pairing(mesh):
         return sharded(px, py, qxx, qyy, pair_mask)
 
     return jax.jit(pairing)
+
+
+class _PairingPrograms:
+    """Stage 4 on one chip, under the one stage name. Where the Miller
+    loop carries a row of accumulators (miller_lane_plan's W > 1: every
+    batch bucket) it is TWO jitted programs, `_stage_miller` then
+    `_stage_final_exp`, enqueued back to back — the Miller value stays on
+    the device, the host waits for neither. They share no compilation on
+    purpose: beside final exponentiation's one-lane scans the compiler
+    lays the wide scan's point carry limb-minor and the stage takes 226 ms
+    where the two take 127 at 257 pairs, 116 where they take 89 at 65.
+    With one accumulator (the urgent bucket's 5 pairs, KZG's 4) the ONE
+    program `_stage_pairing` stays: there the scan already runs at its own
+    time and the two programs take 4.5 ms more than the one (157.8 against
+    153.3; all six on a v5e, scripts/measure_miller_lanes.py --stage,
+    PERF.md S6, PR 32). Callable like a jitted stage; `.lower` gives the
+    lowerings of what serves the shape, so program capture sees the whole
+    stage."""
+
+    def __init__(self, one, miller, final_exp):
+        self.one = one
+        self.miller = miller
+        self.final_exp = final_exp
+
+    @staticmethod
+    def _split(px) -> bool:
+        return po.miller_lane_plan(px.shape[0])[0] > 1
+
+    def __call__(self, px, py, qxx, qyy, pair_mask):
+        if not self._split(px):
+            return self.one(px, py, qxx, qyy, pair_mask)
+        with _obs_dev.annotation_scope("jaxbls:pairing.miller"):
+            f = self.miller(px, py, qxx, qyy, pair_mask)
+        with _obs_dev.annotation_scope("jaxbls:pairing.final_exp"):
+            return self.final_exp(f)
+
+    def lower(self, *args):
+        if not self._split(args[0]):
+            return self.one.lower(*args)
+        miller = self.miller.lower(*args)
+        return miller, self.final_exp.lower(miller.out_info)
 
 
 class _PairingDispatch:
@@ -445,11 +504,15 @@ class _PairingDispatch:
 
 
 def _get_stages(mesh=None):
-    """Jitted stage functions (each cached separately on disk).
+    """The four stage callables (each program cached separately on disk).
 
-    With `mesh=None` (the urgent single-chip lane, host-side callers like
-    aggregate_verify, and single-device processes) the stages are plain
-    jits — input placement decides the executable. With a mesh, the
+    Four stages, five programs a wide bucket: with `mesh=None` (the urgent
+    single-chip lane, host-side callers like aggregate_verify, and
+    single-device processes) stages 1-3 are plain jits — input placement
+    decides the executable — and stage 4 is a _PairingPrograms: from 33
+    pairs on the Miller loop and the final exponentiation as two jits
+    under the one stage name, because compiled together the wide Miller
+    scan runs at half its speed; below, the one program. With a mesh, the
     stages compile under that mesh's contract: explicit `in_shardings`
     over the 1-D `sets` (2-D `(sets, pks)`) axes for every host-marshalled
     input — exactly the NamedShardings `put_sets`/`put_pk_grid` commit, so
@@ -473,7 +536,9 @@ def _get_stages(mesh=None):
       h2c:     us (consumed into the SSWU map);
       pairs:   the stage-1/2 intermediates (z_pk, h_jac, sig_acc) and
                set_mask — all dead after pair assembly;
-      pairing: everything (the output is one scalar).
+      pairing: everything (the output is one scalar) — of a wide
+               bucket's two programs, the Miller loop its five inputs,
+               the final exponentiation its one.
 
     Cached per (donation mode, mesh signature) — tests flip
     LIGHTHOUSE_TPU_DONATE and the mesh seams within one process and both
@@ -503,7 +568,11 @@ def _get_stages(mesh=None):
                 jax.jit(_stage_prepare, **donate_kw["prepare"]),
                 jax.jit(h2.hash_to_g2_jacobian, **donate_kw["h2c"]),
                 jax.jit(_stage_pairs, **donate_kw["pairs"]),
-                jax.jit(_stage_pairing, **donate_kw["pairing"]),
+                _PairingPrograms(
+                    jax.jit(_stage_pairing, **donate_kw["pairing"]),
+                    jax.jit(_stage_miller, **donate_kw["miller"]),
+                    jax.jit(_stage_final_exp, **donate_kw["final_exp"]),
+                ),
             )
         else:
             from ...parallel import mesh as pm

@@ -26,8 +26,9 @@ dispatch & buffer donation"):
     pow2 buckets, whole-array placement on one device, the unsharded
     stage programs — mesh padding and collective latency never tax the
     ~ms path (`mesh_sharded_dispatch_total{lane}` counts both lanes).
-  - **input-buffer donation policy**: whether the four staged jit
-    programs are built with `donate_argnums` (crypto/jaxbls/backend.py
+  - **input-buffer donation policy**: whether the staged jit programs
+    (four stages; five programs for a batch bucket on one chip) are
+    built with `donate_argnums` (crypto/jaxbls/backend.py
     `_get_stages`). Donated per-batch inputs (sig/z/us/stage
     intermediates — never the cached pubkey grids) let XLA reuse their
     HBM for same-shaped intermediates instead of fresh allocations.

@@ -1,8 +1,17 @@
 """Per-stage device-time attribution for the jaxbls verify pipeline.
 
-The dispatch path runs four jit stages (prepare, hash-to-G2, pairs,
-pairing — `crypto/jaxbls/backend.py`) asynchronously: the host enqueues
-all four and blocks once, on the final result. That is the right shape
+The dispatch path runs four stages (prepare, hash-to-G2, pairs, pairing —
+`crypto/jaxbls/backend.py`) asynchronously: the host enqueues all four and
+blocks once, on the final result. Four stages, five jitted programs for a
+batch bucket on one chip: from 33 pairs on stage 4 is the Miller loop
+(`_stage_miller`, a program a bucket) and the final exponentiation
+(`_stage_final_exp`, one program for every such bucket), enqueued back to
+back under the one stage name `pairing` — compiled as one program the wide
+Miller scan takes twice its time; below 33 pairs (the urgent bucket) the one
+program `_stage_pairing` stays, which is the faster there (PERF.md S6, PR
+32). A profiler capture shows the two apart (`jaxbls:pairing.miller`,
+`jaxbls:pairing.final_exp`, the programs' own names); everything timed or
+counted here keeps the four stage names. That is the right shape
 for throughput, but it makes the device a single opaque span — PR 2's
 tracer shows one `device` stage and `jaxbls_device_wait_seconds` shows a
 coarse compile/execute split, and nothing says WHICH stage burns the
@@ -250,7 +259,7 @@ def profile_stages(
     n_sets: int, n_pks: int, reps: int = 3, seed: int = 7,
     analytics: bool = True,
 ) -> dict:
-    """Time the four real jitted stages standalone at one padding bucket:
+    """Time the four real stages standalone at one padding bucket:
     warm (first rep = residual compile), then `reps` timed resolves each,
     chaining real intermediates (prepare/h2c outputs feed pairs, pairs
     feeds pairing). THE stage-timing owner — scripts/profile_components.py

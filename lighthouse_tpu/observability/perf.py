@@ -112,30 +112,36 @@ def maybe_capture_program(stage: str, jitted_fn, args, bucket: tuple):
 
 def capture_program(stage: str, jitted_fn, args, bucket: tuple) -> dict | None:
     """Lower+compile one jit stage at concrete args and record its cost/
-    memory analysis. Best-effort: any failure returns None and records
-    nothing (a node on an exotic backend must not lose the verify path
-    to a diagnostics call)."""
+    memory analysis. A stage served as several programs run one after the
+    other (stage 4 of a wide bucket on one chip: `.lower` gives a tuple)
+    records their sum of flops, bytes accessed and generated code, and of
+    each memory region the largest — the programs never hold the device
+    together. Best-effort: any failure returns None and records nothing (a
+    node on an exotic backend must not lose the verify path to a
+    diagnostics call)."""
     n, m = int(bucket[0]), int(bucket[1])
     try:
-        compiled = jitted_fn.lower(*args).compile()
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        ca = ca or {}
-        stats = {
-            "flops": float(ca.get("flops", 0.0)),
-            "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
-        }
-        ma = compiled.memory_analysis()
-        if ma is not None:
-            stats.update(
-                argument_bytes=int(getattr(ma, "argument_size_in_bytes", 0)),
-                output_bytes=int(getattr(ma, "output_size_in_bytes", 0)),
-                temp_bytes=int(getattr(ma, "temp_size_in_bytes", 0)),
-                generated_code_bytes=int(
-                    getattr(ma, "generated_code_size_in_bytes", 0)
-                ),
-            )
+        lowered = jitted_fn.lower(*args)
+        stats = {"flops": 0.0, "bytes_accessed": 0.0}
+        for low in lowered if isinstance(lowered, tuple) else (lowered,):
+            compiled = low.compile()
+            ca = compiled.cost_analysis()
+            if isinstance(ca, (list, tuple)):
+                ca = ca[0] if ca else {}
+            ca = ca or {}
+            stats["flops"] += float(ca.get("flops", 0.0))
+            stats["bytes_accessed"] += float(ca.get("bytes accessed", 0.0))
+            ma = compiled.memory_analysis()
+            if ma is None:
+                continue
+            for region in ("argument", "output", "temp"):
+                stats[f"{region}_bytes"] = max(
+                    stats.get(f"{region}_bytes", 0),
+                    int(getattr(ma, f"{region}_size_in_bytes", 0)),
+                )
+            stats["generated_code_bytes"] = stats.get(
+                "generated_code_bytes", 0
+            ) + int(getattr(ma, "generated_code_size_in_bytes", 0))
     except Exception:
         return None
     record_program(stage, bucket, stats)

@@ -15,18 +15,25 @@ Measures, on the device JAX gives (a TPU, or it says so), what
      limb, to W = 1's;
   3. one traced call of `_stage_pairing` at 65 pairs with W = 1 (the
      program every PR up to 29 served): device seconds by op name, and the
-     compiled HLO beside it, to say which loop `%while.29` is.
+     compiled HLO beside it, to say which loop `%while.29` is;
+  4. (`--stage`, alone) stage 4 as one program and as the two that serve it,
+     at the three served pair counts with the shipped plan: ms a call of
+     `_stage_pairing`, of `_stage_miller`, of `_stage_final_exp`, and of the
+     two enqueued back to back with one `block_until_ready`, the verdicts
+     checked equal. The table behind `backend._PairingPrograms`, which
+     serves the two from 33 pairs on and the one below.
 
     chiprun --chips 1 -- python3 scripts/measure_miller_lanes.py \
-        [--budget-s N] [--jobs 65:1,65:128,...]
+        [--budget-s N] [--jobs 65:1,65:128,...] [--stage]
 
 Part 2 starts no further compile once N seconds (default 1500) have passed;
 `--jobs` runs part 2 alone, on the listed pairs:W (W = 1 first for each
-pair count: it is the reference); `--rehearse` runs toy sizes (a CPU dry
-run of the script, not a measurement). Prints one JSON object and writes
-it, as it grows, to chiprun_out/miller_lanes.json (a rehearsal's beside it,
-under another name). Not part of the benchmark; rerun it when the tower
-arithmetic or the chip changes.
+pair count: it is the reference); `--stage` runs part 4 alone;
+`--rehearse` runs toy sizes (a CPU dry run of the script, not a
+measurement). Prints one JSON object and writes it, as it grows, to
+chiprun_out/miller_lanes.json, part 4 to chiprun_out/stage_split.json (a
+rehearsal's beside it, under another name). Not part of the benchmark;
+rerun it when the tower arithmetic or the chip changes.
 """
 
 from __future__ import annotations
@@ -64,14 +71,23 @@ MILLER_JOBS = (
 TRACED_PAIRS = 65
 REPS = 5
 OUT = "chiprun_out/miller_lanes.json"
+STAGE_OUT = "chiprun_out/stage_split.json"
+STAGE_PAIRS = (5, 65, 257)
 
 
-def _timed(fn, *args):
-    jax.block_until_ready(fn(*args))          # compile + warm
+def _timed(fn, *args, fresh=False):
+    """(median, least) seconds a call over REPS calls after a warm one.
+    `fresh`: `args` are host arrays, placed anew before each call's clock
+    starts — for a program that donates its inputs."""
+    def placed():
+        return jax.block_until_ready(jax.device_put(args)) if fresh else args
+
+    jax.block_until_ready(fn(*placed()))      # compile + warm
     out = []
     for _ in range(REPS):
+        a = placed()
         t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
+        jax.block_until_ready(fn(*a))
         out.append(time.perf_counter() - t0)
     return statistics.median(out), min(out)
 
@@ -149,9 +165,10 @@ def _op_inputs(lanes: int, seed: int):
     return f, g, r, fq(), fq()
 
 
-def _save(out):
+def _save(out, path=OUT):
     # a rehearsal's numbers are the CPU's: never under the measurement's name
-    path = OUT if out["device"]["platform"] == "tpu" else OUT + ".rehearsal"
+    if out["device"]["platform"] != "tpu":
+        path += ".rehearsal"
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as fh:
         json.dump(out, fh, indent=1)
@@ -194,6 +211,40 @@ def _traced_stage(out, n_pairs: int):
     }
 
 
+def _stage_split(out, pair_counts) -> bool:
+    """Part 4: stage 4 as one program against two, the jits the backend
+    holds (donation as the platform has it): on the chip they are the
+    served executables and share their cache entries."""
+    served = be._get_stages()[3]
+    one = served.one
+
+    def back_to_back(*args):                  # no sync between the two
+        return served.final_exp(served.miller(*args))
+
+    same = True
+    for n in pair_counts:
+        p, q, mask = _pairs(n, n)
+        args = tuple(np.asarray(a) for a in (*p, *q, mask))
+        row = {"pairs": n, "accumulators": po.miller_lane_plan(n)[0]}
+        for name, fn in (("stage_pairing", one), ("stage_miller", served.miller),
+                         ("stage_final_exp", served.final_exp),
+                         ("back_to_back", back_to_back)):
+            a = args
+            if fn is served.final_exp:        # the Miller value, from the host
+                a = (np.asarray(served.miller(*jax.device_put(args))),)
+            t0 = time.perf_counter()
+            med, low = _timed(fn, *a, fresh=True)
+            row[name] = {"s": med, "min_s": low,
+                         "first_call_s": time.perf_counter() - t0 - med * REPS}
+        row["same_verdict"] = (bool(one(*jax.device_put(args)))
+                               == bool(back_to_back(*jax.device_put(args))))
+        same = same and row["same_verdict"]
+        out["stage_split"].append(row)
+        print(json.dumps(row), flush=True)
+        _save(out, STAGE_OUT)
+    return same
+
+
 def main() -> int:
     dev = jax.devices()[0]
     def option(name, default=None):
@@ -211,6 +262,13 @@ def main() -> int:
                      for j in jobs_alone.split(","))
         op_lanes = ()
     be._init_consts()
+
+    if "--stage" in sys.argv:
+        out = {"device": {"platform": dev.platform, "kind": dev.device_kind},
+               "stage_split": []}
+        same = _stage_split(out, (2, 5) if small else STAGE_PAIRS)
+        print(json.dumps(out))
+        return 0 if same else 1
 
     out = {"device": {"platform": dev.platform, "kind": dev.device_kind},
            "ops": [], "miller_loop_product": []}

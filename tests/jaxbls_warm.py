@@ -1,7 +1,7 @@
 """Compile the staged BLS programs of a test module once, in threads.
 
-Not a test file. The four staged programs (prepare, hash-to-G2, pairs,
-pairing) are large at any shape: on XLA:CPU one build of them costs
+Not a test file. The programs of the four stages (prepare, hash-to-G2,
+pairs, pairing) are large at any shape: on XLA:CPU one build of them costs
 minutes, and `tests/conftest.py` drops compiled executables at every file
 boundary, so each module that drives the real `JaxBackend` pays for its
 builds itself. The two modules that do (`test_jaxbls_backend.py`,

@@ -15,7 +15,8 @@ entry written for a described chip cannot be read back without one).
 
 Unmarked: the compiles of a few seconds (`pairs` at each served bucket's
 set count). `slow`: the minute-long stage compiles at the served 64x128
-bucket (prepare ~1 min, hash-to-G2 ~3 min, pairing ~2.5 min on eight host
+bucket (prepare ~1 min, hash-to-G2 ~3 min, of stage 4's two programs the
+Miller loop ~2 min and the final exponentiation ~1.25 min on eight host
 cores).
 """
 
@@ -66,8 +67,8 @@ def no_persistent_cache():
 
 
 def _stage_args(n: int, m: int, sharding) -> dict:
-    """Argument shapes of the four staged programs at bucket (n, m), as
-    the marshal and the previous stages produce them."""
+    """Argument shapes of the five programs of the four stages at bucket
+    (n, m), as the marshal and the previous programs produce them."""
     NL = lb.NL
 
     def u32(*shape):
@@ -82,8 +83,9 @@ def _stage_args(n: int, m: int, sharding) -> dict:
                     u32(n, 2, NL), u32(n, 2, NL), u32(n, be.Z_DIGITS), u32(n)),
         "h2c": (u32(n, 2, 2, NL),),
         "pairs": (g1, g2, acc, u32(n)),
-        "pairing": (u32(n + 1, NL), u32(n + 1, NL),
-                    u32(n + 1, 2, NL), u32(n + 1, 2, NL), mask),
+        "miller": (u32(n + 1, NL), u32(n + 1, NL),
+                   u32(n + 1, 2, NL), u32(n + 1, 2, NL), mask),
+        "final_exp": (u32(2, 3, 2, NL),),             # the Miller value
     }
 
 
@@ -91,7 +93,8 @@ _STAGE_FNS = {
     "prepare": be._stage_prepare,
     "h2c": h2.hash_to_g2_jacobian,
     "pairs": be._stage_pairs,
-    "pairing": be._stage_pairing,
+    "miller": be._stage_miller,
+    "final_exp": be._stage_final_exp,
 }
 
 
@@ -118,7 +121,8 @@ def _shapes(tree):
     *[("pairs", b) for b in SERVED_BUCKETS],
     pytest.param("prepare", (N_SETS, N_PKS), marks=pytest.mark.slow),
     pytest.param("h2c", (N_SETS, N_PKS), marks=pytest.mark.slow),
-    pytest.param("pairing", (N_SETS, N_PKS), marks=pytest.mark.slow),
+    pytest.param("miller", (N_SETS, N_PKS), marks=pytest.mark.slow),
+    pytest.param("final_exp", (N_SETS, N_PKS), marks=pytest.mark.slow),
 ], ids=lambda v: v if isinstance(v, str) else "%dx%d" % v)
 def test_stage_compiles_for_v5e(stage, bucket, one_chip,
                                 no_persistent_cache):
@@ -134,7 +138,9 @@ def test_stage_compiles_for_v5e(stage, bucket, one_chip,
     elif stage == "h2c":
         assert out == _shapes(args["pairs"][1])
     elif stage == "pairs":
-        assert out == _shapes(args["pairing"])
+        assert out == _shapes(args["miller"])
+    elif stage == "miller":
+        assert out == _shapes(args["final_exp"][0])
 
 
 def test_tree_hash_ladder_compiles_for_v5e(one_chip, no_persistent_cache):

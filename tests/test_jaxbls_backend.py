@@ -3,7 +3,7 @@ generic BLS API — the same dual-backend strategy the reference uses for
 blst vs fake_crypto (/root/reference/crypto/bls/tests/tests.rs).
 
 This is one of the two modules that drive the real JaxBackend through its
-four staged programs (the other is test_multichip.py). Each compiles its
+four stages (the other is test_multichip.py). Each compiles its
 programs once, in one module-scoped warm-up (tests/jaxbls_warm.py): a new
 test of the staged backend joins one of the two instead of opening a
 file, and keeps to the builds and key-count buckets its module warms."""
@@ -108,6 +108,43 @@ def test_stage_attribution_on_real_dispatch():
         assert obsdev.STAGE_DEVICE_SECONDS.labels(stage, n, m).n >= 1, stage
     device_spans = [s[0] for s in tr.spans if s[0].startswith("device:")]
     assert device_spans == [f"device:{s}" for s in obsdev.STAGES] * 2
+
+
+def test_pairing_stage_is_one_observation_a_dispatch():
+    """One chip's stage 4 is a _PairingPrograms under the ONE stage name:
+    the one program here (the urgent bucket's 5 pairs keep one
+    accumulator), two enqueued back to back from 33 pairs on (test_mesh
+    drives that branch with stand-ins; no toy bucket reaches it). A
+    dispatch with attribution on records one `pairing` resolve, no stage
+    label is new, and the verdicts are right."""
+    from lighthouse_tpu.observability import device as obsdev
+    from lighthouse_tpu.observability import trace as obstrace
+
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+
+    assert obsdev.STAGES == ("prepare", "h2c", "pairs", "pairing")
+    pairing = be._get_stages()[3]
+    assert isinstance(pairing, be._PairingPrograms)
+
+    backend = bls_api.set_backend("jax")
+    good, bad = _mk_set(1, b"\xcd" * 32), _mk_set(1, b"\xce" * 32, valid=False)
+    n, m = be.padding_bucket(1, 1, single_chip=True)
+    tr = obstrace.Trace("gossip_block", 1)
+    obstrace.set_current_trace(tr)
+    try:
+        with obsdev.attributed():
+            # the first resolve of a (stage, bucket) counts as its compile
+            assert backend.verify_signature_sets_urgent([good], [1])
+            seen = obsdev.STAGE_DEVICE_SECONDS.labels("pairing", n, m).n
+            assert backend.verify_signature_sets_urgent([good], [3])
+            assert not backend.verify_signature_sets_urgent([bad], [1])
+    finally:
+        obstrace.set_current_trace(None)
+    assert obsdev.STAGE_DEVICE_SECONDS.labels("pairing", n, m).n == seen + 2
+    assert [s[0] for s in tr.spans if s[0].startswith("device:")] == [
+        f"device:{s}" for s in obsdev.STAGES] * 3
+    for family in (obsdev.STAGE_DEVICE_SECONDS, obsdev.STAGE_COMPILE_SECONDS):
+        assert {key[0] for key, _ in family.children()} <= set(obsdev.STAGES)
 
 
 def test_single_verify_parity():

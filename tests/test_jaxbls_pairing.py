@@ -16,6 +16,7 @@ from lighthouse_tpu.crypto.bls381 import curve as pc
 from lighthouse_tpu.crypto.bls381 import fields as pyf
 from lighthouse_tpu.crypto.bls381 import pairing as pp
 from lighthouse_tpu.crypto.bls381.constants import R
+from lighthouse_tpu.crypto.jaxbls import backend as be
 from lighthouse_tpu.crypto.jaxbls import curve_ops as co
 from lighthouse_tpu.crypto.jaxbls import pairing_ops as po
 from lighthouse_tpu.crypto.jaxbls import tower as tw
@@ -42,6 +43,7 @@ _full_pairing = jax.jit(
 )
 _product_check = jax.jit(po.pairing_product_is_one)
 _final_exp = jax.jit(po.final_exponentiation)
+_stage_final_exp = jax.jit(be._stage_final_exp)
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -147,7 +149,8 @@ def test_miller_lane_plan(n_pairs, plan):
 
 @pytest.fixture(scope="module")
 def miller_loops():
-    """miller_loop_product at nine pair lanes under three plans: "w1" one
+    """The backend's `_stage_miller` (miller_loop_product over the stage's
+    five flat arguments) at nine pair lanes under three plans: "w1" one
     accumulator (today's loop), "pairs" four accumulators of a line pair
     each + the pair over (the block bucket's form), "padded" sixteen
     accumulators of one sparse line, seven of them padding (the gossip
@@ -162,9 +165,10 @@ def miller_loops():
             po.MILLER_LANES, po.MILLER_WIDE_FROM = lanes, wide_from
             assert po.miller_lane_plan(9) == plan
             assert po._lines_per_accumulator(9, plan[0]) == g
-            fn = jax.jit(lambda p, q, m: po.miller_loop_product(p, q, m))
             # trace and compile now, while the patched plan is in force
-            fns[name] = fn.lower(*_device_pairs([], 9)).compile()
+            dp, dq, mask = _device_pairs([], 9)
+            fns[name] = jax.jit(be._stage_miller).lower(
+                *dp, *dq, mask).compile()
     finally:
         po.MILLER_LANES, po.MILLER_WIDE_FROM = shipped
     return fns
@@ -194,7 +198,7 @@ def _nine_lanes(masked, tamper=False, filler=None):
     dp, dq, _ = _device_pairs(lanes, 9)
     mask = np.ones(9, bool)
     mask[list(masked)] = False
-    return dp, dq, jnp.asarray(mask)
+    return (*dp, *dq, jnp.asarray(mask))      # _stage_miller's arguments
 
 
 @pytest.mark.parametrize("tamper", [False, True], ids=["valid", "tampered"])
@@ -204,12 +208,29 @@ def test_wide_accumulators_give_the_one_accumulator_miller_value(
     and of sixteen accumulators equals one accumulator's limb for limb,
     BEFORE final exponentiation, and the verdict after it is the product's
     truth."""
-    dp, dq, mask = _nine_lanes({3, 6}, tamper)
-    narrow = np.asarray(miller_loops["w1"](dp, dq, mask))
+    lanes = _nine_lanes({3, 6}, tamper)
+    narrow = np.asarray(miller_loops["w1"](*lanes))
     for name in ("pairs", "padded"):
-        wide = miller_loops[name](dp, dq, mask)
+        wide = miller_loops[name](*lanes)
         assert np.array_equal(np.asarray(wide), narrow), name
         assert bool(tw.fq12_eq_one(_final_exp(wide))) is (not tamper)
+
+
+@pytest.mark.parametrize("tamper", [False, True], ids=["valid", "tampered"])
+@pytest.mark.parametrize("form", ["w1", "padded"])
+def test_stage_four_as_two_programs_gives_the_product_checks_verdict(
+        miller_loops, form, tamper):
+    """Stage 4 as one chip serves a wide bucket,
+    `_stage_final_exp(_stage_miller(...))`, the Miller value handed from
+    one program to the other: the verdict is pairing_product_is_one's —
+    that function's own two steps over the same Miller value — and the
+    product's truth, at a padded row of accumulators (the served form) and
+    at one accumulator."""
+    f = miller_loops[form](*_nine_lanes({3, 6}, tamper))
+    assert f.shape == tw.FQ12_ONE.shape
+    ok = _stage_final_exp(f)
+    assert ok.shape == () and ok.dtype == jnp.bool_
+    assert bool(ok) is bool(tw.fq12_eq_one(_final_exp(f))) is (not tamper)
 
 
 def test_all_masked_accumulator_lane_leaves_the_product_unchanged(

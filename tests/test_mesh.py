@@ -271,6 +271,99 @@ def test_stage_cache_keyed_by_mesh_and_donation(monkeypatch):
     assert isinstance(
         be._kernel_cache["stages_d0_sets8"][3], be._PairingDispatch
     )
+    # one chip's holds the one program and the two that serve wide
+    # buckets, each jitted under its own name
+    pairing = be._kernel_cache["stages_d0"][3]
+    assert isinstance(pairing, be._PairingPrograms)
+    assert pairing.one.__wrapped__ is be._stage_pairing
+    assert pairing.miller.__wrapped__ is be._stage_miller
+    assert pairing.final_exp.__wrapped__ is be._stage_final_exp
+
+
+@pytest.mark.parametrize("lanes,programs", [
+    (5, ["@jit_one"]), (32, ["@jit_one"]),
+    (33, ["@jit_miller", "@jit_final_exp"]),
+    (257, ["@jit_miller", "@jit_final_exp"]),
+], ids=["5-one", "32-one", "33-two", "257-two"])
+def test_pairing_programs_by_pair_lanes(lanes, programs):
+    """The one-chip stage-4 callable with stand-in programs (nothing here
+    compiles a Miller loop). What serves follows the Miller loop's plan:
+    one accumulator -> the one program, a row of them (33 pairs on) -> two
+    programs chained on the device. `.lower` gives the lowerings of what
+    serves — the second at the first's output shape — program capture
+    records their sum under the one stage, an attributed dispatch is ONE
+    `pairing` resolve, and with donation on each dispatch consumes its own
+    inputs and holds nothing over to the next."""
+    import jax
+    import jax.numpy as jnp
+
+    from lighthouse_tpu.crypto.jaxbls import backend as be
+    from lighthouse_tpu.crypto.jaxbls import pairing_ops as po
+    from lighthouse_tpu.observability import device as obsdev
+    from lighthouse_tpu.observability import perf
+
+    assert (po.miller_lane_plan(lanes)[0] > 1) == (len(programs) == 2)
+
+    def miller(px, py, qxx, qyy, pair_mask):
+        return jnp.where(pair_mask, px + py + qxx + qyy, 0)
+
+    def final_exp(f):
+        return jnp.sum(f * f) == 100.0 * (lanes - 1)
+
+    def one(px, py, qxx, qyy, pair_mask):
+        return final_exp(miller(px, py, qxx, qyy, pair_mask))
+
+    donate = be.STAGE_DONATE_ARGNUMS
+    pairing = be._PairingPrograms(
+        jax.jit(one, donate_argnums=donate["pairing"]),
+        jax.jit(miller, donate_argnums=donate["miller"]),
+        jax.jit(final_exp, donate_argnums=donate["final_exp"]),
+    )
+
+    def inputs():
+        return tuple(jnp.full((lanes,), v, jnp.float32)
+                     for v in (1, 2, 3, 4)) + (jnp.arange(lanes) < lanes - 1,)
+
+    lowered = pairing.lower(*inputs())
+    as_tuple = lowered if isinstance(lowered, tuple) else (lowered,)
+    assert [low.as_text().split()[1] for low in as_tuple] == programs
+    if len(programs) == 2:
+        assert lowered[0].out_info.shape == (lanes,)
+        assert lowered[1].in_avals[0][0].shape == (lanes,)
+
+    first = inputs()
+    assert bool(pairing(*first)) is True
+    if len(programs) == 2:
+        # the stand-in Miller value can live in a donated input (the one
+        # program's scalar cannot, and XLA:CPU then keeps the inputs)
+        assert first[0].is_deleted()
+        with pytest.raises((RuntimeError, ValueError), match="deleted"):
+            pairing(*first)
+    resolves = obsdev.STAGE_DEVICE_SECONDS.labels("pairing", lanes - 1, 1)
+    with obsdev.attributed():
+        attr = obsdev.begin((lanes - 1, 1))
+        obsdev.run_stage(attr, "pairing", pairing, *inputs())  # "compile"
+        seen = resolves.n
+        assert bool(obsdev.run_stage(attr, "pairing", pairing, *inputs()))
+    assert resolves.n == seen + 1                    # fresh buffers: served
+
+    perf.reset_programs()
+    try:
+        served = perf.capture_program("pairing", pairing, inputs(), (4, 1))
+        alone = [perf.capture_program("pairing", fn, a, (4, 1)) for fn, a in (
+            (pairing.miller, inputs()),
+            (pairing.final_exp, (jnp.ones(lanes, jnp.float32),)),
+            (pairing.one, inputs()))]
+    finally:
+        perf.reset_programs()
+    if len(programs) == 1:
+        assert served == alone[2]
+        return
+    for summed in ("flops", "bytes_accessed", "generated_code_bytes"):
+        assert served[summed] == alone[0][summed] + alone[1][summed]
+    assert alone[0]["flops"] > 0 and alone[1]["flops"] > 0
+    for largest in ("argument_bytes", "output_bytes", "temp_bytes"):
+        assert served[largest] == max(alone[0][largest], alone[1][largest])
 
 
 def test_pairing_dispatch_flips_to_fallback_once(monkeypatch):
