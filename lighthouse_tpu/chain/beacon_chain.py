@@ -1101,11 +1101,68 @@ class BeaconChain:
         from .data_availability import verify_blob_sidecar_for_gossip
 
         block_root = verify_blob_sidecar_for_gossip(self, sidecar)
+        return self._accept_gossip_blob(block_root, sidecar)
+
+    def _accept_gossip_blob(self, block_root: bytes, sidecar):
+        """A gossip-verified sidecar joins its block; the block imports if
+        that made it available."""
         got = self.data_availability.put_blob(block_root, sidecar)
         if got is not None:
             block, sidecars = got
             return self.process_block(block, blobs=sidecars, blobs_verified=True)
         return None
+
+    def submit_gossip_blob_batch(self, sidecars, on_done=None):
+        """Pipelined form of `process_gossip_blob` for the sidecars the
+        processor coalesced: every check but the KZG proof a sidecar on the
+        host, then ONE KZG batch of the survivors submitted async. Returns
+        (handle, continuation); the continuation gives every sidecar its
+        outcome — the root of the block its arrival imported, None, or the
+        exception `process_gossip_blob` would have raised — as a list in
+        submission order, which it also hands to on_done. Returns None
+        (after on_done) when no sidecar reached the KZG check."""
+        from .data_availability import (
+            AvailabilityPendingError,
+            BlobError,
+            BlobIgnoreError,
+            gossip_checks_before_kzg,
+        )
+
+        outcomes: list = [None] * len(sidecars)
+        survivors = []
+        for i, sc in enumerate(sidecars):
+            try:
+                survivors.append((i, sc) + gossip_checks_before_kzg(self, sc))
+            except (BlobError, BlobIgnoreError) as e:
+                outcomes[i] = e
+        if not survivors:
+            if on_done is not None:
+                on_done(outcomes)
+            return None
+        handle, verdicts_of = self.data_availability.submit_kzg_batch(
+            [sc for _i, sc, _root, _key in survivors]
+        )
+
+        def continuation(result):
+            for (i, sc, root, key), ok in zip(survivors, verdicts_of(result)):
+                if not ok:
+                    outcomes[i] = BlobError("KZG proof invalid")
+                elif key in self.observed_blob_sidecars:
+                    # the same sidecar twice in one batch, or verified
+                    # inline while this batch was in flight
+                    outcomes[i] = BlobIgnoreError(
+                        "sidecar already seen", retriable=False)
+                else:
+                    self.observed_blob_sidecars.add(key)
+                    try:
+                        outcomes[i] = self._accept_gossip_blob(root, sc)
+                    except (BlockError, AvailabilityPendingError) as e:
+                        outcomes[i] = e
+            if on_done is not None:
+                on_done(outcomes)
+            return outcomes
+
+        return handle, continuation
 
     def get_blobs(self, block_root: bytes):
         """Stored sidecars for an imported block (by-root RPC / API serve)."""

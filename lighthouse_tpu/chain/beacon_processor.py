@@ -4,7 +4,9 @@ Parity surface: /root/reference/beacon_node/beacon_processor/src/lib.rs —
 the Work queue kinds (:549-658), bounded FIFO/LIFO queues per kind
 (:301-372), explicit priority order (:955-1090), and the dynamic coalescing
 of queued gossip attestations/aggregates into batch work items
-(:970-1087). That coalescing is the upstream feeder for the TPU backend:
+(:970-1087) — and, unlike the reference, of a block's queued blob sidecars
+into one KZG batch (a dispatch here costs the same whatever it carries).
+That coalescing is the upstream feeder for the TPU backend:
 the reference caps batches at 64 because CPU batch verification saturates;
 here the default batch caps are sized for chip occupancy instead
 (DEFAULT_MAX_*_BATCH), and the scheduler drains widest-first.
@@ -49,19 +51,22 @@ class WorkKind(IntEnum):
 
     chain_reprocess = 0
     gossip_block = 1
-    api_request_p0 = 2
-    gossip_aggregate = 3
-    gossip_attestation = 4
-    gossip_sync_contribution = 5
-    gossip_sync_signature = 6
-    rpc_block = 7
-    chain_segment = 8
-    api_request_p1 = 9
-    gossip_voluntary_exit = 10
-    gossip_proposer_slashing = 11
-    gossip_attester_slashing = 12
-    gossip_bls_change = 13
-    backfill_segment = 14
+    # directly behind the block, as the reference's GossipBlobSidecar: the
+    # block cannot import before its sidecars' proofs have verified
+    gossip_blob_sidecar = 2
+    api_request_p0 = 3
+    gossip_aggregate = 4
+    gossip_attestation = 5
+    gossip_sync_contribution = 6
+    gossip_sync_signature = 7
+    rpc_block = 8
+    chain_segment = 9
+    api_request_p1 = 10
+    gossip_voluntary_exit = 11
+    gossip_proposer_slashing = 12
+    gossip_attester_slashing = 13
+    gossip_bls_change = 14
+    backfill_segment = 15
 
 
 DEFAULT_MAX_ATTESTATION_BATCH = 1024   # reference default 64; sized for TPU
@@ -223,7 +228,10 @@ class BeaconProcessorConfig:
 
 
 class BeaconProcessor:
-    BATCHABLE = (WorkKind.gossip_attestation, WorkKind.gossip_aggregate)
+    # blob sidecars coalesce to at most a block's worth, a cap the
+    # scheduler holds fixed (scheduler.FIXED_CAPS); the other two retune
+    BATCHABLE = (WorkKind.gossip_blob_sidecar, WorkKind.gossip_attestation,
+                 WorkKind.gossip_aggregate)
 
     def __init__(self, config: BeaconProcessorConfig | None = None,
                  admission=None):

@@ -15,20 +15,61 @@ Parity surface:
     blob spam the in-memory footprint stays capped while no verified
     component is lost.
 
-KZG proofs of all sidecars of a block verify as ONE batch through the shared
-pairing kernel (crypto/kzg.verify_blob_kzg_proof_batch — the same device
-path as BLS, the north-star workload sharing noted in SURVEY.md §2.4).
+KZG proofs go through ONE path, `crypto/kzg.BlobBatch`: the host's field
+work, then the group side on the active BLS backend (on the jax backend one
+pipelined dispatch on the device ledger's `kzg` tenant: subgroup checks,
+linear combinations and the two-pair check, one device read). The checker
+has its two forms: `submit_kzg_batch(sidecars)` -> `(handle, continuation)`
+with ONE verdict a sidecar — what the beacon processor runs for the gossip
+sidecars it coalesced (`WorkKind.gossip_blob_sidecar`; a batch that comes
+back False is verified again sidecar by sidecar, so one bad sidecar never
+condemns its block's others) — and the synchronous `verify_kzg_proofs`, the
+batch as one boolean, for block import, the RPC path and the inline gossip
+check. The reference verifies gossip sidecars one by one (a blst check is
+~2 ms); here a dispatch costs the same whatever it carries, so sidecars
+that are queued together are one batch.
 """
 
 from __future__ import annotations
 
+import contextlib
+import sys
 from collections import OrderedDict
 from dataclasses import dataclass, field
+from time import perf_counter
 
 from ..crypto import kzg as ckzg
+from ..observability import device as _obs_dev
+from ..observability import trace as _obs
 from ..ssz.proof import branch_for, build_tree, verify_branch
 from ..types.containers import KZGCommitment
 from ..types import helpers as h
+from ..utils.metrics import REGISTRY
+
+_KZG_BATCH_SECONDS = REGISTRY.histogram(
+    "kzg_batch_seconds",
+    "one batch of blob sidecars verified: submit_kzg_batch() to one verdict "
+    "a sidecar delivered — the host's field work, the wait behind batches "
+    "in flight, the device, the continuation, the single re-verifications "
+    "after a False batch included",
+    buckets=(0.001, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0,
+             120.0, 600.0),
+)
+_KZG_BATCHES = REGISTRY.counter(
+    "kzg_batches_total",
+    "batches of blob sidecars whose verdicts submit_kzg_batch delivered",
+)
+_KZG_BATCH_SIDECARS = REGISTRY.counter(
+    "kzg_batch_sidecars_total",
+    "blob sidecars whose verdict a batch delivered",
+)
+_KZG_BATCH_FALLBACK = REGISTRY.counter(
+    "kzg_batch_fallback_total",
+    "batches that did not decide every sidecar (False, or a point outside "
+    "the subgroup among them) and verified the undecided ones again alone",
+)
+KZG_BATCH_SPAN = "gossip:blob_batch"
+
 
 class BlobError(Exception):
     """Blob sidecar rejected (blob_verification.rs GossipBlobError analog)."""
@@ -383,7 +424,9 @@ class DataAvailabilityChecker:
         return e.block, sidecars
 
     def verify_kzg_proofs(self, sidecars) -> bool:
-        """One batched pairing check for all sidecars (kzg batch verify)."""
+        """All of `sidecars` as one boolean (kzg batch verify): the
+        synchronous form of `submit_kzg_batch`, through the same
+        `BlobBatch`."""
         if not sidecars:
             return True
         if self.setup is None:
@@ -395,6 +438,53 @@ class DataAvailabilityChecker:
             self.setup,
         )
 
+    def submit_kzg_batch(self, sidecars):
+        """The sidecars' KZG proofs as ONE pipelined batch (at most
+        crypto/kzg.MAX_BATCH): the host's part and the dispatch on the
+        caller's thread. Returns `(handle, continuation)`:
+        `continuation(handle.result())` gives `list[bool]`, one verdict a
+        sidecar. A batch that verifies gives True to all; malformed bytes
+        and points outside the subgroup are their own sidecar's False;
+        whatever a False batch left undecided is verified again alone."""
+        if self.setup is None:
+            raise BlobError("no KZG trusted setup loaded")
+        sidecars = list(sidecars)
+        tr = _obs.current_trace()
+        t0 = perf_counter()
+        # a host scope in the profiler's own trace, but only where jax is
+        # loaded already: a host-backend node must not import it for a name
+        scope = (
+            _obs_dev.annotation_scope(KZG_BATCH_SPAN, sidecars=len(sidecars))
+            if "jax" in sys.modules else contextlib.nullcontext()
+        )
+        with scope:
+            batch = ckzg.BlobBatch(
+                [sc.blob for sc in sidecars],
+                [sc.kzg_commitment for sc in sidecars],
+                [sc.kzg_proof for sc in sidecars],
+                self.setup,
+            )
+            handle = batch.submit()
+        lanes = 6 * len(batch.members)
+
+        def continuation(result) -> list:
+            verdicts = batch.verdicts(result)
+            undecided = [i for i, v in enumerate(verdicts) if v is None]
+            if undecided:
+                _KZG_BATCH_FALLBACK.inc()
+                for i in undecided:
+                    verdicts[i] = self.verify_kzg_proofs([sidecars[i]])
+            t1 = perf_counter()
+            _KZG_BATCH_SECONDS.observe(t1 - t0)
+            _KZG_BATCHES.inc()
+            _KZG_BATCH_SIDECARS.inc(len(sidecars))
+            if tr is not None:
+                tr.add_span(KZG_BATCH_SPAN, t0, t1, sidecars=len(sidecars),
+                            lanes=lanes, fallback=len(undecided))
+            return verdicts
+
+        return handle, continuation
+
 
 # --------------------------------------------------- gossip verification
 
@@ -405,7 +495,21 @@ def verify_blob_sidecar_for_gossip(chain, sidecar, verify_kzg: bool = True) -> b
     Mirrors blob_verification.rs GossipVerifiedBlob::new order: index bound,
     slot window, (root, index) dedup, parent known + slot ordering, not
     pre-finalization, inclusion proof, proposer signature (batched through
-    the BLS backend), KZG proof."""
+    the BLS backend), KZG proof. The synchronous form: the processor's
+    coalesced batches run `gossip_checks_before_kzg` a sidecar and ONE
+    `submit_kzg_batch` (`BeaconChain.submit_gossip_blob_batch`)."""
+    block_root, key = gossip_checks_before_kzg(chain, sidecar)
+    if verify_kzg:
+        if not chain.data_availability.verify_kzg_proofs([sidecar]):
+            raise BlobError("KZG proof invalid")
+    chain.observed_blob_sidecars.add(key)
+    return block_root
+
+
+def gossip_checks_before_kzg(chain, sidecar) -> tuple:
+    """Every gossip check of a sidecar but its KZG proof. Returns
+    (block root, the (root, index) key `observed_blob_sidecars` takes once
+    the proof has verified); raises BlobError / BlobIgnoreError."""
     from ..state_transition import signature_sets as sigs
     from ..state_transition.block import SignatureBatch
     from ..state_transition.slot import types_for_slot
@@ -463,10 +567,4 @@ def verify_blob_sidecar_for_gossip(chain, sidecar, verify_kzg: bool = True) -> b
         raise BlobError(f"undecodable header signature: {e}") from e
     if not batch.verify():
         raise BlobError("invalid header proposer signature")
-
-    if verify_kzg:
-        if not chain.data_availability.verify_kzg_proofs([sidecar]):
-            raise BlobError("KZG proof invalid")
-
-    chain.observed_blob_sidecars.add(key)
-    return block_root
+    return block_root, key

@@ -70,6 +70,7 @@ from __future__ import annotations
 import threading
 from collections import deque
 
+from ..types.spec import ChainSpec
 from ..utils.logging import get_logger
 from ..utils.metrics import REGISTRY
 
@@ -146,6 +147,12 @@ def pow2ceil(n: int) -> int:
     return 1 << (n - 1).bit_length()
 
 
+#: batch caps the control loop never moves: a block's blob sidecars are one
+#: KZG batch, so the cap is the preset's MAX_BLOBS_PER_BLOCK (Deneb's; a node
+#: rebases its scheduler's copy with spec.max_blobs(fork))
+FIXED_CAPS = {"gossip_blob_sidecar": ChainSpec.max_blobs_per_block}
+
+
 class Decision:
     """One batch-formation verdict."""
 
@@ -177,6 +184,7 @@ class CapacityScheduler:
             "gossip_attestation": int(config.max_attestation_batch),
             "gossip_aggregate": int(config.max_aggregate_batch),
         }
+        self.fixed_caps = dict(FIXED_CAPS)
         self.pinned = {
             "gossip_attestation": bool(
                 getattr(config, "max_attestation_batch_explicit", False)
@@ -316,7 +324,7 @@ class CapacityScheduler:
         under the processor lock: O(1), no blocking, no re-entry."""
         name = getattr(kind, "name", str(kind))
         with self._lock:
-            cap = self.caps.get(name, MAX_CAP)
+            cap = self.caps.get(name, self.fixed_caps.get(name, MAX_CAP))
             gate = self._budget_gate
             if depth > self._depth_hw.get(name, 0):
                 self._depth_hw[name] = depth
@@ -353,8 +361,10 @@ class CapacityScheduler:
     # --------------------------------------------------------------- model
 
     def observe_verify(self, kind, n_sets: int, secs: float) -> None:
-        """One resolved batch's measured verify time feeds the cost fit."""
-        if n_sets <= 0 or secs < 0:
+        """One resolved batch's measured verify time feeds the cost fit —
+        a signature batch's: the fit sizes the caps it retunes, and a kind
+        with a fixed cap (a KZG batch is another program) stays out of it."""
+        if n_sets <= 0 or secs < 0 or kind not in self.caps:
             return
         with self._lock:
             self._obs.append((pow2ceil(n_sets), float(secs)))

@@ -41,6 +41,18 @@ def hash_to_g2_point(message: bytes):
 # ----------------------------------------------------------------- backends
 
 
+class _ReadyHandle:
+    """Immediate-resolution handle for backends without async submission."""
+
+    __slots__ = ("_value",)
+
+    def __init__(self, value: bool):
+        self._value = value
+
+    def result(self) -> bool:
+        return self._value
+
+
 class PythonBackend:
     """Pure-Python ground-truth backend."""
 
@@ -73,6 +85,33 @@ class PythonBackend:
         return pr.multi_pairing_is_one(pairs)
 
 
+    def verify_kzg_batch_async(self, commitments, proofs, r_pows,
+                               y_scalars, z_scalars, tau_g2):
+        """The group side of `verify_blob_kzg_proof_batch` in Python
+        integers, resolved at once (the entry `crypto/kzg.py` submits blob
+        batches through on every backend; the jax backend's form is the
+        served one): every point times the group order, then
+        e(C', H) e(-W', tau H) == 1. Returns a handle whose `.result()` is
+        (ok, [(C_i in the subgroup, W_i in it), ...])."""
+        flags = [
+            (c is None or cv.g1_in_subgroup(c), w is None or cv.g1_in_subgroup(w))
+            for c, w in zip(commitments, proofs)
+        ]
+        if not all(c and w for c, w in flags):
+            return _ReadyHandle((False, flags))
+        c_prime = w_prime = None
+        for c, w, r, ys, zs in zip(commitments, proofs, r_pows, y_scalars,
+                                   z_scalars):
+            for point, scalar in ((c, r), (cv.G1_GEN, ys), (w, zs)):
+                if point is not None:
+                    c_prime = cv.g1_add(c_prime, cv.g1_mul(point, scalar))
+            if w is not None:
+                w_prime = cv.g1_add(w_prime, cv.g1_mul(w, r))
+        pairs = [(c_prime, cv.G2_GEN), (cv.g1_neg(w_prime), tau_g2)]
+        ok = pr.multi_pairing_is_one([p for p in pairs if p[0] is not None])
+        return _ReadyHandle((ok, flags))
+
+
 class FakeBackend:
     """Always-valid stub (plumbing tests only). Like the reference's
     fake_crypto.rs it also no-ops SIGNING: `sign()` returns a fixed valid
@@ -91,6 +130,9 @@ class FakeBackend:
 
     def aggregate_verify(self, pks, messages, sig) -> bool:
         return True
+
+    def verify_kzg_batch_async(self, commitments, proofs, *_scalars):
+        return _ReadyHandle((True, [(True, True)] * len(commitments)))
 
     def sign(self, sk: SecretKey, message: bytes) -> Signature:
         if FakeBackend._sig_cache is None:
@@ -219,18 +261,6 @@ def verify_signature_sets(
     if any(z % _R == 0 for z in rands):
         raise ValueError("batch verification coefficients must be nonzero")
     return get_backend().verify_signature_sets(sets, rands)
-
-
-class _ReadyHandle:
-    """Immediate-resolution handle for backends without async submission."""
-
-    __slots__ = ("_value",)
-
-    def __init__(self, value: bool):
-        self._value = value
-
-    def result(self) -> bool:
-        return self._value
 
 
 def verify_signature_sets_async(

@@ -586,14 +586,24 @@ class HybridBackend:
             return api._ReadyHandle(self._host().verify_signature_sets(sets, rands))
 
     def __getattr__(self, name):
-        # accelerated primitives (device MSM / pairing product for KZG)
-        # exist as attributes ONLY while the device is up — consumers probe
-        # with getattr(..., None) and fall back to their host paths
+        # accelerated primitives (the device MSMs KZG commits and proves
+        # with) exist as attributes ONLY while the device is up — consumers
+        # probe with getattr(..., None) and fall back to their host paths
         # (crypto/kzg.py), so a device outage degrades instead of crashing
-        if name in ("g1_msm", "g1_msm_fixed", "pairing_product_is_one"):
+        if name in ("g1_msm", "g1_msm_fixed"):
             if self._device_state() == "up" and self._device is not None:
                 return getattr(self._device, name)
         raise AttributeError(name)
+
+    def verify_kzg_batch_async(self, *batch):
+        """A blob batch rides the device while it is up; otherwise, and on
+        a device error at submission, the host resolves it."""
+        if self._device_state() == "up" and self._device is not None:
+            try:
+                return self._device.verify_kzg_batch_async(*batch)
+            except Exception as e:
+                self._record_device_error(e)
+        return self._host().verify_kzg_batch_async(*batch)
 
     def verify_single(self, pk, message: bytes, sig) -> bool:
         if sig.is_infinity():

@@ -116,6 +116,14 @@ _MILLER_PLAN = REGISTRY.counter_vec(
     "dispatches is 0 where the lines are narrowed only after the loop",
     ("kind",),
 )
+_KZG_LANES = REGISTRY.counter_vec(
+    "kzg_lanes_total",
+    "lanes of the KZG batch check's scalar-multiplication pass, per "
+    "dispatch: real = six a blob (its three terms of C', its term of W', "
+    "its commitment and its proof times the group order), padded = the "
+    "program's one row of lanes; real over padded is the pass's fill",
+    ("kind",),
+)
 _seen_exec_buckets: set = set()  # buckets that have resolved at least once
 
 MIN_SETS = 4          # smallest bucket (pairs axis = sets + 1 rounded up)
@@ -747,6 +755,10 @@ class JaxBackend:
         # a profile installed later re-resolves through the plan listener
         # (autotune/runtime.add_plan_listener).
         self.dispatcher = pl.PipelinedDispatcher(workload="bls")
+        # blob batches (verify_kzg_batch_async) are the device ledger's
+        # `kzg` tenant: a window of their own, so a blob-carrying block's
+        # sidecars never queue behind four attestation batches
+        self.kzg_dispatcher = pl.PipelinedDispatcher(workload="kzg")
         try:
             from ...autotune import runtime as _at_runtime
 
@@ -760,8 +772,9 @@ class JaxBackend:
         live-retune contract as the hybrid router's budgets)."""
         from . import pipeline as pl
 
-        if self.dispatcher.depth_source in ("profile", "default"):
-            self.dispatcher.set_depth(*pl.resolve_depth())
+        for dispatcher in (self.dispatcher, self.kzg_dispatcher):
+            if dispatcher.depth_source in ("profile", "default"):
+                dispatcher.set_depth(*pl.resolve_depth())
 
     # -- the multi-set hot path ------------------------------------------
 
@@ -1073,25 +1086,147 @@ class JaxBackend:
                 cache.pop(order.pop(0), None)
         return hit[0].msm(scalars)
 
-    def pairing_product_is_one(self, pairs) -> bool:
-        """prod e(P_i, Q_i) == 1 for host affine pairs, on the SAME jitted
-        pairing stage the signature verifier uses (the north star's "blob
-        proofs reuse the pairing kernel" — BASELINE.json;
-        /root/reference/crypto/kzg/src/lib.rs:81)."""
-        live = [(p, q) for p, q in pairs if p is not None and q is not None]
-        if not live:
-            return True
-        n = max(MIN_SETS, _next_pow2(len(live)))
-        pad = n - len(live)
-        xp = tw.fq_batch_to_device([p[0] for p, _ in live] + [0] * pad)
-        yp = tw.fq_batch_to_device([p[1] for p, _ in live] + [0] * pad)
-        xq = tw.fq2_batch_to_device([q[0] for _, q in live] + [(0, 0)] * pad)
-        yq = tw.fq2_batch_to_device([q[1] for _, q in live] + [(0, 0)] * pad)
-        mask = np.zeros((n,), bool)
-        mask[: len(live)] = True
-        _, _, _, pairing_stage = _get_stages()
-        ok = pairing_stage(xp, yp, xq, yq, mask)
-        return bool(np.asarray(ok))
+    # -- the KZG blob batch ----------------------------------------------
+
+    def verify_kzg_batch_async(self, commitments, proofs, r_pows,
+                               y_scalars, z_scalars, tau_g2):
+        """The group side of `verify_blob_kzg_proof_batch` for up to
+        msm.KZG_BLOB_SLOTS blobs (one program, one row of lanes, whatever
+        the batch holds), as ONE pipelined dispatch on the `kzg` tenant's
+        batch lane: every commitment C_i and proof W_i times the
+        group order (the subgroup checks), the spec's two linear
+        combinations C' = sum r_pows[i] C_i + y_scalars[i] G1 + z_scalars[i]
+        W_i and W' = sum r_pows[i] W_i in the same double-and-add pass
+        (msm.kzg_lincomb_kernel), then e(C', H) e(-W', tau H) == 1 on the
+        W = 1 pairing program the urgent BLS bucket runs — C' and W' never
+        leave the device. Points are host affine pairs on the curve (None =
+        infinity), scalars ints mod r. Returns a ticket; `.result()` reads
+        the device ONCE: (ok, [(C_i in the subgroup, W_i in it), ...]).
+        `ok` means nothing unless every flag is True."""
+        import time
+
+        from ...parallel import put_single
+        from . import msm as _msm
+
+        n_real = len(commitments)
+        slots, rows = _msm.KZG_BLOB_SLOTS, _msm.KZG_ROWS
+        if not 1 <= n_real <= slots:
+            raise ValueError(f"a KZG batch holds 1 to {slots} blobs, "
+                             f"got {n_real}")
+        lanes = slots * rows
+        lincomb, verdict = _get_kzg_kernels()
+        pairing_stage = _get_stages()[3]
+        points, scalars, index = [], [], []
+        for i, (c, w) in enumerate(zip(commitments, proofs)):
+            for row, point, scalar in (
+                (_msm.KZG_ROW_C, c, r_pows[i]),
+                (_msm.KZG_ROW_G1, pc.G1_GEN, y_scalars[i]),
+                (_msm.KZG_ROW_ZW, w, z_scalars[i]),
+                (_msm.KZG_ROW_W, w, r_pows[i]),
+                (_msm.KZG_ROW_ORDER_C, c, R),
+                (_msm.KZG_ROW_ORDER_W, w, R),
+            ):
+                if point is not None:
+                    points.append(point)
+                    scalars.append(scalar)
+                    index.append(i * rows + row)
+        px = np.zeros((lanes, lb.NL), np.uint32)
+        py = np.zeros((lanes, lb.NL), np.uint32)
+        live = np.zeros((lanes,), np.uint32)
+        bits = np.zeros((lanes, _msm.KZG_SCALAR_BITS), np.uint32)
+        px[index] = pack_ints_vec([p[0] for p in points])
+        py[index] = pack_ints_vec([p[1] for p in points])
+        live[index] = 1
+        raw = np.frombuffer(
+            b"".join(k.to_bytes(32, "big") for k in scalars), np.uint8
+        ).reshape(len(scalars), 32)
+        bits[index] = np.unpackbits(raw, axis=1)[:, 256 - _msm.KZG_SCALAR_BITS:]
+        qx, qy = _kzg_g2_side(tau_g2)
+        _KZG_LANES.labels("real").inc(6 * n_real)
+        _KZG_LANES.labels("padded").inc(lanes)
+        _MARSHALLED_BYTES.labels("kzg").inc(
+            px.nbytes + py.nbytes + live.nbytes + bits.nbytes
+            + qx.nbytes + qy.nbytes
+        )
+        tr = _obs.current_trace()
+
+        def dispatch():
+            # the stage programs' outputs feed each other on the device;
+            # the G2 side goes up every dispatch because the pairing
+            # program is built to consume (donate) its inputs
+            t0 = time.perf_counter()
+            attr = _obs_dev.begin((slots, rows), trace=tr)
+            gx, gy, pair_mask, in_subgroup = _obs_dev.run_stage(
+                attr, _obs_dev.KZG_LINCOMB_STAGE, lincomb,
+                put_single(px), put_single(py), put_single(live),
+                put_single(bits),
+            )
+            ok = _obs_dev.run_stage(
+                attr, "pairing", pairing_stage,
+                gx, gy, put_single(qx), put_single(qy), pair_mask,
+            )
+            packed = verdict(ok, in_subgroup)
+            _DISPATCH_ENQUEUE_SECONDS.observe(time.perf_counter() - t0)
+            return KzgHandle(packed, n_real)
+
+        return self.kzg_dispatcher.submit(dispatch, bucket=(slots, rows))
+
+
+class KzgHandle:
+    """In-flight KZG batch: `.result()` blocks on the device and reads it
+    once: (ok, [(commitment in the subgroup, proof in it), ...] a blob)."""
+
+    __slots__ = ("_packed", "_n")
+
+    def __init__(self, packed, n: int):
+        self._packed = packed
+        self._n = n
+
+    def result(self) -> tuple:
+        out = np.asarray(self._packed)
+        flags = out[1 : 1 + 2 * self._n].reshape(self._n, 2)
+        return bool(out[0]), [(bool(c), bool(w)) for c, w in flags]
+
+
+def _get_kzg_kernels():
+    """(the jitted lane pass, the jitted verdict packer)."""
+    import jax
+
+    from . import msm as _msm
+
+    _init_consts()
+    if "kzg" not in _kernel_cache:
+        from ...utils.jaxcfg import setup_compilation_cache
+
+        setup_compilation_cache()
+        _kernel_cache["kzg"] = (
+            jax.jit(_msm.kzg_lincomb_kernel),
+            jax.jit(_msm.kzg_verdict_kernel),
+        )
+    return _kernel_cache["kzg"]
+
+
+_kzg_g2_cache: dict = {}
+
+
+def _kzg_g2_side(tau_g2) -> tuple:
+    """Host arrays (x, y), each (KZG_PAIR_LANES, 2, NL) Montgomery limbs, of
+    the two-pair check's G2 side: (H, tau H, pad, pad). A constant of the
+    trusted setup, so packed once a setup."""
+    from . import msm as _msm
+
+    key = (tuple(tau_g2[0]), tuple(tau_g2[1]))
+    hit = _kzg_g2_cache.get(key)
+    if hit is None:
+        pad = [(0, 0)] * (_msm.KZG_PAIR_LANES - 2)
+        hit = tuple(
+            np.stack([tw._fq2_const_np(c) for c in
+                      [pc.G2_GEN[k], tau_g2[k]] + pad])
+            for k in (0, 1)
+        )
+        _kzg_g2_cache.clear()       # one setup a process is the rule
+        _kzg_g2_cache[key] = hit
+    return hit
 
 
 def _get_msm_kernel():

@@ -124,6 +124,89 @@ def varying_base_msm_kernel(px, py, mask, digits, window: int = 4):
     return lb.from_mont(x), lb.from_mont(y), inf
 
 
+# --- the KZG batch check's lane pass (crypto/kzg.py, backend.verify_kzg_batch_async)
+#
+# ONE program whatever the batch holds: KZG_BLOB_SLOTS x KZG_ROWS = 128 lanes,
+# one row of the chip's vector lanes, laid out blob-major (lane = blob *
+# KZG_ROWS + row). Measured on a v5e (PR 33, scripts/measure_kzg_lanes.py,
+# ms a call, median of 5): 8 lanes 55.2, 64 lanes 92.3, 128 lanes 50.7 — as
+# for the Miller loop (pairing_ops.MILLER_LANES), a field operation costs a
+# full row what it costs one lane and a part row up to twice that, so a
+# batch of six (36 real lanes) is served at 128 and not at 64, and a batch of
+# one by the same program. On XLA:CPU 128 lanes cost 128 lanes (~50 s a
+# call): tier-1 patches KZG_BLOB_SLOTS down, the kernel reads its shape.
+KZG_ROWS = 8
+KZG_BLOB_SLOTS = 16         # also the most blobs a batch holds
+#: rows of a blob: the spec's terms of C' = sum r^i (C_i - y_i G1 + z_i W_i),
+#: its term of W' = sum r^i W_i, then C_i and W_i times the group order;
+#: two rows of padding keep the lane count a power of two
+(KZG_ROW_C, KZG_ROW_G1, KZG_ROW_ZW, KZG_ROW_W,
+ KZG_ROW_ORDER_C, KZG_ROW_ORDER_W) = range(6)
+KZG_SCALAR_BITS = 255       # r < 2^255, and so is every scalar mod r
+KZG_PAIR_LANES = 4          # the W = 1 pairing program's smallest bucket
+
+
+def kzg_lincomb_kernel(px, py, live, bits):
+    """Validation and linear combinations of one KZG blob batch.
+
+    px, py: (slots * KZG_ROWS, NL) standard-form affine G1 coordinates;
+    live: (lanes,) 1 = a point, 0 = the identity (padding, or a commitment
+    / proof that IS the point at infinity); bits: (lanes, 255) each lane's
+    scalar, MSB first, never truncated. One double-and-add pass multiplies
+    every lane; the rows of the lincombs are then summed over the blobs.
+    Returns the G1 side of the two-pair check as the pairing stage takes
+    it — lanes (C', -W', pad, pad) in Montgomery affine form with their
+    mask: an identity side contributes 1 — and in_subgroup (slots, 2):
+    [order] * C_i and [order] * W_i are the identity. Exact for every
+    point of the curve: jac_add is complete, and E(Fp) has odd cofactor,
+    so no point has y = 0."""
+    import jax
+    import jax.numpy as jnp
+
+    r2 = jnp.broadcast_to(lb.R2, px.shape)
+    jac = co.affine_to_jac(
+        co.FQ_OPS, (lb.mont_mul(px, r2), lb.mont_mul(py, r2)),
+        inf_mask=jnp.logical_not(jnp.asarray(live, bool)),
+    )
+    prod = co.scalar_mul_bits(jac, bits, co.FQ_OPS)
+    slots = px.shape[0] // KZG_ROWS
+    grid = tuple(c.reshape((slots, KZG_ROWS) + c.shape[1:]) for c in prod)
+    rows = co.tree_sum(grid, co.FQ_OPS)                  # (KZG_ROWS,) sums
+
+    def row(i):
+        return tuple(c[i] for c in rows)
+
+    c_prime = co.jac_add(
+        co.jac_add(row(KZG_ROW_C), row(KZG_ROW_G1), co.FQ_OPS),
+        row(KZG_ROW_ZW), co.FQ_OPS,
+    )
+    wx, wy, wz = row(KZG_ROW_W)
+    sides = jax.tree_util.tree_map(
+        lambda a, b: jnp.stack([a, b]), c_prime, (wx, co.FQ_OPS.neg(wy), wz)
+    )
+    x, y, inf = co.jac_to_affine(sides, co.FQ_OPS)
+    pad = jnp.zeros((KZG_PAIR_LANES - 2,) + x.shape[1:], x.dtype)
+    pair_mask = jnp.concatenate(
+        [jnp.logical_not(inf), jnp.zeros((KZG_PAIR_LANES - 2,), bool)]
+    )
+    at_identity = co.FQ_OPS.is_zero(prod[2]).reshape(slots, KZG_ROWS)
+    in_subgroup = at_identity[:, KZG_ROW_ORDER_C:KZG_ROW_ORDER_W + 1]
+    return (jnp.concatenate([x, pad]), jnp.concatenate([y, pad]), pair_mask,
+            in_subgroup)
+
+
+def kzg_verdict_kernel(ok, in_subgroup):
+    """The batch's verdict and its points' validity flags as ONE array, so
+    the host reads the device once a batch: [ok, flags of blob 0 (C, W),
+    blob 1, ...] as uint32."""
+    import jax.numpy as jnp
+
+    return jnp.concatenate([
+        jnp.asarray(ok, jnp.uint32).reshape(1),
+        jnp.asarray(in_subgroup, jnp.uint32).reshape(-1),
+    ])
+
+
 def _next_pow2(n: int) -> int:
     p = 1
     while p < n:

@@ -687,22 +687,8 @@ class Gossipsub:
         with self._lock:
             self._pending_validation.pop(mid, None)   # synchronous outcome
         if accept is IGNORE_RETRY:
-            # Validation could not run yet (e.g. parent unavailable) —
-            # neither propagate nor penalize the sender, and drop the
-            # message id from the seen cache so a retransmission can
-            # re-validate once the missing dependency arrives (redelivery
-            # plus the owner's local reprocess queue stand in for the
-            # reference's ReprocessQueue). Bounded per mid: past
-            # MAX_IGNORE_RETRIES the ignore turns terminal and the mid
-            # stays deduped.
             with self._lock:
-                n = self._ignore_retries.get(mid, 0) + 1
-                if n <= MAX_IGNORE_RETRIES:
-                    self._ignore_retries[mid] = n
-                    self.seen.pop(mid, None)
-                    self._deliverers.pop(mid, None)
-                else:
-                    self._ignore_retries.pop(mid, None)
+                self._ignore_retry_locked(mid)
             return
         if accept is None:
             # Terminal IGNORE (duplicate, pre-finalization): no propagation,
@@ -727,14 +713,34 @@ class Gossipsub:
                 self._send(p, Rpc(msgs=[(topic, data)], ctx=fwd_ctx))
         GS_DELIVERED.labels(short_topic(topic)).inc()
 
+    def _ignore_retry_locked(self, mid: bytes) -> None:
+        """Validation could not run yet (e.g. parent unavailable) — neither
+        propagate nor penalize the sender, and drop the message id from the
+        seen cache so a retransmission can re-validate once the missing
+        dependency arrives (redelivery plus the owner's local reprocess
+        queue stand in for the reference's ReprocessQueue). Bounded per
+        mid: past MAX_IGNORE_RETRIES the ignore turns terminal and the mid
+        stays deduped."""
+        n = self._ignore_retries.get(mid, 0) + 1
+        if n <= MAX_IGNORE_RETRIES:
+            self._ignore_retries[mid] = n
+            self.seen.pop(mid, None)
+            self._deliverers.pop(mid, None)
+        else:
+            self._ignore_retries.pop(mid, None)
+
     def report_validation_result(self, mid: bytes, accept) -> None:
         """Resolve a PENDING validation (the async counterpart of the
         handler's return value): True = accept (credit the deliverers,
         cache, forward to the mesh), False = reject (penalize every sender),
-        None = terminal ignore. No-op for unknown/expired mids."""
+        None = terminal ignore, IGNORE_RETRY = ignore that a retransmission
+        may re-validate. No-op for unknown/expired mids."""
         with self._lock:
             entry = self._pending_validation.pop(mid, None)
             if entry is None:
+                return
+            if accept is IGNORE_RETRY:
+                self._ignore_retry_locked(mid)
                 return
             topic, data, _ts = entry
             got = self._deliverers.get(mid)

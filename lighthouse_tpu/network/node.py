@@ -235,6 +235,10 @@ class NetworkNode:
         if fork >= ForkName.deneb:
             for i in range(spec.max_blobs(fork)):
                 self.gossipsub.subscribe(gs.blob_sidecar_topic(fd, i), self._on_blob)
+            # a block's sidecars are one KZG batch: this fork's block size
+            self.processor.scheduler.fixed_caps["gossip_blob_sidecar"] = (
+                spec.max_blobs(fork)
+            )
 
     # ------------------------------------------------------------ transport glue
 
@@ -783,48 +787,89 @@ class NetworkNode:
             sidecar = types.BlobSidecar.deserialize(msg.decompressed)
         except Exception:
             return False
+        if self.batch_gossip:
+            from ..chain.beacon_processor import WorkItem, WorkKind
+            from .gossipsub import PENDING
+
+            # a block's sidecars arrive on their subnets within tens of
+            # milliseconds: whatever of them is queued together when the
+            # pump pops is ONE KZG batch (the reference verifies each alone)
+            accepted = self.processor.submit(WorkItem(
+                kind=WorkKind.gossip_blob_sidecar,
+                payload=(sidecar, msg.message_id),
+                run_batch=self._run_blob_batch,
+                on_shed=self._mk_shed_resolver(msg.message_id),
+            ))
+            return PENDING if accepted else None
         with self._lock:
             try:
-                root = self.chain.process_gossip_blob(sidecar)
-                # a returned root means the sidecar COMPLETED a block
-                # import: children waiting on that block can now verify
-                if root is not None:
-                    self._retry_pending_sidecars(root)
-            except BlobIgnoreError as e:
-                # verification could not run. Three cases:
-                #  - missing parent: retriable over gossip AND queued for a
-                #    local retry when the parent imports
-                #  - future slot: terminal for dedup (mesh duplicates must
-                #    not burn retries) but queued for the slot start
-                #  - duplicate/finalized: terminal, stay deduped
-                if e.retry_at_slot is not None:
-                    # hard-capped: these sidecars are UNVERIFIED (the
-                    # future-slot check precedes proof/signature checks), so
-                    # a flood of distinct junk must not grow memory
-                    if (
-                        sum(len(v) for v in self._early_sidecars.values())
-                        < self.MAX_PENDING_SIDECARS
-                    ):
-                        self._early_sidecars.setdefault(
-                            e.retry_at_slot, []
-                        ).append(sidecar)
-                        while len(self._early_sidecars) > 4:
-                            # evict the FARTHEST future slot: junk for
-                            # slot+5 must not displace the nearest-due
-                            # bucket (which is about to be drained)
-                            self._early_sidecars.pop(max(self._early_sidecars))
-                    return None
-                if e.retriable:
-                    if e.missing_parent is not None:
-                        self._stash_pending_sidecar(e.missing_parent, sidecar)
-                    return IGNORE_RETRY
+                outcome = self.chain.process_gossip_blob(sidecar)
+            except (BlobIgnoreError, BlobError, BlockError,
+                    AvailabilityPendingError) as e:
+                outcome = e
+            return self._settle_blob(sidecar, outcome)
+
+    def _run_blob_batch(self, payloads):
+        """Coalesced blob-sidecar runner (pump thread): every gossip check
+        but the KZG proof a sidecar, ONE async KZG batch of the survivors
+        (chain.submit_gossip_blob_batch), then a sidecar's own gossip
+        resolution from its own verdict."""
+        sidecars = [sc for sc, _mid in payloads]
+
+        def on_done(outcomes):
+            for (sc, mid), outcome in zip(payloads, outcomes):
+                self.gossipsub.report_validation_result(
+                    mid, self._settle_blob(sc, outcome)
+                )
+
+        with self._lock:
+            pair = self.chain.submit_gossip_blob_batch(sidecars, on_done=on_done)
+        return self._locked_continuation(pair)
+
+    def _settle_blob(self, sidecar, outcome):
+        """The gossip validation result of a sidecar from the outcome of
+        its processing: the root of the block it completed, None, or the
+        exception raised. Caller holds self._lock."""
+        if isinstance(outcome, BlobIgnoreError):
+            # verification could not run. Three cases:
+            #  - missing parent: retriable over gossip AND queued for a
+            #    local retry when the parent imports
+            #  - future slot: terminal for dedup (mesh duplicates must
+            #    not burn retries) but queued for the slot start
+            #  - duplicate/finalized: terminal, stay deduped
+            e = outcome
+            if e.retry_at_slot is not None:
+                # hard-capped: these sidecars are UNVERIFIED (the
+                # future-slot check precedes proof/signature checks), so
+                # a flood of distinct junk must not grow memory
+                if (
+                    sum(len(v) for v in self._early_sidecars.values())
+                    < self.MAX_PENDING_SIDECARS
+                ):
+                    self._early_sidecars.setdefault(
+                        e.retry_at_slot, []
+                    ).append(sidecar)
+                    while len(self._early_sidecars) > 4:
+                        # evict the FARTHEST future slot: junk for
+                        # slot+5 must not displace the nearest-due
+                        # bucket (which is about to be drained)
+                        self._early_sidecars.pop(max(self._early_sidecars))
                 return None
-            except BlobError:
-                return False
-            except (BlockError, AvailabilityPendingError):
-                # sidecar itself fully verified; only the joined block could
-                # not import (yet) — still propagate
-                return True
+            if e.retriable:
+                if e.missing_parent is not None:
+                    self._stash_pending_sidecar(e.missing_parent, sidecar)
+                return IGNORE_RETRY
+            return None
+        if isinstance(outcome, BlobError):
+            return False
+        if isinstance(outcome, (BlockError, AvailabilityPendingError)):
+            # sidecar itself fully verified; only the joined block could
+            # not import (yet) — still propagate
+            return True
+        # a returned root means the sidecar COMPLETED a block import:
+        # children waiting on that block can now verify
+        if outcome is not None:
+            self._retry_pending_sidecars(outcome)
         return True
 
     # ------------------------------------------------------------ publishing

@@ -63,6 +63,10 @@ from . import perf as _perf
 #: (`_verify_kernel` in crypto/jaxbls/backend.py)
 STAGES = ("prepare", "h2c", "pairs", "pairing")
 
+#: the KZG blob batch's first stage (validation + linear combinations,
+#: `crypto/jaxbls/msm.kzg_lincomb_kernel`); its second is `pairing`
+KZG_LINCOMB_STAGE = "kzg_lincomb"
+
 #: Trace span-name prefix that routes a span onto a device lane in the
 #: Chrome trace-event export (observability/trace.py)
 DEVICE_SPAN_PREFIX = "device:"

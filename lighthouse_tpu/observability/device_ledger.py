@@ -2,8 +2,9 @@
 device, who is waiting, and which tenant's SLO paid for it".
 
 Every batched workload that reaches the device registers here under a
-workload name (`bls`, `tree_hash`, `epoch`, `meshsim`, later `kzg`) —
-the `PipelinedDispatcher` does it from its constructor, the epoch-vector
+workload name (`bls`, `kzg`, `tree_hash`, `epoch`, `meshsim`) — the
+`PipelinedDispatcher` does it from its constructor (the jax backend holds
+two: signature batches are `bls`, blob batches `kzg`), the epoch-vector
 path does it around its direct dispatch. Each submission opens a ledger
 *interval* at admit (workload, lane, bucket, est-cost from the
 autotune/capacity cost model), marks it busy when the device dispatch

@@ -63,6 +63,7 @@ class PriorityClass(IntEnum):
 _CLASS_BY_KIND = {
     "chain_reprocess": PriorityClass.CRITICAL,
     "gossip_block": PriorityClass.CRITICAL,
+    "gossip_blob_sidecar": PriorityClass.CRITICAL,
     "api_request_p0": PriorityClass.CRITICAL,
     "gossip_aggregate": PriorityClass.TIMELY,
     "gossip_attestation": PriorityClass.TIMELY,

@@ -42,6 +42,8 @@ METRIC_MODULES = (
     "lighthouse_tpu.observability.propagation",
     "lighthouse_tpu.chain.beacon_chain",
     "lighthouse_tpu.chain.aggregate_batch",
+    "lighthouse_tpu.chain.data_availability",
+    "lighthouse_tpu.crypto.kzg",
     "lighthouse_tpu.state_transition.block",
     "lighthouse_tpu.loadgen.netfaults",
     "lighthouse_tpu.loadgen.meshsim",

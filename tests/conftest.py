@@ -66,10 +66,10 @@ _LONGEST_FIRST = (
     "test_multichip.py",                # 568
     "test_multichip_2d.py",             # 380 (1 test: takes the 7th along)
     "test_jaxbls_pairing.py",           # 369
+    "test_kzg.py",                      # 297 alone (PR 33: the blob batches)
+    "test_fleet.py",                    # 183 (seventh: the short one)
     "test_beacon_chain.py",             # 250
-    "test_fleet.py",                    # 183
     "test_jaxbls_h2c.py",               # 167
-    "test_kzg.py",                      # 154
     "test_jaxbls_msm.py",               # 123
 )
 

@@ -159,3 +159,74 @@ def test_chain_submit_attestation_batch_pipelined():
     )
     proc.run_until_idle()
     assert len(got) == len(singles)
+
+
+def test_blob_sidecars_run_directly_behind_the_block():
+    """gossip_blob_sidecar sits where the reference's GossipBlobSidecar
+    does, and the kinds that were there keep their order."""
+    names = [k.name for k in WorkKind]
+    assert names[:4] == ["chain_reprocess", "gossip_block",
+                         "gossip_blob_sidecar", "api_request_p0"]
+    assert names[4:] == [
+        "gossip_aggregate", "gossip_attestation", "gossip_sync_contribution",
+        "gossip_sync_signature", "rpc_block", "chain_segment",
+        "api_request_p1", "gossip_voluntary_exit", "gossip_proposer_slashing",
+        "gossip_attester_slashing", "gossip_bls_change", "backfill_segment"]
+    bp = BeaconProcessor()
+    order = []
+    bp.submit(WorkItem(WorkKind.gossip_aggregate, payload=1, run_batch=lambda xs: order.append("agg")))
+    bp.submit(WorkItem(WorkKind.api_request_p0, run=lambda: order.append("api")))
+    bp.submit(WorkItem(WorkKind.gossip_blob_sidecar, payload=1, run_batch=lambda xs: order.append("blob")))
+    bp.submit(WorkItem(WorkKind.gossip_block, run=lambda: order.append("block")))
+    bp.run_until_idle()
+    assert order == ["block", "blob", "api", "agg"]
+
+
+def test_six_queued_blob_sidecars_are_one_batch():
+    """A block's six queued sidecars coalesce into ONE batch (the preset's
+    MAX_BLOBS_PER_BLOCK, scheduler.FIXED_CAPS); a seventh starts the next."""
+    from lighthouse_tpu.chain.scheduler import FIXED_CAPS
+
+    assert FIXED_CAPS == {"gossip_blob_sidecar": 6}
+    bp = BeaconProcessor()
+    got = []
+    for i in range(6):
+        bp.submit(WorkItem(WorkKind.gossip_blob_sidecar, payload=i, run_batch=lambda xs: got.append(list(xs))))
+    bp.run_until_idle()
+    assert got == [[0, 1, 2, 3, 4, 5]]
+    assert bp.processed[WorkKind.gossip_blob_sidecar] == 6
+    got.clear()
+    for i in range(7):
+        bp.submit(WorkItem(WorkKind.gossip_blob_sidecar, payload=i, run_batch=lambda xs: got.append(list(xs))))
+    bp.run_until_idle()
+    assert got == [[0, 1, 2, 3, 4, 5], [6]]
+    # a node on a fork with larger blocks rebases its scheduler's copy
+    bp.scheduler.fixed_caps["gossip_blob_sidecar"] = 9
+    got.clear()
+    for i in range(9):
+        bp.submit(WorkItem(WorkKind.gossip_blob_sidecar, payload=i, run_batch=lambda xs: got.append(list(xs))))
+    bp.run_until_idle()
+    assert [len(b) for b in got] == [9]
+
+
+def test_a_lone_blob_sidecar_is_a_batch_of_one_and_pipelines():
+    """One queued sidecar reaches run_batch as a batch of one; its
+    (handle, continuation) rides the in-flight window like a signature
+    batch's, and its device time stays out of the signature cost model."""
+    bp = BeaconProcessor()
+    log = []
+
+    class Handle:
+        def result(self):
+            log.append("resolved")
+            return "verdicts"
+
+    def run_batch(xs):
+        log.append(("batch", list(xs)))
+        return Handle(), lambda res: log.append(("cont", res))
+
+    bp.submit(WorkItem(WorkKind.gossip_blob_sidecar, payload="sc", run_batch=run_batch))
+    bp.run_until_idle()
+    assert log == [("batch", ["sc"]), "resolved", ("cont", "verdicts")]
+    assert bp.pipelined_batches == 1
+    assert bp.scheduler.model()["samples"] == 0

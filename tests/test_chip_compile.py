@@ -14,7 +14,7 @@ in the test's own process, with the persistent cache off around them (an
 entry written for a described chip cannot be read back without one).
 
 Unmarked: the compiles of a few seconds (`pairs` at each served bucket's
-set count). `slow`: the minute-long stage compiles at the served 64x128
+set count) and the KZG lane pass at its one served size (~half a minute). `slow`: the minute-long stage compiles at the served 64x128
 bucket (prepare ~1 min, hash-to-G2 ~3 min, of stage 4's two programs the
 Miller loop ~2 min and the final exponentiation ~1.25 min on eight host
 cores).
@@ -151,3 +151,29 @@ def test_tree_hash_ladder_compiles_for_v5e(one_chip, no_persistent_cache):
     ladder = engine._make_ladder(n, 1, True, None)
     words = jax.ShapeDtypeStruct((n, 8), np.uint32, sharding=one_chip)
     _assert_fits_hbm(ladder.lower(words).compile())
+
+
+def test_kzg_lane_pass_compiles_for_v5e_at_its_served_row(one_chip,
+                                                          no_persistent_cache):
+    """`msm.kzg_lincomb_kernel` at the ONE size it is served at — 16 blob
+    slots of 8 lanes, a full row of 128 — which tier-1 never executes
+    (XLA:CPU pays it lane by lane; tests/test_kzg.py patches the slots
+    down). Its outputs are the W = 1 pairing program's inputs at 4 pair
+    lanes. About half a minute."""
+    from lighthouse_tpu.crypto.jaxbls import msm
+
+    lanes = msm.KZG_BLOB_SLOTS * msm.KZG_ROWS
+    assert lanes == 128
+
+    def u32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=one_chip)
+
+    args = (u32(lanes, lb.NL), u32(lanes, lb.NL), u32(lanes),
+            u32(lanes, msm.KZG_SCALAR_BITS))
+    _assert_fits_hbm(jax.jit(msm.kzg_lincomb_kernel).lower(*args).compile())
+    gx, gy, pair_mask, in_subgroup = _shapes(
+        jax.eval_shape(msm.kzg_lincomb_kernel, *args))
+    pairs = msm.KZG_PAIR_LANES
+    assert gx == gy == ((pairs, lb.NL), jnp.uint32)
+    assert pair_mask == ((pairs,), jnp.bool_)
+    assert in_subgroup == ((msm.KZG_BLOB_SLOTS, 2), jnp.bool_)

@@ -266,3 +266,173 @@ def test_da_checker_lru_bounds():
     da.put_blob(b"\x03" * 32, FakeSC(0))
     assert len(da._pending) == 2
     assert b"\x01" * 32 not in da._pending
+
+
+# ------------------------------------------- the pipelined KZG batch
+
+
+class _Sc:
+    """What of a sidecar the KZG check reads."""
+
+    def __init__(self, blob, commitment, proof):
+        self.blob, self.kzg_commitment, self.kzg_proof = blob, commitment, proof
+
+
+@pytest.fixture(scope="module")
+def six_sidecars(env):
+    from lighthouse_tpu.crypto.bls381 import serde
+
+    _harness, _chain, setup = env
+    out = []
+    for i in range(6):
+        blob = _mk_blob(10 + i)
+        c = serde.g1_compress(kzg.blob_to_kzg_commitment(blob, setup))
+        p = serde.g1_compress(kzg.compute_blob_kzg_proof(blob, c, setup))
+        out.append(_Sc(blob, c, p))
+    return out
+
+
+def _counters():
+    from lighthouse_tpu.utils.metrics import REGISTRY
+
+    want = ("kzg_batches_total", "kzg_batch_sidecars_total",
+            "kzg_batch_fallback_total", "kzg_blobs_evaluated_total",
+            "kzg_points_validated_total")
+    by_name = {m.name: m for m in REGISTRY.all_metrics()}
+    return {n: by_name[n].value for n in want}
+
+
+def _moved(before):
+    after = _counters()
+    return {k.removeprefix("kzg_").removesuffix("_total"): after[k] - before[k]
+            for k in after}
+
+
+def _off_subgroup_commitment(seed: int) -> bytes:
+    """A compressed point on the curve and outside G1's subgroup."""
+    from lighthouse_tpu.crypto.bls381 import curve as cv, serde
+    from lighthouse_tpu.crypto.bls381.constants import P
+
+    x = seed
+    while True:
+        x += 1
+        y = pow((x ** 3 + 4) % P, (P + 1) // 4, P)
+        if y * y % P == (x ** 3 + 4) % P and not cv.g1_in_subgroup((x, y)):
+            return serde.g1_compress((x, y))
+
+
+def test_kzg_batch_true_gives_every_sidecar_true(env, six_sidecars):
+    _harness, chain, _setup = env
+    da = chain.data_availability
+    before = _counters()
+    handle, verdicts_of = da.submit_kzg_batch(six_sidecars)
+    assert verdicts_of(handle.result()) == [True] * 6
+    assert _moved(before) == {
+        "batches": 1, "batch_sidecars": 6, "batch_fallback": 0,
+        "blobs_evaluated": 6, "points_validated": 12}
+    assert da.verify_kzg_proofs(six_sidecars) is True
+    assert da.verify_kzg_proofs([]) is True
+
+
+def test_kzg_batch_false_is_resolved_sidecar_by_sidecar(env, six_sidecars):
+    """One sidecar carries another's proof: the batch is False, the fallback
+    verifies each alone, five True and that one False."""
+    _harness, chain, _setup = env
+    da = chain.data_availability
+    bad = list(six_sidecars)
+    bad[2] = _Sc(bad[2].blob, bad[2].kzg_commitment, bad[4].kzg_proof)
+    before = _counters()
+    handle, verdicts_of = da.submit_kzg_batch(bad)
+    assert verdicts_of(handle.result()) == [True, True, False, True, True, True]
+    # the batch's six, then six batches of one that are no `submit_kzg_batch`
+    assert _moved(before) == {
+        "batches": 1, "batch_sidecars": 6, "batch_fallback": 1,
+        "blobs_evaluated": 12, "points_validated": 24}
+    assert da.verify_kzg_proofs(bad) is False
+    # a batch of one that is False needs no second verification
+    before = _counters()
+    handle, verdicts_of = da.submit_kzg_batch([bad[2]])
+    assert verdicts_of(handle.result()) == [False]
+    assert _moved(before)["batch_fallback"] == 0
+
+
+def test_kzg_batch_malformed_input_is_its_own_sidecars_false(env, six_sidecars):
+    """A point outside the subgroup, a point off the curve, a field element
+    >= r, a wrong length: each is that sidecar's False and no one else's."""
+    from lighthouse_tpu.crypto.bls381.constants import R
+
+    _harness, chain, _setup = env
+    da = chain.data_availability
+    a, b, c, d, e, f = six_sidecars
+    # x = 0 is on no point of the curve with this flag byte: 4 is a square,
+    # so take an x whose x^3 + 4 is none
+    off_curve = None
+    x = 1
+    from lighthouse_tpu.crypto.bls381.constants import P
+    while off_curve is None:
+        x += 1
+        if pow((x ** 3 + 4) % P, (P - 1) // 2, P) != 1:
+            raw = bytearray(x.to_bytes(48, "big"))
+            raw[0] |= 0x80
+            off_curve = bytes(raw)
+    big = bytearray(c.blob)
+    big[32:64] = R.to_bytes(32, "big")
+    batch = [
+        _Sc(a.blob, _off_subgroup_commitment(7), a.kzg_proof),
+        _Sc(b.blob, b.kzg_commitment, off_curve),
+        _Sc(bytes(big), c.kzg_commitment, c.kzg_proof),
+        _Sc(d.blob[:-1], d.kzg_commitment, d.kzg_proof),
+        e, f,
+    ]
+    before = _counters()
+    handle, verdicts_of = da.submit_kzg_batch(batch)
+    assert verdicts_of(handle.result()) == [False, False, False, False, True, True]
+    moved = _moved(before)
+    # three never joined the batch; the bad point spoiled its sums, so the
+    # two sound ones were verified again alone
+    assert moved["batch_fallback"] == 1 and moved["batch_sidecars"] == 6
+    assert moved["blobs_evaluated"] == 3 + 2
+    assert da.verify_kzg_proofs(batch) is False
+    assert da.verify_kzg_proofs([e, f]) is True
+    # without the bad point nothing is verified twice
+    before = _counters()
+    handle, verdicts_of = da.submit_kzg_batch(batch[1:])
+    assert verdicts_of(handle.result()) == [False, False, False, True, True]
+    assert _moved(before) == {
+        "batches": 1, "batch_sidecars": 5, "batch_fallback": 0,
+        "blobs_evaluated": 2, "points_validated": 4}
+
+
+def test_gossip_blob_batch_imports_the_block_it_completes(env):
+    """chain.submit_gossip_blob_batch: the gossip checks a sidecar, ONE KZG
+    batch, and every sidecar its own outcome."""
+    harness, chain, setup = env
+    signed, sidecars = _blob_block(harness, chain, setup, 2)
+    types = types_for_slot(harness.spec, signed.message.slot)
+    root = types.BeaconBlock.hash_tree_root(signed.message)
+    with pytest.raises(AvailabilityPendingError):
+        chain.process_block(signed)
+    bad = sidecars[1].copy_with(kzg_proof=bytes(sidecars[0].kzg_proof))
+    stale = sidecars[0].copy_with(index=100)
+    done = []
+    before = _counters()
+    handle, cont = chain.submit_gossip_blob_batch(
+        [sidecars[0], bad, stale], on_done=done.append)
+    out = cont(handle.result())
+    assert done == [out]
+    assert out[0] is None                      # verified, block still short
+    assert isinstance(out[1], BlobError) and "KZG" in str(out[1])
+    assert isinstance(out[2], BlobError) and "index" in str(out[2])
+    moved = _moved(before)
+    assert moved["batches"] == 1 and moved["batch_sidecars"] == 2
+    assert moved["batch_fallback"] == 1
+    # the true second sidecar completes the block; a replay is ignored
+    handle, cont = chain.submit_gossip_blob_batch([sidecars[1], sidecars[0]])
+    out = cont(handle.result())
+    assert out[0] == root and chain.head_root == root
+    assert isinstance(out[1], BlobIgnoreError)
+    # nothing reaches the KZG check: no handle, on_done all the same
+    done.clear()
+    assert chain.submit_gossip_blob_batch([stale], on_done=done.append) is None
+    assert len(done) == 1 and isinstance(done[0][0], BlobError)
+    harness.apply_block(signed)
