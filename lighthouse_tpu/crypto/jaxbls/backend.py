@@ -110,7 +110,8 @@ _TREE_SUM_LANE_ADDS = REGISTRY.counter_vec(
 _MILLER_PLAN = REGISTRY.counter_vec(
     "jaxbls_miller_plan_total",
     "what pairing_ops.miller_lane_plan gives the pairing stage, per "
-    "dispatch, for the pair lanes one Miller loop sees: dispatches, "
+    "dispatch (BLS and KZG alike), for the pair lanes one Miller loop "
+    "sees: dispatches, "
     "accumulators = W carried through the loop, in_step_levels = dense "
     "Fq12 tree levels left inside each of its steps; in_step_levels over "
     "dispatches is 0 where the lines are narrowed only after the loop",
@@ -132,6 +133,15 @@ Z_WINDOW = 1          # z-scaling digit width: 1 = plain double-and-add bits
 Z_DIGITS = 64 // Z_WINDOW
 
 _LIVE_MESH = object()  # sentinel: "resolve parallel.get_mesh() lazily"
+
+
+def _count_miller_plan(miller_pairs: int) -> None:
+    """One dispatch into jaxbls_miller_plan_total: the plan of the pair
+    lanes ONE Miller loop of its pairing stage sees."""
+    w, in_step_levels, _ = po.miller_lane_plan(miller_pairs)
+    _MILLER_PLAN.labels("dispatches").inc()
+    _MILLER_PLAN.labels("accumulators").inc(w)
+    _MILLER_PLAN.labels("in_step_levels").inc(in_step_levels)
 
 
 def _next_pow2(n: int) -> int:
@@ -287,28 +297,30 @@ def _stage_pairs(z_pk, h_jac, sig_acc, set_mask):
 
 def _stage_pairing(px, py, qxx, qyy, pair_mask):
     """Stage 4 as ONE program: shared-accumulator multi-Miller loop + final
-    exponentiation (_verify_kernel, the meshed jit build and one chip's
-    one-accumulator buckets; its wide buckets run the two programs below)."""
+    exponentiation (_verify_kernel, the meshed jit build, and the
+    one-accumulator buckets of a platform without the row; where the loop
+    carries the row, one chip runs the two programs below)."""
     return po.pairing_product_is_one((px, py), (qxx, qyy), pair_mask)
 
 
 def _stage_miller(px, py, qxx, qyy, pair_mask):
-    """Stage 4 of a wide bucket, first program: the shared-accumulator
-    multi-Miller loop, one Fq12 out. Compiled per bucket (n + 1 pairs)."""
+    """Stage 4 on a row of accumulators, first program: the
+    shared-accumulator multi-Miller loop, one Fq12 out. Compiled per bucket
+    (n + 1 pairs)."""
     return po.miller_loop_product((px, py), (qxx, qyy), pair_mask)
 
 
 def _stage_final_exp(f):
-    """Stage 4 of a wide bucket, second program: final exponentiation of
-    the Miller value and the comparison with one. No pair axis: one
-    program for every such bucket."""
+    """Stage 4 on a row of accumulators, second program: final
+    exponentiation of the Miller value and the comparison with one. No pair
+    axis: one program for every bucket, the KZG check included."""
     return tw.fq12_eq_one(po.final_exponentiation(f))
 
 
 def _verify_kernel(pk_x, pk_y, pk_mask, sig_x, sig_y, us, z_digits, set_mask):
     """The full device program as ONE composition (kept for the sharding
     tests and the multichip dryrun; the hot path runs the four stages as
-    SEPARATE jit calls, five for a wide bucket on one chip — smaller
+    SEPARATE jit calls, five on one chip where the row is — smaller
     programs compile minutes faster and cache independently,
     intermediates stay device-resident between calls, and stage 4's wide
     Miller loop keeps the layout it has compiled alone: _PairingPrograms).
@@ -406,20 +418,21 @@ def _build_shard_map_pairing(mesh):
 
 class _PairingPrograms:
     """Stage 4 on one chip, under the one stage name. Where the Miller
-    loop carries a row of accumulators (miller_lane_plan's W > 1: every
-    batch bucket) it is TWO jitted programs, `_stage_miller` then
-    `_stage_final_exp`, enqueued back to back — the Miller value stays on
-    the device, the host waits for neither. They share no compilation on
-    purpose: beside final exponentiation's one-lane scans the compiler
-    lays the wide scan's point carry limb-minor and the stage takes 226 ms
-    where the two take 127 at 257 pairs, 116 where they take 89 at 65.
-    With one accumulator (the urgent bucket's 5 pairs, KZG's 4) the ONE
-    program `_stage_pairing` stays: there the scan already runs at its own
-    time and the two programs take 4.5 ms more than the one (157.8 against
-    153.3; all six on a v5e, scripts/measure_miller_lanes.py --stage,
-    PERF.md S6, PR 32). Callable like a jitted stage; `.lower` gives the
-    lowerings of what serves the shape, so program capture sees the whole
-    stage."""
+    loop carries a row of accumulators (miller_lane_plan's W > 1: on a TPU
+    every bucket, the urgent one's 5 pairs and KZG's 4 padded to the row
+    inside the program; elsewhere from 33 pairs on) it is TWO jitted
+    programs, `_stage_miller` then `_stage_final_exp`, enqueued back to
+    back — the Miller value stays on the device, the host waits for
+    neither. They share no compilation on purpose: beside final
+    exponentiation's one-lane scans the compiler lays the wide scan's
+    point carry limb-minor and the stage takes 226 ms where the two take
+    127 at 257 pairs, 116 where they take 89 at 65, and the same 116.4
+    against 89.1 / 89.2 at 5 and 4 pairs on the row (a v5e,
+    scripts/measure_miller_lanes.py --stage, PERF.md S6, PR 32 and PR 35).
+    With one accumulator (few pairs on a platform whose lanes cost a lane
+    each: the CPU tests) the ONE program `_stage_pairing` stays. Callable
+    like a jitted stage; `.lower` gives the lowerings of what serves the
+    shape, so program capture sees the whole stage."""
 
     def __init__(self, one, miller, final_exp):
         self.one = one
@@ -514,14 +527,16 @@ class _PairingDispatch:
 def _get_stages(mesh=None):
     """The four stage callables (each program cached separately on disk).
 
-    Four stages, five programs a wide bucket: with `mesh=None` (the urgent
-    single-chip lane, host-side callers like aggregate_verify, and
+    Four stages, five programs a bucket on a TPU: with `mesh=None` (the
+    urgent single-chip lane, host-side callers like aggregate_verify, and
     single-device processes) stages 1-3 are plain jits — input placement
-    decides the executable — and stage 4 is a _PairingPrograms: from 33
-    pairs on the Miller loop and the final exponentiation as two jits
-    under the one stage name, because compiled together the wide Miller
-    scan runs at half its speed; below, the one program. With a mesh, the
-    stages compile under that mesh's contract: explicit `in_shardings`
+    decides the executable — and stage 4 is a _PairingPrograms: where the
+    Miller loop carries a row of accumulators (every pair count on a TPU,
+    from 33 pairs on elsewhere) the Miller loop and the final
+    exponentiation as two jits under the one stage name, because compiled
+    together the wide Miller scan runs at half its speed; with one
+    accumulator, the one program. With a mesh, the stages compile under
+    that mesh's contract: explicit `in_shardings`
     over the 1-D `sets` (2-D `(sets, pks)`) axes for every host-marshalled
     input — exactly the NamedShardings `put_sets`/`put_pk_grid` commit, so
     the lowered programs (and their persistent-cache keys) are identical
@@ -544,9 +559,9 @@ def _get_stages(mesh=None):
       h2c:     us (consumed into the SSWU map);
       pairs:   the stage-1/2 intermediates (z_pk, h_jac, sig_acc) and
                set_mask — all dead after pair assembly;
-      pairing: everything (the output is one scalar) — of a wide
-               bucket's two programs, the Miller loop its five inputs,
-               the final exponentiation its one.
+      pairing: everything (the output is one scalar) — of the two
+               programs, the Miller loop its five inputs, the final
+               exponentiation its one.
 
     Cached per (donation mode, mesh signature) — tests flip
     LIGHTHOUSE_TPU_DONATE and the mesh seams within one process and both
@@ -892,10 +907,7 @@ class JaxBackend:
         miller_pairs = n + 1
         if isinstance(pairing_stage, _PairingDispatch):
             miller_pairs = pairing_stage.miller_pairs(miller_pairs)
-        w, in_step_levels, _ = po.miller_lane_plan(miller_pairs)
-        _MILLER_PLAN.labels("dispatches").inc()
-        _MILLER_PLAN.labels("accumulators").inc(w)
-        _MILLER_PLAN.labels("in_step_levels").inc(in_step_levels)
+        _count_miller_plan(miller_pairs)
 
         pk_x, pk_y, pk_mask = self._marshal_pubkeys(
             sets, n, m, single_chip=single_chip
@@ -1098,11 +1110,13 @@ class JaxBackend:
         combinations C' = sum r_pows[i] C_i + y_scalars[i] G1 + z_scalars[i]
         W_i and W' = sum r_pows[i] W_i in the same double-and-add pass
         (msm.kzg_lincomb_kernel), then e(C', H) e(-W', tau H) == 1 on the
-        W = 1 pairing program the urgent BLS bucket runs — C' and W' never
-        leave the device. Points are host affine pairs on the curve (None =
-        infinity), scalars ints mod r. Returns a ticket; `.result()` reads
-        the device ONCE: (ok, [(C_i in the subgroup, W_i in it), ...]).
-        `ok` means nothing unless every flag is True."""
+        BLS stage 4 (`_get_stages()[3]`: on a TPU its four pair lanes padded
+        to the Miller loop's row, then the final exponentiation every
+        bucket shares) — C' and W' never leave the device. Points are host
+        affine pairs on the curve (None = infinity), scalars ints mod r.
+        Returns a ticket; `.result()` reads the device ONCE: (ok, [(C_i in
+        the subgroup, W_i in it), ...]). `ok` means nothing unless every
+        flag is True."""
         import time
 
         from ...parallel import put_single
@@ -1144,6 +1158,7 @@ class JaxBackend:
         qx, qy = _kzg_g2_side(tau_g2)
         _KZG_LANES.labels("real").inc(6 * n_real)
         _KZG_LANES.labels("padded").inc(lanes)
+        _count_miller_plan(_msm.KZG_PAIR_LANES)
         _MARSHALLED_BYTES.labels("kzg").inc(
             px.nbytes + py.nbytes + live.nbytes + bits.nbytes
             + qx.nbytes + qy.nbytes

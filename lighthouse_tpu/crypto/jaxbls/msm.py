@@ -143,7 +143,8 @@ KZG_BLOB_SLOTS = 16         # also the most blobs a batch holds
 (KZG_ROW_C, KZG_ROW_G1, KZG_ROW_ZW, KZG_ROW_W,
  KZG_ROW_ORDER_C, KZG_ROW_ORDER_W) = range(6)
 KZG_SCALAR_BITS = 255       # r < 2^255, and so is every scalar mod r
-KZG_PAIR_LANES = 4          # the W = 1 pairing program's smallest bucket
+KZG_PAIR_LANES = 4          # the two-pair check's lanes: the BLS stage 4 at its
+                            # smallest bucket (on a TPU it pads them to its row)
 
 
 def kzg_lincomb_kernel(px, py, live, bits):

@@ -17,12 +17,13 @@ W is read from the pair count (`miller_lane_plan`). On this chip a field
 operation on exactly 128 pair lanes costs what it costs on one lane and
 less than on 2 to 64, so narrowing a step's lines to ONE value inside every
 step (log2(n/2) dense products, each on fewer lanes than the last) costs
-more than everything else in the step. From 33 pairs on the loop carries
-W = 128 accumulators, one full row of vector lanes, and pads the pair axis
-to whole rows with masked lanes; a few pairs keep W = 1, the whole product
-tree inside the step. Squaring distributes over a product and every field
-operation ends canonical (< P), so the product of the W finals is limb for
-limb the W = 1 value.
+more than everything else in the step. So the loop carries W = 128
+accumulators, one full row of vector lanes, and pads the pair axis to whole
+rows with masked lanes: at every pair count in a program built for a TPU,
+from 33 pairs on elsewhere, where a lane costs a lane and a few pairs keep
+W = 1, the whole product tree inside the step. Squaring distributes over a
+product and every field operation ends canonical (< P), so the product of
+the W finals is limb for limb the W = 1 value.
 
 Line evaluations use inversion-free Jacobian steps; every line is scaled by
 the Fq2 unit 2YZ^3 (doubling) or Z3 (addition), which the final
@@ -239,14 +240,32 @@ def _combine_lines(line, valid_mask, w: int):
 #    65 pairs   W=1 270.5   W=16 175.5   W=32 140.4   W=64 157.0 (no padding)
 #                                                     W=128  42.2 (padded)
 #     5 pairs   W=1 110.9   W=2  118.1                W=128  42.0 (padded)
+#     4 pairs   W=1 103.7                             W=128  43.4 (padded; PR 35)
 #
-# MILLER_WIDE_FROM keeps the one-accumulator loop (the program PR 29 served,
-# byte for byte) for the few-pair shapes: the urgent bucket's 5 pairs, KZG's
-# 4, and the 3..17 pairs the CPU tests run, where 128 lanes cost 128 lanes.
-# On the chip the padded row wins there too (last line): lowering the
-# constant is the next step, once the CPU tests' cost of it is dealt with.
+# Stage 4 on the padded row (--stage, PR 35; ms a call: the one program
+# _stage_pairing / _stage_miller / _stage_final_exp / the two back to back):
+#
+#     5 pairs   116.4 / 42.1 / 48.0 / 89.1     (W = 1, PR 32: 153.3 / 111.0 / 48.0 / 157.8)
+#     4 pairs   116.4 / 42.1 / 48.0 / 89.2
+#
+# the 65-pair row's numbers (116.3 / 42.1 / 47.9 / 89.3): a row is a row,
+# whatever it holds, and the two programs beat the one wherever it is carried.
+#
+# XLA:CPU (sandbox, jax.jit(miller_loop_product), s a call, compile aside):
+#
+#     5 pairs   W=1   2.42                            W=128 46.25 (padded)
+#
+# The two platforms differ in what a lane is. The chip runs 128 lanes in one
+# instruction, so a padded row costs what its one live lane costs and saves
+# the in-step tree; a CPU core walks the lanes, so 128 lanes cost 128 lanes
+# and the row is worth it only from a few dozen pairs on. MILLER_WIDE_FROM is
+# therefore read by the platform the program is built for: on a TPU every pair
+# count takes the row (the urgent bucket's 5 pairs, KZG's 4, a chip's share of
+# a meshed bucket); elsewhere fewer than 33 pair lanes keep ONE accumulator,
+# the loop PR 29 served, byte for byte.
 MILLER_LANES = 128          # accumulators of the wide loop: one row of vector lanes
-MILLER_WIDE_FROM = 33       # fewer pair lanes than this keep ONE accumulator
+#: fewest pair lanes that take the row, by platform (one not named: "cpu"'s)
+MILLER_WIDE_FROM = {"tpu": 1, "cpu": 33}
 
 
 def _lines_per_accumulator(n_pairs: int, w: int) -> int:
@@ -259,30 +278,35 @@ def _lines_per_accumulator(n_pairs: int, w: int) -> int:
     return g
 
 
-def miller_lane_plan(n_pairs: int) -> tuple:
-    """What miller_loop_product does with n_pairs pair lanes:
-    (W, in_step_levels, after_loop_levels). Pure, no jit — the loop follows
-    it, the backend's plan counter reads it.
+def miller_lane_plan(n_pairs: int, platform: str | None = None) -> tuple:
+    """What miller_loop_product does with n_pairs pair lanes in a program
+    built for `platform`: (W, in_step_levels, after_loop_levels). Pure, no
+    jit — the loop follows it, the backend's plan counter reads it.
+    `platform` None is the process's own, jax.default_backend(), read here
+    and nowhere else; a process that lowers for a chip it does not run on
+    names it.
 
-    From MILLER_WIDE_FROM pair lanes on, W = MILLER_LANES accumulators: the
-    pair axis is padded with masked lanes to W * g (g a power of two, + the
-    one pair over), each accumulator takes g lines a step — one sparse
-    line (g = 1), a line pair (g = 2), or line pairs and in_step_levels =
-    log2(g) - 1 dense tree levels — and one product tree of
-    after_loop_levels levels narrows the W accumulators after the loop.
-    Below that, W = 1 and the whole tree over the n // 2 line pairs stays
-    in the step."""
+    From the platform's MILLER_WIDE_FROM pair lanes on (every count on a
+    TPU), W = MILLER_LANES accumulators: the pair axis is padded with
+    masked lanes to W * g (g a power of two, + the one pair over), each
+    accumulator takes g lines a step — one sparse line (g = 1), a line pair
+    (g = 2), or line pairs and in_step_levels = log2(g) - 1 dense tree
+    levels — and one product tree of after_loop_levels levels narrows the W
+    accumulators after the loop. Below that, W = 1 and the whole tree over
+    the n // 2 line pairs stays in the step."""
     assert n_pairs >= 1
-    if n_pairs < MILLER_WIDE_FROM:
+    if platform is None:
+        platform = jax.default_backend()
+    if n_pairs < MILLER_WIDE_FROM.get(platform, MILLER_WIDE_FROM["cpu"]):
         return 1, max(n_pairs // 2, 1).bit_length() - 1, 0
     w = MILLER_LANES
     g = _lines_per_accumulator(n_pairs, w)
     return w, max(g.bit_length() - 2, 0), w.bit_length() - 1
 
 
-def miller_loop_product(p_aff, q_aff, valid_mask):
+def miller_loop_product(p_aff, q_aff, valid_mask, platform: str | None = None):
     """Multi-pairing Miller loop over W shared accumulators (W from
-    miller_lane_plan; W = 1 is one shared f).
+    miller_lane_plan for `platform`; W = 1 is one shared f).
 
     Per bit: one fq12_sqr of the W accumulators (instead of one per pair),
     each pair's line folded into its accumulator — sparsely, or through
@@ -292,7 +316,7 @@ def miller_loop_product(p_aff, q_aff, valid_mask):
     xp, yp = p_aff
     xq, yq = q_aff
     n = xp.shape[0]
-    w = miller_lane_plan(n)[0]
+    w = miller_lane_plan(n, platform)[0]
     g = _lines_per_accumulator(n, w)
     f = tw.FQ12_ONE
     if w > 1:

@@ -2,14 +2,15 @@
 
 The dispatch path runs four stages (prepare, hash-to-G2, pairs, pairing —
 `crypto/jaxbls/backend.py`) asynchronously: the host enqueues all four and
-blocks once, on the final result. Four stages, five jitted programs for a
-batch bucket on one chip: from 33 pairs on stage 4 is the Miller loop
-(`_stage_miller`, a program a bucket) and the final exponentiation
-(`_stage_final_exp`, one program for every such bucket), enqueued back to
-back under the one stage name `pairing` — compiled as one program the wide
-Miller scan takes twice its time; below 33 pairs (the urgent bucket) the one
-program `_stage_pairing` stays, which is the faster there (PERF.md S6, PR
-32). A profiler capture shows the two apart (`jaxbls:pairing.miller`,
+blocks once, on the final result. Four stages, five jitted programs a
+bucket on one chip: where the Miller loop carries a row of accumulators
+(`pairing_ops.miller_lane_plan`: every pair count on a TPU, from 33 pairs on
+elsewhere) stage 4 is the Miller loop (`_stage_miller`, a program a bucket)
+and the final exponentiation (`_stage_final_exp`, one program for every
+bucket), enqueued back to back under the one stage name `pairing` — compiled
+as one program the wide Miller scan takes twice its time; with one
+accumulator the one program `_stage_pairing` stays (PERF.md S6, PR 32 and
+PR 35). A profiler capture shows the two apart (`jaxbls:pairing.miller`,
 `jaxbls:pairing.final_exp`, the programs' own names); everything timed or
 counted here keeps the four stage names. That is the right shape
 for throughput, but it makes the device a single opaque span — PR 2's

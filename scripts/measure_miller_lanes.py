@@ -2,33 +2,36 @@
 """What does the Miller loop pay for, a lane or a call?
 
 Measures, on the device JAX gives (a TPU, or it says so), what
-`pairing_ops.miller_lane_plan`'s two constants are set from:
+`pairing_ops.miller_lane_plan`'s constants (`MILLER_LANES`, and
+`MILLER_WIDE_FROM`'s line for the platform) are set from:
 
   1. the field operations of one Miller step, each on L pair lanes inside a
      `fori_loop` (as the scan runs them): `fq12_mul`, `fq12_sqr`,
      `_line_mul_line`, `fq12_mul_by_014` and `_dbl_step`, for L = 1 .. 257
      (L = 0: no batch axis at all, the shape of the W = 1 accumulator);
      seconds a call;
-  2. `miller_loop_product` itself at the three served pair counts (5, 65,
-     257) with `MILLER_LANES` patched over a ladder of W (W = 1: the
-     one-accumulator loop), every Miller value checked equal, limb for
-     limb, to W = 1's;
+  2. `miller_loop_product` itself at the served pair counts (4 = the KZG
+     check, 5, 65, 257) with `MILLER_LANES` and `MILLER_WIDE_FROM` patched
+     over a ladder of W (W = 1: the one-accumulator loop), every Miller
+     value checked equal, limb for limb, to W = 1's;
   3. one traced call of `_stage_pairing` at 65 pairs with W = 1 (the
      program every PR up to 29 served): device seconds by op name, and the
      compiled HLO beside it, to say which loop `%while.29` is;
   4. (`--stage`, alone) stage 4 as one program and as the two that serve it,
-     at the three served pair counts with the shipped plan: ms a call of
-     `_stage_pairing`, of `_stage_miller`, of `_stage_final_exp`, and of the
-     two enqueued back to back with one `block_until_ready`, the verdicts
-     checked equal. The table behind `backend._PairingPrograms`, which
-     serves the two from 33 pairs on and the one below.
+     at the served pair counts with the shipped plan (on a TPU: the row at
+     every count): ms a call of `_stage_pairing`, of `_stage_miller`, of
+     `_stage_final_exp`, and of the two enqueued back to back with one
+     `block_until_ready`, the verdicts checked equal. The table behind
+     `backend._PairingPrograms`, which serves the two wherever the plan's
+     W > 1.
 
     chiprun --chips 1 -- python3 scripts/measure_miller_lanes.py \
-        [--budget-s N] [--jobs 65:1,65:128,...] [--stage]
+        [--budget-s N] [--jobs 65:1,65:128,...] [--stage [--pairs 5,4]]
 
 Part 2 starts no further compile once N seconds (default 1500) have passed;
 `--jobs` runs part 2 alone, on the listed pairs:W (W = 1 first for each
-pair count: it is the reference); `--stage` runs part 4 alone;
+pair count: it is the reference); `--stage` runs part 4 alone, `--pairs`
+names its pair counts;
 `--rehearse` runs toy sizes (a CPU dry run of the script, not a
 measurement). Prints one JSON object and writes it, as it grows, to
 chiprun_out/miller_lanes.json, part 4 to chiprun_out/stage_split.json (a
@@ -65,6 +68,7 @@ CALLS_PER_LOOP = 16
 # then narrower rows
 MILLER_JOBS = (
     (257, 1), (257, 128), (65, 1), (65, 128), (5, 1), (5, 128),
+    (4, 1), (4, 128),
     (65, 64), (65, 32), (257, 64), (65, 16), (257, 32), (5, 2),
     (257, 16), (65, 8), (257, 8),
 )
@@ -72,7 +76,7 @@ TRACED_PAIRS = 65
 REPS = 5
 OUT = "chiprun_out/miller_lanes.json"
 STAGE_OUT = "chiprun_out/stage_split.json"
-STAGE_PAIRS = (5, 65, 257)
+STAGE_PAIRS = (5, 4, 65, 257)
 
 
 def _timed(fn, *args, fresh=False):
@@ -174,12 +178,19 @@ def _save(out, path=OUT):
         json.dump(out, fh, indent=1)
 
 
+def _wide_from(lanes: int) -> dict:
+    """`MILLER_WIDE_FROM` that gives every pair count W = `MILLER_LANES`
+    (lanes > 1) or W = 1, on whatever platform this runs: the one entry
+    every platform falls back to."""
+    return {"cpu": 1 if lanes > 1 else 1 << 30}
+
+
 def _traced_stage(out, n_pairs: int):
     """One profiled window of `_stage_pairing` at W = 1: device seconds by
     op name (the benchmark's own reduction of names), HLO kept beside."""
     from benchmarks import trace_reduce
 
-    po.MILLER_WIDE_FROM = 1 << 30
+    po.MILLER_WIDE_FROM = _wide_from(1)
     p, q, mask = _pairs(n_pairs, 11)
     compiled = jax.jit(be._stage_pairing).lower(*p, *q, mask).compile()
     with gzip.open("chiprun_out/stage_pairing_w1_%d.hlo.txt.gz" % n_pairs,
@@ -266,7 +277,9 @@ def main() -> int:
     if "--stage" in sys.argv:
         out = {"device": {"platform": dev.platform, "kind": dev.device_kind},
                "stage_split": []}
-        same = _stage_split(out, (2, 5) if small else STAGE_PAIRS)
+        pairs = option("--pairs")
+        same = _stage_split(out, tuple(map(int, pairs.split(","))) if pairs
+                            else (2, 5) if small else STAGE_PAIRS)
         print(json.dumps(out))
         return 0 if same else 1
 
@@ -298,8 +311,7 @@ def main() -> int:
         if n not in operands:
             operands[n] = _pairs(n, n)
         p, q, mask = operands[n]
-        po.MILLER_LANES = lanes
-        po.MILLER_WIDE_FROM = 0 if lanes > 1 else 1 << 30
+        po.MILLER_LANES, po.MILLER_WIDE_FROM = lanes, _wide_from(lanes)
         w, in_step, after = po.miller_lane_plan(n)
         assert w == lanes, (n, lanes, w)
         fn = jax.jit(lambda p, q, m: po.miller_loop_product(p, q, m))

@@ -17,7 +17,8 @@ Unmarked: the compiles of a few seconds (`pairs` at each served bucket's
 set count) and the KZG lane pass at its one served size (~half a minute). `slow`: the minute-long stage compiles at the served 64x128
 bucket (prepare ~1 min, hash-to-G2 ~3 min, of stage 4's two programs the
 Miller loop ~2 min and the final exponentiation ~1.25 min on eight host
-cores).
+cores), and the Miller loop of the urgent 4x128 bucket, whose 5 pairs a
+program built for a TPU pads to one row of 128 lanes.
 """
 
 import numpy as np
@@ -30,6 +31,7 @@ from jax.sharding import SingleDeviceSharding
 from lighthouse_tpu.crypto.jaxbls import backend as be
 from lighthouse_tpu.crypto.jaxbls import h2c_ops as h2
 from lighthouse_tpu.crypto.jaxbls import limbs as lb
+from lighthouse_tpu.crypto.jaxbls import pairing_ops as po
 
 V5E_HBM_BYTES = 16 * 1024**3
 N_SETS, N_PKS = 64, 128   # the served gossip bucket (chip_smoke.py)
@@ -89,11 +91,19 @@ def _stage_args(n: int, m: int, sharding) -> dict:
     }
 
 
+def _stage_miller_for_a_tpu(px, py, qxx, qyy, pair_mask):
+    """`backend._stage_miller` as a process on the chip lowers it. The
+    Miller loop's lane plan reads the platform off the process, which is
+    the CPU here, so this one names the platform it compiles for."""
+    return po.miller_loop_product((px, py), (qxx, qyy), pair_mask,
+                                  platform="tpu")
+
+
 _STAGE_FNS = {
     "prepare": be._stage_prepare,
     "h2c": h2.hash_to_g2_jacobian,
     "pairs": be._stage_pairs,
-    "miller": be._stage_miller,
+    "miller": _stage_miller_for_a_tpu,
     "final_exp": be._stage_final_exp,
 }
 
@@ -122,6 +132,7 @@ def _shapes(tree):
     pytest.param("prepare", (N_SETS, N_PKS), marks=pytest.mark.slow),
     pytest.param("h2c", (N_SETS, N_PKS), marks=pytest.mark.slow),
     pytest.param("miller", (N_SETS, N_PKS), marks=pytest.mark.slow),
+    pytest.param("miller", SERVED_BUCKETS[0], marks=pytest.mark.slow),
     pytest.param("final_exp", (N_SETS, N_PKS), marks=pytest.mark.slow),
 ], ids=lambda v: v if isinstance(v, str) else "%dx%d" % v)
 def test_stage_compiles_for_v5e(stage, bucket, one_chip,
@@ -158,8 +169,7 @@ def test_kzg_lane_pass_compiles_for_v5e_at_its_served_row(one_chip,
     """`msm.kzg_lincomb_kernel` at the ONE size it is served at — 16 blob
     slots of 8 lanes, a full row of 128 — which tier-1 never executes
     (XLA:CPU pays it lane by lane; tests/test_kzg.py patches the slots
-    down). Its outputs are the W = 1 pairing program's inputs at 4 pair
-    lanes. About half a minute."""
+    down). Its outputs are the pairing stage's inputs at 4 pair lanes. About half a minute."""
     from lighthouse_tpu.crypto.jaxbls import msm
 
     lanes = msm.KZG_BLOB_SLOTS * msm.KZG_ROWS

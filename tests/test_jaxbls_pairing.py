@@ -115,26 +115,33 @@ def test_final_exp_matches_python_on_random_miller_output():
 
 # ------------------------------------------- the Miller loop's lane plan
 
-# (pair lanes, plan): W accumulators, dense tree levels left in each step,
-# levels of the one tree after the loop. 5 / 65 / 257 are the served
-# buckets (4x128, 64x128, 256x512); 17 and 65 are also a chip's share of
-# the gossip and block buckets' pairs (65 -> 68, 257 -> 260) on a four-chip
-# `sets` mesh.
-_PLAN_ROWS = [
+# (platform the program is built for, pair lanes, plan): W accumulators,
+# dense tree levels left in each step, levels of the one tree after the
+# loop. 5 / 65 / 257 are the served buckets (4x128, 64x128, 256x512), 4 the
+# KZG check's lanes; 17 and 65 are also a chip's share of the gossip and
+# block buckets' pairs (65 -> 68, 257 -> 260) on a four-chip `sets` mesh. On
+# a TPU a row of lanes costs what one lane costs and every count takes the
+# row; on the CPU, where the tests run, a lane costs a lane.
+_CPU_PLANS = [
     (1, (1, 0, 0)), (2, (1, 0, 0)), (3, (1, 0, 0)), (5, (1, 1, 0)),
     (9, (1, 2, 0)), (17, (1, 3, 0)), (33, (128, 0, 7)), (65, (128, 0, 7)),
     (129, (128, 0, 7)), (257, (128, 0, 7)), (258, (128, 1, 7)),
 ]
+_PLAN_ROWS = [("cpu", n, plan) for n, plan in _CPU_PLANS] + [
+    ("tpu", n, plan if n >= 33 else (128, 0, 7)) for n, plan in _CPU_PLANS]
 
 
-@pytest.mark.parametrize("n_pairs,plan", _PLAN_ROWS,
-                         ids=[str(n) for n, _ in _PLAN_ROWS])
-def test_miller_lane_plan(n_pairs, plan):
-    w, in_step, after = po.miller_lane_plan(n_pairs)
+@pytest.mark.parametrize("platform,n_pairs,plan", _PLAN_ROWS,
+                         ids=["%s-%d" % row[:2] for row in _PLAN_ROWS])
+def test_miller_lane_plan(platform, n_pairs, plan):
+    w, in_step, after = po.miller_lane_plan(n_pairs, platform)
     assert (w, in_step, after) == plan
+    if platform == jax.default_backend():
+        assert po.miller_lane_plan(n_pairs) == plan     # None = this process
     if w == 1:
-        # today's loop: the whole tree over the line pairs is in the step
-        assert n_pairs < po.MILLER_WIDE_FROM and after == 0
+        # the loop of a platform without the row: the whole tree over the
+        # line pairs is in the step
+        assert n_pairs < po.MILLER_WIDE_FROM[platform] and after == 0
         return
     # a full row of accumulators, each taking g lines a step: g the
     # smallest power of two that seats every pair but the one over
@@ -151,18 +158,21 @@ def test_miller_lane_plan(n_pairs, plan):
 def miller_loops():
     """The backend's `_stage_miller` (miller_loop_product over the stage's
     five flat arguments) at nine pair lanes under three plans: "w1" one
-    accumulator (today's loop), "pairs" four accumulators of a line pair
+    accumulator (the CPU's loop), "pairs" four accumulators of a line pair
     each + the pair over (the block bucket's form), "padded" sixteen
-    accumulators of one sparse line, seven of them padding (the gossip
-    bucket's form). The module's three Miller-loop compiles."""
+    accumulators of one sparse line, seven of them padding (the form of
+    the gossip bucket and, on a TPU, of the urgent bucket and the KZG
+    check). The module's three Miller-loop compiles."""
     shipped = po.MILLER_LANES, po.MILLER_WIDE_FROM
     plans = {"w1": (128, 1 << 30, (1, 2, 0), 8),
-             "pairs": (4, 0, (4, 0, 2), 2),
-             "padded": (16, 0, (16, 0, 4), 1)}
+             "pairs": (4, 1, (4, 0, 2), 2),
+             "padded": (16, 1, (16, 0, 4), 1)}
     fns = {}
     try:
         for name, (lanes, wide_from, plan, g) in plans.items():
-            po.MILLER_LANES, po.MILLER_WIDE_FROM = lanes, wide_from
+            # the one entry every platform falls back to: whatever this
+            # process runs on takes it
+            po.MILLER_LANES, po.MILLER_WIDE_FROM = lanes, {"cpu": wide_from}
             assert po.miller_lane_plan(9) == plan
             assert po._lines_per_accumulator(9, plan[0]) == g
             # trace and compile now, while the patched plan is in force
@@ -202,13 +212,16 @@ def _nine_lanes(masked, tamper=False, filler=None):
 
 
 @pytest.mark.parametrize("tamper", [False, True], ids=["valid", "tampered"])
+@pytest.mark.parametrize("masked", [{3, 6}, {2, 3, 4, 5, 6, 7}],
+                         ids=["two_masked", "kzg_shape"])
 def test_wide_accumulators_give_the_one_accumulator_miller_value(
-        miller_loops, tamper):
-    """Nine pairs, two masked lanes (3 and 6): the Miller value of four
-    and of sixteen accumulators equals one accumulator's limb for limb,
-    BEFORE final exponentiation, and the verdict after it is the product's
-    truth."""
-    lanes = _nine_lanes({3, 6}, tamper)
+        miller_loops, masked, tamper):
+    """Nine pairs, some lanes masked — two (3 and 6), or all but two and
+    the closing pair, what the KZG check's two pairs are in a padded row:
+    the Miller value of four and of sixteen accumulators equals one
+    accumulator's limb for limb, BEFORE final exponentiation, and the
+    verdict after it is the product's truth."""
+    lanes = _nine_lanes(masked, tamper)
     narrow = np.asarray(miller_loops["w1"](*lanes))
     for name in ("pairs", "padded"):
         wide = miller_loops[name](*lanes)

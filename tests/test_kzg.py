@@ -328,9 +328,12 @@ def test_blob_batch_counters_move_by_the_stated_amounts(smoke):
 def test_device_subgroup_check_is_multiplication_by_the_group_order(monkeypatch):
     """The device's flags against `curve.g1_in_subgroup` (multiplication by
     r in integers) on seeded points of the curve in and outside the
-    subgroup, through the served entry; lanes are counted as they go."""
+    subgroup, through the served entry; lanes are counted as they go, and
+    each dispatch's Miller plan (4 pair lanes: one accumulator here, on the
+    CPU) into the family the BLS dispatches count theirs into."""
     from lighthouse_tpu.crypto import bls
     from lighthouse_tpu.crypto.jaxbls import backend as jb
+    from lighthouse_tpu.crypto.jaxbls import msm
 
     _few_lanes(monkeypatch)
     prev = bls.get_backend()
@@ -341,6 +344,8 @@ def test_device_subgroup_check_is_multiplication_by_the_group_order(monkeypatch)
         outside = [_off_subgroup_point(1000 * k) for k in (1, 2)]
         lanes = dict(jb._KZG_LANES.children())
         real0 = lanes[("real",)].value if ("real",) in lanes else 0
+        plan0 = {k: jb._MILLER_PLAN.labels(k).value for k in
+                 ("dispatches", "accumulators", "in_step_levels")}
         for c, w in ((outside[0], inside[0]), (inside[1], outside[1]),
                      (None, inside[0])):
             ok, flags = device.verify_kzg_batch_async(
@@ -352,6 +357,10 @@ def test_device_subgroup_check_is_multiplication_by_the_group_order(monkeypatch)
             assert ok is False                   # nothing here is a proof
         lanes = dict(jb._KZG_LANES.children())
         assert lanes[("real",)].value - real0 == 3 * 6
+        assert jb.po.miller_lane_plan(msm.KZG_PAIR_LANES) == (1, 1, 0)
+        assert {k: jb._MILLER_PLAN.labels(k).value - v
+                for k, v in plan0.items()} == {
+            "dispatches": 3, "accumulators": 3, "in_step_levels": 3}
         with pytest.raises(ValueError, match="1 to 1 blobs"):
             device.verify_kzg_batch_async(inside, inside, [1, 1], [3, 3],
                                           [5, 5], tau_g2)

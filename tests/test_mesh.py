@@ -280,15 +280,21 @@ def test_stage_cache_keyed_by_mesh_and_donation(monkeypatch):
     assert pairing.final_exp.__wrapped__ is be._stage_final_exp
 
 
-@pytest.mark.parametrize("lanes,programs", [
-    (5, ["@jit_one"]), (32, ["@jit_one"]),
-    (33, ["@jit_miller", "@jit_final_exp"]),
-    (257, ["@jit_miller", "@jit_final_exp"]),
-], ids=["5-one", "32-one", "33-two", "257-two"])
-def test_pairing_programs_by_pair_lanes(lanes, programs):
+@pytest.mark.parametrize("lanes,platform,programs", [
+    (5, "cpu", ["@jit_one"]), (32, "cpu", ["@jit_one"]),
+    (33, "cpu", ["@jit_miller", "@jit_final_exp"]),
+    (257, "cpu", ["@jit_miller", "@jit_final_exp"]),
+    (5, "tpu", ["@jit_miller", "@jit_final_exp"]),
+    (4, "tpu", ["@jit_miller", "@jit_final_exp"]),
+], ids=["5-one", "32-one", "33-two", "257-two", "5-two-on-a-tpu",
+        "4-two-on-a-tpu"])
+def test_pairing_programs_by_pair_lanes(lanes, platform, programs,
+                                        monkeypatch):
     """The one-chip stage-4 callable with stand-in programs (nothing here
     compiles a Miller loop). What serves follows the Miller loop's plan:
-    one accumulator -> the one program, a row of them (33 pairs on) -> two
+    one accumulator -> the one program, a row of them (33 pairs on here,
+    every pair count — the urgent bucket's 5, KZG's 4 — where the process
+    runs on a TPU, which the plan reads from jax.default_backend()) -> two
     programs chained on the device. `.lower` gives the lowerings of what
     serves — the second at the first's output shape — program capture
     records their sum under the one stage, an attributed dispatch is ONE
@@ -302,6 +308,8 @@ def test_pairing_programs_by_pair_lanes(lanes, programs):
     from lighthouse_tpu.observability import device as obsdev
     from lighthouse_tpu.observability import perf
 
+    assert jax.default_backend() == "cpu"
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
     assert (po.miller_lane_plan(lanes)[0] > 1) == (len(programs) == 2)
 
     def miller(px, py, qxx, qyy, pair_mask):
