@@ -19,12 +19,9 @@ verified again alone, so each aggregate gets its own exact verdict
 
 from __future__ import annotations
 
-import contextlib
-import sys
 from time import perf_counter
 
 from ..crypto import bls
-from ..observability import device as _obs_dev
 from ..observability import trace as _obs
 from ..utils.metrics import REGISTRY
 
@@ -68,15 +65,7 @@ class AggregateBatch:
             distinct_messages=len({s.message for s in sets}),
             widest_keys=max((len(s.signing_keys) for s in sets), default=0),
         )
-        # a host scope in the profiler's own trace, but only where jax is
-        # loaded already: a host-backend node must not import it for a name
-        scope = (
-            _obs_dev.annotation_scope(BATCH_SPAN, **args)
-            if "jax" in sys.modules else contextlib.nullcontext()
-        )
-        tr = _obs.current_trace()
-        t0 = perf_counter()
-        with scope:
+        with _obs.span(BATCH_SPAN, **args) as sp:
             handle = bls.verify_signature_sets_async(sets)
 
         def continuation(ok: bool) -> list:
@@ -85,11 +74,8 @@ class AggregateBatch:
             else:
                 _BATCH_FALLBACK.inc(len(trios))
                 verdicts = [bls.verify_signature_sets(trio) for trio in trios]
-            t1 = perf_counter()
-            _BATCH_SECONDS.observe(t1 - t0)
+            _BATCH_SECONDS.observe(perf_counter() - sp.t0)
             _BATCH_AGGREGATES.inc(len(trios))
-            if tr is not None:
-                tr.add_span(BATCH_SPAN, t0, t1, **args)
             return verdicts
 
         return handle, continuation

@@ -481,32 +481,30 @@ class BeaconProcessor:
         return trace
 
     def _execute(self, single, batch, trace=None) -> None:
-        t_wait = perf_counter()
-        self._exec_lock.acquire()
-        _EXEC_LOCK_WAIT.observe(perf_counter() - t_wait)
         obs.set_current_trace(trace)
-        t_marshal = perf_counter()
+        with obs.span("exec_lock_wait", trace) as waited:
+            self._exec_lock.acquire()
+        _EXEC_LOCK_WAIT.observe(waited.t1 - waited.t0)
         try:
-            if batch is not None:
-                kind = batch[0].kind
-                runner = batch[0].run_batch
-                payloads = [it.payload for it in batch]
-                result = runner(payloads)
-            elif single is not None:
-                kind = single.kind
-                if single.run is not None:
-                    result = single.run()
-                elif single.run_batch is not None:
-                    result = single.run_batch([single.payload])
+            with obs.span("marshal", trace):
+                if batch is not None:
+                    kind = batch[0].kind
+                    runner = batch[0].run_batch
+                    payloads = [it.payload for it in batch]
+                    result = runner(payloads)
+                elif single is not None:
+                    kind = single.kind
+                    if single.run is not None:
+                        result = single.run()
+                    elif single.run_batch is not None:
+                        result = single.run_batch([single.payload])
+                    else:
+                        result = None
                 else:
-                    result = None
-            else:
-                return
+                    return
         finally:
             obs.set_current_trace(None)
             self._exec_lock.release()
-        if trace is not None:
-            trace.add_span("marshal", t_marshal, perf_counter())
         n = len(batch) if batch is not None else 1
         self.processed[kind] += n
         self._m_processed[kind].inc(n)
@@ -542,12 +540,23 @@ class BeaconProcessor:
                 return False
             handle, cont, trace, kind, n = self._inflight.popleft()
             _INFLIGHT.set(len(self._inflight))
+        # the unit's trace is current again while its handle resolves and
+        # its continuation runs, on whichever worker got here: what they
+        # call below (a fallback verify) joins the unit's spans
+        outer = obs.current_trace()
+        obs.set_current_trace(trace)
+        try:
+            return self._resolve(handle, cont, trace, kind, n)
+        finally:
+            obs.set_current_trace(outer)
+
+    def _resolve(self, handle, cont, trace, kind, n) -> bool:
         # a device failure mid-batch (device lost) must never kill the pump
         # worker: the batch is lost (its deferred gossip validations expire
         # as ignores) but the node keeps verifying
-        t_dev = perf_counter()
         try:
-            res = handle.result()      # device wait: outside the exec lock
+            with obs.span("device", trace) as waited:
+                res = handle.result()  # device wait: outside the exec lock
         except Exception as e:
             _ERRORS.labels("device").inc()
             log.error(
@@ -556,17 +565,14 @@ class BeaconProcessor:
             )
             obs.TRACER.finish(trace)
             return True
-        if trace is not None:
-            trace.add_span("device", t_dev, perf_counter())
-        dev_secs = perf_counter() - t_dev
+        dev_secs = waited.t1 - waited.t0
         self.slo.record_verify_latency(dev_secs)
         if kind is not None and kind in self.BATCHABLE:
             # the scheduler's batch cost model learns from DEVICE resolves
             # only (host-path wall time must not steer device batch sizing)
             self.scheduler.observe_verify(kind.name, n, dev_secs)
-        t_cont = perf_counter()
         try:
-            with self._exec_lock:
+            with obs.span("continuation", trace), self._exec_lock:
                 cont(res)              # chain mutation: serialized
         except Exception as e:
             _ERRORS.labels("continuation").inc()
@@ -574,8 +580,6 @@ class BeaconProcessor:
                 "batch continuation failed",
                 error=f"{type(e).__name__}: {e}",
             )
-        if trace is not None:
-            trace.add_span("continuation", t_cont, perf_counter())
         obs.TRACER.finish(trace)
         return True
 

@@ -32,14 +32,11 @@ that are queued together are one batch.
 
 from __future__ import annotations
 
-import contextlib
-import sys
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from time import perf_counter
 
 from ..crypto import kzg as ckzg
-from ..observability import device as _obs_dev
 from ..observability import trace as _obs
 from ..ssz.proof import branch_for, build_tree, verify_branch
 from ..types.containers import KZGCommitment
@@ -449,23 +446,15 @@ class DataAvailabilityChecker:
         if self.setup is None:
             raise BlobError("no KZG trusted setup loaded")
         sidecars = list(sidecars)
-        tr = _obs.current_trace()
-        t0 = perf_counter()
-        # a host scope in the profiler's own trace, but only where jax is
-        # loaded already: a host-backend node must not import it for a name
-        scope = (
-            _obs_dev.annotation_scope(KZG_BATCH_SPAN, sidecars=len(sidecars))
-            if "jax" in sys.modules else contextlib.nullcontext()
-        )
-        with scope:
+        with _obs.span(KZG_BATCH_SPAN, sidecars=len(sidecars)) as sp:
             batch = ckzg.BlobBatch(
                 [sc.blob for sc in sidecars],
                 [sc.kzg_commitment for sc in sidecars],
                 [sc.kzg_proof for sc in sidecars],
                 self.setup,
             )
+            sp.args["lanes"] = 6 * len(batch.members)
             handle = batch.submit()
-        lanes = 6 * len(batch.members)
 
         def continuation(result) -> list:
             verdicts = batch.verdicts(result)
@@ -474,13 +463,9 @@ class DataAvailabilityChecker:
                 _KZG_BATCH_FALLBACK.inc()
                 for i in undecided:
                     verdicts[i] = self.verify_kzg_proofs([sidecars[i]])
-            t1 = perf_counter()
-            _KZG_BATCH_SECONDS.observe(t1 - t0)
+            _KZG_BATCH_SECONDS.observe(perf_counter() - sp.t0)
             _KZG_BATCHES.inc()
             _KZG_BATCH_SIDECARS.inc(len(sidecars))
-            if tr is not None:
-                tr.add_span(KZG_BATCH_SPAN, t0, t1, sidecars=len(sidecars),
-                            lanes=lanes, fallback=len(undecided))
             return verdicts
 
         return handle, continuation

@@ -52,32 +52,15 @@ from . import h2c_ops as h2
 from . import pairing_ops as po
 
 # ------------------------------------------------------------------ metrics
-# the dispatch pipeline's own breakdown: host marshal cost, async-enqueue
-# cost (the jit-call returns once the work is queued), and the blocking
-# device wait split compile-vs-execute (first resolve at a padding bucket
-# pays XLA compilation; the autotune profiler folds that into compile_secs,
-# this family makes the split visible on a plain scrape)
+# the marshal as one number (the benchmark's marshal_ms reads it); its
+# parts, the enqueue and the device wait are spans of the dispatch's
+# pipeline Trace (`jaxbls:marshal.*`, `jaxbls:enqueue`,
+# `jaxbls:device_wait`: observability/trace.py), the device's time a
+# dispatch is the dispatcher's jaxbls_dispatch_device_seconds
 _MARSHAL_SECONDS = REGISTRY.histogram(
     "jaxbls_marshal_seconds",
     "host-side batch marshalling time (packing + device placement)",
     buckets=(0.0001, 0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0),
-)
-_DISPATCH_ENQUEUE_SECONDS = REGISTRY.histogram(
-    "jaxbls_dispatch_enqueue_seconds",
-    "async submission time of the staged device program (host blocked)",
-    buckets=(0.0001, 0.001, 0.01, 0.1, 0.5, 1.0, 5.0, 30.0, 120.0, 600.0),
-)
-_DEVICE_WAIT_SECONDS = REGISTRY.histogram_vec(
-    "jaxbls_device_wait_seconds",
-    "blocking wait for a dispatched batch, by phase (compile = first "
-    "resolve at a padding bucket, execute = steady state)",
-    ("phase",),
-    buckets=(0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 30.0, 120.0, 600.0),
-)
-_MARSHALLED_BYTES = REGISTRY.counter_vec(
-    "jaxbls_marshalled_bytes_total",
-    "bytes packed for device upload, by array family",
-    ("array",),
 )
 _PK_CACHE = REGISTRY.counter_vec(
     "jaxbls_pubkey_cache_total",
@@ -125,7 +108,9 @@ _KZG_LANES = REGISTRY.counter_vec(
     "program's one row of lanes; real over padded is the pass's fill",
     ("kind",),
 )
-_seen_exec_buckets: set = set()  # buckets that have resolved at least once
+# buckets that have resolved at least once: the benchmark's drivers and
+# chip_smoke.py check that a run compiled the one bucket it meant to
+_seen_exec_buckets: set = set()
 
 MIN_SETS = 4          # smallest bucket (pairs axis = sets + 1 rounded up)
 MIN_PKS = 1
@@ -446,9 +431,9 @@ class _PairingPrograms:
     def __call__(self, px, py, qxx, qyy, pair_mask):
         if not self._split(px):
             return self.one(px, py, qxx, qyy, pair_mask)
-        with _obs_dev.annotation_scope("jaxbls:pairing.miller"):
+        with _obs.annotation_scope("jaxbls:pairing.miller"):
             f = self.miller(px, py, qxx, qyy, pair_mask)
-        with _obs_dev.annotation_scope("jaxbls:pairing.final_exp"):
+        with _obs.annotation_scope("jaxbls:pairing.final_exp"):
             return self.final_exp(f)
 
     def lower(self, *args):
@@ -713,37 +698,38 @@ class VerifyHandle:
     asynchronously; result() blocks on the device and applies the host-side
     semantic (bad aggregate pubkey => False). Dispatch-timed handles carry
     their padding bucket and submit time so resolving feeds the autotune
-    profiler (first resolve only — result() is idempotent)."""
+    profiler (first resolve only — result() is idempotent). The handle
+    keeps the pipeline Trace current at its dispatch: `jaxbls:device_wait`
+    (result() entered -> the verdict read; `t_ready` is its end, which
+    the dispatcher reads) lands on the unit that owns the dispatch,
+    whichever thread resolves it."""
 
-    __slots__ = ("_ok", "_bad", "_hostfail", "_bucket", "_t0", "_n_real")
+    __slots__ = ("_ok", "_bad", "_hostfail", "_bucket", "_t0", "_n_real",
+                 "_trace", "t_ready")
 
     def __init__(self, ok=None, bad=None, hostfail=False,
-                 bucket=None, t0=None, n_real=0):
+                 bucket=None, t0=None, n_real=0, trace=None):
         self._ok = ok
         self._bad = bad
         self._hostfail = hostfail
         self._bucket = bucket
         self._t0 = t0
         self._n_real = n_real
+        self._trace = trace
+        self.t_ready = None
 
     def result(self) -> bool:
         if self._hostfail:
             return False
-        import time
-
-        t_wait = time.perf_counter()
-        r = bool(np.asarray(self._ok)) and not bool(np.asarray(self._bad))
+        with _obs.span("jaxbls:device_wait", self._trace) as waited:
+            r = bool(np.asarray(self._ok)) and not bool(np.asarray(self._bad))
+        self.t_ready = waited.t1
         if self._t0 is not None and self._bucket is not None:
             from ...autotune import profiler
 
-            now = time.perf_counter()
-            dt, self._t0 = now - self._t0, None
+            dt, self._t0 = self.t_ready - self._t0, None
             profiler.observe_dispatch(*self._bucket, dt, self._n_real)
-            # compile-vs-execute split: the first resolve at a bucket paid
-            # XLA compilation for whatever stages were still cold
-            phase = "execute" if self._bucket in _seen_exec_buckets else "compile"
             _seen_exec_buckets.add(self._bucket)
-            _DEVICE_WAIT_SECONDS.labels(phase).observe(now - t_wait)
         return r
 
 
@@ -805,55 +791,56 @@ class JaxBackend:
         by TOPOLOGY: a grid sharded for one mesh must never feed the
         urgent single-chip program or a re-resolved mesh of another
         shape (the --mesh-devices sweep flips topologies mid-process)."""
-        import jax
+        with _obs.span("jaxbls:marshal.pubkeys") as packed:
+            if single_chip:
+                lane = "single"
+            else:
+                from ...parallel import mesh_shape_key
 
-        if single_chip:
-            lane = "single"
-        else:
-            from ...parallel import mesh_shape_key
+                lane = mesh_shape_key()
+            # fingerprint covers the set grouping, not just the flat key
+            # sequence: the same keys split differently must not reuse
+            # another layout's aggregation mask
+            fp = (
+                lane,
+                tuple(len(s.signing_keys) for s in sets),
+                tuple(id(pk) for s in sets for pk in s.signing_keys),
+            )
+            hit = self._pk_cache.get(fp)
+            packed.args["hit"] = int(hit is not None)
+            if hit is not None:
+                _PK_CACHE.labels("hit").inc()
+                return hit[0], hit[1], hit[2]
+            _PK_CACHE.labels("miss").inc()
 
-            lane = mesh_shape_key()
-        # fingerprint covers the set grouping, not just the flat key sequence:
-        # the same keys split differently must not reuse another layout's
-        # aggregation mask
-        fp = (
-            lane,
-            tuple(len(s.signing_keys) for s in sets),
-            tuple(id(pk) for s in sets for pk in s.signing_keys),
-        )
-        hit = self._pk_cache.get(fp)
-        if hit is not None:
-            _PK_CACHE.labels("hit").inc()
-            return hit[0], hit[1], hit[2]
-        _PK_CACHE.labels("miss").inc()
-
-        pk_x = np.zeros((n, m, lb.NL), np.uint32)
-        pk_y = np.zeros((n, m, lb.NL), np.uint32)
-        pk_mask = np.zeros((n, m), np.uint32)
-        for i, s in enumerate(sets):
-            keys = s.signing_keys
-            xs = pack_ints_vec([pk.point[0] for pk in keys])
-            ys = pack_ints_vec([pk.point[1] for pk in keys])
-            pk_x[i, : len(keys)] = xs
-            pk_y[i, : len(keys)] = ys
-            pk_mask[i, : len(keys)] = 1
+            pk_x = np.zeros((n, m, lb.NL), np.uint32)
+            pk_y = np.zeros((n, m, lb.NL), np.uint32)
+            pk_mask = np.zeros((n, m), np.uint32)
+            for i, s in enumerate(sets):
+                keys = s.signing_keys
+                xs = pack_ints_vec([pk.point[0] for pk in keys])
+                ys = pack_ints_vec([pk.point[1] for pk in keys])
+                pk_x[i, : len(keys)] = xs
+                pk_y[i, : len(keys)] = ys
+                pk_mask[i, : len(keys)] = 1
+            nbytes = pk_x.nbytes + pk_y.nbytes + pk_mask.nbytes
+            packed.args["bytes"] = nbytes
         from ...parallel import put_pk_grid, put_single
 
-        _MARSHALLED_BYTES.labels("pubkeys").inc(
-            pk_x.nbytes + pk_y.nbytes + pk_mask.nbytes
-        )
         # (n, m, ...) pubkey arrays: set axis sharded; on a 2-D mesh the
         # pubkey axis is sharded too (within-set aggregation parallelism).
         # Urgent single-chip batches place whole on one device instead.
         put = put_single if single_chip else put_pk_grid
-        dx, dy, dm = put(pk_x), put(pk_y), put(pk_mask)
-        # keep strong refs to the key objects so ids stay valid while cached
-        keepalive = (fp, [pk for s in sets for pk in s.signing_keys])
-        self._pk_cache[fp] = (dx, dy, dm, keepalive)
-        self._pk_cache_order.append(fp)
-        if len(self._pk_cache_order) > 8:
-            old = self._pk_cache_order.pop(0)
-            self._pk_cache.pop(old, None)
+        with _obs.span("jaxbls:marshal.pubkeys_upload", bytes=nbytes):
+            dx, dy, dm = put(pk_x), put(pk_y), put(pk_mask)
+            # keep strong refs to the key objects so ids stay valid while
+            # cached; the oldest grid leaves the cache, and the device, here
+            keepalive = (fp, [pk for s in sets for pk in s.signing_keys])
+            self._pk_cache[fp] = (dx, dy, dm, keepalive)
+            self._pk_cache_order.append(fp)
+            if len(self._pk_cache_order) > 8:
+                old = self._pk_cache_order.pop(0)
+                self._pk_cache.pop(old, None)
         return dx, dy, dm
 
     def verify_signature_sets_async(self, sets, rands, urgent: bool = False):
@@ -874,83 +861,89 @@ class JaxBackend:
         from ...parallel import get_mesh, put_single, put_sets
         from ...parallel.mesh import MESH_DISPATCH
 
-        t_marshal = time.perf_counter()
-        mesh = None if urgent else get_mesh()
-        single_chip = mesh is None
-        prepare, h2c_stage, pairs_stage, pairing_stage = _get_stages(mesh=mesh)
-        n_real = len(sets)
-        # pad the set axis to the compile bucket AND to a multiple of the
-        # device mesh (multi-chip: sets are data-parallel over the mesh,
-        # the cross-set reductions become collectives — parallel/mesh.py);
-        # the urgent lane keeps plain pow2 buckets on one chip
-        n, m = padding_bucket(
-            n_real, max(len(s.signing_keys) for s in sets),
-            mesh=mesh, single_chip=single_chip,
-        )
-        # three truthful lanes: urgent bypass (pinned to one chip), meshed
-        # batch, and ordinary batch on a mesh-less node — a dashboard must
-        # never read single-device batch traffic as urgent-path activity
-        MESH_DISPATCH.labels(
-            "urgent" if urgent else ("sharded" if mesh is not None
-                                     else "single_device")
-        ).inc()
-        real_keys = sum(len(s.signing_keys) for s in sets)
-        _BUCKET_SLOTS.labels("sets", "real").inc(n_real)
-        _BUCKET_SLOTS.labels("sets", "padded").inc(n)
-        _BUCKET_SLOTS.labels("keys", "real").inc(real_keys)
-        _BUCKET_SLOTS.labels("keys", "padded").inc(n * m)
-        distinct_messages = len({s.message for s in sets})
-        _DISPATCH_MESSAGES.labels("sent").inc(n_real)
-        _DISPATCH_MESSAGES.labels("distinct").inc(distinct_messages)
-        _TREE_SUM_LANE_ADDS.labels("done").inc(co.tree_sum_plan(m, n)[3])
-        _TREE_SUM_LANE_ADDS.labels("needed").inc(real_keys - n_real)
-        miller_pairs = n + 1
-        if isinstance(pairing_stage, _PairingDispatch):
-            miller_pairs = pairing_stage.miller_pairs(miller_pairs)
-        _count_miller_plan(miller_pairs)
+        # the whole marshal is one span (the benchmark's marshal_ms reads
+        # its seconds off the histogram), its five parts the children and
+        # the bucket's choice and the dispatch's counters its self time:
+        # an idle gap of the device that covers several parts is named by
+        # this one in a profiler capture, not by the caller's scope
+        with _obs.span("jaxbls:marshal") as marshalled:
+            mesh = None if urgent else get_mesh()
+            single_chip = mesh is None
+            prepare, h2c_stage, pairs_stage, pairing_stage = _get_stages(mesh=mesh)
+            n_real = len(sets)
+            # pad the set axis to the compile bucket AND to a multiple of the
+            # device mesh (multi-chip: sets are data-parallel over the mesh,
+            # the cross-set reductions become collectives — parallel/mesh.py);
+            # the urgent lane keeps plain pow2 buckets on one chip
+            n, m = padding_bucket(
+                n_real, max(len(s.signing_keys) for s in sets),
+                mesh=mesh, single_chip=single_chip,
+            )
+            # three truthful lanes: urgent bypass (pinned to one chip), meshed
+            # batch, and ordinary batch on a mesh-less node — a dashboard must
+            # never read single-device batch traffic as urgent-path activity
+            MESH_DISPATCH.labels(
+                "urgent" if urgent else ("sharded" if mesh is not None
+                                         else "single_device")
+            ).inc()
+            real_keys = sum(len(s.signing_keys) for s in sets)
+            _BUCKET_SLOTS.labels("sets", "real").inc(n_real)
+            _BUCKET_SLOTS.labels("sets", "padded").inc(n)
+            _BUCKET_SLOTS.labels("keys", "real").inc(real_keys)
+            _BUCKET_SLOTS.labels("keys", "padded").inc(n * m)
+            distinct_messages = len({s.message for s in sets})
+            _DISPATCH_MESSAGES.labels("sent").inc(n_real)
+            _DISPATCH_MESSAGES.labels("distinct").inc(distinct_messages)
+            _TREE_SUM_LANE_ADDS.labels("done").inc(co.tree_sum_plan(m, n)[3])
+            _TREE_SUM_LANE_ADDS.labels("needed").inc(real_keys - n_real)
+            miller_pairs = n + 1
+            if isinstance(pairing_stage, _PairingDispatch):
+                miller_pairs = pairing_stage.miller_pairs(miller_pairs)
+            _count_miller_plan(miller_pairs)
 
-        pk_x, pk_y, pk_mask = self._marshal_pubkeys(
-            sets, n, m, single_chip=single_chip
-        )
+            pk_x, pk_y, pk_mask = self._marshal_pubkeys(
+                sets, n, m, single_chip=single_chip
+            )
 
-        sig_x = np.zeros((n, 2, lb.NL), np.uint32)
-        sig_y = np.zeros((n, 2, lb.NL), np.uint32)
-        z_digits = np.zeros((n, Z_DIGITS), np.uint32)
-        set_mask = np.zeros((n,), np.uint32)
+            with _obs.span("jaxbls:marshal.sigs"):
+                sig_x = np.zeros((n, 2, lb.NL), np.uint32)
+                sig_y = np.zeros((n, 2, lb.NL), np.uint32)
+                z_digits = np.zeros((n, Z_DIGITS), np.uint32)
+                set_mask = np.zeros((n,), np.uint32)
 
-        sig_ints = []
-        for s in sets:
-            sp = s.signature.point
-            if sp is None:
-                return VerifyHandle(hostfail=True)  # infinity signature fails
-            sig_ints.append(sp)
-        sig_x[:n_real, 0] = pack_ints_vec([sp[0][0] for sp in sig_ints])
-        sig_x[:n_real, 1] = pack_ints_vec([sp[0][1] for sp in sig_ints])
-        sig_y[:n_real, 0] = pack_ints_vec([sp[1][0] for sp in sig_ints])
-        sig_y[:n_real, 1] = pack_ints_vec([sp[1][1] for sp in sig_ints])
+                sig_ints = []
+                for s in sets:
+                    sp = s.signature.point
+                    if sp is None:
+                        return VerifyHandle(hostfail=True)  # infinity signature fails
+                    sig_ints.append(sp)
+                sig_x[:n_real, 0] = pack_ints_vec([sp[0][0] for sp in sig_ints])
+                sig_x[:n_real, 1] = pack_ints_vec([sp[0][1] for sp in sig_ints])
+                sig_y[:n_real, 0] = pack_ints_vec([sp[1][0] for sp in sig_ints])
+                sig_y[:n_real, 1] = pack_ints_vec([sp[1][1] for sp in sig_ints])
 
-        zmask = (1 << 64) - 1
-        z_digits[:n_real] = co.scalars_to_digits(
-            [z & zmask for z in rands], 64, Z_WINDOW
-        )[:, :Z_DIGITS]
-        set_mask[:n_real] = 1
+                zmask = (1 << 64) - 1
+                z_digits[:n_real] = co.scalars_to_digits(
+                    [z & zmask for z in rands], 64, Z_WINDOW
+                )[:, :Z_DIGITS]
+                set_mask[:n_real] = 1
 
-        us = np.zeros((n, 2, 2, lb.NL), np.uint32)
-        us[:n_real] = h2.hash_to_field_batch([s.message for s in sets], self.dst)
+            with _obs.span("jaxbls:marshal.h2f"):
+                us = np.zeros((n, 2, 2, lb.NL), np.uint32)
+                us[:n_real] = h2.hash_to_field_batch(
+                    [s.message for s in sets], self.dst)
 
-        _MARSHALLED_BYTES.labels("sets").inc(
-            sig_x.nbytes + sig_y.nbytes + z_digits.nbytes
-            + set_mask.nbytes + us.nbytes
-        )
-        # staged dispatch: intermediates stay on device between jit calls,
-        # inputs placed with the set axis sharded over the mesh (urgent:
-        # whole on one chip; also the no-mesh single-device case)
-        put = put_single if single_chip else put_sets
-        sig_x, sig_y, z_digits, set_mask, us = (
-            put(sig_x), put(sig_y), put(z_digits), put(set_mask), put(us),
-        )
-        t_marshalled = time.perf_counter()
-        _MARSHAL_SECONDS.observe(t_marshalled - t_marshal)
+            nbytes = (sig_x.nbytes + sig_y.nbytes + z_digits.nbytes
+                      + set_mask.nbytes + us.nbytes)
+            # staged dispatch: intermediates stay on device between jit calls,
+            # inputs placed with the set axis sharded over the mesh (urgent:
+            # whole on one chip; also the no-mesh single-device case)
+            put = put_single if single_chip else put_sets
+            with _obs.span("jaxbls:marshal.upload", bytes=nbytes):
+                sig_x, sig_y, z_digits, set_mask, us = (
+                    put(sig_x), put(sig_y), put(z_digits), put(set_mask), put(us),
+                )
+        _MARSHAL_SECONDS.observe(marshalled.t1 - marshalled.t0)
         tr = _obs.current_trace()
         if tr is not None:
             tr.annotate(bucket=f"{n}x{m}", real_sets=n_real,
@@ -958,12 +951,13 @@ class JaxBackend:
                         distinct_messages=distinct_messages)
 
         def dispatch():
-            # each stage dispatch runs under a named annotation scope;
-            # with device attribution on (bn --device-trace, bench,
+            # the dispatcher's `jaxbls:enqueue` span is open around this
+            # call and each stage's jit call a `jaxbls:<stage>` child of
+            # it; with device attribution on (bn --device-trace, bench,
             # calibrator) run_stage also event-times each resolve into
             # the per-stage jaxbls_stage_* families and device:<stage>
-            # trace sub-spans — which SERIALIZES the stages (diagnostic
-            # mode; the default path stays fully async)
+            # spans — which SERIALIZES the stages (diagnostic mode; the
+            # default path stays fully async)
             t0 = time.perf_counter()
             attr = _obs_dev.begin((n, m), trace=tr)
             z_pk, sig_acc, bad = _obs_dev.run_stage(
@@ -977,8 +971,8 @@ class JaxBackend:
             ok = _obs_dev.run_stage(
                 attr, "pairing", pairing_stage, px, py, qxx, qyy, pair_mask
             )
-            _DISPATCH_ENQUEUE_SECONDS.observe(time.perf_counter() - t0)
-            return VerifyHandle(ok, bad, bucket=(n, m), t0=t0, n_real=n_real)
+            return VerifyHandle(ok, bad, bucket=(n, m), t0=t0, n_real=n_real,
+                                trace=tr)
 
         return self.dispatcher.submit(dispatch, urgent=urgent)
 
@@ -1117,8 +1111,6 @@ class JaxBackend:
         Returns a ticket; `.result()` reads the device ONCE: (ok, [(C_i in
         the subgroup, W_i in it), ...]). `ok` means nothing unless every
         flag is True."""
-        import time
-
         from ...parallel import put_single
         from . import msm as _msm
 
@@ -1130,46 +1122,43 @@ class JaxBackend:
         lanes = slots * rows
         lincomb, verdict = _get_kzg_kernels()
         pairing_stage = _get_stages()[3]
-        points, scalars, index = [], [], []
-        for i, (c, w) in enumerate(zip(commitments, proofs)):
-            for row, point, scalar in (
-                (_msm.KZG_ROW_C, c, r_pows[i]),
-                (_msm.KZG_ROW_G1, pc.G1_GEN, y_scalars[i]),
-                (_msm.KZG_ROW_ZW, w, z_scalars[i]),
-                (_msm.KZG_ROW_W, w, r_pows[i]),
-                (_msm.KZG_ROW_ORDER_C, c, R),
-                (_msm.KZG_ROW_ORDER_W, w, R),
-            ):
-                if point is not None:
-                    points.append(point)
-                    scalars.append(scalar)
-                    index.append(i * rows + row)
-        px = np.zeros((lanes, lb.NL), np.uint32)
-        py = np.zeros((lanes, lb.NL), np.uint32)
-        live = np.zeros((lanes,), np.uint32)
-        bits = np.zeros((lanes, _msm.KZG_SCALAR_BITS), np.uint32)
-        px[index] = pack_ints_vec([p[0] for p in points])
-        py[index] = pack_ints_vec([p[1] for p in points])
-        live[index] = 1
-        raw = np.frombuffer(
-            b"".join(k.to_bytes(32, "big") for k in scalars), np.uint8
-        ).reshape(len(scalars), 32)
-        bits[index] = np.unpackbits(raw, axis=1)[:, 256 - _msm.KZG_SCALAR_BITS:]
-        qx, qy = _kzg_g2_side(tau_g2)
-        _KZG_LANES.labels("real").inc(6 * n_real)
-        _KZG_LANES.labels("padded").inc(lanes)
-        _count_miller_plan(_msm.KZG_PAIR_LANES)
-        _MARSHALLED_BYTES.labels("kzg").inc(
-            px.nbytes + py.nbytes + live.nbytes + bits.nbytes
-            + qx.nbytes + qy.nbytes
-        )
+        with _obs.span("kzg:pack", blobs=n_real, lanes=lanes):
+            points, scalars, index = [], [], []
+            for i, (c, w) in enumerate(zip(commitments, proofs)):
+                for row, point, scalar in (
+                    (_msm.KZG_ROW_C, c, r_pows[i]),
+                    (_msm.KZG_ROW_G1, pc.G1_GEN, y_scalars[i]),
+                    (_msm.KZG_ROW_ZW, w, z_scalars[i]),
+                    (_msm.KZG_ROW_W, w, r_pows[i]),
+                    (_msm.KZG_ROW_ORDER_C, c, R),
+                    (_msm.KZG_ROW_ORDER_W, w, R),
+                ):
+                    if point is not None:
+                        points.append(point)
+                        scalars.append(scalar)
+                        index.append(i * rows + row)
+            px = np.zeros((lanes, lb.NL), np.uint32)
+            py = np.zeros((lanes, lb.NL), np.uint32)
+            live = np.zeros((lanes,), np.uint32)
+            bits = np.zeros((lanes, _msm.KZG_SCALAR_BITS), np.uint32)
+            px[index] = pack_ints_vec([p[0] for p in points])
+            py[index] = pack_ints_vec([p[1] for p in points])
+            live[index] = 1
+            raw = np.frombuffer(
+                b"".join(k.to_bytes(32, "big") for k in scalars), np.uint8
+            ).reshape(len(scalars), 32)
+            bits[index] = np.unpackbits(
+                raw, axis=1)[:, 256 - _msm.KZG_SCALAR_BITS:]
+            qx, qy = _kzg_g2_side(tau_g2)
+            _KZG_LANES.labels("real").inc(6 * n_real)
+            _KZG_LANES.labels("padded").inc(lanes)
+            _count_miller_plan(_msm.KZG_PAIR_LANES)
         tr = _obs.current_trace()
 
         def dispatch():
             # the stage programs' outputs feed each other on the device;
             # the G2 side goes up every dispatch because the pairing
             # program is built to consume (donate) its inputs
-            t0 = time.perf_counter()
             attr = _obs_dev.begin((slots, rows), trace=tr)
             gx, gy, pair_mask, in_subgroup = _obs_dev.run_stage(
                 attr, _obs_dev.KZG_LINCOMB_STAGE, lincomb,
@@ -1180,25 +1169,28 @@ class JaxBackend:
                 attr, "pairing", pairing_stage,
                 gx, gy, put_single(qx), put_single(qy), pair_mask,
             )
-            packed = verdict(ok, in_subgroup)
-            _DISPATCH_ENQUEUE_SECONDS.observe(time.perf_counter() - t0)
-            return KzgHandle(packed, n_real)
+            return KzgHandle(verdict(ok, in_subgroup), n_real, trace=tr)
 
         return self.kzg_dispatcher.submit(dispatch, bucket=(slots, rows))
 
 
 class KzgHandle:
     """In-flight KZG batch: `.result()` blocks on the device and reads it
-    once: (ok, [(commitment in the subgroup, proof in it), ...] a blob)."""
+    once: (ok, [(commitment in the subgroup, proof in it), ...] a blob).
+    Carries its dispatch's pipeline Trace, as VerifyHandle does."""
 
-    __slots__ = ("_packed", "_n")
+    __slots__ = ("_packed", "_n", "_trace", "t_ready")
 
-    def __init__(self, packed, n: int):
+    def __init__(self, packed, n: int, trace=None):
         self._packed = packed
         self._n = n
+        self._trace = trace
+        self.t_ready = None
 
     def result(self) -> tuple:
-        out = np.asarray(self._packed)
+        with _obs.span("jaxbls:device_wait", self._trace) as waited:
+            out = np.asarray(self._packed)
+        self.t_ready = waited.t1
         flags = out[1 : 1 + 2 * self._n].reshape(self._n, 2)
         return bool(out[0]), [(bool(c), bool(w)) for c, w in flags]
 

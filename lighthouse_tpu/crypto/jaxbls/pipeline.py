@@ -49,6 +49,7 @@ import threading
 from collections import deque
 from time import perf_counter
 
+from ...observability import trace as _obs
 from ...observability.device_ledger import LEDGER
 from ...utils.metrics import REGISTRY
 
@@ -91,6 +92,18 @@ _ADMIT_WAIT = REGISTRY.histogram_vec(
     "never waits",
     ("lane",),
     buckets=(0.0001, 0.001, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 30.0, 120.0),
+)
+
+_DISPATCH_DEVICE = REGISTRY.histogram_vec(
+    "jaxbls_dispatch_device_seconds",
+    "the device's time for one dispatch as served, by lane: its verdict "
+    "on the host (the end of result()'s one read, no sync of its own) "
+    "less the later of its first stage's enqueue and the previous "
+    "dispatch of this dispatcher read; with stage attribution on it is the serialized time, and the "
+    "difference is what attribution costs",
+    ("lane",),
+    buckets=(0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 30.0,
+             120.0, 600.0),
 )
 
 DEFAULT_DEPTH = 4
@@ -160,17 +173,21 @@ class PipelineTicket:
     (continuations included) in submission order. Urgent tickets resolve
     independently; they were never in the window. A handle/continuation
     exception is captured once and re-raised to EVERY result() caller —
-    it never poisons later tickets."""
+    it never poisons later tickets. The ticket keeps when its first stage
+    was enqueued, for the dispatch's device time."""
 
     __slots__ = ("_dispatcher", "lane", "handle", "continuation",
-                 "done", "value", "error", "claimed", "_ev", "interval")
+                 "done", "value", "error", "claimed", "_ev", "interval",
+                 "t_enqueued")
 
-    def __init__(self, dispatcher, lane, handle, continuation, interval=None):
+    def __init__(self, dispatcher, lane, handle, continuation, interval=None,
+                 t_enqueued=None):
         self._dispatcher = dispatcher
         self.lane = lane
         self.handle = handle
         self.continuation = continuation
         self.interval = interval       # device-ledger interval, or None
+        self.t_enqueued = t_enqueued
         self.done = False
         self.value = None
         self.error = None
@@ -216,6 +233,7 @@ class PipelinedDispatcher:
         self._reserved = 0
         self._slot_free = threading.Condition(self._lock)
         self._urgent_inflight = 0
+        self._last_ready = 0.0         # when the previous dispatch was ready
         _DEPTH_GAUGE.labels(self.depth_source).set(self.depth)
         _DONATE_GAUGE.labels(self.donate_source).set(int(self.donate))
 
@@ -241,36 +259,20 @@ class PipelinedDispatcher:
             interval = LEDGER.open(
                 self.workload, lane=lane, bucket=bucket, est_cost=est_cost
             )
-        t0 = perf_counter()
+        waited = 0.0
         if not urgent:
             # claim a window slot ATOMICALLY (len(window) + reserved <
             # depth) so concurrent submitters can never overfill the
             # window between this check and the post-dispatch append
-            while True:
-                with self._lock:
-                    if len(self._window) + self._reserved < self.depth:
-                        self._reserved += 1
-                        break
-                    oldest = self._window[0] if self._window else None
-                if oldest is not None:
-                    try:
-                        self.resolve(oldest)  # blocking wait: backpressure
-                    except Exception:
-                        # the failure belongs to the OLDEST batch and
-                        # stays recorded on its ticket (its owner
-                        # re-raises at result()); it must not surface
-                        # into this unrelated submission
-                        pass
-                else:
-                    # every slot is a reservation held by a submitter
-                    # still inside dispatch(): wait for one to land
-                    with self._slot_free:
-                        self._slot_free.wait(timeout=0.05)
-        _ADMIT_WAIT.labels(lane).observe(perf_counter() - t0)
+            with _obs.span("jaxbls:admit") as admit:
+                self._claim_slot()
+            waited = admit.t1 - admit.t0
+        _ADMIT_WAIT.labels(lane).observe(waited)
         if interval is not None:
             interval.start()           # admit wait over: device dispatch
         try:
-            handle = dispatch()
+            with _obs.span("jaxbls:enqueue", lane=lane) as enqueue:
+                handle = dispatch()
         except BaseException:
             if interval is not None:
                 interval.close("error")
@@ -279,7 +281,8 @@ class PipelinedDispatcher:
                     self._reserved -= 1
                     self._slot_free.notify_all()
             raise
-        ticket = PipelineTicket(self, lane, handle, continuation, interval)
+        ticket = PipelineTicket(self, lane, handle, continuation, interval,
+                                t_enqueued=enqueue.t0)
         with self._lock:
             if urgent:
                 self._urgent_inflight += 1
@@ -290,6 +293,30 @@ class PipelinedDispatcher:
                 _INFLIGHT.labels("batch").set(len(self._window))
         _SUBMITTED.labels(lane).inc()
         return ticket
+
+    def _claim_slot(self) -> None:
+        """Block until the window has a free slot and reserve it,
+        resolving the oldest in-flight batch while it is full."""
+        while True:
+            with self._lock:
+                if len(self._window) + self._reserved < self.depth:
+                    self._reserved += 1
+                    return
+                oldest = self._window[0] if self._window else None
+            if oldest is not None:
+                try:
+                    self.resolve(oldest)  # blocking wait: backpressure
+                except Exception:
+                    # the failure belongs to the OLDEST batch and stays
+                    # recorded on its ticket (its owner re-raises at
+                    # result()); it must not surface into this unrelated
+                    # submission
+                    pass
+            else:
+                # every slot is a reservation held by a submitter still
+                # inside dispatch(): wait for one to land
+                with self._slot_free:
+                    self._slot_free.wait(timeout=0.05)
 
     # -- resolution ------------------------------------------------------
 
@@ -350,6 +377,13 @@ class PipelinedDispatcher:
             return
         try:
             value = ticket.handle.result()
+            # a handle that kept when its read ended (the jaxbls ones)
+            # gives the dispatch its device time, no sync added
+            t_ready = getattr(ticket.handle, "t_ready", None)
+            if t_ready is not None:
+                _DISPATCH_DEVICE.labels(ticket.lane).observe(
+                    t_ready - max(ticket.t_enqueued, self._last_ready))
+                self._last_ready = t_ready
             if ticket.continuation is not None:
                 ticket.continuation(value)
             ticket.value = value

@@ -33,8 +33,7 @@ from __future__ import annotations
 import hashlib
 import json
 from dataclasses import dataclass, field
-from time import perf_counter
-
+from ..observability import trace as _obs
 from ..utils.metrics import REGISTRY
 from .bls381 import curve as cv
 from .bls381 import serde
@@ -413,39 +412,39 @@ class BlobBatch:
         self._commitments, self._proofs = [], []
         self._zs, self._ys = [], []
         member_cbs, member_pbs = [], []
-        t_points = t_field = 0.0
-        for i in range(n):
-            blob, cb, pb = bytes(blobs[i]), bytes(commitments_bytes[i]), bytes(proofs_bytes[i])
-            t0 = perf_counter()
-            try:
-                c = serde.g1_decompress(cb, subgroup_check=False)
-                w = serde.g1_decompress(pb, subgroup_check=False)
-            except serde.DecodeError:
-                self.malformed[i] = True
-                t_points += perf_counter() - t0
-                continue
-            t1 = perf_counter()
-            t_points += t1 - t0
-            z = compute_challenge(blob, cb, setup)
-            try:
-                self._ys.append(_evaluate_blob(blob, z, setup))
-            except KzgError:
-                self.malformed[i] = True
-                t_field += perf_counter() - t1
-                continue
-            self._zs.append(z)
-            self._commitments.append(c)
-            self._proofs.append(w)
-            self.members.append(i)
-            member_cbs.append(cb)
-            member_pbs.append(pb)
-            t_field += perf_counter() - t1
-        t0 = perf_counter()
-        self._r_pows = compute_r_powers(member_cbs, self._zs, self._ys,
-                                        member_pbs, setup)
-        t_field += perf_counter() - t0
-        _HOST_SECONDS.labels("points").observe(t_points)
-        _HOST_SECONDS.labels("field").observe(t_field)
+        blobs = [bytes(b) for b in blobs]
+        cbs = [bytes(c) for c in commitments_bytes]
+        pbs = [bytes(p) for p in proofs_bytes]
+        points: list = [None] * n
+        with _obs.span("kzg:points", blobs=n) as sp:
+            for i in range(n):
+                try:
+                    points[i] = (
+                        serde.g1_decompress(cbs[i], subgroup_check=False),
+                        serde.g1_decompress(pbs[i], subgroup_check=False),
+                    )
+                except serde.DecodeError:
+                    self.malformed[i] = True
+        _HOST_SECONDS.labels("points").observe(sp.t1 - sp.t0)
+        with _obs.span("kzg:field", blobs=n) as sp:
+            for i in range(n):
+                if self.malformed[i]:
+                    continue
+                z = compute_challenge(blobs[i], cbs[i], setup)
+                try:
+                    self._ys.append(_evaluate_blob(blobs[i], z, setup))
+                except KzgError:
+                    self.malformed[i] = True
+                    continue
+                self._zs.append(z)
+                self._commitments.append(points[i][0])
+                self._proofs.append(points[i][1])
+                self.members.append(i)
+                member_cbs.append(cbs[i])
+                member_pbs.append(pbs[i])
+            self._r_pows = compute_r_powers(member_cbs, self._zs, self._ys,
+                                            member_pbs, setup)
+        _HOST_SECONDS.labels("field").observe(sp.t1 - sp.t0)
         _BLOBS_EVALUATED.inc(len(self.members))
 
     def submit(self):

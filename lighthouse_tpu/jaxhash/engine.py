@@ -49,7 +49,7 @@ from ..ssz.sha256_batch import (
     sha256_pairs,
     words_from_bytes,
 )
-from ..observability.device import annotation_scope
+from ..observability.trace import span
 from ..utils.metrics import REGISTRY
 
 # ------------------------------------------------------------------ metrics
@@ -344,7 +344,7 @@ def device_build_levels(leaves: np.ndarray, depth: int,
         )
     t0 = time.perf_counter()
     ladder, stop = _get_ladder(nb, mesh)
-    with annotation_scope("jaxhash:marshal"):
+    with span("jaxhash:marshal"):
         words, path = _native_words(leaves, nb)
     _LEAF_MARSHAL.labels(path).inc()
     _MARSHALLED.labels("leaves").inc(words.nbytes)
@@ -352,14 +352,14 @@ def device_build_levels(leaves: np.ndarray, depth: int,
         "sharded" if mesh is not None else "single_device"
     ).inc()
     put = put_single if mesh is None else (lambda a: put_sets(a, mesh=mesh))
-    with annotation_scope("jaxhash:upload"):
+    with span("jaxhash:upload"):
         placed = put(words)
-    with annotation_scope("jaxhash:ladder"):
+    with span("jaxhash:ladder"):
         ticket = _get_dispatcher().submit(
             lambda: _LevelsHandle(ladder(placed), last_only=root_only,
                                   first=min_level)
         )
-    with annotation_scope("jaxhash:readback"):
+    with span("jaxhash:readback"):
         dev_levels = ticket.result()
 
     import hashlib

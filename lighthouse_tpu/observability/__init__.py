@@ -5,9 +5,12 @@ batch -> host marshal -> device dispatch -> device wait -> continuation) is
 the system's hot path; this package makes it legible from the outside:
 
   - `trace`: a lightweight span tracer. Every executed work unit carries a
-    Trace through the pipeline stages; completed traces land in a bounded
-    ring and feed per-stage Prometheus histograms, and the ring exports as
-    Chrome trace-event (Perfetto) JSON (`bn --trace-out trace.json`).
+    Trace through the pipeline stages, and every layer below the processor
+    adds its phases to it through the one primitive `span` (parent, trace
+    id, a profiler scope of the same name); completed traces land in a
+    bounded ring and feed per-stage Prometheus histograms, and the ring
+    exports as Chrome trace-event (Perfetto) JSON (`bn --trace-out
+    trace.json`).
   - `pipeline`: the stage-timing snapshot behind the
     `/lighthouse_tpu/pipeline` ops endpoint.
   - `device`: per-stage device-time attribution for the jaxbls dispatch
@@ -48,6 +51,7 @@ from .trace import (  # noqa: F401
     chrome_trace_events,
     current_trace,
     set_current_trace,
+    span,
 )
 from .pipeline import register_processor, snapshot  # noqa: F401
 from . import device, perf  # noqa: F401  (registers the device/xla families)

@@ -13,18 +13,23 @@ accumulator the one program `_stage_pairing` stays (PERF.md S6, PR 32 and
 PR 35). A profiler capture shows the two apart (`jaxbls:pairing.miller`,
 `jaxbls:pairing.final_exp`, the programs' own names); everything timed or
 counted here keeps the four stage names. That is the right shape
-for throughput, but it makes the device a single opaque span — PR 2's
-tracer shows one `device` stage and `jaxbls_device_wait_seconds` shows a
-coarse compile/execute split, and nothing says WHICH stage burns the
-7x headroom against estimated blst (ROADMAP "kernel speed").
+for throughput, and it leaves the device's own time as one number a
+dispatch: the pipeline tracer (observability/trace.py) shows the host's
+phases around it — `jaxbls:enqueue` with the stage scopes as children,
+`jaxbls:device_wait` — and `jaxbls_dispatch_device_seconds{lane}` the time
+from the first stage's enqueue (or the previous dispatch's end, whichever
+is later) to the verdict being read; WHICH stage takes what share needs the attribution
+below, or the device line of a profiler capture.
 
 This module is the one owner of per-stage device timing:
 
   - `run_stage(attr, stage, fn, *args)` wraps every stage dispatch. In
-    the default (attribution OFF) mode it only opens a
-    `jax.profiler.TraceAnnotation` scope — nanoseconds when no profiler
-    session is active, and the stage shows up named in an `xprof`/
-    Perfetto device capture when one is. Dispatch stays fully async.
+    the default (attribution OFF) mode it only records the tracer's
+    `jaxbls:<stage>` span: the host's time inside the jit call, and a
+    `jax.profiler.TraceAnnotation` scope of that name — nanoseconds when
+    no profiler session is active, and the stage shows up named in an
+    `xprof`/Perfetto device capture when one is. Dispatch stays fully
+    async.
   - With attribution ON (`bn --device-trace`, bench, the calibrator,
     `scripts/profile_components.py`, env
     `LIGHTHOUSE_TPU_DEVICE_ATTRIBUTION=1`), each stage dispatch is
@@ -36,8 +41,9 @@ This module is the one owner of per-stage device timing:
     stage's residual compile and lands in
     `jaxbls_stage_compile_seconds{stage,n_sets,n_pks}` instead (the same
     first-dispatch convention as the autotune profiler), giving the
-    compile/execute split per padding bucket. The resolve also adds a
-    `device:<stage>` sub-span to the current pipeline Trace, so the
+    compile/execute split per padding bucket. The timed interval is a
+    `device:<stage>` span of the dispatch's pipeline Trace (enqueue +
+    resolve, the `jaxbls:<stage>` span its child), so the
     Chrome/Perfetto export renders host lanes AND a device lane per
     stage in one timeline (observability/trace.py routes `device:*`
     spans onto dedicated tracks).
@@ -52,13 +58,12 @@ report` and the metrics lint run with no device attached.
 
 from __future__ import annotations
 
-import contextlib
 import os
 import threading
-from time import perf_counter
 
 from ..utils.metrics import REGISTRY
 from . import perf as _perf
+from . import trace as _trace
 
 #: canonical jit-stage order of the multi-set verify kernel
 #: (`_verify_kernel` in crypto/jaxbls/backend.py)
@@ -99,7 +104,6 @@ STAGE_COMPILE_SECONDS = REGISTRY.gauge_vec(
 _lock = threading.Lock()
 _seen: set = set()          # (stage, bucket) pairs that resolved timed once
 _enabled_override: bool | None = None
-_trace_annotation = None    # cached jax.profiler.TraceAnnotation (or False)
 
 
 def set_enabled(on: bool | None) -> None:
@@ -148,74 +152,43 @@ def begin(bucket: tuple, trace=None) -> DispatchAttribution | None:
     if not enabled():
         return None
     if trace is None:
-        from . import trace as _trace
-
         trace = _trace.current_trace()
     return DispatchAttribution(bucket, trace)
 
 
-def _annotation():
-    """jax.profiler.TraceAnnotation, imported once; False if unavailable
-    (annotation then degrades to a plain call)."""
-    global _trace_annotation
-    if _trace_annotation is None:
-        try:
-            from jax.profiler import TraceAnnotation
-
-            _trace_annotation = TraceAnnotation
-        except Exception:
-            _trace_annotation = False
-    return _trace_annotation
-
-
-def annotation_scope(name: str, **args):
-    """`with annotation_scope("jaxhash:upload"):` — a named host scope in
-    the profiler's own trace (nanoseconds when no profiler session is
-    active), `args` shown with it; a no-op context where TraceAnnotation
-    is unavailable."""
-    ta = _annotation()
-    return contextlib.nullcontext() if ta is False else ta(name, **args)
-
-
 def run_stage(attr: DispatchAttribution | None, stage: str, fn, *args):
-    """Dispatch one jit stage under a named annotation scope; with an
-    attribution handle, also event-time the resolve and record it."""
-    t0 = perf_counter()
-    with annotation_scope(f"jaxbls:{stage}"):
-        out = fn(*args)
+    """Dispatch one jit stage under the tracer's `jaxbls:<stage>` span;
+    with an attribution handle, also event-time the resolve and record
+    it, the whole a `device:<stage>` span of the dispatch's trace."""
     if attr is None:
-        return out
-    try:
-        import jax
-
-        jax.block_until_ready(out)
-    except ImportError:  # pragma: no cover - jax is baked into the image
-        pass
-    t1 = perf_counter()
-    _record(attr, stage, t0, t1)
-    if _perf.analytics_enabled():
-        _perf.maybe_capture_program(stage, fn, args, attr.bucket)
-    return out
-
-
-def _record(attr: DispatchAttribution, stage: str, t0: float, t1: float) -> None:
+        with _trace.span(f"jaxbls:{stage}"):
+            return fn(*args)
+    n, m = attr.bucket
     key = (stage, attr.bucket)
     with _lock:
         first = key not in _seen
         _seen.add(key)
-    n, m = attr.bucket
-    dt = t1 - t0
+    with _trace.span(
+        f"{DEVICE_SPAN_PREFIX}{stage}", trace=attr.trace,
+        phase="compile" if first else "execute",
+    ) as sp:
+        with _trace.span(f"jaxbls:{stage}", trace=attr.trace):
+            out = fn(*args)
+        try:
+            import jax
+
+            jax.block_until_ready(out)
+        except ImportError:  # pragma: no cover - jax is baked into the image
+            pass
     if first:
         # residual compile (whatever XLA work this stage still owed at
         # this bucket) — keep it out of the steady-state distribution
-        STAGE_COMPILE_SECONDS.labels(stage, n, m).set(dt)
+        STAGE_COMPILE_SECONDS.labels(stage, n, m).set(sp.t1 - sp.t0)
     else:
-        STAGE_DEVICE_SECONDS.labels(stage, n, m).observe(dt)
-    if attr.trace is not None:
-        attr.trace.add_span(
-            f"{DEVICE_SPAN_PREFIX}{stage}", t0, t1,
-            phase="compile" if first else "execute", bucket=f"{n}x{m}",
-        )
+        STAGE_DEVICE_SECONDS.labels(stage, n, m).observe(sp.t1 - sp.t0)
+    if _perf.analytics_enabled():
+        _perf.maybe_capture_program(stage, fn, args, attr.bucket)
+    return out
 
 
 def reset_seen() -> None:

@@ -313,7 +313,7 @@ class FlightRecorder:
                     "duration_seconds": round(tr.duration(), 6),
                     "spans": [
                         {"stage": name, "seconds": round(t1 - t0, 6)}
-                        for name, t0, t1, _ in tr.spans
+                        for name, t0, t1, *_ in tr.spans
                     ],
                 }
             )

@@ -72,7 +72,7 @@ def snapshot() -> dict:
                 "duration_seconds": round(tr.duration(), 6),
                 "spans": [
                     {"stage": name, "seconds": round(t1 - t0, 6)}
-                    for name, t0, t1, _ in tr.spans
+                    for name, t0, t1, *_ in tr.spans
                 ],
                 **({"meta": {k: str(v) for k, v in tr.meta.items()}}
                    if tr.meta else {}),

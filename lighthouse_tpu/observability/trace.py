@@ -1,14 +1,23 @@
 """Span tracer for the verification dataflow.
 
-Model: a `Trace` is one work unit's journey through the pipeline — a
-coalesced gossip batch, a single work item, a device dispatch. Stages are
-recorded as closed spans (name, t0, t1, args); the processor owns the
-canonical stage names (PIPELINE_STAGES) but producers may add sub-spans
-(the jaxbls backend annotates marshalled bytes and its dispatch split).
+Model: a `Trace` is one work unit's journey through the pipeline, from the
+processor's pop to the end of its continuation, as closed spans (name, t0,
+t1, args, parent). The processor owns the top level (PIPELINE_STAGES and
+`exec_lock_wait`); every layer below it records its own phases through
+ONE primitive, `span(name, trace=None, **args)`: a closed span on the
+given (or the thread's current) trace, its parent the span open around
+it, and a `jax.profiler.TraceAnnotation` scope of the same name carrying
+the trace's `trace_id`, so a profiler capture shows the span on the
+device line's clock and joins this record by (trace_id, name). A handle
+(`VerifyHandle`, `KzgHandle`) keeps the trace current at its dispatch, and
+the processor keeps the unit's trace beside the handle, so `result()` and
+the continuation add their spans to the unit that owns them whichever
+thread resolves it (docs/OBSERVABILITY.md "Trace stages" has the tree).
 
 Every finished span feeds the `pipeline_stage_seconds{stage,kind}`
-histogram family; the finished trace lands in a bounded ring. The ring
-serves two consumers:
+histogram family (a span outside any trace: `kind="direct"`, at once);
+the finished trace lands in a bounded ring. The ring serves two
+consumers:
 
   - `/lighthouse_tpu/pipeline` (observability/pipeline.py): recent-trace
     summaries next to the aggregate stage timings;
@@ -23,8 +32,10 @@ serves two consumers:
     so backlog renders next to the spans.
 
 Cost model: the hot path pays one Trace alloc + a span tuple append per
-stage per BATCH (not per attestation), and one histogram observe per span
-— dict lookups and float math, no syscalls, no locks beyond the metric's.
+phase per BATCH (not per attestation; ~25 spans a device dispatch), two
+clock reads, a profiler scope that is nanoseconds while no capture runs,
+and one histogram observe per span — dict lookups and float math, no
+syscalls, no locks beyond the metric's.
 Timestamps are time.perf_counter() (monotonic); the export rebases them so
 t=0 is the oldest event in the ring.
 """
@@ -34,7 +45,9 @@ from __future__ import annotations
 import hashlib
 import itertools
 import json
+import contextlib
 import os
+import sys
 import threading
 from collections import deque
 from time import perf_counter
@@ -87,7 +100,7 @@ class Trace:
         self.n_items = n_items
         self.t0 = perf_counter()
         self.trace_id = next(_next_trace_id)
-        self.spans: list = []        # (name, t0, t1, args|None)
+        self.spans: list = []        # (name, t0, t1, args|None, parent|None)
         self.meta: dict = {}
         # wire-propagated origin context (observability/propagation.py):
         # set on the producer side at publish and ADOPTED on every
@@ -97,7 +110,10 @@ class Trace:
         self.ctx = None
 
     def add_span(self, name: str, t0: float, t1: float, **args) -> None:
-        self.spans.append((name, t0, t1, args or None))
+        """A closed span whose times the caller stamped itself; its parent
+        is the span this thread holds open on this trace (`span`), None at
+        top level."""
+        self.spans.append((name, t0, t1, args or None, _open_parent(self)))
 
     def adopt(self, ctx) -> None:
         """Adopt a WireTraceContext into this trace (cross-node causal
@@ -116,9 +132,34 @@ class Trace:
     def duration(self) -> float:
         if not self.spans:
             return 0.0
-        return max(t1 for _, _, t1, _ in self.spans) - min(
-            t0 for _, t0, _, _ in self.spans
-        )
+        return max(s[2] for s in self.spans) - min(s[1] for s in self.spans)
+
+    def self_seconds(self) -> list:
+        """One number a span, in the order of `spans`: its duration less
+        the part of its interval its children cover. A span's children
+        name it as their parent and lie inside it; where a name repeats on
+        a trace the innermost span that contains the child owns it."""
+        spans = self.spans
+        covered: list = [[] for _ in spans]
+        for name, t0, t1, _args, parent in spans:
+            if parent is None:
+                continue
+            owner, width = None, None
+            for i, (pname, p0, p1, _a, _p) in enumerate(spans):
+                if (pname == parent and p0 <= t0 and t1 <= p1
+                        and (width is None or p1 - p0 < width)):
+                    owner, width = i, p1 - p0
+            if owner is not None:
+                covered[owner].append((t0, t1))
+        out = []
+        for (_n, t0, t1, _a, _p), kids in zip(spans, covered):
+            busy, edge = 0.0, t0
+            for k0, k1 in sorted(kids):
+                if k1 > edge:
+                    busy += k1 - max(k0, edge)
+                    edge = k1
+            out.append((t1 - t0) - busy)
+        return out
 
 
 # wire-context thread-local (set by the transport's CREQ serve path):
@@ -170,7 +211,7 @@ class Tracer:
     def finish(self, trace: Trace | None) -> None:
         if trace is None:
             return
-        for name, t0, t1, _args in trace.spans:
+        for name, t0, t1, _args, _parent in trace.spans:
             STAGE_SECONDS.labels(name, trace.kind).observe(t1 - t0)
         TRACES_TOTAL.labels(trace.kind).inc()
         with self._lock:
@@ -320,7 +361,7 @@ def chrome_trace_events(
     span_starts = [
         t0
         for tr in traces
-        for _, t0, _, _ in tr.spans or [("", tr.t0, tr.t0, None)]
+        for _, t0, *_ in tr.spans or [("", tr.t0)]
     ]
     if base is None:
         base = min(
@@ -335,7 +376,7 @@ def chrome_trace_events(
     device_lanes: dict = {}  # span name -> dedicated tid
     for i, tr in enumerate(traces):
         host_tid = _host_tid(i)
-        for name, t0, t1, args in tr.spans:
+        for name, t0, t1, args, _parent in tr.spans:
             if name.startswith("device:"):
                 tid = device_lanes.get(name)
                 if tid is None:
@@ -495,7 +536,7 @@ def merge_chrome_traces(named_tracers, path: str, instants=None,
         t0
         for _, traces, counters in snaps
         for tr in traces
-        for _, t0, _, _ in tr.spans or [("", tr.t0, tr.t0, None)]
+        for _, t0, *_ in tr.spans or [("", tr.t0)]
     ] + [t for _, _, counters in snaps for t, _, _ in counters] + [
         t for t, _, _ in instants
     ] + [t0 for _, _, t0, _, _ in device_timeline]
@@ -570,3 +611,78 @@ def set_current_trace(trace: Trace | None) -> None:
 
 def current_trace() -> Trace | None:
     return getattr(_tls, "trace", None)
+
+
+_NO_SCOPE = contextlib.nullcontext()
+_trace_annotation = None    # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def annotation_scope(name: str, **args):
+    """`with annotation_scope("jaxbls:pairing.miller"):` — a named host
+    scope in the profiler's own trace (nanoseconds when no profiler
+    session is active), `args` shown with it. A no-op context where jax is
+    not loaded already (a host-backend node must not import it for a
+    name). `span` opens one for every span it records."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        if "jax" not in sys.modules:
+            return _NO_SCOPE
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(name, **args)
+
+
+def _open_parent(trace: Trace):
+    """Name of the innermost span this thread holds open on `trace`."""
+    for tr, name in reversed(getattr(_tls, "open", ())):
+        if tr is trace:
+            return name
+    return None
+
+
+class span:
+    """`with span("jaxbls:marshal.h2f", bytes=n) as sp:` — THE span
+    primitive: one closed span on `trace` (the thread's current trace when
+    None) whose parent is the span this thread holds open around it on
+    that trace, and a profiler scope of the same name with the trace's id
+    (`annotation_scope`, which opens one only where jax is loaded
+    already), so the span stands in a profiler capture on the device
+    line's clock. With no trace at all the scope still opens and the
+    seconds go straight to `pipeline_stage_seconds{stage=name,
+    kind="direct"}`. `sp.t0` / `sp.t1` are the span's clock reads;
+    `sp.args` may take more keys until the block ends (what a look-up
+    found, the bytes it packed) — they reach the record, not the scope."""
+
+    __slots__ = ("name", "trace", "args", "t0", "t1", "_scope")
+
+    def __init__(self, name: str, trace: Trace | None = None, **args):
+        self.name = name
+        self.trace = current_trace() if trace is None else trace
+        self.args = args
+        self.t0 = self.t1 = None
+
+    def __enter__(self):
+        tr = self.trace
+        stack = getattr(_tls, "open", None)
+        if stack is None:
+            stack = _tls.open = []
+        stack.append((tr, self.name))
+        self._scope = (
+            annotation_scope(self.name, **self.args) if tr is None else
+            annotation_scope(self.name, trace_id=tr.trace_id, **self.args)
+        )
+        self._scope.__enter__()
+        self.t0 = perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = perf_counter()
+        self._scope.__exit__(*exc)
+        _tls.open.pop()
+        tr = self.trace
+        if tr is None:
+            STAGE_SECONDS.labels(self.name, "direct").observe(self.t1 - self.t0)
+        else:
+            tr.add_span(self.name, self.t0, self.t1, **self.args)
+        return False

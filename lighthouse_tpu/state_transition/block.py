@@ -18,13 +18,9 @@ limits and EIP-7044 exit domains (signature_sets.py).
 
 from __future__ import annotations
 
-import contextlib
-import sys
 from enum import Enum
-from time import perf_counter
 
 from ..crypto import bls
-from ..observability import device as _obs_dev
 from ..observability import trace as _obs
 from ..types import helpers as h
 from ..types.spec import ChainSpec, ForkName, FAR_FUTURE_EPOCH
@@ -78,21 +74,10 @@ class SignatureBatch:
             return True
         n = len(self.sets)
         widest = max(len(s.signing_keys) for s in self.sets)
-        # a host scope in the profiler's own trace, but only where jax is
-        # loaded already: a host-backend node must not import it for a name
-        scope = (
-            _obs_dev.annotation_scope(BATCH_SPAN, sets=n, widest_keys=widest)
-            if "jax" in sys.modules else contextlib.nullcontext()
-        )
-        t0 = perf_counter()
-        with scope:
+        with _obs.span(BATCH_SPAN, sets=n, widest_keys=widest) as sp:
             ok = bls.verify_signature_sets(self.sets)
-        t1 = perf_counter()
-        _BATCH_SECONDS.observe(t1 - t0)
+        _BATCH_SECONDS.observe(sp.t1 - sp.t0)
         _BATCH_SETS.inc(n)
-        tr = _obs.current_trace()
-        if tr is not None:
-            tr.add_span(BATCH_SPAN, t0, t1, sets=n, widest_keys=widest)
         return ok
 
 

@@ -8,6 +8,7 @@ programs once, in one module-scoped warm-up (tests/jaxbls_warm.py): a new
 test of the staged backend joins one of the two instead of opening a
 file, and keeps to the builds and key-count buckets its module warms."""
 
+import json
 import random
 
 import pytest
@@ -400,6 +401,131 @@ def test_aggregate_shaped_batch_through_aggregate_batch_parity(victim, role):
     args = dict(aggregates=2, sets=6, distinct_messages=4, widest_keys=4)
     assert [s[3] for s in tr.spans if s[0] == ab.BATCH_SPAN] == [args]
     assert tr.meta["distinct_messages"] == 4 and tr.meta["bucket"] == "8x4"
+
+
+#: every span of a dispatch below the processor's own, by the lane it took;
+#: benchmarks/layer_metrics reads the jaxbls:marshal.* ones
+#: (test_observability pins which file reads which)
+_DISPATCH_SPANS = [
+    "jaxbls:marshal.pubkeys", "jaxbls:marshal.pubkeys_upload",
+    "jaxbls:marshal.sigs", "jaxbls:marshal.h2f", "jaxbls:marshal.upload",
+    "jaxbls:marshal", "jaxbls:admit", "jaxbls:prepare", "jaxbls:h2c", "jaxbls:pairs",
+    "jaxbls:pairing", "jaxbls:enqueue",
+]
+
+
+#: the per-layer metrics PR 37 reads off a BLS dispatch (benchmarks/
+#: layer_metrics/<name>.json, evaluated by benchmarks/layer_reader.py)
+_BLS_LAYER_METRICS = [
+    "marshal_pubkeys_ms", "marshal_pubkeys_upload_ms", "marshal_sigs_ms",
+    "marshal_h2f_ms", "marshal_upload_ms", "dispatch_device_ms",
+    "exec_lock_wait_ms", "continuation_ms",
+]
+
+
+def _bench_file(*parts):
+    import os
+
+    return os.path.join(os.path.dirname(__file__), "..", "benchmarks", *parts)
+
+
+def _layer_reader():
+    """benchmarks/layer_reader.py, loaded by its path: the benchmark is a
+    directory of scripts, not a package."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "bench_layer_reader", _bench_file("layer_reader.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.parametrize("entry", ["batch", "urgent", "signature_batch"])
+def test_real_dispatch_emits_the_span_tree(entry):
+    """One real dispatch a lane through a BeaconProcessor work item, no
+    attribution: the unit's trace holds the marshal and its five parts, the
+    dispatcher's admit (batch lane only) and enqueue with the four stage
+    calls under it, the handle's wait under `device` — or,
+    where the runner resolves its own handle (SignatureBatch.verify()),
+    under the entry batch's span inside `marshal`, whose self time leaves
+    the wait out — and the processor's six at the top."""
+    import lighthouse_tpu.crypto.jaxbls.pipeline as pl
+    from lighthouse_tpu.chain.beacon_processor import (
+        BeaconProcessor,
+        WorkItem,
+        WorkKind,
+    )
+    from lighthouse_tpu.observability import TRACER
+    from lighthouse_tpu.state_transition.block import SignatureBatch
+    from lighthouse_tpu.utils.metrics import REGISTRY
+
+    backend = bls_api.set_backend("jax")
+    sets = [_mk_set(1, bytes([0xD0 + len(entry)]) * 32)]
+    verdicts = []
+
+    def run():
+        if entry == "signature_batch":
+            batch = SignatureBatch()
+            batch.add(sets)
+            verdicts.append(batch.verify())
+            return None
+        return (backend.verify_signature_sets_async(
+            sets, [1], urgent=entry == "urgent"), verdicts.append)
+
+    lane = "urgent" if entry == "urgent" else "batch"
+    device = pl._DISPATCH_DEVICE.labels(lane)
+    n0 = device.n
+    reader = _layer_reader()
+    before = reader.snapshot(REGISTRY)
+    proc = BeaconProcessor()
+    proc.submit(WorkItem(WorkKind.gossip_block, run=run))
+    proc.run_until_idle()
+    assert verdicts == [True] and device.n == n0 + 1
+    # the benchmark's reader finds every one of this PR's BLS metrics in
+    # the window of this one dispatch (a synchronous runner has no
+    # continuation: block_import_131 is off that metric's list)
+    after = reader.snapshot(REGISTRY)
+    for metric in _BLS_LAYER_METRICS:
+        with open(_bench_file("layer_metrics", metric + ".json")) as f:
+            source = json.load(f)["source"]
+        value = reader.evaluate(source, before, after, {}, {})
+        if metric == "continuation_ms" and entry == "signature_batch":
+            assert not value, (metric, value)
+        else:
+            assert value is not None and value > 0, (metric, value)
+    tr = TRACER.snapshot_ring()[-1]
+    names = [s[0] for s in tr.spans]
+    parent = {s[0]: s[4] for s in tr.spans}
+    assert len(set(names)) == len(names)
+    inner = [n for n in _DISPATCH_SPANS if lane == "batch" or n != "jaxbls:admit"]
+    waits = ["jaxbls:device_wait"]
+    if entry == "signature_batch":
+        assert names == (["enqueue", "coalesce", "exec_lock_wait"] + inner
+                         + waits + ["block:signature_batch", "marshal"])
+        under = "block:signature_batch"
+        assert parent[under] == "marshal"
+        assert {parent[n] for n in waits} == {under}
+        selfs = dict(zip(names, tr.self_seconds()))
+        spans = {s[0]: s[2] - s[1] for s in tr.spans}
+        assert selfs["marshal"] <= spans["marshal"] - spans[under] + 1e-9
+        assert selfs[under] <= spans[under] - spans["jaxbls:device_wait"]
+    else:
+        assert names == (["enqueue", "coalesce", "exec_lock_wait"] + inner
+                         + ["marshal"] + waits + ["device", "continuation"])
+        under = "marshal"
+        assert {parent[n] for n in waits} == {"device"}
+    for n in inner:
+        want = ("jaxbls:enqueue" if n in (
+            "jaxbls:prepare", "jaxbls:h2c", "jaxbls:pairs", "jaxbls:pairing")
+            else "jaxbls:marshal" if n.startswith("jaxbls:marshal.")
+            else under)
+        assert parent[n] == want, n
+    args = {s[0]: s[3] for s in tr.spans}
+    assert args["jaxbls:marshal.pubkeys"] == {"hit": 0, "bytes": args[
+        "jaxbls:marshal.pubkeys_upload"]["bytes"]}
+    assert args["jaxbls:enqueue"] == {"lane": lane}
+    assert tr.meta["real_sets"] == 1
 
 
 @pytest.mark.slow

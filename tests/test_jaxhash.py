@@ -51,8 +51,16 @@ def test_device_levels_match_host_builder(n, depth):
     """Level arrays AND root bit-identical to tree_cache._build —
     including non-pow2 leaf counts (odd-tail zero-hash folding) and deep
     virtual depth."""
+    from lighthouse_tpu.observability.trace import STAGE_SECONDS
+
+    # the engine's four phases are spans; with no trace current they feed
+    # the `direct` series at once (the benchmark's tree cell reads two)
+    phases = [STAGE_SECONDS.labels(f"jaxhash:{p}", "direct")
+              for p in ("marshal", "upload", "ladder", "readback")]
+    seen = [c.n for c in phases]
     leaves = _rand_leaves(n, seed=n)
     lv_d, root_d = engine.device_build_levels(leaves, depth)
+    assert [c.n for c in phases] == [k + 1 for k in seen]
     lv_h, root_h = tc._build(leaves, depth)
     assert root_d == root_h
     assert len(lv_d) == len(lv_h) == depth

@@ -111,8 +111,23 @@ def test_jax_backend_device_kzg(setup, monkeypatch):
         bad[7] ^= 1
         assert not kzg.verify_blob_kzg_proof(bytes(bad), cb, pb, setup)
 
-        # batch path: one two-pairing check on the device pairing stage
-        assert kzg.verify_blob_kzg_proof_batch([blob], [cb], [pb], setup)
+        # batch path: one two-pairing check on the device pairing stage,
+        # and the batch's own spans on the trace that is current: the
+        # host's two parts, the packing, the dispatcher's and the handle's
+        from lighthouse_tpu.observability import trace as obstrace
+
+        tr = obstrace.Trace("gossip_blob_sidecar", 1)
+        obstrace.set_current_trace(tr)
+        try:
+            assert kzg.verify_blob_kzg_proof_batch([blob], [cb], [pb], setup)
+        finally:
+            obstrace.set_current_trace(None)
+        assert [s[0] for s in tr.spans] == [
+            "kzg:points", "kzg:field", "kzg:pack", "jaxbls:admit",
+            "jaxbls:kzg_lincomb", "jaxbls:pairing", "jaxbls:enqueue",
+            "jaxbls:device_wait"]
+        assert {s[4] for s in tr.spans} == {None, "jaxbls:enqueue"}
+        assert tr.spans[2][3] == {"blobs": 1, "lanes": 8}
     finally:
         bls.set_backend(prev.name)
 

@@ -6,6 +6,7 @@ import json
 import subprocess
 import sys
 import urllib.request
+from time import perf_counter
 from types import SimpleNamespace
 
 from lighthouse_tpu.observability import (
@@ -112,10 +113,18 @@ def test_device_attribution_records_split_and_spans():
     assert obsdev.begin(bucket) is None
     assert obsdev.run_stage(None, "prepare", lambda: 5) == 5
 
-    names = [s[0] for s in tr.spans]
+    timed = [s for s in tr.spans if s[0].startswith("device:")]
+    names = [s[0] for s in timed]
     assert names == ["device:prepare", "device:prepare", "device:pairing"]
-    phases = [s[3]["phase"] for s in tr.spans]
+    phases = [s[3]["phase"] for s in timed]
     assert phases == ["compile", "execute", "compile"]
+    # the jit call's own span is the timed interval's child on that trace
+    calls = [s for s in tr.spans if s[0].startswith("jaxbls:")]
+    assert [(s[0], s[4]) for s in calls] == [
+        ("jaxbls:prepare", "device:prepare"),
+        ("jaxbls:prepare", "device:prepare"),
+        ("jaxbls:pairing", "device:pairing")]
+    assert all(s[4] is None for s in timed)
     assert obsdev.STAGE_COMPILE_SECONDS.labels("prepare", 16, 2).value > 0
     assert obsdev.STAGE_DEVICE_SECONDS.labels("prepare", 16, 2).n == 1
     assert obsdev.STAGE_DEVICE_SECONDS.labels("pairing", 16, 2).n == 0
@@ -285,8 +294,8 @@ def test_signature_batch_records_span_and_block_families():
     assert blk._BATCH_SECONDS.n == n0 + 1
     assert blk._BATCH_SETS.value == sets0 + 2
     (span,) = [sp for sp in tr.spans if sp[0] == blk.BATCH_SPAN]
-    name, t0, t1, args = span
-    assert name == "block:signature_batch"
+    name, t0, t1, args, parent = span
+    assert name == "block:signature_batch" and parent is None
     assert args == {"sets": 2, "widest_keys": 2}
     assert 0 < t1 - t0 == blk._BATCH_SECONDS.total - total0
     names = {m.name for m in REGISTRY.all_metrics()}
@@ -304,8 +313,10 @@ def test_processor_traces_every_stage():
     assert TRACER.completed > before
     tr = TRACER.snapshot_ring()[-1]
     assert tr.kind == "gossip_attestation" and tr.n_items == 8
-    stages = [s[0] for s in tr.spans]
-    assert stages == list(PIPELINE_STAGES)
+    stages = [s[0] for s in tr.spans if s[4] is None]
+    assert stages == ["enqueue", "coalesce", "exec_lock_wait", "marshal",
+                      "device", "continuation"]
+    assert [s for s in stages if s != "exec_lock_wait"] == list(PIPELINE_STAGES)
     for stage in PIPELINE_STAGES:
         child = STAGE_SECONDS.labels(stage, "gossip_attestation")
         assert child.n > 0, f"stage {stage} never observed"
@@ -384,6 +395,287 @@ def test_processor_device_failure_counted_and_logged():
     )
     proc.run_until_idle()
     assert _ERRORS.labels("continuation").value == cont0 + 1
+
+
+# ------------------------------------------- the span primitive and joins
+
+
+def _tick(seconds: float = 0.002) -> None:
+    import time
+
+    time.sleep(seconds)
+
+
+def test_span_records_parent_and_self_seconds():
+    """`span` records a closed span with the span open around it on that
+    trace as its parent; `self_seconds` is a span's duration less what its
+    children cover: children tile, and never exceed, their parent. With no
+    trace at all the seconds go to the `direct` series at once."""
+    from lighthouse_tpu.observability import trace as obstrace
+    from lighthouse_tpu.observability.trace import STAGE_SECONDS, span
+
+    tr = Trace("gossip_block", 1)
+    other = Trace("gossip_attestation", 1)
+    obstrace.set_current_trace(tr)
+    try:
+        with span("marshal") as top:
+            _tick()
+            with span("block:signature_batch", sets=3) as mid:
+                with span("jaxbls:marshal.pubkeys") as leaf:
+                    _tick()
+                    leaf.args["hit"] = 0
+                with span("jaxbls:marshal.pubkeys"):   # the name repeats
+                    _tick()
+                # a span of ANOTHER trace opened here is nobody's child
+                with span("jaxbls:device_wait", other):
+                    pass
+                tr.add_span("stamped", mid.t0, mid.t0 + 1e-4)
+            _tick()
+    finally:
+        obstrace.set_current_trace(None)
+    assert [(s[0], s[4]) for s in tr.spans] == [
+        ("jaxbls:marshal.pubkeys", "block:signature_batch"),
+        ("jaxbls:marshal.pubkeys", "block:signature_batch"),
+        ("stamped", "block:signature_batch"),
+        ("block:signature_batch", "marshal"),
+        ("marshal", None)]
+    assert tr.spans[0][3] == {"hit": 0} and tr.spans[3][3] == {"sets": 3}
+    assert other.spans == [("jaxbls:device_wait", other.spans[0][1],
+                            other.spans[0][2], None, None)]
+    selfs = dict(zip(("leaf1", "leaf2", "stamped", "mid", "top"),
+                     tr.self_seconds()))
+    durs = [s[2] - s[1] for s in tr.spans]
+    assert selfs["leaf1"] == durs[0] and selfs["leaf2"] == durs[1]
+    # the stamped span overlaps the first leaf: the union is what is covered
+    assert 0 <= selfs["mid"] <= durs[3] - durs[0] - durs[1] + 1e-9
+    assert abs(selfs["top"] - (durs[4] - durs[3])) < 1e-9
+    assert selfs["top"] >= 0.003                   # the two ticks outside mid
+    assert (top.t0, top.t1) == tr.spans[4][1:3]
+
+    direct = STAGE_SECONDS.labels("jaxbls:marshal.h2f", "direct")
+    n0 = direct.n
+    with span("jaxbls:marshal.h2f", bytes=1):
+        pass
+    assert direct.n == n0 + 1 and obstrace.current_trace() is None
+
+
+class _StubArray:
+    """What a handle waits on: `__array__` sleeps, as a device array's
+    does until it is ready, then gives the verdict."""
+
+    def __init__(self, value, seconds=0.0):
+        self._value, self._seconds = value, seconds
+
+    def __array__(self, dtype=None, copy=None):
+        import numpy as np
+
+        _tick(self._seconds)
+        return np.asarray(self._value)
+
+
+def _stub_dispatch(dispatcher, seconds=0.005, urgent=False):
+    """A ticket of `dispatcher` whose handle is a real VerifyHandle over
+    stub arrays: the trace is the thread's current one, as in the backend."""
+    from lighthouse_tpu.crypto.jaxbls.backend import VerifyHandle
+    from lighthouse_tpu.observability import trace as obstrace
+
+    tr = obstrace.current_trace()
+    return dispatcher.submit(
+        lambda: VerifyHandle(_StubArray(True, seconds), _StubArray(False),
+                             trace=tr),
+        urgent=urgent)
+
+
+def test_handle_resolved_on_another_thread_joins_its_trace():
+    """The unit is dispatched on this thread and resolved on another, where
+    no trace is current: `jaxbls:device_wait` and the processor's
+    `device` and `continuation` land on the trace that
+    dispatched it, and the dispatcher counts the dispatch's device time."""
+    import threading
+
+    from lighthouse_tpu.chain.beacon_processor import (
+        BeaconProcessor,
+        WorkItem,
+        WorkKind,
+    )
+    from lighthouse_tpu.crypto.jaxbls import pipeline as pl
+    from lighthouse_tpu.observability import trace as obstrace
+
+    dispatcher = pl.PipelinedDispatcher(depth=2)
+    device = pl._DISPATCH_DEVICE.labels("batch")
+    n0, total0 = device.n, device.total
+    verdicts = []
+    proc = BeaconProcessor()
+    proc.submit(WorkItem(
+        WorkKind.gossip_attestation, payload=0,
+        run_batch=lambda p: (_stub_dispatch(dispatcher), verdicts.append)))
+    single, batch, tr = proc._next_work(force=True)
+    proc._execute(single, batch, tr)
+    assert [s[0] for s in tr.spans] == [
+        "enqueue", "coalesce", "exec_lock_wait", "jaxbls:admit",
+        "jaxbls:enqueue", "marshal"]
+    seen = []
+
+    def resolve():
+        seen.append(obstrace.current_trace())
+        proc._resolve_oldest()
+        seen.append(obstrace.current_trace())
+
+    t = threading.Thread(target=resolve)
+    t.start()
+    t.join(timeout=30)
+    assert not t.is_alive() and seen == [None, None] and verdicts == [True]
+    by_name = {s[0]: s for s in tr.spans}
+    assert by_name["jaxbls:device_wait"][4] == "device"
+    assert by_name["jaxbls:admit"][4] == by_name["jaxbls:enqueue"][4] == "marshal"
+    assert by_name["device"][4] is None and by_name["continuation"][4] is None
+    wait = by_name["jaxbls:device_wait"]
+    assert wait[2] - wait[1] >= 0.004
+    assert TRACER.snapshot_ring()[-1] is tr
+    # ready less the first stage's enqueue: at least the stub's wait
+    assert device.n == n0 + 1 and device.total - total0 >= 0.004
+
+
+def test_top_level_spans_tile_submit_to_continuation():
+    """The top-level spans of a processed unit run from its submit to the
+    end of its continuation, in order, never overlapping; between them is
+    only the processor's own bookkeeping: no gap of a millisecond in ANY
+    of five units. The machine that runs the tests is shared and may take
+    a thread off its core inside a gap, so a round of five that holds a
+    wider gap is run again, three rounds at most; the order, the overlap
+    and the parents are asserted on every unit of every round."""
+    import gc
+
+    from lighthouse_tpu.chain.beacon_processor import (
+        BeaconProcessor,
+        WorkItem,
+        WorkKind,
+    )
+    from lighthouse_tpu.crypto.jaxbls import pipeline as pl
+
+    dispatcher = pl.PipelinedDispatcher(depth=2)
+    proc = BeaconProcessor()
+    done = []
+
+    def widest_gap_of_one_unit():
+        item = WorkItem(
+            WorkKind.gossip_attestation, payload=0,
+            run_batch=lambda p: (_stub_dispatch(dispatcher, 0.002),
+                                 lambda ok: done.append(perf_counter())))
+        proc.submit(item)
+        proc.run_until_idle()
+        tr = TRACER.snapshot_ring()[-1]
+        top = [s for s in tr.spans if s[4] is None]
+        assert [s[0] for s in top] == [
+            "enqueue", "coalesce", "exec_lock_wait", "marshal", "device",
+            "continuation"]
+        assert top[0][1] == item.t_enq
+        assert top[-1][1] <= done[-1] <= top[-1][2]
+        gaps = [b[1] - a[2] for a, b in zip(top, top[1:])]
+        assert min(gaps) >= 0, gaps                  # no overlap, in order
+        # every other span hangs under a top-level one
+        names = {s[0] for s in tr.spans}
+        assert all(s[4] in names for s in tr.spans if s[4] is not None)
+        return max(gaps)
+
+    rounds = []
+    gc.disable()                     # a collection inside a gap is not a gap
+    try:
+        for _ in range(3):
+            rounds.append([widest_gap_of_one_unit() for _ in range(5)])
+            if max(rounds[-1]) < 1e-3:
+                break
+    finally:
+        gc.enable()
+    assert max(rounds[-1]) < 1e-3, rounds
+
+
+def test_synchronous_runner_marshal_holds_the_device_wait():
+    """A runner that resolves its own handle (SignatureBatch.verify() in a
+    gossip_block item) has no `device` span: `jaxbls:device_wait` is a
+    descendant of `marshal`, under the entry batch's span, and marshal's
+    self time leaves it out."""
+    from lighthouse_tpu.chain.beacon_processor import (
+        BeaconProcessor,
+        WorkItem,
+        WorkKind,
+    )
+    from lighthouse_tpu.crypto.jaxbls import pipeline as pl
+    from lighthouse_tpu.observability.trace import span
+
+    dispatcher = pl.PipelinedDispatcher(depth=2)
+
+    def run():
+        with span("block:signature_batch", sets=1):
+            assert _stub_dispatch(dispatcher, 0.02).result() is True
+
+    proc = BeaconProcessor()
+    proc.submit(WorkItem(WorkKind.gossip_block, run=run))
+    proc.run_until_idle()
+    tr = TRACER.snapshot_ring()[-1]
+    by_name = {s[0]: s for s in tr.spans}
+    assert "device" not in by_name and "continuation" not in by_name
+    chain, name = [], "jaxbls:device_wait"
+    while name is not None:
+        chain.append(name)
+        name = by_name[name][4]
+    assert chain == ["jaxbls:device_wait", "block:signature_batch", "marshal"]
+    selfs = dict(zip([s[0] for s in tr.spans], tr.self_seconds()))
+    marshal = by_name["marshal"]
+    assert selfs["jaxbls:device_wait"] >= 0.019
+    assert marshal[2] - marshal[1] >= 0.019
+    assert selfs["marshal"] < 0.01 and selfs["block:signature_batch"] < 0.01
+
+
+def test_new_layer_metrics_read_spans_the_program_emits():
+    """Every `pipeline_stage_seconds` stage a layer metric of
+    benchmarks/layer_metrics reads is a span name the program emits (the
+    real-dispatch tests of test_jaxbls_backend, test_kzg and test_jaxhash
+    see each emitted), and the two other families this PR's metrics read
+    are registered."""
+    import glob
+    import os
+
+    from lighthouse_tpu.crypto.jaxbls import pipeline as pl  # noqa: F401
+    from lighthouse_tpu.utils.metrics import REGISTRY
+
+    root = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "layer_metrics")
+    read = {}
+    for path in glob.glob(os.path.join(root, "*.json")):
+        with open(path) as f:
+            spec = json.load(f)
+        src = spec["source"]
+        # a span's seconds over the device backend's dispatches: a ratio
+        # that reads nothing where no dispatch reached that backend
+        per_dispatch = src.get("reduce") == "ratio" and src["den"] == {
+            "family": "jaxbls_dispatch_device_seconds", "reduce": "count"}
+        if per_dispatch:
+            assert src["scale"] == 1000 and src["num"]["reduce"] == "sum"
+            src = src["num"]
+        if src.get("family") == "pipeline_stage_seconds":
+            assert per_dispatch or src["reduce"] == "mean_ms"
+            assert spec["origin"] == "host_clock"
+            read[spec["name"]] = src["labels"]["stage"]
+        elif per_dispatch:
+            read[spec["name"]] = src["family"]
+    assert read == {
+        "marshal_pubkeys_ms": "jaxbls:marshal.pubkeys",
+        "marshal_pubkeys_upload_ms": "jaxbls:marshal.pubkeys_upload",
+        "marshal_sigs_ms": "jaxbls:marshal.sigs",
+        "marshal_h2f_ms": "jaxbls:marshal.h2f",
+        "marshal_upload_ms": "jaxbls:marshal.upload",
+        "kzg_pack_ms": "kzg:pack",
+        "continuation_ms": "continuation",
+        "exec_lock_wait_ms": "beacon_processor_exec_lock_wait_seconds",
+        "tree_upload_ms": "jaxhash:upload",
+        "tree_readback_ms": "jaxhash:readback",
+    }
+    families = {m.name for m in REGISTRY.all_metrics()}
+    assert {"jaxbls_dispatch_device_seconds",
+            "beacon_processor_exec_lock_wait_seconds"} <= families
+    assert not {"jaxbls_dispatch_enqueue_seconds",
+                "jaxbls_device_wait_seconds"} & families
 
 
 # ------------------------------------------------------------ monitoring
