@@ -4,9 +4,11 @@ Split host/device at the hashing boundary (SURVEY.md §7 step 1):
   host   — expand_message_xmd with SHA-256 (hashlib; sequential, tiny) and
            hash_to_field reduction to Fq2 elements (Python bigints).
   device — everything algebraic and batch-parallel: simplified SWU with a
-           single-exponentiation sqrt_ratio (branch-free candidate selects),
-           3-isogeny in projective form (no inversions), Jacobian point add
-           and cofactor clearing by h_eff.
+           single-exponentiation sqrt_ratio (branch-free candidate selects)
+           whose 758-bit exponent is split over the Frobenius into one
+           joint chain of 381 squarings (fq2_pow_frobenius), 3-isogeny in
+           projective form (no inversions), Jacobian point add and
+           psi-based cofactor clearing.
 
 Ground truth: lighthouse_tpu/crypto/bls381/hash_to_curve.py (itself pinned by
 the RFC 9380 J.10.1 vector). The device path is differentially tested against
@@ -42,7 +44,31 @@ _ZA_NP = np.asarray(tw._fq2_const_np(pyf.fq2_mul(ph2c.ISO_Z, ph2c.ISO_A)))
 # sqrt_ratio exponent: s = u * v^7 * (u * v^15)^E with E = (q-9)/16 gives
 # s^2 = omega * u/v for an 8th root of unity omega.
 _E = (Q - 9) // 16
-_E_BITS = np.array([int(b) for b in bin(_E)[2:]], np.uint32)
+# Fq2's Frobenius is conjugation, so with E = e1*p + e0 the one power is
+# a^E = (a^p)^e1 * a^e0 = conj(a)^e1 * a^e0: two powers of 377 and 381 bits
+# that share their squarings, half the chain of the 758-bit one.
+_E1, _E0 = divmod(_E, P)
+assert _E1 < P and _E0 < P and _E1 * P + _E0 == _E
+
+# Bits of e1 and of e0 a joint digit of fq2_pow_frobenius holds. A measured
+# constant (scripts/measure_h2c_chain.py on a v5e, PERF.md S6, PR 38), ms a
+# call, median of 5; "old" is the one-base 4-bit chain over all 758 bits:
+#
+#                           old      2 + 2    3 + 3
+#   chain alone,  8 lanes   51.38    33.23    29.17
+#               128 lanes   46.11    31.04    29.58
+#               512 lanes   39.73    27.19    26.96
+#   hash_to_g2_jacobian,
+#     4 sets (urgent)      126.17   105.74   103.92
+#    64 sets (gossip)      115.17    97.88    99.06
+#   256 sets (block)       141.50   115.40   112.61
+#
+# A scan step costs about one field operation more than it holds (~42 us
+# beside ~40 us an operation at 128 lanes), so 3 + 3's 126 steps of 4 beat
+# 2 + 2's 190 steps of 3 by less than the count says, and its table of 64
+# (one stacked multiply over 64 x lanes) takes part of that back: ahead by
+# 1.8 and 2.8 ms in the whole program at 4 and 256 sets, behind by 1.2 at 64.
+SQRT_WINDOW = 3
 
 # Candidate correction constants: y = s*c with c^2 = 1/omega (QR cases,
 # omega in the 4th roots of unity) or c^2 = Z/omega (non-QR cases, omega a
@@ -89,47 +115,46 @@ _ISO_K_NP = np.stack(
 # ------------------------------------------------------------ device pieces
 
 
-def fq2_pow_static(a, bits: np.ndarray, window: int = 4):
-    """a^e for a static exponent given as an MSB-first bit array.
+def fq2_pow_frobenius(a, e1: int, e0: int):
+    """conj(a)^e1 * a^e0 for static e1, e0: a^(e1*p + e0), since the
+    Frobenius of Fq2 is conjugation.
 
-    Fixed-window form: a runtime table of a^0..a^(2^w-1), then one scan over
-    base-2^w digits (w squarings + one table multiply per step) — ~5 field
-    muls per 4 bits instead of 1.5 per bit, and 4x fewer scan iterations."""
-    e = int("".join(str(int(b)) for b in np.asarray(bits)), 2)
-    if e == 0:
-        return jnp.broadcast_to(tw.FQ2_ONE, a.shape)
-    digits = []
-    while e:
-        digits.append(e & ((1 << window) - 1))
-        e >>= window
-    digits.reverse()
+    One joint fixed-window chain (Straus): a runtime table of
+    conj(a)^i * a^j for i, j < 2^w, then one scan over the joint base-2^w
+    digits of (e1, e0), MSB first: w squarings + one table multiply a step.
+    The digits are static and the same for every lane."""
+    w = SQRT_WINDOW
+    nt = 1 << w
+    steps = max(-(-max(e1.bit_length(), e0.bit_length()) // w), 1)
+    digits = [
+        (e1 >> w * k & (nt - 1)) << w | (e0 >> w * k & (nt - 1))
+        for k in reversed(range(steps))
+    ]
 
-    # log-round stacked table build (a^j = a^(j//2) * a^(j-j//2))
-    nt = 1 << window
-    table = [jnp.broadcast_to(tw.FQ2_ONE, a.shape), a]
-    while len(table) < nt:
-        m = len(table)
+    # powers of a in log rounds (a^j = a^(j//2) * a^(j-j//2)), their
+    # conjugates, then every product in one stacked multiply
+    pows = [jnp.broadcast_to(tw.FQ2_ONE, a.shape), a]
+    while len(pows) < nt:
+        m = len(pows)
         idx = list(range(m, min(2 * (m - 1), nt - 1) + 1))
         prod = tw.fq2_mul(
-            jnp.stack([table[j // 2] for j in idx]),
-            jnp.stack([table[j - j // 2] for j in idx]),
+            jnp.stack([pows[j // 2] for j in idx]),
+            jnp.stack([pows[j - j // 2] for j in idx]),
         )
-        for k in range(len(idx)):
-            table.append(prod[k])
-    table_arr = jnp.stack(table)
-
-    acc = table_arr[digits[0]]
-    rest = jnp.asarray(np.array(digits[1:], np.uint32))
-    if rest.size == 0:
-        return acc
+        pows.extend(prod[k] for k in range(len(idx)))
+    pows = jnp.stack(pows)
+    table = tw.fq2_mul(tw.fq2_conj(pows)[:, None], pows[None, :])
+    table = table.reshape((nt * nt,) + a.shape)
 
     def body(acc, digit):
-        for _ in range(window):
+        for _ in range(w):
             acc = tw.fq2_sqr(acc)
-        acc = tw.fq2_mul(acc, lax.dynamic_index_in_dim(table_arr, digit, 0, keepdims=False))
+        acc = tw.fq2_mul(acc, lax.dynamic_index_in_dim(table, digit, 0, keepdims=False))
         return acc, None
 
-    acc, _ = lax.scan(body, acc, rest)
+    acc, _ = lax.scan(
+        body, table[digits[0]], jnp.asarray(np.array(digits[1:], np.uint32))
+    )
     return acc
 
 
@@ -153,7 +178,7 @@ def fq2_sqrt_ratio(u, v):
     v7 = tw.fq2_mul(v4, tw.fq2_mul(v2, v))
     v15 = tw.fq2_mul(v8, v7)
     uv15 = tw.fq2_mul(u, v15)
-    s = tw.fq2_mul(tw.fq2_mul(u, v7), fq2_pow_static(uv15, _E_BITS))
+    s = tw.fq2_mul(tw.fq2_mul(u, v7), fq2_pow_frobenius(uv15, _E1, _E0))
 
     ys = tw.fq2_mul(                                          # (..., 8, 2, NL)
         s[..., None, :, :], jnp.asarray(_CAND_CONSTS_NP)

@@ -15,6 +15,47 @@ from lighthouse_tpu.crypto.jaxbls import h2c_ops as h2
 from lighthouse_tpu.crypto.jaxbls import tower as tw
 
 
+def test_sqrt_exponent_splits_over_the_frobenius():
+    """Host only: E = e1*p + e0 with both halves below p, so that
+    a^E = conj(a)^e1 * a^e0 is the same element of Fq2."""
+    assert h2._E1 * P + h2._E0 == (P * P - 9) // 16 == h2._E
+    assert 0 < h2._E1 < P and 0 < h2._E0 < P
+    a = (0x1234567, 0x89ABCDE)
+    conj_a = (a[0], (-a[1]) % P)
+    assert pyf.fq2_pow(a, P) == conj_a
+    assert pyf.fq2_pow(a, h2._E) == pyf.fq2_mul(
+        pyf.fq2_pow(conj_a, h2._E1), pyf.fq2_pow(a, h2._E0))
+
+
+def _joint_power_inputs():
+    import random
+
+    rng = random.Random(0xE1E0)
+    return {
+        "random0": (rng.randrange(P), rng.randrange(P)),
+        "random1": (rng.randrange(P), rng.randrange(P)),
+        "zero": (0, 0),
+        "one": (1, 0),
+        "real": (rng.randrange(2, P), 0),           # conj(a) == a
+        "imaginary": (0, rng.randrange(2, P)),      # conj(a) == -a
+    }
+
+
+@pytest.fixture(scope="module")
+def joint_power():
+    return jax.jit(lambda a: h2.fq2_pow_frobenius(a, h2._E1, h2._E0))
+
+
+@pytest.mark.parametrize("a", list(_joint_power_inputs().values()),
+                         ids=list(_joint_power_inputs()))
+def test_joint_power_matches_python(joint_power, a):
+    """The square root's exponentiation, conj(a)^e1 * a^e0 in one joint
+    chain, against the pure-Python tower's a^E: a conjugate on the wrong
+    factor hides on a real element and shows on every other."""
+    got = tw.fq2_from_device(joint_power(tw.fq2_to_device(a)))
+    assert got == pyf.fq2_pow(a, h2._E)
+
+
 def test_sqrt_ratio_qr_and_nqr():
     import random
 
