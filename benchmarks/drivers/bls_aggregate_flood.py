@@ -50,7 +50,8 @@ import time
 
 import layer_reader  # benchmarks/layer_reader.py
 import numpy as np
-from common import check, emit  # benchmarks/common.py
+from common import (  # benchmarks/common.py
+    check, emit, runtime_call, verdict)
 
 #: the damaged operands check_outputs.py puts into the window as controls
 CONTROLS = ("swap_signature", "flip_message")
@@ -310,6 +311,7 @@ def run(config, params, seed, seconds, trace, h) -> dict:
     feed([_Item(t) for t in swapped_trios] + [_Item(it.trio) for it in fill])
     proc.run_until_idle()
     setup_verdicts = [x[2] for x in delivered]
+    runtime_call()
 
     # --- the flood: the backlog first, then one worker pumps as the node's
     # does; each delivery feeds one batch more
@@ -415,26 +417,31 @@ def run(config, params, seed, seconds, trace, h) -> dict:
 
     # --- correct: each number compared, beside its limit (all exact)
     compared = [
-        {"what": "reference verdicts (valid sample, sample with a swap)",
+        {"name": "reference", "what": "reference verdicts (valid sample, "
+         "sample with a swap)",
          "value": ref, "limit": [True, False]},
-        {"what": "set-up verdicts (valid, valid, one swapped signature)",
+        {"name": "setup", "what": "set-up verdicts (valid, valid, one "
+         "swapped signature)",
          "value": setup_verdicts, "limit": [True, True, False]},
-        {"what": "the timed backend on the reference's own operands in a "
+        {"name": "timed_against_reference",
+         "what": "the timed backend on the reference's own operands in a "
          "full batch (sample, sample with the swap), against the "
          "pure-Python backend's verdicts",
          "value": setup_verdicts[0::2], "limit": ref},
-        {"what": "sets of the window with a wrong verdict",
+        {"name": "window_wrong", "what": "sets of the window with a wrong "
+         "verdict",
          "value": wrong, "limit": 0},
-        {"what": "sets submitted whose verdict never came",
+        {"name": "window_missing", "what": "sets submitted whose verdict "
+         "never came",
          "value": missing, "limit": 0},
-        {"what": "verdict of the batch with a damaged selection-proof "
+        {"name": "after_window_damaged",
+         "what": "verdict of the batch with a damaged selection-proof "
          "message after the window",
          "value": after_verdict, "limit": False},
     ]
-    emit(step="compared", compared=compared)
-    correct = all(c["value"] == c["limit"] for c in compared)
     return {
-        "correct": correct,
+        "correct": verdict(compared),
+        "compared": compared,
         "attempted": n_sets + missing,
         "failed": wrong + missing,
         "end_to_end": {

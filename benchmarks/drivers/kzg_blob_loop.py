@@ -43,7 +43,8 @@ import time
 
 import layer_reader  # benchmarks/layer_reader.py
 import numpy as np
-from common import check, emit  # benchmarks/common.py
+from common import (  # benchmarks/common.py
+    check, emit, runtime_call, verdict)
 
 #: the damaged operands check_outputs.py puts into the window as controls
 CONTROLS = ("swap_proof", "flip_blob_byte")
@@ -296,16 +297,19 @@ def run(config, params, seed, seconds, trace, h) -> dict:
                 state["t_close"] = h.close_window()
                 state["i_close"] = len(delivered)
                 state["b_close"] = len(blocks_done)
-            feeding = state["feeding"]
-        if feeding:
-            block = draw_block()
-            if tamper and state["t_open"] is not None and not state.get(
-                    "tampered"):
-                state["tampered"] = True
-                damage = (with_swapped_proof if tamper == "swap_proof"
-                          else with_flipped_blob_byte)
-                block, _v = damage(block)
-            feed(block)
+            # fed under the lock the main thread ends the feeding under: a
+            # block decided on here is queued before the queues are looked
+            # at, never after the processor stopped (where the next block,
+            # fed after the window, would share its batch)
+            if state["feeding"]:
+                block = draw_block()
+                if tamper and state["t_open"] is not None and not state.get(
+                        "tampered"):
+                    state["tampered"] = True
+                    damage = (with_swapped_proof if tamper == "swap_proof"
+                              else with_flipped_blob_byte)
+                    block, _v = damage(block)
+                feed(block)
         if state["t_close"] is not None:
             # only now, with the next block queued: the main thread stops
             # the feeding and waits for the queues to drain
@@ -323,6 +327,7 @@ def run(config, params, seed, seconds, trace, h) -> dict:
         warm_ids.append(feed(b))
         proc.run_until_idle()
     setup_verdicts = [all(verdicts_of_block[i]) for i in warm_ids]
+    runtime_call()
 
     # --- the loop: one block outstanding; one worker pumps as the node's
     # does; the delivery of a block's last verdict feeds the next block
@@ -373,6 +378,7 @@ def run(config, params, seed, seconds, trace, h) -> dict:
     t_ref_after += time.perf_counter() - t0
     expected = [k != v for k in range(B)]
     after_checks.append({
+        "name": "after_swapped_proof",
         "what": "after the window: a block in which one sidecar carries "
         "another's proof: [verdicts, the reference's a sidecar, fallbacks] "
         "against [that one False and the others True, the same, 1]",
@@ -388,12 +394,14 @@ def run(config, params, seed, seconds, trace, h) -> dict:
                             ref_setup)
     t_ref_after += time.perf_counter() - t0
     after_checks.append({
+        "name": "after_off_subgroup",
         "what": "after the window: a commitment on the curve outside the "
         "subgroup: [its sidecar's verdict, the others all True] against "
         "[the reference's, True]",
         "value": [got[v], all(got[:v] + got[v + 1:])],
         "limit": [want_v, True]})
     after_checks.append({
+        "name": "reference_off_subgroup",
         "what": "the reference on that commitment", "value": want_v,
         "limit": False})
     block = draw_block()
@@ -410,12 +418,14 @@ def run(config, params, seed, seconds, trace, h) -> dict:
                             ref_setup)
     t_ref_after += time.perf_counter() - t0
     after_checks.append({
+        "name": "after_element_over_r",
         "what": "after the window: a blob with one field element >= r: [its "
         "sidecar's verdict, the others all True, fallbacks] against [the "
         "reference's, True, 0]",
         "value": [got[v], all(got[:v] + got[v + 1:]), fell],
         "limit": [want_v, True, 0]})
     after_checks.append({
+        "name": "reference_element_over_r",
         "what": "the reference on that blob", "value": want_v,
         "limit": False})
 
@@ -473,26 +483,29 @@ def run(config, params, seed, seconds, trace, h) -> dict:
 
     # --- correct: each number compared, beside its limit (all exact)
     compared = [
-        {"what": "the reference on the set-up's blocks",
+        {"name": "reference", "what": "the reference on the set-up's blocks",
          "value": ref_setup_verdicts, "limit": [True] * k_ref},
-        {"what": "the timed backend on the same blocks, against the "
+        {"name": "timed_against_reference",
+         "what": "the timed backend on the same blocks, against the "
          "reference's verdicts",
          "value": setup_verdicts, "limit": ref_setup_verdicts},
-        {"what": "sidecars of the window with a False verdict",
+        {"name": "window_wrong", "what": "sidecars of the window with a "
+         "False verdict",
          "value": wrong, "limit": 0},
-        {"what": "sidecars submitted whose verdict never came",
+        {"name": "window_missing", "what": "sidecars submitted whose "
+         "verdict never came",
          "value": missing, "limit": 0},
-        {"what": "the window's counters [blobs evaluated, points validated, "
+        {"name": "window_counters",
+         "what": "the window's counters [blobs evaluated, points validated, "
          "batches, sidecars, fallbacks] against [B, 2 B, 1, B, 0] a block",
          "value": [counted[k] for k in ("blobs_evaluated", "points_validated",
                                         "batches", "sidecars", "fallbacks")],
          "limit": [B * n_blocks, 2 * B * n_blocks, n_blocks, B * n_blocks, 0]},
         *after_checks,
     ]
-    emit(step="compared", compared=compared)
-    correct = all(c["value"] == c["limit"] for c in compared)
     return {
-        "correct": correct,
+        "correct": verdict(compared),
+        "compared": compared,
         "attempted": n_sidecars + missing,
         "failed": wrong + missing,
         "end_to_end": {

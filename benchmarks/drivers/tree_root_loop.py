@@ -23,7 +23,7 @@ import time
 
 import layer_reader  # benchmarks/layer_reader.py
 import numpy as np
-from common import check, emit  # benchmarks/common.py
+from common import check, emit, runtime_call, verdict  # benchmarks/common.py
 
 #: the damaged operand check_outputs.py puts into the window as control
 CONTROLS = ("flip_leaf",)
@@ -84,6 +84,9 @@ def run(config, params, seed, seconds, trace, h) -> dict:
     t_second = time.perf_counter() - t0
     check(first is not None and second is not None,
           "the device tree hash returned None (the host ladder would serve)")
+    # which of the two levels this process drew: a root costs ~2 ms more
+    # for the life of a process whose runtime calls are dear (PERF.md S2)
+    runtime_call()
 
     # --- the window: a closed loop of one caller; it closes with the call
     # during which the time ran out
@@ -154,17 +157,19 @@ def run(config, params, seed, seconds, trace, h) -> dict:
     warm_ok = bytes(first) == want[1 % n_planes] == bytes(second)
     moved_ok = moved is not None and bytes(moved) != want[1 % n_planes]
     compared = [
-        {"what": "warm-up roots equal to hashlib's", "value": warm_ok,
+        {"name": "warmup_roots", "what": "warm-up roots equal to hashlib's",
+         "value": warm_ok,
          "limit": True},
-        {"what": "roots of the window that differ from hashlib's",
+        {"name": "window_wrong",
+         "what": "roots of the window that differ from hashlib's",
          "value": wrong, "limit": 0},
-        {"what": "one flipped bit after the window moves the root",
+        {"name": "after_window_flipped_bit",
+         "what": "one flipped bit after the window moves the root",
          "value": moved_ok, "limit": True},
     ]
-    emit(step="compared", compared=compared)
-    correct = all(c["value"] == c["limit"] for c in compared)
     return {
-        "correct": correct,
+        "correct": verdict(compared),
+        "compared": compared,
         "attempted": n_roots,
         "failed": wrong,
         "end_to_end": {

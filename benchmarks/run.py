@@ -15,8 +15,10 @@ found by name, never listed here:
     layer_metrics/<metric>.json  one per-layer metric, read by layer_reader.py
 
 Stdout is one JSON object per line. The LAST line is the result:
-`{"correct", "attempted", "failed", "metrics", "device"}` and, in a traced
-run, `"breakdown"`. `--trace 0` reports the cell's end-to-end metrics,
+`{"correct", "attempted", "failed", "metrics", "device"}`, in a traced
+run `"breakdown"`, and last `"compared"`: each number `correct` compared
+beside its limit, which are also the last lines of standard error.
+`--trace 0` reports the cell's end-to-end metrics,
 `--trace 1` its per-layer metrics (attribution and the profiler are on, so
 its end-to-end numbers go to an earlier line only).
 """
@@ -272,6 +274,17 @@ class Harness:
         return max(peaks) if peaks else None
 
 
+def print_compared(compared: list) -> dict:
+    """Each number compared beside its limit: the last lines of standard
+    error, and the object that comes last on the result line."""
+    out = {c["name"]: {"value": c["value"], "limit": c["limit"]}
+           for c in compared}
+    for name, c in out.items():
+        print(f"compared {name}: value {json.dumps(c['value'])} "
+              f"limit {json.dumps(c['limit'])}", file=sys.stderr, flush=True)
+    return out
+
+
 def cache_entries(cache_dir: str) -> int:
     if not os.path.isdir(cache_dir):
         return 0
@@ -350,6 +363,7 @@ def measure(workload: str, seed: int, seconds: float, trace: bool, devices,
     if not trace:
         result["metrics"] = end_to_end
         result["device"] = device
+        result["compared"] = print_compared(res["compared"])
         return result
 
     # traced run: the per-layer metrics; its own end-to-end numbers (taken
@@ -388,6 +402,7 @@ def measure(workload: str, seed: int, seconds: float, trace: bool, devices,
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
         result["breakdown"] = reduced["breakdown"]
+    result["compared"] = print_compared(res["compared"])
     return result
 
 

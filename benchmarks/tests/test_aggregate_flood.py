@@ -197,7 +197,7 @@ def test_the_new_files_are_found_by_name_and_match_benchmark_json():
             == cfg["aggregates_per_slot"] == 1024)
     mine = {k for k, v in bench_run.load_layer_metrics().items()
             if "aggregate_flood" in (v.get("cells") or ())}
-    assert mine == MINE
+    assert mine >= MINE       # among them: later PRs name this cell too
     for m in bench["per_layer"]:
         if m["name"] in MINE:
             assert m["workloads"] == ["aggregate_flood"]

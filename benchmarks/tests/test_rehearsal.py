@@ -33,8 +33,19 @@ def test_a_sound_run_is_correct_and_reports_the_end_to_end_metrics(
     assert res["failed"] == 0 and res["attempted"] > 0
     assert set(res["metrics"]) == E2E[cell]
     assert all(m["value"] > 0 for m in res["metrics"].values())
-    assert set(res) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(res) == ["correct", "attempted", "failed", "metrics",
+                         "device", "compared"]       # compared comes last
+    assert all(c["value"] == c["limit"] for c in res["compared"].values())
     json.dumps(res)     # plain numbers only
+
+
+@pytest.mark.parametrize("cell", ["tiny_flood", "tiny_root"])
+def test_a_run_says_which_level_its_process_drew(rehearsal_dir, cell, capsys):
+    measure(rehearsal_dir, cell, seconds=0.3)
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith('{"step": "runtime_call"')]
+    assert len(lines) == 1 and lines[0]["n"] == 64
+    assert 0 < lines[0]["min_us"] <= lines[0]["median_us"]
 
 
 @pytest.mark.parametrize("cell,tamper", [

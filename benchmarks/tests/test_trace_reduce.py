@@ -47,6 +47,32 @@ def test_breakdown_names_ops_and_what_the_host_did_in_the_gaps():
     assert len(out["breakdown"]["idle_gaps"]) <= 10
 
 
+def test_a_device_line_that_stops_early_ends_the_window():
+    """The profiler wrote device events up to 70 ms of a 100 ms scope: the
+    last 30 ms are not in the trace, so they are neither window nor idle,
+    and no gap is named after what the host did in them."""
+    raw = _trace()
+    raw["planes"][1]["lines"][1]["events"] = raw["planes"][1]["lines"][1][
+        "events"][:3]                                  # ends at 70
+    out = tr.reduce(raw, n_devices=1)
+    assert out["window_source"] == "host_scope_to_device_line_end"
+    assert out["window_s"] == pytest.approx(0.070)
+    assert out["window_cut_s"] == pytest.approx(0.030)
+    assert out["busy_s"] == pytest.approx(0.040)       # 10..40, 60..70
+    assert out["values"]["device_idle_share"] == pytest.approx(
+        100 * 3 / 7)
+    gaps = dict(out["breakdown"]["idle_gaps"])
+    assert "host in SomethingElse" not in gaps         # 75..90: cut off
+    assert gaps["host in bench:marshal_dispatch"] == pytest.approx(0.020)
+
+
+def test_a_device_line_that_reaches_the_end_leaves_the_window_whole():
+    out = tr.reduce(_trace(), n_devices=1)             # last op 90..120
+    assert out["window_source"] == "host_scope"
+    assert out["window_cut_s"] == 0.0
+    assert out["window_s"] == pytest.approx(0.100)
+
+
 def test_without_the_scope_the_window_is_the_device_extent():
     raw = _trace()
     raw["planes"][0]["lines"][0]["events"] = []

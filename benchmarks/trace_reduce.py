@@ -12,7 +12,11 @@ On a TPU the device planes are named `/device:TPU:<n>`; their `XLA Ops`
 line holds one event per executed operation and `XLA Modules` one per
 executed program. Busy is the union of the `XLA Ops` intervals inside the
 traced window; the window is the harness's own `bench:trace_window` scope
-on the host plane (host and device lines share the trace's clock), or the
+on the host plane (host and device lines share the trace's clock), ended at
+the device lines' last event where that comes first (a long window's device
+line stops early: the profiler keeps a few million events, and the seconds
+it never wrote are not idle; `window_source` then says
+`host_scope_to_device_line_end` and `window_cut_s` how much went), or the
 extent of the device events where that scope cannot be placed.
 """
 
@@ -104,7 +108,7 @@ def _top(totals: dict, k: int) -> list:
 
 def reduce(raw: dict, window_scope: str = "bench:trace_window",
            n_devices: int = 1) -> dict:
-    """{"busy_s", "window_s", "window_source", "values": {...},
+    """{"busy_s", "window_s", "window_source", "window_cut_s", "values": {...},
     "breakdown": {"device_ops", "idle_gaps"}} — raises ValueError when no
     operation ran on a device inside the window."""
     device_planes = [p for p in raw["planes"]
@@ -137,8 +141,14 @@ def reduce(raw: dict, window_scope: str = "bench:trace_window",
         if name == window_scope and d > 0:
             window = (s, s + d)
     source = "host_scope"
+    cut_ns = 0.0
     if window is None or window[1] <= dev_lo or window[0] >= dev_hi:
         window, source = (dev_lo, dev_hi), "device_extent"
+    elif dev_hi < window[1]:
+        # the profiler stops writing a device line at a few million events:
+        # what follows its last event is not in the trace, and is not idle
+        cut_ns = window[1] - dev_hi
+        window, source = (window[0], dev_hi), "host_scope_to_device_line_end"
     lo, hi = window
     window_ns = hi - lo
 
@@ -212,6 +222,7 @@ def reduce(raw: dict, window_scope: str = "bench:trace_window",
         "busy_s": busy_s,
         "window_s": window_s,
         "window_source": source,
+        "window_cut_s": cut_ns / 1e9,
         "device_planes": [p["name"] for p in device_planes],
         "values": {"device_idle_share": 100.0 * (1.0 - busy_s / window_s)},
         "breakdown": {"device_ops": device_ops,
