@@ -340,6 +340,7 @@ def start_warmup(buckets=None, warm_fn=None,
     def attempt():
         # raises on failure — the CALLER owns the retry policy (see below)
         single_chip_too = False
+        backend = None
         if warm_fn is not None:
             fn = warm_fn
         else:
@@ -386,6 +387,20 @@ def start_warmup(buckets=None, warm_fn=None,
                 fn(n_sets, n_pks, single_chip=True)
                 log.info("urgent single-chip bucket done", n_sets=n_sets,
                          n_pks=n_pks, secs=round(_time.time() - t0, 1))
+        # one chip, and the chain (built while the buckets above compiled)
+        # keeps its registry on the device: every set its builders make
+        # names rows of that table, so the batch lane serves the INDEXED
+        # prepare at these buckets. A chain not built yet, or a table that
+        # grows later, pays that compile at its first dispatch.
+        table = getattr(backend, "registry", None)
+        if table is not None and len(table) and not single_chip_too:
+            from ..crypto.jaxbls.backend import warm_prepare_indexed
+
+            for n_sets, n_pks in plan_buckets:
+                t0 = _time.time()
+                warm_prepare_indexed(n_sets, n_pks, table)
+                log.info("indexed prepare done", n_sets=n_sets, n_pks=n_pks,
+                         rows=len(table), secs=round(_time.time() - t0, 1))
 
     if supervisor is not None:
         # node bring-up path: a warmup crash (device error mid-compile)

@@ -179,7 +179,15 @@ class BeaconChain:
         }
         self.head_root = self.genesis_block_root
 
-        self.pubkey_cache = ValidatorPubkeyCache(self.store)
+        # a backend that can keep the registry's keys on the device (the
+        # jax backend) gets them from this chain's cache, where no living
+        # chain feeds it already: one table, one registry (the backend
+        # holds it weakly, so it is released with this chain's cache)
+        backend = bls.get_backend()
+        table = None
+        if getattr(backend, "registry", False) is None:
+            table = backend.install_registry()
+        self.pubkey_cache = ValidatorPubkeyCache(self.store, table=table)
         self.pubkey_cache.import_new_pubkeys(genesis_state)
         self.shuffling_cache = ShufflingCache()
         self.proposer_cache: dict[tuple[int, bytes], list[int]] = {}

@@ -2,28 +2,28 @@
 
 Parity surface: /root/reference/beacon_node/beacon_chain/src/
 validator_pubkey_cache.rs:17-146. This cache is THE feed for batch
-verification: signature-set constructors resolve indices through it, and the
-TPU backend packs the decompressed affine coordinates straight into device
-arrays (a per-pubkey Montgomery-form limb array is memoized so repeat
-verifications skip the int->limb conversion)."""
+verification: signature-set constructors resolve indices through it
+(`pubkey_getter`), and the sets they build carry the decompressed keys,
+their validator indices and this cache's table. What feeds the device: given a `table`
+(crypto/jaxbls/registry.py `PubkeyTable`, the jax backend's
+`install_registry()`), every key the cache takes in is appended to it, row
+= validator index, before `import_new_pubkeys` returns, so a dispatch
+submitted after that gathers the key on the device by its index. Without a
+table the backend packs the keys' coordinates per dispatch, as before."""
 
 from __future__ import annotations
 
-import numpy as np
-
 from ..crypto import bls
-from ..crypto.bls381.constants import P
-from ..crypto.jaxbls import limbs as lb
 from ..store.kv import Column, KeyValueOp
 
 
 class ValidatorPubkeyCache:
-    def __init__(self, store=None):
+    def __init__(self, store=None, table=None):
         self.store = store
+        self.table = table
         self.pubkeys: list[bls.PublicKey] = []
         self.pubkey_bytes: list[bytes] = []
         self.index_by_bytes: dict[bytes, int] = {}
-        self._mont_coords: list[tuple[np.ndarray, np.ndarray] | None] = []
         if store is not None:
             self._load()
 
@@ -34,12 +34,17 @@ class ValidatorPubkeyCache:
             assert index == len(self.pubkeys), "pubkey cache gap"
             pk = bls.PublicKey.deserialize(value)
             self._push(pk, value)
+        self._feed_table(0)
 
     def _push(self, pk: bls.PublicKey, pk_bytes: bytes):
         self.index_by_bytes[bytes(pk_bytes)] = len(self.pubkeys)
         self.pubkeys.append(pk)
         self.pubkey_bytes.append(bytes(pk_bytes))
-        self._mont_coords.append(None)
+
+    def _feed_table(self, first: int) -> None:
+        """Rows `first`.. of the device's table, from the keys just pushed."""
+        if self.table is not None:
+            self.table.append(self.pubkeys[first:])
 
     def import_new_pubkeys(self, state) -> None:
         """Add any validators beyond the cache length (import_new_pubkeys
@@ -47,7 +52,8 @@ class ValidatorPubkeyCache:
         if len(state.validators) <= len(self.pubkeys):
             return
         ops = []
-        for i in range(len(self.pubkeys), len(state.validators)):
+        first = len(self.pubkeys)
+        for i in range(first, len(state.validators)):
             pkb = bytes(state.validators[i].pubkey)
             pk = bls.PublicKey.deserialize(pkb)
             self._push(pk, pkb)
@@ -57,24 +63,13 @@ class ValidatorPubkeyCache:
                 )
         if ops:
             self.store.hot.do_atomically(ops)
+        self._feed_table(first)
 
     def get(self, index: int) -> bls.PublicKey:
         return self.pubkeys[index]
 
     def get_index(self, pubkey_bytes: bytes) -> int | None:
         return self.index_by_bytes.get(bytes(pubkey_bytes))
-
-    def mont_coords(self, index: int) -> tuple[np.ndarray, np.ndarray]:
-        """Montgomery-form limb arrays (x, y) for direct device packing."""
-        cached = self._mont_coords[index]
-        if cached is None:
-            x, y = self.pubkeys[index].point
-            cached = (
-                lb.pack(x * lb.R_MONT % P),
-                lb.pack(y * lb.R_MONT % P),
-            )
-            self._mont_coords[index] = cached
-        return cached
 
     def __len__(self):
         return len(self.pubkeys)
@@ -92,4 +87,7 @@ class ValidatorPubkeyCache:
             return bls.PublicKey.deserialize(bytes(pkb))
 
         get_pubkey.by_bytes = by_bytes
+        get_pubkey.index_by_bytes = self.get_index
+        # whose rows the indices are: travels with the sets built from here
+        get_pubkey.registry = self.table
         return get_pubkey

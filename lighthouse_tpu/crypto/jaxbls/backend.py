@@ -32,10 +32,15 @@ numpy (no per-element Python bigint work) and pubkey limb arrays are
 cached on device keyed by the identity of the key objects, mirroring the
 reference's decompressed ValidatorPubkeyCache
 (validator_pubkey_cache.rs:17) feeding blst — which is also why the
-pubkey grids are the one input family donation never touches.
+pubkey grids are the one input family donation never touches. Where a
+chain keeps its registry's keys on the device (`install_registry`,
+registry.py) and a batch's sets carry validator indices, the marshal sends
+an index grid and stage 1 gathers the rows: no key is packed at all.
 """
 
 from __future__ import annotations
+
+import weakref
 
 import numpy as np
 
@@ -107,6 +112,14 @@ _KZG_LANES = REGISTRY.counter_vec(
     "its commitment and its proof times the group order), padded = the "
     "program's one row of lanes; real over padded is the pass's fill",
     ("kind",),
+)
+_REGISTRY_KEYS = REGISTRY.counter_vec(
+    "jaxbls_registry_keys_total",
+    "signing keys of the real sets per dispatch, by where stage 1 took "
+    "them from: table = gathered by validator index from the registry "
+    "table on the device (registry.py), packed = coordinates packed on the "
+    "host into the (n, m) limb grid",
+    ("source",),
 )
 # buckets that have resolved at least once: the benchmark's drivers and
 # chip_smoke.py check that a run compiled the one bucket it meant to
@@ -257,6 +270,22 @@ def _stage_prepare(pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits, set_mask):
     return z_pk, sig_acc, bad_aggpk
 
 
+def _stage_prepare_indexed(table_x, table_y, pk_idx, pk_mask,
+                           sig_x, sig_y, z_digits, set_mask):
+    """Stage 1 with the keys gathered by validator index from the
+    registry table on the device (registry.py: `table_x` / `table_y`
+    uint32[capacity, NL], `pk_idx` int32[n, m]), then `_stage_prepare`
+    itself: the arithmetic exists once. A masked slot gathers row 0 and is
+    the identity by its mask, as a zero slot of the packed grid is. The
+    host has refused every index outside the table (registry.index_grid),
+    so the gather promises the compiler what is true. One chip: there is
+    no meshed build of this program, a mesh keeps to the packed grid."""
+    pk_x = table_x.at[pk_idx].get(mode="promise_in_bounds")
+    pk_y = table_y.at[pk_idx].get(mode="promise_in_bounds")
+    return _stage_prepare(pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits,
+                          set_mask)
+
+
 def _stage_pairs(z_pk, h_jac, sig_acc, set_mask):
     """Stage 3: batched affine conversion + pair-array assembly."""
     import jax.numpy as jnp
@@ -336,6 +365,8 @@ _kernel_cache: dict = {}
 STAGE_DONATE_ARGNUMS = dict(
     prepare=(3, 4, 5), h2c=(0,), pairs=(0, 1, 2, 3), pairing=(0, 1, 2, 3, 4),
     miller=(0, 1, 2, 3, 4), final_exp=(0,),
+    # the packed prepare's three, one place on: never the table
+    prepare_indexed=(4, 5, 6),
 )
 
 
@@ -613,6 +644,30 @@ def _get_stages(mesh=None):
     return _kernel_cache[key]
 
 
+def _get_prepare_indexed():
+    """Stage 1 of the indexed path, jitted: `_stage_prepare_indexed` for
+    one chip (the batch lane of a process without a mesh), under the
+    donation mode of `_get_stages`. The fifth stage-1 program a node may
+    serve beside the packed prepare of its buckets."""
+    import jax
+
+    from . import pipeline as pl
+
+    _init_consts()
+    donate = pl.donation_enabled()[0]
+    key = f"prepare_indexed_d{int(donate)}"
+    if key not in _kernel_cache:
+        from ...utils.jaxcfg import setup_compilation_cache
+
+        setup_compilation_cache()
+        _kernel_cache[key] = jax.jit(
+            _stage_prepare_indexed,
+            **(dict(donate_argnums=STAGE_DONATE_ARGNUMS["prepare_indexed"])
+               if donate else {}),
+        )
+    return _kernel_cache[key]
+
+
 def warm_stages(n_sets: int, n_pks: int, single_chip: bool = False) -> None:
     """Pre-compile the prepare and hash-to-G2 stages for one bucket shape,
     CONCURRENTLY. Their input layouts are fully determined by the marshal
@@ -691,6 +746,33 @@ def warm_stages(n_sets: int, n_pks: int, single_chip: bool = False) -> None:
         _obs_perf.maybe_capture_program("h2c", h2c_stage, (us,), (n, m))
 
 
+def warm_prepare_indexed(n_sets: int, n_pks: int, table) -> None:
+    """Pre-compile stage 1 of the indexed path (`_stage_prepare_indexed`)
+    for one bucket shape against `table`, on zero inputs placed as
+    `_marshal_indices` and the marshal place the real ones. For a node on
+    ONE chip whose chain keeps its registry on the device: there every set
+    the chain's builders make names rows of the table, so gossip batches
+    and blocks alike take this program at their own buckets, not the packed
+    prepare `warm_stages` compiles. The table's capacity is part of the
+    program's shape: a registry that outgrows it (a `registry.ROW_CHUNK` of
+    deposits) compiles anew at its next dispatch."""
+    import jax
+
+    from ...parallel import put_single
+
+    n, m = padding_bucket(n_sets, n_pks, single_chip=True)
+    table_x, table_y, _ = table.snapshot()
+    jax.block_until_ready(_get_prepare_indexed()(
+        table_x, table_y,
+        put_single(np.zeros((n, m), np.int32)),
+        put_single(np.ones((n, m), np.uint32)),
+        put_single(np.zeros((n, 2, lb.NL), np.uint32)),
+        put_single(np.zeros((n, 2, lb.NL), np.uint32)),
+        put_single(np.ones((n, Z_DIGITS), np.uint32)),
+        put_single(np.ones((n,), np.uint32)),
+    ))
+
+
 class VerifyHandle:
     """In-flight verification: resolves to bool on .result().
 
@@ -760,6 +842,7 @@ class JaxBackend:
         # `kzg` tenant: a window of their own, so a blob-carrying block's
         # sidecars never queue behind four attestation batches
         self.kzg_dispatcher = pl.PipelinedDispatcher(workload="kzg")
+        self._registry = None    # see `registry`
         try:
             from ...autotune import runtime as _at_runtime
 
@@ -777,7 +860,59 @@ class JaxBackend:
             if dispatcher.depth_source in ("profile", "default"):
                 dispatcher.set_depth(*pl.resolve_depth())
 
+    @property
+    def registry(self):
+        """The registry table this backend gathers keys from
+        (registry.py), or None: every dispatch then packs its keys. Held
+        weakly: the table lives as long as the pubkey cache that feeds it,
+        and a chain that goes away leaves the place to the next."""
+        return None if self._registry is None else self._registry()
+
+    @registry.setter
+    def registry(self, table) -> None:
+        self._registry = None if table is None else weakref.ref(table)
+
+    def install_registry(self):
+        """Keep the validator registry's keys on the device: installs a
+        fresh, empty `registry.PubkeyTable` in place of any other and
+        returns it, for a `ValidatorPubkeyCache` to feed and to keep. From
+        then on a batch-lane dispatch whose sets all name rows of THIS
+        table gathers its keys from it (`_marshal_indices`). One table a
+        backend: the sets of a second registry in the process (another
+        chain) name another table or none, and keep to the packed grid."""
+        from .registry import PubkeyTable
+
+        table = PubkeyTable()
+        self.registry = table
+        return table
+
     # -- the multi-set hot path ------------------------------------------
+
+    def _marshal_indices(self, sets, n: int, m: int, real_keys: int):
+        """The key side of a dispatch as registry rows: (table_x, table_y,
+        idx, mask) on the device, the index grid int32[n, m] and its mask
+        in place of `_marshal_pubkeys`' limb grid (2 x 2 MB where that is
+        100 MB at 16 x 32,768). Chosen by the data alone: None — the batch
+        packs its keys — unless a table is installed and EVERY set carries
+        indices into that very table (`signing_registry`: a set built
+        against another registry, or against none, does not, whatever its
+        indices are). False when a set of this registry names a row outside
+        the table: refused and counted, the dispatch is a host failure.
+        Rows are taken with the `len` of one snapshot, so a key appended
+        before this call is visible to it."""
+        table = self.registry
+        if table is None or any(s.signing_registry is not table for s in sets):
+            return None
+        table_x, table_y, rows = table.snapshot()
+        from ...parallel import put_single
+
+        with _obs.span("jaxbls:marshal.indices", keys=real_keys) as packed:
+            grid = table.index_grid(sets, n, m, rows)
+            if grid is None:
+                return False
+            packed.args["bytes"] = grid[0].nbytes + grid[1].nbytes
+            idx, mask = put_single(grid[0]), put_single(grid[1])
+        return table_x, table_y, idx, mask
 
     def _marshal_pubkeys(self, sets, n: int, m: int, single_chip: bool = False):
         """(n, m, NL) standard-form limb arrays for all signing keys.
@@ -901,9 +1036,21 @@ class JaxBackend:
                 miller_pairs = pairing_stage.miller_pairs(miller_pairs)
             _count_miller_plan(miller_pairs)
 
-            pk_x, pk_y, pk_mask = self._marshal_pubkeys(
-                sets, n, m, single_chip=single_chip
-            )
+            # the keys: registry rows gathered on the device where the data
+            # allows it (one chip's batch lane; a mesh keeps the packed
+            # grid), else their coordinates packed into the (n, m) grid
+            indexed = (None if urgent or mesh is not None
+                       else self._marshal_indices(sets, n, m, real_keys))
+            if indexed is False:
+                return VerifyHandle(hostfail=True)  # a row the table lacks
+            if indexed is None:
+                _REGISTRY_KEYS.labels("packed").inc(real_keys)
+                keys_in = self._marshal_pubkeys(
+                    sets, n, m, single_chip=single_chip
+                )
+            else:
+                _REGISTRY_KEYS.labels("table").inc(real_keys)
+                prepare, keys_in = _get_prepare_indexed(), indexed
 
             with _obs.span("jaxbls:marshal.sigs"):
                 sig_x = np.zeros((n, 2, lb.NL), np.uint32)
@@ -962,7 +1109,7 @@ class JaxBackend:
             attr = _obs_dev.begin((n, m), trace=tr)
             z_pk, sig_acc, bad = _obs_dev.run_stage(
                 attr, "prepare", prepare,
-                pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits, set_mask,
+                *keys_in, sig_x, sig_y, z_digits, set_mask,
             )
             h_jac = _obs_dev.run_stage(attr, "h2c", h2c_stage, us)
             px, py, qxx, qyy, pair_mask = _obs_dev.run_stage(

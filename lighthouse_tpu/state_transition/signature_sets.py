@@ -40,6 +40,12 @@ def _sig(signature_bytes: bytes) -> bls.Signature:
         raise SignatureSetError(f"undecodable signature: {e}") from e
 
 
+def _registry_of(get_pubkey):
+    """The key table a resolver's indices are rows of (the pubkey cache's
+    `pubkey_getter` names its own), or None."""
+    return getattr(get_pubkey, "registry", None)
+
+
 def block_proposal_set(state, spec: ChainSpec, types, signed_block, get_pubkey, block_root=None):
     """Proposer signature over the block root."""
     block = signed_block.message
@@ -50,7 +56,9 @@ def block_proposal_set(state, spec: ChainSpec, types, signed_block, get_pubkey, 
         block_root = types.BeaconBlock.hash_tree_root(block)
     message = h.compute_signing_root_from_root(block_root, domain)
     pk = get_pubkey(block.proposer_index)
-    return bls.SignatureSet(_sig(signed_block.signature), (pk,), message)
+    return bls.SignatureSet(_sig(signed_block.signature), (pk,), message,
+                            signing_indices=(block.proposer_index,),
+                            signing_registry=_registry_of(get_pubkey))
 
 
 def historical_block_proposal_set(
@@ -91,17 +99,22 @@ def randao_set(state, spec: ChainSpec, types, block, get_pubkey):
     domain = h.get_domain(state, spec, DOMAIN_RANDAO, epoch)
     message = h.compute_signing_root(uint64, epoch, domain)
     pk = get_pubkey(block.proposer_index)
-    return bls.SignatureSet(_sig(block.body.randao_reveal), (pk,), message)
+    return bls.SignatureSet(_sig(block.body.randao_reveal), (pk,), message,
+                            signing_indices=(block.proposer_index,),
+                            signing_registry=_registry_of(get_pubkey))
 
 
 def indexed_attestation_set(state, spec: ChainSpec, types, indexed_att, get_pubkey):
     data = indexed_att.data
     domain = h.get_domain(state, spec, DOMAIN_BEACON_ATTESTER, data.target.epoch)
     message = h.compute_signing_root(types.AttestationData, data, domain)
-    pks = [get_pubkey(i) for i in indexed_att.attesting_indices]
+    indices = [int(i) for i in indexed_att.attesting_indices]
+    pks = [get_pubkey(i) for i in indices]
     if not pks:
         raise SignatureSetError("empty attesting indices")
-    return bls.SignatureSet(_sig(indexed_att.signature), pks, message)
+    return bls.SignatureSet(_sig(indexed_att.signature), pks, message,
+                            signing_indices=indices,
+                            signing_registry=_registry_of(get_pubkey))
 
 
 def proposer_slashing_sets(state, spec: ChainSpec, types, slashing, get_pubkey):
@@ -159,17 +172,26 @@ def sync_aggregate_set(state, spec: ChainSpec, types, sync_aggregate, block_slot
     root = acc.get_block_root_at_slot(state, spec, prev_slot)
     message = h.compute_signing_root_from_root(root, domain)
     committee_pubkeys = state.current_sync_committee.pubkeys
-    pks = [
-        get_pubkey_by_bytes(get_pubkey, bytes(pk))
+    signers = [
+        bytes(pk)
         for pk, bit in zip(committee_pubkeys, sync_aggregate.sync_committee_bits)
         if bit
     ]
+    pks = [get_pubkey_by_bytes(get_pubkey, pkb) for pkb in signers]
     sig = _sig(sync_aggregate.sync_committee_signature)
     if not pks:
         # empty aggregate must carry the infinity signature; callers check
         # via eth_fast_aggregate_verify semantics
         return None
-    return bls.SignatureSet(sig, pks, message)
+    # the committee is named by key: the indices travel only where the
+    # caller's resolver knows every signer's (the pubkey cache's does)
+    index_of = getattr(get_pubkey, "index_by_bytes", None)
+    indices = None if index_of is None else [index_of(pkb) for pkb in signers]
+    if indices is not None and any(i is None for i in indices):
+        indices = None
+    return bls.SignatureSet(
+        sig, pks, message, signing_indices=indices,
+        signing_registry=None if indices is None else _registry_of(get_pubkey))
 
 
 def bls_to_execution_change_set(state, spec: ChainSpec, types, signed_change):

@@ -68,6 +68,7 @@ _LONGEST_FIRST = (
     "test_jaxbls_pairing.py",           # 369
     "test_kzg.py",                      # 297 alone (PR 33: the blob batches)
     "test_fleet.py",                    # 183 (seventh: the short one)
+    "test_jaxbls_registry.py",          # 300 alone (PR 41: five one-device programs)
     "test_beacon_chain.py",             # 250
     "test_jaxbls_h2c.py",               # 167
     "test_jaxbls_msm.py",               # 123

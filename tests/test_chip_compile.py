@@ -17,8 +17,10 @@ Unmarked: the compiles of a few seconds (`pairs` at each served bucket's
 set count) and the KZG lane pass at its one served size (~half a minute). `slow`: the minute-long stage compiles at the served 64x128
 bucket (prepare ~1 min, hash-to-G2 ~3 min, of stage 4's two programs the
 Miller loop ~2 min and the final exponentiation ~1.25 min on eight host
-cores), and the Miller loop of the urgent 4x128 bucket, whose 5 pairs a
-program built for a TPU pads to one row of 128 lanes.
+cores), the Miller loop of the urgent 4x128 bucket, whose 5 pairs a
+program built for a TPU pads to one row of 128 lanes, and the indexed
+prepare of the Electra block's 16x32768 bucket over the 1,114,112-row
+registry table (`_stage_prepare_indexed`, minutes).
 """
 
 import numpy as np
@@ -35,8 +37,11 @@ from lighthouse_tpu.crypto.jaxbls import pairing_ops as po
 
 V5E_HBM_BYTES = 16 * 1024**3
 N_SETS, N_PKS = 64, 128   # the served gossip bucket (chip_smoke.py)
-#: the served buckets: urgent, gossip, block (BENCHMARK.json's BLS cells)
-SERVED_BUCKETS = ((4, 128), (64, 128), (256, 512))
+#: the served buckets: urgent, gossip, block, Electra block (BENCHMARK.json's
+#: BLS cells)
+SERVED_BUCKETS = ((4, 128), (64, 128), (256, 512), (16, 32768))
+#: the registry table of 1,048,576 validators with its room for deposits
+TABLE_ROWS = 1_114_112
 
 
 @pytest.fixture(scope="module")
@@ -83,6 +88,12 @@ def _stage_args(n: int, m: int, sharding) -> dict:
     return {
         "prepare": (u32(n, m, NL), u32(n, m, NL), u32(n, m),
                     u32(n, 2, NL), u32(n, 2, NL), u32(n, be.Z_DIGITS), u32(n)),
+        # the table's two coordinates, the index grid, then as `prepare`
+        "prepare_indexed": (
+            u32(TABLE_ROWS, NL), u32(TABLE_ROWS, NL),
+            jax.ShapeDtypeStruct((n, m), jnp.int32, sharding=sharding),
+            u32(n, m), u32(n, 2, NL), u32(n, 2, NL), u32(n, be.Z_DIGITS),
+            u32(n)),
         "h2c": (u32(n, 2, 2, NL),),
         "pairs": (g1, g2, acc, u32(n)),
         "miller": (u32(n + 1, NL), u32(n + 1, NL),
@@ -105,6 +116,7 @@ _STAGE_FNS = {
     "pairs": be._stage_pairs,
     "miller": _stage_miller_for_a_tpu,
     "final_exp": be._stage_final_exp,
+    "prepare_indexed": be._stage_prepare_indexed,
 }
 
 
@@ -134,6 +146,8 @@ def _shapes(tree):
     pytest.param("miller", (N_SETS, N_PKS), marks=pytest.mark.slow),
     pytest.param("miller", SERVED_BUCKETS[0], marks=pytest.mark.slow),
     pytest.param("final_exp", (N_SETS, N_PKS), marks=pytest.mark.slow),
+    pytest.param("prepare_indexed", SERVED_BUCKETS[3],
+                 marks=pytest.mark.slow),
 ], ids=lambda v: v if isinstance(v, str) else "%dx%d" % v)
 def test_stage_compiles_for_v5e(stage, bucket, one_chip,
                                 no_persistent_cache):
@@ -144,7 +158,7 @@ def test_stage_compiles_for_v5e(stage, bucket, one_chip,
     # outputs: a drifted hand-written shape must fail here, not broadcast
     args = _stage_args(n, m, one_chip)
     out = _shapes(jax.eval_shape(_STAGE_FNS[stage], *args[stage]))
-    if stage == "prepare":
+    if stage in ("prepare", "prepare_indexed"):
         assert out[:2] == _shapes((args["pairs"][0], args["pairs"][2]))
     elif stage == "h2c":
         assert out == _shapes(args["pairs"][1])

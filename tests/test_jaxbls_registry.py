@@ -1,0 +1,764 @@
+"""The registry table on the device (crypto/jaxbls/registry.py), the indices
+a SignatureSet carries, what feeds both, and the indexed path of the jax
+backend's batch lane against the packed one and against the pure-Python
+backend. The first half compiles no stage program; the second (from "keys
+by validator index" on) drives the real staged backend on ONE device at the
+(4, 4) bucket, the unsharded programs compiled once by a module fixture
+(five programs: test_jaxbls_backend.py is at its memory-mapping mark with
+its eight-device builds, so these tests have a file of their own). The
+reference is the pure-Python curve code on keys decompressed from their
+48-byte form."""
+
+import hashlib
+import random
+from types import SimpleNamespace
+
+import numpy as np
+import pytest
+
+from lighthouse_tpu.chain.pubkey_cache import ValidatorPubkeyCache
+from lighthouse_tpu.crypto import bls
+from lighthouse_tpu.crypto.bls import api as bls_api
+from lighthouse_tpu.crypto.bls381 import curve as cv
+from lighthouse_tpu.crypto.bls381 import serde
+from lighthouse_tpu.crypto.bls381.constants import R
+from lighthouse_tpu.crypto.jaxbls import registry as reg
+from lighthouse_tpu.observability import trace as obstrace
+from lighthouse_tpu.utils.metrics import REGISTRY
+
+rng = random.Random(0x7AB1E)
+KEYS = [bls.SecretKey(rng.randrange(1, R)).public_key() for _ in range(16)]
+BYTES = [pk.serialize() for pk in KEYS]
+
+
+def _reference_points(key_bytes):
+    """The registry's keys as the reference reads them: decompressed from
+    their bytes by the pure-Python curve code, no cache, no limbs."""
+    return [serde.g1_decompress(b, subgroup_check=True) for b in key_bytes]
+
+
+def _reference_digest(points) -> str:
+    return hashlib.sha256(b"".join(
+        x.to_bytes(48, "little") + y.to_bytes(48, "little")
+        for x, y in points)).hexdigest()
+
+
+def _value(name, *labels):
+    for m in REGISTRY.all_metrics():
+        if m.name == name:
+            return m.labels(*labels).value if labels else m.value
+    raise KeyError(name)
+
+
+def _state(n):
+    return SimpleNamespace(
+        validators=[SimpleNamespace(pubkey=b) for b in BYTES[:n]])
+
+
+@pytest.fixture(autouse=True)
+def _rows_by_eights(monkeypatch):
+    """Tables of a few rows: the capacity rounds to 8 rows, not 65,536."""
+    monkeypatch.setattr(reg, "ROW_CHUNK", 8)
+
+
+@pytest.fixture()
+def one_chip_backend(monkeypatch):
+    """The jax backend with its batch lane on one device (the tests' eight
+    virtual devices otherwise make it a mesh, which keeps to the packed
+    grid), and no table left behind for the files that follow."""
+    from lighthouse_tpu import parallel
+
+    monkeypatch.setenv("LIGHTHOUSE_TPU_MESH_DEVICES", "1")
+    parallel.reset_mesh_cache()
+    backend = bls_api.set_backend("jax")
+    try:
+        yield backend
+    finally:
+        backend.registry = None
+        bls_api.set_backend("python")
+        monkeypatch.undo()
+        parallel.reset_mesh_cache()
+
+
+def test_rows_equal_the_reference_after_build_append_and_growth():
+    table = reg.PubkeyTable()
+    ref = _reference_points(BYTES)
+    assert len(table) == 0 and table.digest() == _reference_digest([])
+    # build (grows 0 -> 16 rows), append in place, append past the capacity
+    for upto, capacity in ((5, 16), (7, 16), (16, 24)):
+        table.append(KEYS[len(table):upto])
+        assert (len(table), table.capacity) == (upto, capacity)
+        assert table.rows(range(upto)) == ref[:upto]
+        assert table.digest() == _reference_digest(ref[:upto])
+        assert table.digest() != _reference_digest(ref[:upto - 1])
+        assert table.spare_nonzero() == 0
+        assert table.x.shape == table.y.shape == (capacity, 24)
+        assert _value("jaxbls_registry_rows") == upto
+        assert _value("jaxbls_registry_bytes") == capacity * 2 * 24 * 4
+    # a spare row that is not zero is seen
+    import jax.numpy as jnp
+
+    table.x = table.x.at[20, 3].set(jnp.uint32(9))
+    assert table.spare_nonzero() == 1
+
+
+def test_default_capacity_is_the_registry_rounded_up_with_room(monkeypatch):
+    monkeypatch.setattr(reg, "ROW_CHUNK", 65_536)     # the module's own
+    table = reg.PubkeyTable()
+    assert table._capacity_for(1_048_576) == 1_114_112
+    assert table._capacity_for(1_048_577) == 1_048_576 + 2 * 65_536
+    assert 1_114_112 * 2 * 24 * 4 == 213_909_504
+
+
+def test_append_is_a_span_with_rows_and_bytes():
+    table = reg.PubkeyTable()
+    tr = obstrace.Trace("gossip_block", 1)
+    obstrace.set_current_trace(tr)
+    try:
+        table.append(KEYS[:3])          # grows: the whole mirror goes up
+        table.append(KEYS[3:6])         # 3 rows padded to 4, in place
+    finally:
+        obstrace.set_current_trace(None)
+    spans = [s for s in tr.spans if s[0] == "jaxbls:registry.append"]
+    assert [s[3] for s in spans] == [
+        {"rows": 3, "bytes": 16 * 2 * 24 * 4},
+        {"rows": 3, "bytes": 4 * 2 * 24 * 4}]
+
+
+@pytest.mark.parametrize("bad", [8, 9, -1, 2**31])
+def test_an_index_outside_the_table_is_refused_and_counted(bad):
+    table = reg.PubkeyTable()
+    table.append(KEYS[:8])
+    sig = bls.Signature(bls_api.hash_to_g2_point(b"\x01" * 32))
+    ok = bls.SignatureSet(sig, KEYS[:3], b"\x01" * 32, signing_indices=[0, 1, 7])
+    idx, mask = table.index_grid([ok], 4, 4, 8)
+    assert idx.dtype == np.int32 and idx.tolist()[0] == [0, 1, 7, 0]
+    assert mask.tolist()[0] == [1, 1, 1, 0] and not mask[1:].any()
+    before = _value("jaxbls_registry_refused_total")
+    named = bls.SignatureSet(sig, KEYS[:2], b"\x01" * 32,
+                             signing_indices=[2, bad])
+    assert table.index_grid([ok, named], 4, 4, 8) is None
+    assert _value("jaxbls_registry_refused_total") == before + 1
+    # the capacity is not the limit: a spare row is refused like any other
+    assert table.capacity == 16
+
+
+def test_a_refused_batch_is_false_before_any_program_runs(one_chip_backend):
+    backend = one_chip_backend
+    cache = ValidatorPubkeyCache(table=backend.install_registry())
+    cache.import_new_pubkeys(_state(8))
+    sig = bls.Signature(bls_api.hash_to_g2_point(b"\x02" * 32))
+    past = bls.SignatureSet(sig, [cache.get(0), KEYS[8]], b"\x02" * 32,
+                            signing_indices=[0, 8],
+                            signing_registry=cache.table)
+    refused = _value("jaxbls_registry_refused_total")
+    taken = {s: _value("jaxbls_registry_keys_total", s)
+             for s in ("table", "packed")}
+    assert backend.verify_signature_sets([past], [3]) is False
+    assert _value("jaxbls_registry_refused_total") == refused + 1
+    assert taken == {s: _value("jaxbls_registry_keys_total", s)
+                     for s in ("table", "packed")}      # no key went anywhere
+
+
+def test_the_path_is_chosen_by_the_data(one_chip_backend):
+    """`_marshal_indices`: None (the batch packs its keys) with no table,
+    with one set lacking indices, or with a set whose indices are rows of
+    another table or of none; the grid on the device otherwise."""
+    backend = one_chip_backend
+    sig = bls.Signature(bls_api.hash_to_g2_point(b"\x03" * 32))
+    assert backend.registry is None
+
+    def named(cache, rows=(1, 5, 2)):
+        return bls.SignatureSet(sig, [cache.get(i) for i in rows],
+                                b"\x03" * 32, signing_indices=rows,
+                                signing_registry=cache.table)
+
+    elsewhere = ValidatorPubkeyCache(table=reg.PubkeyTable())
+    elsewhere.import_new_pubkeys(_state(8))
+    assert backend._marshal_indices([named(elsewhere)], 4, 4, 3) is None
+    table = backend.install_registry()
+    assert backend.registry is table and len(table) == 0
+    cache = ValidatorPubkeyCache(table=table)
+    cache.import_new_pubkeys(_state(8))
+    mine = named(cache)
+    bare = bls.SignatureSet(sig, [cache.get(3)], b"\x03" * 32)
+    # the same validators, the very same key objects, another chain's rows
+    theirs = named(elsewhere)
+    assert theirs.signing_keys == mine.signing_keys
+    unowned = bls.SignatureSet(sig, mine.signing_keys, b"\x03" * 32,
+                               signing_indices=[1, 5, 2])
+    for other in (bare, theirs, unowned):
+        assert backend._marshal_indices([mine, other], 4, 4, 6) is None
+    tr = obstrace.Trace("gossip_block", 1)
+    obstrace.set_current_trace(tr)
+    try:
+        tx, ty, idx, mask = backend._marshal_indices([mine, mine], 4, 4, 6)
+    finally:
+        obstrace.set_current_trace(None)
+    assert tx is table.x and ty is table.y
+    assert np.asarray(idx).tolist() == [[1, 5, 2, 0]] * 2 + [[0] * 4] * 2
+    assert np.asarray(mask).sum() == 6
+    (span,) = [s for s in tr.spans if s[0] == "jaxbls:marshal.indices"]
+    assert span[3] == {"keys": 6, "bytes": 2 * 4 * 4 * 4}
+
+
+def test_the_table_goes_with_the_cache_that_feeds_it(one_chip_backend):
+    """The backend holds its table weakly: the pubkey cache (the chain)
+    keeps it, and when that goes the next chain finds the place free."""
+    import gc
+
+    backend = one_chip_backend
+    cache = ValidatorPubkeyCache(table=backend.install_registry())
+    cache.import_new_pubkeys(_state(3))
+    assert backend.registry is cache.table and len(backend.registry) == 3
+    del cache
+    gc.collect()
+    assert backend.registry is None
+
+
+def test_signing_indices_are_one_a_key_read_only_and_outside_equality():
+    sig = bls.Signature(bls_api.hash_to_g2_point(b"\x04" * 32))
+    plain = bls.SignatureSet(sig, KEYS[:2], b"\x04" * 32)
+    assert plain.signing_indices is None
+    s = bls.SignatureSet(sig, KEYS[:2], b"\x04" * 32, signing_indices=(7, 3))
+    assert s.signing_indices.dtype == np.int64
+    assert s.signing_indices.tolist() == [7, 3]          # the keys' order
+    with pytest.raises(ValueError):
+        s.signing_indices[0] = 1
+    with pytest.raises(ValueError):
+        bls.SignatureSet(sig, KEYS[:2], b"\x04" * 32, signing_indices=[7])
+    # whose rows they are: any object, read by identity; none without rows
+    assert s.signing_registry is None
+    rows_of = object()
+    owned = bls.SignatureSet(sig, KEYS[:2], b"\x04" * 32,
+                             signing_indices=(7, 3), signing_registry=rows_of)
+    assert owned.signing_registry is rows_of and owned == s
+    with pytest.raises(ValueError):
+        bls.SignatureSet(sig, KEYS[:2], b"\x04" * 32, signing_registry=rows_of)
+    assert s == plain and hash(s) == hash(plain)         # the record's keys
+    # the other backends read the keys alone
+    bls_api.set_backend("fake")
+    try:
+        assert bls.verify_signature_sets([s])
+    finally:
+        bls_api.set_backend("python")
+
+
+def test_import_new_pubkeys_feeds_the_table_before_it_returns():
+    table = reg.PubkeyTable()
+    cache = ValidatorPubkeyCache(table=table)
+    cache.import_new_pubkeys(_state(5))
+    assert len(table) == len(cache) == 5
+    cache.import_new_pubkeys(_state(5))                 # nothing new
+    cache.import_new_pubkeys(_state(9))
+    ref = _reference_points(BYTES[:9])
+    assert table.rows(range(9)) == ref
+    assert table.digest() == _reference_digest(ref)
+    get_pubkey = cache.pubkey_getter()
+    assert get_pubkey.index_by_bytes(BYTES[8]) == 8
+    assert get_pubkey.index_by_bytes(BYTES[9]) is None
+    assert get_pubkey.registry is table
+    assert not hasattr(cache, "mont_coords")            # the old device feed
+    # a cache without a table is the cache of before
+    plain = ValidatorPubkeyCache()
+    plain.import_new_pubkeys(_state(3))
+    assert plain.table is None and len(plain) == 3
+    assert plain.pubkey_getter().registry is None
+
+
+def test_the_first_chain_of_a_process_feeds_the_backends_table(monkeypatch):
+    """`BeaconChain` installs a table on a backend that offers one and
+    hands it to its pubkey cache; a second chain (another registry) gets
+    none. A backend without the offer (python, fake, hybrid) is as before."""
+    from lighthouse_tpu.chain.beacon_chain import BeaconChain
+    from lighthouse_tpu.testing.harness import StateHarness, clone_state
+    from lighthouse_tpu.types.spec import minimal_spec
+
+    class Offering:
+        registry = None
+
+        def install_registry(self):
+            self.registry = reg.PubkeyTable()
+            return self.registry
+
+    bls.set_backend("fake")
+    try:
+        spec = minimal_spec()
+        hs = StateHarness.new(spec, 16)
+        plain = BeaconChain(spec, clone_state(hs.state, spec))
+        assert plain.pubkey_cache.table is None
+        backend = Offering()
+        monkeypatch.setattr(bls, "get_backend", lambda: backend)
+        first = BeaconChain(spec, clone_state(hs.state, spec))
+        second = BeaconChain(spec, clone_state(hs.state, spec))
+    finally:
+        bls.set_backend("python")
+    table = first.pubkey_cache.table
+    assert table is backend.registry and len(table) == 16
+    assert table.rows(range(16)) == [
+        first.pubkey_cache.get(i).point for i in range(16)]
+    assert second.pubkey_cache.table is None and len(second.pubkey_cache) == 16
+
+
+def test_an_electra_block_fills_the_batch_with_indices(monkeypatch):
+    """`per_block_processing(VERIFY_BULK)` on an Electra block at the
+    minimal preset: proposal and RANDAO name the proposer, every attestation
+    set carries `get_attesting_indices_electra`'s indices in order beside
+    the keys of those validators, the sync aggregate its signers'."""
+    from lighthouse_tpu.state_transition import accessors as acc
+    from lighthouse_tpu.state_transition import block as blk
+    from lighthouse_tpu.state_transition.slot import (
+        process_slots, state_transition, types_for_slot)
+    from lighthouse_tpu.testing.harness import StateHarness, clone_state
+    from lighthouse_tpu.types.spec import ForkName, minimal_spec
+
+    bls.set_backend("fake")
+    try:
+        spec = minimal_spec(electra_fork_epoch=0)
+        hs = StateHarness.new(spec, 64)
+        (head,) = hs.extend_chain(1)
+        slot = hs.state.slot + 1
+        types = types_for_slot(spec, slot)
+        atts = hs.build_attestations(
+            clone_state(hs.state, spec), hs.state.slot,
+            types.BeaconBlock.hash_tree_root(head.message))
+        signed, _ = hs.produce_block(slot, attestations=atts)
+        assert spec.fork_name_at_slot(slot) == ForkName.electra
+        assert len(signed.message.body.attestations) >= 1
+
+        cache = ValidatorPubkeyCache(table=reg.PubkeyTable())
+        cache.import_new_pubkeys(hs.state)
+        seen = []
+        monkeypatch.setattr(blk.SignatureBatch, "verify",
+                            lambda self: seen.append(list(self.sets)) or True)
+        state = clone_state(hs.state, spec)
+        state_transition(state, signed, spec,
+                         get_pubkey=cache.pubkey_getter())
+    finally:
+        bls.set_backend("python")
+    (sets,) = seen
+    body = signed.message.body
+    proposer = signed.message.proposer_index
+    assert len(sets) == 2 + len(body.attestations) + 1
+    for s in sets:
+        assert s.signing_indices is not None
+        assert s.signing_registry is cache.table
+        assert [cache.get(i) for i in s.signing_indices.tolist()] == list(
+            s.signing_keys)
+    assert sets[0].signing_indices.tolist() == [proposer]
+    assert sets[1].signing_indices.tolist() == [proposer]
+    pre = clone_state(hs.state, spec)
+    process_slots(pre, spec, slot)
+    for att, s in zip(body.attestations, sets[2:]):
+        want = acc.get_attesting_indices_electra(pre, spec, att)
+        assert s.signing_indices.tolist() == want and len(want) > 1
+    signers = [cache.get_index(bytes(pk)) for pk, bit in zip(
+        pre.current_sync_committee.pubkeys,
+        body.sync_aggregate.sync_committee_bits) if bit]
+    assert sets[-1].signing_indices.tolist() == signers and signers
+    # without the cache's resolver the sync set comes without indices, and
+    # the batch as a whole then packs its keys
+    seen.clear()
+    bls.set_backend("fake")
+    try:
+        state_transition(clone_state(hs.state, spec), signed, spec)
+    finally:
+        bls.set_backend("python")
+    assert seen[0][-1].signing_indices is None
+    assert seen[0][0].signing_indices.tolist() == [proposer]
+    assert all(s.signing_registry is None for s in seen[0])
+
+
+def test_start_up_warms_the_indexed_prepare_where_a_table_is_fed(
+        one_chip_backend, monkeypatch):
+    """`autotune.runtime.start_warmup` on one chip: the packed stages per
+    bucket as before, then — the chain's table holding keys — the indexed
+    prepare per bucket against that table; without keys, or under a mesh,
+    the packed stages alone."""
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+    from lighthouse_tpu import parallel
+    from lighthouse_tpu.autotune import runtime
+
+    backend = one_chip_backend
+    calls = []
+    monkeypatch.setattr(be, "warm_stages",
+                        lambda n, m, **kw: calls.append(("packed", n, m)))
+    monkeypatch.setattr(be, "warm_prepare_indexed",
+                        lambda n, m, table: calls.append(("indexed", n, m, table)))
+    buckets = ((64, 128), (4, 128))
+
+    def warm():
+        calls.clear()
+        runtime.start_warmup(buckets=buckets).join(timeout=30)
+        return list(calls)
+
+    packed = [("packed", n, m) for n, m in buckets]
+    assert warm() == packed                               # no table
+    cache = ValidatorPubkeyCache(table=backend.install_registry())
+    assert warm() == packed                               # no key in it yet
+    cache.import_new_pubkeys(_state(3))
+    assert warm() == packed + [("indexed", n, m, cache.table)
+                               for n, m in buckets]
+    monkeypatch.setenv("LIGHTHOUSE_TPU_MESH_DEVICES", "8")
+    parallel.reset_mesh_cache()
+    assert [c[0] for c in warm()].count("indexed") == 0   # a mesh packs
+
+
+def _benchmark_reference():
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "reference",
+        "bls_registry_spec.py")
+    spec = importlib.util.spec_from_file_location("bls_registry_spec", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("what", ["imports", "keys", "hash_to_g2",
+                                  "pairing", "verdicts"])
+def test_the_benchmarks_reference_is_its_own_and_agrees(what):
+    """benchmarks/reference/bls_registry_spec.py, the plain reference that
+    decides `correct` in `block_import_electra`: a BLS12-381 of its own
+    (no module of this program) that agrees with the pure-Python backend on
+    seeded keys, messages and sets."""
+    ref = _benchmark_reference()
+    if what == "imports":
+        import ast
+
+        tree = ast.parse(open(ref.__file__).read())
+        imported = {n.module if isinstance(n, ast.ImportFrom) else a.name
+                    for n in ast.walk(tree)
+                    if isinstance(n, (ast.Import, ast.ImportFrom))
+                    for a in n.names}
+        assert imported == {"__future__", "hashlib"}
+    elif what == "keys":
+        for pk, b in zip(KEYS, BYTES):
+            assert ref.decompress_key(b) == pk.point
+            assert ref.compress_key(pk.point) == b
+        points = [pk.point for pk in KEYS]
+        assert ref.keys_not_of(BYTES, points) == 0
+        x, y = points[3]
+        points[3] = (x, ref.P - y)
+        points[5] = cv.g1_add(points[5], points[5])
+        assert ref.keys_not_of(BYTES, points) == 2
+        total = None
+        for pk in KEYS:
+            total = cv.g1_add(total, pk.point)
+        assert ref.sum_keys([pk.point for pk in KEYS]) == total
+        off = next(x for x in range(1, 50) if ref.fp_sqrt(x ** 3 + 4) is None)
+        with pytest.raises(ValueError):
+            ref.decompress_key((off | 4 << 381).to_bytes(48, "big"))
+    elif what == "hash_to_g2":
+        for msg in (b"", b"\x00" * 32, bytes(range(32)), b"abc"):
+            assert ref.hash_to_g2(msg) == bls_api.hash_to_g2_point(msg)
+    elif what == "pairing":
+        a, b = rng.randrange(1, R), rng.randrange(1, R)
+        g2 = ref.hash_to_g2(b"a point of order r")
+        pa, qb = ref.g1_mul(ref.G1, a), ref.g2_mul(g2, b)
+        assert pa == cv.g1_mul(cv.G1_GEN, a) and qb == cv.g2_mul(g2, b)
+        assert ref.pairing_product_is_one(
+            [(pa, qb), (ref.g1_neg(ref.g1_mul(ref.G1, a * b % R)), g2)])
+        assert not ref.pairing_product_is_one(
+            [(pa, qb), (ref.g1_neg(ref.g1_mul(ref.G1, (a * b + 1) % R)), g2)])
+    else:
+        sks = [rng.randrange(1, R) for _ in range(6)]
+        keys = [bls.PublicKey(cv.g1_mul(cv.G1_GEN, sk)) for sk in sks]
+        m1, m2 = b"\xA1" * 32, b"\xA2" * 32
+
+        def signed(who, msg):
+            return cv.g2_mul(bls_api.hash_to_g2_point(msg),
+                             sum(sks[i] for i in who) % R)
+
+        good = [(signed((0, 1, 2, 3), m1), (0, 1, 2, 3), m1),
+                (signed((4,), m2), (4,), m2)]
+        swapped = [(good[1][0], good[0][1], m1), good[1]]
+        replaced = [(good[0][0], (0, 1, 5, 3), m1), good[1]]
+        bls_api.set_backend("python")
+        for block, want in ((good, True), (swapped, False),
+                            (replaced, False)):
+            assert ref.verify_signature_sets(
+                [(sig, [keys[i].serialize() for i in who], msg)
+                 for sig, who, msg in block], [2**63 + 11, 7]) is want
+            assert bls.verify_signature_sets([bls.SignatureSet(
+                bls.Signature(sig), [keys[i] for i in who], msg)
+                for sig, who, msg in block]) is want
+
+
+# ------------------------------------------------ keys by validator index
+# A block of four sets (proposal and RANDAO of one key, an attestation of
+# four, a sync aggregate of three) whose sets carry validator indices, on
+# the batch lane of ONE device (the mesh taken away: under a mesh the
+# packed grid stays), against a 12-validator registry whose keys the
+# chain's pubkey cache has put into the table on the device; bucket (4, 4).
+# The reference is the pure-Python backend on the sets' own keys.
+
+_TABLE_ROWS = 24      # 12 validators, headroom 8: the table's capacity
+
+
+@pytest.fixture(scope="module")
+def _one_device_programs():
+    """The five programs the tests below dispatch, compiled side by side:
+    the unsharded four stages at 4 sets with the packed prepare at m = 4,
+    and the indexed prepare at (4, 4) over the 24-row table."""
+    import functools
+
+    import jax
+    from jaxbls_warm import run_in_threads, warm_build
+
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+    from lighthouse_tpu.crypto.jaxbls import limbs as lb
+
+    def limbs(*shape):
+        return np.zeros(shape + (lb.NL,), np.uint32)
+
+    def warm_indexed():
+        jax.block_until_ready(be._get_prepare_indexed()(
+            limbs(_TABLE_ROWS), limbs(_TABLE_ROWS),
+            np.zeros((4, 4), np.int32), np.ones((4, 4), np.uint32),
+            limbs(4, 2), limbs(4, 2), np.ones((4, be.Z_DIGITS), np.uint32),
+            np.ones((4,), np.uint32)))
+
+    run_in_threads(functools.partial(warm_build, 4, (4,), None), warm_indexed)
+
+
+
+@pytest.fixture()
+def registry_chain(monkeypatch, _one_device_programs):
+    """(backend, cache, secret keys): the jax backend with a table fed by a
+    ValidatorPubkeyCache of 12 seeded validators."""
+    from types import SimpleNamespace
+
+    from lighthouse_tpu import parallel
+    from lighthouse_tpu.chain.pubkey_cache import ValidatorPubkeyCache
+    rng = random.Random(0x2E6)
+    sks = [rng.randrange(1, R) for _ in range(14)]
+    pks = [bls.PublicKey(cv.g1_mul(cv.G1_GEN, sk)) for sk in sks]
+    state = SimpleNamespace(validators=[
+        SimpleNamespace(pubkey=pk.serialize()) for pk in pks[:12]])
+    monkeypatch.setenv("LIGHTHOUSE_TPU_MESH_DEVICES", "1")
+    parallel.reset_mesh_cache()
+    backend = bls_api.set_backend("jax")
+    cache = ValidatorPubkeyCache(table=backend.install_registry())
+    cache.import_new_pubkeys(state)
+    assert backend.registry.capacity == _TABLE_ROWS
+    cache.state, cache.all_keys = state, pks      # for the append test
+    try:
+        yield backend, cache, sks
+    finally:
+        backend.registry = None
+        bls_api.set_backend("python")
+        monkeypatch.undo()
+        parallel.reset_mesh_cache()
+
+
+def _set_by_index(cache, sks, indices, msg, signers=None):
+    """The set the builders make: the cache's key objects and their
+    indices; signed by `signers` (the same validators by default)."""
+    h = bls_api.hash_to_g2_point(msg)
+    agg = sum(sks[i] for i in (indices if signers is None else signers)) % R
+    return bls.SignatureSet(
+        bls.Signature(cv.g2_mul(h, agg)), [cache.get(i) for i in indices],
+        msg, signing_indices=indices, signing_registry=cache.table)
+
+
+_BLOCK_BY_INDEX = ((5,), (5,), (0, 3, 7, 11), (2, 9, 4))
+
+
+def _block_by_index(cache, sks, damage=None):
+    sets = [_set_by_index(cache, sks, list(ix), bytes([0xE0 + i]) * 32)
+            for i, ix in enumerate(_BLOCK_BY_INDEX)]
+    att = sets[2]
+    if damage == "swapped_signature":
+        sets[2] = bls.SignatureSet(sets[3].signature, att.signing_keys,
+                                   att.message, signing_indices=[0, 3, 7, 11],
+                                   signing_registry=cache.table)
+    elif damage == "dropped_signer":
+        # the signature is all four's, the set names three of them
+        sets[2] = _set_by_index(cache, sks, [0, 3, 11], att.message,
+                                signers=[0, 3, 7, 11])
+    elif damage == "replaced_signer":
+        # validator 7 replaced by validator 8, who did not sign
+        sets[2] = _set_by_index(cache, sks, [0, 3, 8, 11], att.message,
+                                signers=[0, 3, 7, 11])
+    return sets
+
+
+def _keys_taken():
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+
+    return {s: be._REGISTRY_KEYS.labels(s).value for s in ("table", "packed")}
+
+
+def test_indexed_prepare_is_bit_equal_to_the_packed_prepare(registry_chain):
+    """`_stage_prepare_indexed` over the table and `_stage_prepare` over
+    the packed grid of the same keys: z_pk, sig_acc and bad_aggpk equal
+    limb for limb (the arithmetic exists once; a masked slot is the
+    identity whatever row it gathered)."""
+    import numpy as np
+
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+    from lighthouse_tpu.crypto.jaxbls import curve_ops as co
+
+    backend, cache, sks = registry_chain
+    sets = _block_by_index(cache, sks)
+    idx, mask = backend.registry.index_grid(sets, 4, 4, 12)
+    pk_x, pk_y, pk_mask = backend._marshal_pubkeys(sets, 4, 4,
+                                                   single_chip=True)
+    assert np.array_equal(np.asarray(pk_mask), mask)
+    sig_x = np.zeros((4, 2, 24), np.uint32)
+    sig_y = np.zeros((4, 2, 24), np.uint32)
+    for i, s in enumerate(sets):
+        (x0, x1), (y0, y1) = s.signature.point
+        sig_x[i] = be.pack_ints_vec([x0, x1])
+        sig_y[i] = be.pack_ints_vec([y0, y1])
+    z = co.scalars_to_digits([3, 0xDEADBEEF12345677, 0x42, 2**63 + 9],
+                             64, be.Z_WINDOW)[:, :be.Z_DIGITS]
+    rest = (sig_x, sig_y, np.asarray(z, np.uint32), np.ones((4,), np.uint32))
+    table = backend.registry
+    got = be._get_prepare_indexed()(table.x, table.y, idx, mask, *rest)
+    want = be._get_stages(mesh=None)[0](pk_x, pk_y, pk_mask, *rest)
+    import jax
+
+    flat_got = jax.tree_util.tree_leaves(got)
+    flat_want = jax.tree_util.tree_leaves(want)
+    assert len(flat_got) == len(flat_want) == 7
+    for a, b in zip(flat_got, flat_want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert not bool(np.asarray(got[2]))
+
+
+@pytest.mark.parametrize("damage", [
+    None, "swapped_signature", "dropped_signer", "replaced_signer"],
+    ids=lambda d: d or "valid")
+def test_block_by_index_through_signature_batch_parity(registry_chain,
+                                                       damage):
+    """`SignatureBatch.verify()` -> `bls.verify_signature_sets` -> the batch
+    lane takes the indexed path, no other entry: every key of the dispatch
+    comes from the table, the marshal's key part is `jaxbls:marshal.indices`
+    (no grid is packed), the bucket's slots are counted as the packed path
+    counts them, and the verdict is the pure-Python backend's."""
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+    from lighthouse_tpu.observability import trace as obstrace
+    from lighthouse_tpu.state_transition.block import SignatureBatch
+
+    backend, cache, sks = registry_chain
+    batch = SignatureBatch()
+    batch.add(_block_by_index(cache, sks, damage))
+    # the start-up warm-up compiles the very program the dispatch runs
+    program = be._get_prepare_indexed()
+    be.warm_prepare_indexed(3, 4, backend.registry)       # rounds to (4, 4)
+    compiled = program._cache_size()
+    slots = {k: be._BUCKET_SLOTS.labels("keys", k) for k in ("real", "padded")}
+    before = {k: c.value for k, c in slots.items()}
+    taken = _keys_taken()
+    tr = obstrace.Trace("gossip_block", 1)
+    obstrace.set_current_trace(tr)
+    try:
+        on_jax = batch.verify()
+    finally:
+        obstrace.set_current_trace(None)
+    n_keys = sum(len(s.signing_keys) for s in batch.sets)
+    assert program._cache_size() == compiled
+    assert _keys_taken() == {"table": taken["table"] + n_keys,
+                             "packed": taken["packed"]}
+    assert {k: c.value - before[k] for k, c in slots.items()} == {
+        "real": n_keys, "padded": 16}
+    spans = {s[0]: s for s in tr.spans}
+    assert "jaxbls:marshal.pubkeys" not in spans
+    assert "jaxbls:marshal.pubkeys_upload" not in spans
+    assert spans["jaxbls:marshal.indices"][4] == "jaxbls:marshal"
+    assert spans["jaxbls:marshal.indices"][3] == {
+        "keys": n_keys, "bytes": 2 * 4 * 4 * 4}
+    assert spans["jaxbls:prepare"][4] == "jaxbls:enqueue"   # stage 1's name
+    assert tr.meta["bucket"] == "4x4" and tr.meta["real_keys"] == n_keys
+    bls_api.set_backend("python")
+    on_python = batch.verify()
+    assert on_python is (damage is None)
+    assert on_jax is on_python
+
+
+def test_a_set_without_indices_sends_the_batch_down_the_packed_path(
+        registry_chain):
+    backend, cache, sks = registry_chain
+    sets = _block_by_index(cache, sks, "replaced_signer")
+    sets[1] = bls.SignatureSet(sets[1].signature, sets[1].signing_keys,
+                               sets[1].message)              # no indices
+    taken = _keys_taken()
+    n_keys = sum(len(s.signing_keys) for s in sets)
+    assert bls.verify_signature_sets(sets) is False
+    assert _keys_taken() == {"table": taken["table"],
+                             "packed": taken["packed"] + n_keys}
+
+
+def test_an_appended_key_verifies_in_the_next_dispatch(registry_chain):
+    """Validator 12 before `import_new_pubkeys` brought it: the row past
+    `len` is refused and counted, not read as a spare (zero) row. After:
+    the next dispatch gathers it and verifies."""
+    from types import SimpleNamespace
+
+    from lighthouse_tpu.crypto.jaxbls import registry as reg
+
+    backend, cache, sks = registry_chain
+    msg = b"\xEE" * 32
+    h = bls_api.hash_to_g2_point(msg)
+    sig = bls.Signature(cv.g2_mul(h, (sks[12] + sks[1]) % R))
+    early = bls.SignatureSet(sig, [cache.all_keys[12], cache.get(1)], msg,
+                             signing_indices=[12, 1],
+                             signing_registry=cache.table)
+    block = _block_by_index(cache, sks)
+    refused = reg.REFUSED.value
+    assert bls.verify_signature_sets(block[:3] + [early]) is False
+    assert reg.REFUSED.value == refused + 1
+    cache.state.validators += [SimpleNamespace(pubkey=pk.serialize())
+                               for pk in cache.all_keys[12:]]
+    cache.import_new_pubkeys(cache.state)
+    table = backend.registry
+    assert len(table) == 14 and table.capacity == _TABLE_ROWS
+    assert table.spare_nonzero() == 0
+    late = bls.SignatureSet(sig, [cache.get(12), cache.get(1)], msg,
+                            signing_indices=[12, 1],
+                            signing_registry=cache.table)
+    taken = _keys_taken()
+    assert bls.verify_signature_sets(block[:3] + [late]) is True
+    assert _keys_taken()["table"] == taken["table"] + 8
+    assert reg.REFUSED.value == refused + 1
+
+
+def test_another_chains_sets_pack_their_keys_whatever_their_indices(
+        registry_chain):
+    """A second chain in the process: no table of its own, a LONGER
+    registry of other keys. Its sets name its own rows — some past the
+    first chain's `len`, some inside it — and none is this table's
+    business: the batch packs the sets' keys, nothing is refused, and the
+    verdict is the keys' own (a set past the first chain's `len` among
+    them: under the table it would have been refused, or read a spare row)."""
+    from types import SimpleNamespace
+
+    from lighthouse_tpu.chain.pubkey_cache import ValidatorPubkeyCache
+    from lighthouse_tpu.crypto.jaxbls import registry as reg
+
+    backend, cache, first_sks = registry_chain
+    rng = random.Random(0x0DD)
+    sks = [rng.randrange(1, R) for _ in range(15)]
+    second = ValidatorPubkeyCache()
+    second.import_new_pubkeys(SimpleNamespace(validators=[
+        SimpleNamespace(pubkey=bls.PublicKey(
+            cv.g1_mul(cv.G1_GEN, sk)).serialize()) for sk in sks]))
+    assert len(second) == 15 > len(backend.registry) == 12
+    assert second.table is None
+    sets = [_set_by_index(second, sks, list(ix), bytes([0xD0 + i]) * 32)
+            for i, ix in enumerate(((13,), (14, 0), (2, 9, 4), (1, 12, 3)))]
+    assert all(s.signing_registry is None for s in sets)
+    # beside the first chain's own sets: one foreign set and the batch packs
+    mixed = _block_by_index(cache, first_sks)[:2] + [sets[0], sets[2]]
+    assert [s.signing_registry for s in mixed] == [cache.table] * 2 + [None] * 2
+    refused, taken = reg.REFUSED.value, _keys_taken()
+    assert bls.verify_signature_sets(mixed) is True
+    assert reg.REFUSED.value == refused
+    assert _keys_taken() == {"table": taken["table"],
+                             "packed": taken["packed"] + 6}
+    assert backend._marshal_indices(sets, 4, 4, 9) is None

@@ -630,8 +630,8 @@ def test_synchronous_runner_marshal_holds_the_device_wait():
 def test_new_layer_metrics_read_spans_the_program_emits():
     """Every `pipeline_stage_seconds` stage a layer metric of
     benchmarks/layer_metrics reads is a span name the program emits (the
-    real-dispatch tests of test_jaxbls_backend, test_kzg and test_jaxhash
-    see each emitted), and the two other families this PR's metrics read
+    real-dispatch tests of test_jaxbls_backend, test_jaxbls_registry,
+    test_kzg and test_jaxhash see each emitted), and the two other families this PR's metrics read
     are registered."""
     import glob
     import os
@@ -670,10 +670,24 @@ def test_new_layer_metrics_read_spans_the_program_emits():
         "exec_lock_wait_ms": "beacon_processor_exec_lock_wait_seconds",
         "tree_upload_ms": "jaxhash:upload",
         "tree_readback_ms": "jaxhash:readback",
+        # the index marshal of the registry path (test_jaxbls_registry's
+        # test_block_by_index_through_signature_batch_parity sees it), and
+        # the twins that name the cell block_import_electra
+        "el_marshal_indices_ms": "jaxbls:marshal.indices",
+        "el_marshal_sigs_ms": "jaxbls:marshal.sigs",
+        "el_marshal_h2f_ms": "jaxbls:marshal.h2f",
+        "el_marshal_upload_ms": "jaxbls:marshal.upload",
+        "el_exec_lock_wait_ms": "beacon_processor_exec_lock_wait_seconds",
     }
+    from lighthouse_tpu.crypto.jaxbls import registry  # noqa: F401
+
     families = {m.name for m in REGISTRY.all_metrics()}
     assert {"jaxbls_dispatch_device_seconds",
-            "beacon_processor_exec_lock_wait_seconds"} <= families
+            "beacon_processor_exec_lock_wait_seconds",
+            # what el_registry_key_share and el_prepare_key_bytes_share
+            # read, and the table's three other families
+            "jaxbls_registry_keys_total", "jaxbls_registry_refused_total",
+            "jaxbls_registry_rows", "jaxbls_registry_bytes"} <= families
     assert not {"jaxbls_dispatch_enqueue_seconds",
                 "jaxbls_device_wait_seconds"} & families
 
