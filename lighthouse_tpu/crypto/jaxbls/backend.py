@@ -17,7 +17,10 @@ one jitted XLA program:
 
 Shapes are padded to power-of-two buckets (pad lanes masked out) so XLA
 compiles one program per bucket, cached persistently (utils/jaxcfg.py) —
-the bucketing policy answers SURVEY.md §7 hard part (c).
+the bucketing policy answers SURVEY.md §7 hard part (c). Under a bucket's
+(n, m) a one-chip batch of unequal widths lays its keys as two grids, a
+wide and a narrow one, chosen from the widths it holds (`key_grid_plan`):
+the key axis is where padding costs what data costs.
 
 Throughput design (r2, rebuilt r8): a device round trip is pure
 latency the host can hide, so every
@@ -74,9 +77,10 @@ _PK_CACHE = REGISTRY.counter_vec(
 )
 _BUCKET_SLOTS = REGISTRY.counter_vec(
     "jaxbls_bucket_slots_total",
-    "slots of the padding bucket per dispatch, by axis (sets: n; keys: "
-    "n*m) and kind: real = what the caller sent, padded = what the bucket "
-    "holds; real over padded is the bucket's fill",
+    "slots a dispatch lays, by axis (sets: the padding bucket's n; keys: "
+    "every slot of the key grids key_grid_plan chose, n*m where it is the "
+    "one grid) and kind: real = what the caller sent, padded = what is "
+    "laid; real over padded is the fill",
     ("axis", "kind"),
 )
 _DISPATCH_MESSAGES = REGISTRY.counter_vec(
@@ -90,9 +94,10 @@ _DISPATCH_MESSAGES = REGISTRY.counter_vec(
 _TREE_SUM_LANE_ADDS = REGISTRY.counter_vec(
     "jaxbls_tree_sum_lane_additions_total",
     "point additions of the key-axis sum in prepare, per dispatch: done = "
-    "what curve_ops.tree_sum_plan gives for the bucket's (m, n) key grid, "
-    "needed = real keys - real sets; done over needed is what padding and "
-    "the reduction's shape still cost",
+    "what curve_ops.tree_sum_plan gives, summed over the key grids the "
+    "dispatch lays (key_grid_plan: one (n, m) grid, or a wide and a narrow "
+    "one), needed = real keys - real sets; done over needed is what "
+    "padding and the reduction's shape still cost",
     ("kind",),
 )
 _MILLER_PLAN = REGISTRY.counter_vec(
@@ -174,6 +179,66 @@ def padding_bucket(n_sets: int, n_pks: int, mesh=_LIVE_MESH,
     return pad_sets(n, mesh=mesh), pad_pks(m, mesh=mesh)
 
 
+def key_grid_plan(widths, n: int, m: int) -> tuple:
+    """How the keys of a one-chip dispatch lie, from the key counts of its
+    sets alone: (grids, where). `n`, `m` are the dispatch's
+    `padding_bucket`, which stays its NAME whatever is laid. Pure and
+    single owner, as tree_sum_plan is for the sum: the marshal fills what
+    this gives, prepare sums it, the slot and lane-addition counters read
+    it.
+
+    One grid — `one_key_grid(n, m)`, set i in row i: today's layout —
+    unless two lay at most three quarters of its slots. Two grids, `grids`
+    = (wide, narrow), each (rows, width): for each power of two t < m the
+    narrow grid takes the sets of at most t keys and the wide one the
+    rest, each a power of two of rows by a power of two of slots (the wide
+    grid m wide, the narrow one its widest set rounded up), and the
+    cheapest t wins (the smallest of equals: they lay the same grids).
+    `where` is then int32[n]: set i's sum is entry where[i] of the wide
+    grid's row sums followed by the narrow grid's and one identity, which
+    every padded set slot reads. Sets keep their order inside a grid. So a
+    block whose one sync aggregate made every 128-key attestation 512 wide
+    lays 1 x 512 + 256 x 128, and a batch of near-equal widths keeps the
+    program it has."""
+    best = None
+    t = 1
+    while t < m:
+        narrow = [w for w in widths if w <= t]
+        if narrow and len(narrow) < len(widths):
+            grids = ((_next_pow2(len(widths) - len(narrow)), m),
+                     (_next_pow2(len(narrow)), _next_pow2(max(narrow))))
+            slots = sum(rows * width for rows, width in grids)
+            if best is None or slots < best[0]:
+                best = (slots, t, grids)
+        t *= 2
+    if best is None or 4 * best[0] > 3 * n * m:
+        return one_key_grid(n, m)
+    _, t, grids = best
+    (wide_rows, _), (narrow_rows, _) = grids
+    where = np.full((n,), wide_rows + narrow_rows, np.int32)
+    rows = [0, wide_rows]                  # the next free entry of each grid
+    for i, w in enumerate(widths):
+        where[i] = rows[w <= t]
+        rows[w <= t] += 1
+    return grids, where
+
+
+def one_key_grid(n: int, m: int) -> tuple:
+    """The key_grid_plan of ONE (n, m) grid, set i in row i."""
+    return ((n, m),), None
+
+
+def _grid_rows(plan, n_sets: int) -> list:
+    """[(grid, row)] of the first n_sets sets under a key_grid_plan: grid 0
+    the wide (or only) one, grid 1 the narrow one."""
+    grids, where = plan
+    if where is None:
+        return [(0, i) for i in range(n_sets)]
+    wide_rows = grids[0][0]
+    return [(0, int(at)) if at < wide_rows else (1, int(at) - wide_rows)
+            for at in where[:n_sets]]
+
+
 # ------------------------------------------------------------ host marshalling
 
 
@@ -232,24 +297,26 @@ def _batched_affine(z_pk, h_jac, sig_acc):
     return (px, py, p_inf), (qx, qy, q_inf), (sx, sy, s_inf)
 
 
-def _stage_prepare(pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits, set_mask):
-    """Stage 1: mont conversion, pubkey tree-aggregation, z-scaling of
-    aggregate pubkeys and signatures, signature tree-sum."""
+def _sum_key_grid(pk_x, pk_y, pk_mask):
+    """Stage 1, the key side: one key grid, coordinates in Montgomery form
+    (rows, width, NL) and its mask, to one Jacobian sum a row."""
     import jax.numpy as jnp
 
-    pk_x = _to_mont_dev(pk_x)
-    pk_y = _to_mont_dev(pk_y)
-    sig_x = _to_mont_dev(sig_x)
-    sig_y = _to_mont_dev(sig_y)
-
-    # aggregate pubkeys per set: (n, m) -> (n,). tree_sum folds the key
-    # axis down to about co.TREE_SUM_L0 lanes and halves the rest: two add
-    # instances whatever m is (the unrolled tree was the compile whale
-    # here), about m*n lane-additions instead of m*n*log2(m) — which on the
-    # v5e was 1.46 s of a 2.56 s block at 256x512 (PERF.md S6, PR 27-28)
+    # (rows, width) -> (rows,). tree_sum folds the key axis down to about
+    # co.TREE_SUM_L0 lanes and halves the rest: two add instances whatever
+    # the width is (the unrolled tree was the compile whale here), about
+    # width*rows lane-additions instead of width*rows*log2(width) — which on
+    # the v5e was 1.46 s of a 2.56 s block at 256x512 (PERF.md S6, PR 27-28)
     pk_jac = co.affine_to_jac(co.FQ_OPS, (pk_x, pk_y), inf_mask=jnp.logical_not(pk_mask))
     pk_jac_t = tuple(jnp.moveaxis(c, 1, 0) for c in pk_jac)
-    aggpk = co.tree_sum(pk_jac_t, co.FQ_OPS)               # (n,) jacobian G1
+    return co.tree_sum(pk_jac_t, co.FQ_OPS)                # (rows,) jacobian G1
+
+
+def _prepare_from_sums(aggpk, sig_x, sig_y, z_digits, set_mask):
+    """Stage 1 after the key side: the sets' aggregate keys (n,) and the
+    signatures in Montgomery form to (z_pk, sig_acc, bad_aggpk)."""
+    import jax.numpy as jnp
+
     aggpk_inf = co.FQ_OPS.is_zero(aggpk[2])
     bad_aggpk = jnp.any(jnp.logical_and(aggpk_inf, set_mask))
 
@@ -270,20 +337,73 @@ def _stage_prepare(pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits, set_mask):
     return z_pk, sig_acc, bad_aggpk
 
 
+def _stage_prepare(pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits, set_mask):
+    """Stage 1: mont conversion, pubkey tree-aggregation, z-scaling of
+    aggregate pubkeys and signatures, signature tree-sum. The keys as ONE
+    (n, m) grid, set i in row i: what a batch of near-equal widths, the
+    urgent lane and a mesh run (key_grid_plan)."""
+    pk_x = _to_mont_dev(pk_x)
+    pk_y = _to_mont_dev(pk_y)
+    sig_x = _to_mont_dev(sig_x)
+    sig_y = _to_mont_dev(sig_y)
+    aggpk = _sum_key_grid(pk_x, pk_y, pk_mask)             # (n,) jacobian G1
+    return _prepare_from_sums(aggpk, sig_x, sig_y, z_digits, set_mask)
+
+
+def _stage_prepare_grids(wide_x, wide_y, wide_mask, narrow_x, narrow_y,
+                         narrow_mask, where, sig_x, sig_y, z_digits, set_mask):
+    """Stage 1 with the keys as the two grids of key_grid_plan: each grid
+    summed along its own key axis by the one `_sum_key_grid`, the row sums
+    of both and one identity laid end to end, set i's aggregate key read
+    from entry `where[i]` (int32[n]; a padded set slot reads the identity,
+    as its all-masked row of the one grid sums to), then the rest of
+    `_stage_prepare` as it is. Every key is still converted, masked and
+    added by the same arithmetic; the slots that hold none are fewer."""
+    import jax.numpy as jnp
+
+    sums = [
+        _sum_key_grid(_to_mont_dev(x), _to_mont_dev(y), mask)
+        for x, y, mask in ((wide_x, wide_y, wide_mask),
+                           (narrow_x, narrow_y, narrow_mask))
+    ]
+    aggpk = tuple(
+        jnp.concatenate([w, nr, jnp.asarray(one)[None]])[where]
+        for w, nr, one in zip(*sums, co.identity(co.FQ_OPS))
+    )
+    return _prepare_from_sums(aggpk, _to_mont_dev(sig_x), _to_mont_dev(sig_y),
+                              z_digits, set_mask)
+
+
+def _gather_rows(table_x, table_y, pk_idx):
+    """The table's rows named by an index grid. The host has refused every
+    index outside the table (registry.index_grid), so the gather promises
+    the compiler what is true."""
+    return (table_x.at[pk_idx].get(mode="promise_in_bounds"),
+            table_y.at[pk_idx].get(mode="promise_in_bounds"))
+
+
 def _stage_prepare_indexed(table_x, table_y, pk_idx, pk_mask,
                            sig_x, sig_y, z_digits, set_mask):
     """Stage 1 with the keys gathered by validator index from the
     registry table on the device (registry.py: `table_x` / `table_y`
     uint32[capacity, NL], `pk_idx` int32[n, m]), then `_stage_prepare`
     itself: the arithmetic exists once. A masked slot gathers row 0 and is
-    the identity by its mask, as a zero slot of the packed grid is. The
-    host has refused every index outside the table (registry.index_grid),
-    so the gather promises the compiler what is true. One chip: there is
-    no meshed build of this program, a mesh keeps to the packed grid."""
-    pk_x = table_x.at[pk_idx].get(mode="promise_in_bounds")
-    pk_y = table_y.at[pk_idx].get(mode="promise_in_bounds")
-    return _stage_prepare(pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits,
-                          set_mask)
+    the identity by its mask, as a zero slot of the packed grid is. One
+    chip: there is no meshed build of this program, a mesh keeps to the
+    packed grid."""
+    return _stage_prepare(*_gather_rows(table_x, table_y, pk_idx), pk_mask,
+                          sig_x, sig_y, z_digits, set_mask)
+
+
+def _stage_prepare_indexed_grids(table_x, table_y, wide_idx, wide_mask,
+                                 narrow_idx, narrow_mask, where,
+                                 sig_x, sig_y, z_digits, set_mask):
+    """`_stage_prepare_grids` with each grid's rows gathered from the
+    registry table, as `_stage_prepare_indexed` is to `_stage_prepare`."""
+    return _stage_prepare_grids(
+        *_gather_rows(table_x, table_y, wide_idx), wide_mask,
+        *_gather_rows(table_x, table_y, narrow_idx), narrow_mask, where,
+        sig_x, sig_y, z_digits, set_mask)
 
 
 def _stage_pairs(z_pk, h_jac, sig_acc, set_mask):
@@ -367,6 +487,8 @@ STAGE_DONATE_ARGNUMS = dict(
     miller=(0, 1, 2, 3, 4), final_exp=(0,),
     # the packed prepare's three, one place on: never the table
     prepare_indexed=(4, 5, 6),
+    # and behind two grids and `where`: never a key grid nor the table
+    prepare_grids=(7, 8, 9), prepare_indexed_grids=(7, 8, 9),
 )
 
 
@@ -644,25 +766,36 @@ def _get_stages(mesh=None):
     return _kernel_cache[key]
 
 
-def _get_prepare_indexed():
-    """Stage 1 of the indexed path, jitted: `_stage_prepare_indexed` for
-    one chip (the batch lane of a process without a mesh), under the
-    donation mode of `_get_stages`. The fifth stage-1 program a node may
-    serve beside the packed prepare of its buckets."""
+#: stage 1 as one chip serves it beside `_get_stages()[0]`, by the name its
+#: donation goes under in STAGE_DONATE_ARGNUMS
+_PREPARE_VARIANTS = dict(
+    prepare_indexed=_stage_prepare_indexed,
+    prepare_grids=_stage_prepare_grids,
+    prepare_indexed_grids=_stage_prepare_indexed_grids,
+)
+
+
+def _get_prepare_variant(stage: str):
+    """One of `_PREPARE_VARIANTS`, jitted for one chip (the batch lane of a
+    process without a mesh), under the donation mode of `_get_stages`:
+    the keys by index from the registry table, the keys as the two grids
+    of key_grid_plan, or both. With the packed one-grid prepare of
+    `_get_stages` the four stage-1 programs a node may serve at a bucket;
+    the dispatch's own keys say which."""
     import jax
 
     from . import pipeline as pl
 
     _init_consts()
     donate = pl.donation_enabled()[0]
-    key = f"prepare_indexed_d{int(donate)}"
+    key = f"{stage}_d{int(donate)}"
     if key not in _kernel_cache:
         from ...utils.jaxcfg import setup_compilation_cache
 
         setup_compilation_cache()
         _kernel_cache[key] = jax.jit(
-            _stage_prepare_indexed,
-            **(dict(donate_argnums=STAGE_DONATE_ARGNUMS["prepare_indexed"])
+            _PREPARE_VARIANTS[stage],
+            **(dict(donate_argnums=STAGE_DONATE_ARGNUMS[stage])
                if donate else {}),
         )
     return _kernel_cache[key]
@@ -678,7 +811,11 @@ def warm_stages(n_sets: int, n_pks: int, single_chip: bool = False) -> None:
     largest programs instead of their sum (the r4 multichip dryrun timed
     out in sequential XLA:CPU stage compiles — ~3 min for prepare alone).
     Stages 3/4 take stage OUTPUTS as inputs (shardings chosen by XLA), so
-    they still compile on first real dispatch.
+    they still compile on first real dispatch. Prepare is warmed over the
+    ONE (n, m) key grid, which a batch of near-equal widths, the urgent
+    lane and a mesh run; a one-chip batch of unequal widths (a block, a
+    dispatch of aggregates) runs the two-grid prepare of its own
+    key_grid_plan and compiles it at its first dispatch.
 
     Callers: the node's startup warmup thread walks the autotune plan's
     bucket list through here (autotune/runtime.start_warmup — which also
@@ -753,16 +890,17 @@ def warm_prepare_indexed(n_sets: int, n_pks: int, table) -> None:
     ONE chip whose chain keeps its registry on the device: there every set
     the chain's builders make names rows of the table, so gossip batches
     and blocks alike take this program at their own buckets, not the packed
-    prepare `warm_stages` compiles. The table's capacity is part of the
-    program's shape: a registry that outgrows it (a `registry.ROW_CHUNK` of
-    deposits) compiles anew at its next dispatch."""
+    prepare `warm_stages` compiles — over one (n, m) grid here as there;
+    a block's two grids compile at its first dispatch. The table's capacity
+    is part of the program's shape: a registry that outgrows it (a
+    `registry.ROW_CHUNK` of deposits) compiles anew at its next dispatch."""
     import jax
 
     from ...parallel import put_single
 
     n, m = padding_bucket(n_sets, n_pks, single_chip=True)
     table_x, table_y, _ = table.snapshot()
-    jax.block_until_ready(_get_prepare_indexed()(
+    jax.block_until_ready(_get_prepare_variant("prepare_indexed")(
         table_x, table_y,
         put_single(np.zeros((n, m), np.int32)),
         put_single(np.ones((n, m), np.uint32)),
@@ -888,10 +1026,11 @@ class JaxBackend:
 
     # -- the multi-set hot path ------------------------------------------
 
-    def _marshal_indices(self, sets, n: int, m: int, real_keys: int):
+    def _marshal_indices(self, sets, plan, real_keys: int):
         """The key side of a dispatch as registry rows: (table_x, table_y,
-        idx, mask) on the device, the index grid int32[n, m] and its mask
-        in place of `_marshal_pubkeys`' limb grid (2 x 2 MB where that is
+        then per grid of `plan` (key_grid_plan) idx int32[rows, width] and
+        its mask, then `where` if there are two) on the device, in place of
+        `_marshal_pubkeys`' limb grids (2 x 2 MB where one limb grid is
         100 MB at 16 x 32,768). Chosen by the data alone: None — the batch
         packs its keys — unless a table is installed and EVERY set carries
         indices into that very table (`signing_registry`: a set built
@@ -907,15 +1046,20 @@ class JaxBackend:
         from ...parallel import put_single
 
         with _obs.span("jaxbls:marshal.indices", keys=real_keys) as packed:
-            grid = table.index_grid(sets, n, m, rows)
-            if grid is None:
+            grids = table.index_grid(sets, plan, rows)
+            if grids is None:
                 return False
-            packed.args["bytes"] = grid[0].nbytes + grid[1].nbytes
-            idx, mask = put_single(grid[0]), put_single(grid[1])
-        return table_x, table_y, idx, mask
+            packed.args["bytes"] = sum(g.nbytes for g in grids)
+            if plan[1] is not None:
+                grids += (plan[1],)
+            grids = tuple(put_single(g) for g in grids)
+        return (table_x, table_y) + grids
 
-    def _marshal_pubkeys(self, sets, n: int, m: int, single_chip: bool = False):
-        """(n, m, NL) standard-form limb arrays for all signing keys.
+    def _marshal_pubkeys(self, sets, plan, single_chip: bool = False):
+        """Standard-form limb arrays for all signing keys, as the grids of
+        `plan` (key_grid_plan; `one_key_grid(n, m)` for the (n, m) grid):
+        per grid x and y (rows, width, NL) and the mask (rows, width), then
+        `where` if there are two — stage 1's key arguments, in its order.
 
         Cached on device keyed by the identity of the pubkey objects — the
         steady-state path (gossip firehose over a known validator registry)
@@ -935,9 +1079,10 @@ class JaxBackend:
                 lane = mesh_shape_key()
             # fingerprint covers the set grouping, not just the flat key
             # sequence: the same keys split differently must not reuse
-            # another layout's aggregation mask
+            # another layout's aggregation mask — nor the urgent lane's one
+            # grid serve the batch lane's two (the grids are in the key)
             fp = (
-                lane,
+                lane, plan[0],
                 tuple(len(s.signing_keys) for s in sets),
                 tuple(id(pk) for s in sets for pk in s.signing_keys),
             )
@@ -945,38 +1090,43 @@ class JaxBackend:
             packed.args["hit"] = int(hit is not None)
             if hit is not None:
                 _PK_CACHE.labels("hit").inc()
-                return hit[0], hit[1], hit[2]
+                return hit[:-1]
             _PK_CACHE.labels("miss").inc()
 
-            pk_x = np.zeros((n, m, lb.NL), np.uint32)
-            pk_y = np.zeros((n, m, lb.NL), np.uint32)
-            pk_mask = np.zeros((n, m), np.uint32)
-            for i, s in enumerate(sets):
+            grids = [
+                (np.zeros(g + (lb.NL,), np.uint32),
+                 np.zeros(g + (lb.NL,), np.uint32), np.zeros(g, np.uint32))
+                for g in plan[0]
+            ]
+            for (g, row), s in zip(_grid_rows(plan, len(sets)), sets):
                 keys = s.signing_keys
-                xs = pack_ints_vec([pk.point[0] for pk in keys])
-                ys = pack_ints_vec([pk.point[1] for pk in keys])
-                pk_x[i, : len(keys)] = xs
-                pk_y[i, : len(keys)] = ys
-                pk_mask[i, : len(keys)] = 1
-            nbytes = pk_x.nbytes + pk_y.nbytes + pk_mask.nbytes
+                pk_x, pk_y, pk_mask = grids[g]
+                pk_x[row, : len(keys)] = pack_ints_vec([pk.point[0] for pk in keys])
+                pk_y[row, : len(keys)] = pack_ints_vec([pk.point[1] for pk in keys])
+                pk_mask[row, : len(keys)] = 1
+            arrays = [a for grid in grids for a in grid]
+            nbytes = sum(a.nbytes for a in arrays)
             packed.args["bytes"] = nbytes
+            if plan[1] is not None:
+                arrays.append(plan[1])
         from ...parallel import put_pk_grid, put_single
 
-        # (n, m, ...) pubkey arrays: set axis sharded; on a 2-D mesh the
-        # pubkey axis is sharded too (within-set aggregation parallelism).
-        # Urgent single-chip batches place whole on one device instead.
+        # (rows, width, ...) pubkey arrays: set axis sharded; on a 2-D mesh
+        # the pubkey axis is sharded too (within-set aggregation
+        # parallelism). Urgent single-chip batches place whole on one
+        # device instead, and so do the two grids (never laid over a mesh).
         put = put_single if single_chip else put_pk_grid
         with _obs.span("jaxbls:marshal.pubkeys_upload", bytes=nbytes):
-            dx, dy, dm = put(pk_x), put(pk_y), put(pk_mask)
+            placed = tuple(put(a) for a in arrays)
             # keep strong refs to the key objects so ids stay valid while
             # cached; the oldest grid leaves the cache, and the device, here
             keepalive = (fp, [pk for s in sets for pk in s.signing_keys])
-            self._pk_cache[fp] = (dx, dy, dm, keepalive)
+            self._pk_cache[fp] = placed + (keepalive,)
             self._pk_cache_order.append(fp)
             if len(self._pk_cache_order) > 8:
                 old = self._pk_cache_order.pop(0)
                 self._pk_cache.pop(old, None)
-        return dx, dy, dm
+        return placed
 
     def verify_signature_sets_async(self, sets, rands, urgent: bool = False):
         """Marshal + submit one batch through the pipelined executor.
@@ -1010,10 +1160,17 @@ class JaxBackend:
             # device mesh (multi-chip: sets are data-parallel over the mesh,
             # the cross-set reductions become collectives — parallel/mesh.py);
             # the urgent lane keeps plain pow2 buckets on one chip
+            widths = [len(s.signing_keys) for s in sets]
             n, m = padding_bucket(
-                n_real, max(len(s.signing_keys) for s in sets),
-                mesh=mesh, single_chip=single_chip,
+                n_real, max(widths), mesh=mesh, single_chip=single_chip,
             )
+            # the bucket is the dispatch's NAME (the handle's, the trace's,
+            # the autotune keys'); how its keys lie is the plan's: one
+            # (n, m) grid on the urgent lane, over a mesh and for near-equal
+            # widths, else a wide grid and a narrow one
+            plan = (one_key_grid(n, m) if urgent or mesh is not None
+                    else key_grid_plan(widths, n, m))
+            grids = plan[0]
             # three truthful lanes: urgent bypass (pinned to one chip), meshed
             # batch, and ordinary batch on a mesh-less node — a dashboard must
             # never read single-device batch traffic as urgent-path activity
@@ -1021,15 +1178,17 @@ class JaxBackend:
                 "urgent" if urgent else ("sharded" if mesh is not None
                                          else "single_device")
             ).inc()
-            real_keys = sum(len(s.signing_keys) for s in sets)
+            real_keys = sum(widths)
             _BUCKET_SLOTS.labels("sets", "real").inc(n_real)
             _BUCKET_SLOTS.labels("sets", "padded").inc(n)
             _BUCKET_SLOTS.labels("keys", "real").inc(real_keys)
-            _BUCKET_SLOTS.labels("keys", "padded").inc(n * m)
+            _BUCKET_SLOTS.labels("keys", "padded").inc(
+                sum(rows * width for rows, width in grids))
             distinct_messages = len({s.message for s in sets})
             _DISPATCH_MESSAGES.labels("sent").inc(n_real)
             _DISPATCH_MESSAGES.labels("distinct").inc(distinct_messages)
-            _TREE_SUM_LANE_ADDS.labels("done").inc(co.tree_sum_plan(m, n)[3])
+            _TREE_SUM_LANE_ADDS.labels("done").inc(
+                sum(co.tree_sum_plan(width, rows)[3] for rows, width in grids))
             _TREE_SUM_LANE_ADDS.labels("needed").inc(real_keys - n_real)
             miller_pairs = n + 1
             if isinstance(pairing_stage, _PairingDispatch):
@@ -1038,19 +1197,25 @@ class JaxBackend:
 
             # the keys: registry rows gathered on the device where the data
             # allows it (one chip's batch lane; a mesh keeps the packed
-            # grid), else their coordinates packed into the (n, m) grid
+            # grid), else their coordinates packed into the plan's grids
             indexed = (None if urgent or mesh is not None
-                       else self._marshal_indices(sets, n, m, real_keys))
+                       else self._marshal_indices(sets, plan, real_keys))
             if indexed is False:
                 return VerifyHandle(hostfail=True)  # a row the table lacks
             if indexed is None:
                 _REGISTRY_KEYS.labels("packed").inc(real_keys)
                 keys_in = self._marshal_pubkeys(
-                    sets, n, m, single_chip=single_chip
+                    sets, plan, single_chip=single_chip
                 )
             else:
                 _REGISTRY_KEYS.labels("table").inc(real_keys)
-                prepare, keys_in = _get_prepare_indexed(), indexed
+                keys_in = indexed
+            # stage 1 by what was laid: `prepare` stays the stages' own for
+            # one packed grid, the program every lane ran before the plan
+            variant = ("prepare" + ("" if indexed is None else "_indexed")
+                       + ("_grids" if len(grids) == 2 else ""))
+            if variant != "prepare":
+                prepare = _get_prepare_variant(variant)
 
             with _obs.span("jaxbls:marshal.sigs"):
                 sig_x = np.zeros((n, 2, lb.NL), np.uint32)
@@ -1093,7 +1258,9 @@ class JaxBackend:
         _MARSHAL_SECONDS.observe(marshalled.t1 - marshalled.t0)
         tr = _obs.current_trace()
         if tr is not None:
-            tr.annotate(bucket=f"{n}x{m}", real_sets=n_real,
+            tr.annotate(bucket=f"{n}x{m}",
+                        key_grids="+".join(f"{r}x{w}" for r, w in grids),
+                        real_sets=n_real,
                         real_keys=real_keys,
                         distinct_messages=distinct_messages)
 

@@ -11,8 +11,10 @@ device arrays
                                   makes them; row i = validator i's
                                   decompressed key, rows >= `len` zero
 
-and a dispatch uploads an (n, m) int32 index grid and its mask; stage 1
-gathers the rows (`backend._stage_prepare_indexed`).
+and a dispatch uploads an int32 index grid and its mask for each key grid
+it lays (`backend.key_grid_plan`: one (n, m) grid, or a wide and a narrow
+one); stage 1 gathers the rows (`backend._stage_prepare_indexed`,
+`_stage_prepare_indexed_grids`).
 
 Fed by `chain/pubkey_cache.py` `ValidatorPubkeyCache`: every key the cache
 takes in is appended here before `import_new_pubkeys` returns. A host
@@ -51,7 +53,7 @@ import numpy as np
 from ...observability import trace as _obs
 from ...utils.metrics import REGISTRY
 from . import limbs as lb
-from .backend import _next_pow2, pack_ints_vec
+from .backend import _grid_rows, _next_pow2, pack_ints_vec
 
 #: rows the capacity is rounded to, and the room kept above the registry
 ROW_CHUNK = 65_536
@@ -151,20 +153,23 @@ class PubkeyTable:
 
     # ------------------------------------------------------ the marshal
 
-    def index_grid(self, sets, n: int, m: int, rows: int):
-        """(idx int32[n, m], mask uint32[n, m]) of the sets' signing
-        indices against a table of `rows` rows, or None — refused and
-        counted — when one of them names a row outside [0, rows)."""
-        idx = np.zeros((n, m), np.int32)
-        mask = np.zeros((n, m), np.uint32)
-        for i, s in enumerate(sets):
+    def index_grid(self, sets, plan, rows: int):
+        """Per grid of `plan` (backend.key_grid_plan; `one_key_grid(n, m)`
+        for the (n, m) grid) idx int32[rows, width] and mask uint32[rows,
+        width] of the sets' signing indices against a table of `rows`
+        rows, as one flat tuple in stage 1's order — or None, refused and
+        counted, when one of them names a row outside [0, rows)."""
+        grids = [(np.zeros(g, np.int32), np.zeros(g, np.uint32))
+                 for g in plan[0]]
+        for (g, row), s in zip(_grid_rows(plan, len(sets)), sets):
             ind = s.signing_indices
             if int(ind.min()) < 0 or int(ind.max()) >= rows:
                 REFUSED.inc(int(np.count_nonzero((ind < 0) | (ind >= rows))))
                 return None
-            idx[i, :len(ind)] = ind
-            mask[i, :len(ind)] = 1
-        return idx, mask
+            idx, mask = grids[g]
+            idx[row, :len(ind)] = ind
+            mask[row, :len(ind)] = 1
+        return tuple(a for grid in grids for a in grid)
 
     # ------------------------------------------------------- the checks
 

@@ -72,7 +72,7 @@ def main():
     n = pad_sets(max(be.MIN_SETS, be._next_pow2(1)))
     m = pad_pks(max(be.MIN_PKS, be._next_pow2(len(s.signing_keys))))
     print(f"bisecting at bucket n={n} m={m}", flush=True)
-    pk_x, pk_y, pk_mask = backend._marshal_pubkeys([s], n, m)
+    pk_x, pk_y, pk_mask = backend._marshal_pubkeys([s], be.one_key_grid(n, m))
     sig_x = np.zeros((n, 2, lb.NL), np.uint32)
     sig_y = np.zeros((n, 2, lb.NL), np.uint32)
     z_digits = np.zeros((n, be.Z_DIGITS), np.uint32)

@@ -64,11 +64,11 @@ _LONGEST_FIRST = (
     "test_ef_vectors.py",               # 738
     "test_jaxbls_backend.py",           # 622
     "test_multichip.py",                # 568
+    "test_jaxbls_registry.py",          # ~500 alone (300 at PR 41: five one-device programs; eight since PR 42)
     "test_multichip_2d.py",             # 380 (1 test: takes the 7th along)
     "test_jaxbls_pairing.py",           # 369
-    "test_kzg.py",                      # 297 alone (PR 33: the blob batches)
     "test_fleet.py",                    # 183 (seventh: the short one)
-    "test_jaxbls_registry.py",          # 300 alone (PR 41: five one-device programs)
+    "test_kzg.py",                      # 297 alone (PR 33: the blob batches)
     "test_beacon_chain.py",             # 250
     "test_jaxbls_h2c.py",               # 167
     "test_jaxbls_msm.py",               # 123

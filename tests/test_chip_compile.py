@@ -20,7 +20,10 @@ Miller loop ~2 min and the final exponentiation ~1.25 min on eight host
 cores), the Miller loop of the urgent 4x128 bucket, whose 5 pairs a
 program built for a TPU pads to one row of 128 lanes, and the indexed
 prepare of the Electra block's 16x32768 bucket over the 1,114,112-row
-registry table (`_stage_prepare_indexed`, minutes).
+registry table (`_stage_prepare_indexed`, minutes), and the two-grid
+prepares (`backend.key_grid_plan`) that the three cells of unequal widths
+serve since PR 42: the Electra block's 8x32768 + 4x512 by index, the Deneb
+block's 1x512 + 256x128 and the aggregates' 64x512 + 128x1 packed.
 """
 
 import numpy as np
@@ -102,6 +105,36 @@ def _stage_args(n: int, m: int, sharding) -> dict:
     }
 
 
+#: the served dispatches of unequal widths (BENCHMARK.json's three such
+#: cells): bucket, key counts, and the stage-1 program that serves them
+MIXED_DISPATCHES = {
+    "electra_block": ((16, 32768), [1, 1] + [32_400] * 8 + [512],
+                      "prepare_indexed_grids"),
+    "deneb_block": ((256, 512), [1, 1] + [128] * 128 + [512],
+                    "prepare_grids"),
+    "aggregates": ((256, 512), [1] * 128 + [480] * 64, "prepare_grids"),
+}
+
+
+def _grids_args(stage: str, n: int, m: int, widths, sharding) -> tuple:
+    """Argument shapes of a two-grid prepare for a dispatch of `widths`,
+    laid as `backend.key_grid_plan` lays it."""
+    NL = lb.NL
+
+    def arr(shape, dtype=jnp.uint32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    grids, where = be.key_grid_plan(widths, n, m)
+    if stage == "prepare_indexed_grids":
+        keys = (arr((TABLE_ROWS, NL)), arr((TABLE_ROWS, NL))) + tuple(
+            a for g in grids for a in (arr(g, jnp.int32), arr(g)))
+    else:
+        keys = tuple(a for g in grids
+                     for a in (arr(g + (NL,)), arr(g + (NL,)), arr(g)))
+    return keys + (arr(where.shape, jnp.int32), arr((n, 2, NL)),
+                   arr((n, 2, NL)), arr((n, be.Z_DIGITS)), arr((n,)))
+
+
 def _stage_miller_for_a_tpu(px, py, qxx, qyy, pair_mask):
     """`backend._stage_miller` as a process on the chip lowers it. The
     Miller loop's lane plan reads the platform off the process, which is
@@ -166,6 +199,26 @@ def test_stage_compiles_for_v5e(stage, bucket, one_chip,
         assert out == _shapes(args["miller"])
     elif stage == "miller":
         assert out == _shapes(args["final_exp"][0])
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("dispatch", list(MIXED_DISPATCHES))
+def test_two_grid_prepare_compiles_for_v5e(dispatch, one_chip,
+                                           no_persistent_cache):
+    """Stage 1 over a wide and a narrow key grid, as the TPU node jits it
+    for each served dispatch of unequal widths: it compiles, fits the
+    chip, and hands stage 3 what the one-grid prepare hands it."""
+    (n, m), widths, stage = MIXED_DISPATCHES[dispatch]
+    be._init_consts()
+    fn = be._PREPARE_VARIANTS[stage]
+    args = _grids_args(stage, n, m, widths, one_chip)
+    compiled = jax.jit(
+        fn, donate_argnums=be.STAGE_DONATE_ARGNUMS[stage]
+    ).lower(*args).compile()
+    _assert_fits_hbm(compiled)
+    pairs = _stage_args(n, m, one_chip)["pairs"]
+    assert _shapes(jax.eval_shape(fn, *args))[:2] == _shapes(
+        (pairs[0], pairs[2]))
 
 
 def test_tree_hash_ladder_compiles_for_v5e(one_chip, no_persistent_cache):

@@ -306,6 +306,11 @@ def test_block_shaped_batch_through_signature_batch_parity(damaged):
     assert on_python is (damaged is None)
     assert on_jax is on_python
     assert be.padding_bucket(7, 4) == (8, 4)
+    # the slots LAID, which over this module's eight-device mesh are the one
+    # (8, 4) grid's 32: a mesh keeps it. One chip's batch lane would lay
+    # these widths as 1 x 4 + 8 x 2 = 20 slots and add 3 + 8 times
+    # (tests/test_jaxbls_registry.py drives that lane)
+    assert be.key_grid_plan(list(_BLOCK_WIDTHS), 8, 4)[0] == ((1, 4), (8, 2))
     # (8, 4): four keys a set sum unrolled, 3 adds on each of 8 set lanes;
     # 14 real keys in 7 sets need 7
     assert be.co.tree_sum_plan(4, 8) == (4, 0, 2, 24)

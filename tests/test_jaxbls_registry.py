@@ -1,13 +1,15 @@
 """The registry table on the device (crypto/jaxbls/registry.py), the indices
 a SignatureSet carries, what feeds both, and the indexed path of the jax
 backend's batch lane against the packed one and against the pure-Python
-backend. The first half compiles no stage program; the second (from "keys
-by validator index" on) drives the real staged backend on ONE device at the
-(4, 4) bucket, the unsharded programs compiled once by a module fixture
-(five programs: test_jaxbls_backend.py is at its memory-mapping mark with
-its eight-device builds, so these tests have a file of their own). The
-reference is the pure-Python curve code on keys decompressed from their
-48-byte form."""
+backend; and the key grids that lane lays (backend.key_grid_plan): the
+plan as a pure function, and a wide and a narrow grid against the one grid
+and the pure-Python backend. The first half compiles no stage program; the
+second (from "keys by validator index" on) drives the real staged backend
+on ONE device at the (4, 4) bucket, the unsharded programs compiled once by
+a module fixture (eight programs: test_jaxbls_backend.py is at its
+memory-mapping mark with its eight-device builds, whose mesh keeps the one
+grid, so these tests have a file of their own). The reference is the
+pure-Python curve code on keys decompressed from their 48-byte form."""
 
 import hashlib
 import random
@@ -23,12 +25,14 @@ from lighthouse_tpu.crypto.bls381 import curve as cv
 from lighthouse_tpu.crypto.bls381 import serde
 from lighthouse_tpu.crypto.bls381.constants import R
 from lighthouse_tpu.crypto.jaxbls import registry as reg
+from lighthouse_tpu.crypto.jaxbls.backend import one_key_grid
 from lighthouse_tpu.observability import trace as obstrace
 from lighthouse_tpu.utils.metrics import REGISTRY
 
 rng = random.Random(0x7AB1E)
 KEYS = [bls.SecretKey(rng.randrange(1, R)).public_key() for _ in range(16)]
 BYTES = [pk.serialize() for pk in KEYS]
+ONE_GRID = one_key_grid(4, 4)     # the (4, 4) bucket's keys as one grid
 
 
 def _reference_points(key_bytes):
@@ -78,6 +82,67 @@ def one_chip_backend(monkeypatch):
         bls_api.set_backend("python")
         monkeypatch.undo()
         parallel.reset_mesh_cache()
+
+
+# ------------------------------------------------------- the key grids
+# backend.key_grid_plan: how a one-chip dispatch's keys lie, from the key
+# counts of its sets. Pure; the cells' width lists are BENCHMARK.json's.
+
+_ELECTRA = [1, 1, 32_093, 32_752, 32_400, 32_511, 32_601, 32_333, 32_700,
+            32_204, 512]
+_DENEB = [1, 1] + [128] * 128 + [512]
+_AGGREGATES = [1] * 64 + [1] * 64 + [448 + i for i in range(64)]
+
+
+@pytest.mark.parametrize("widths,bucket,wide,narrow,slots,lane_adds", [
+    (_ELECTRA, (16, 32_768), (8, 32_768), (4, 512), 264_192, 294_912),
+    # whatever the participation: the same grids, so the same program
+    ([1, 1] + [16_385] * 8 + [512], (16, 32_768), (8, 32_768), (4, 512),
+     264_192, 294_912),
+    (_DENEB, (256, 512), (1, 512), (256, 128), 33_280, 41_472),
+    (_AGGREGATES, (256, 512), (64, 512), (128, 1), 32_896, 40_960),
+    ([128] * 64, (64, 128), (64, 128), None, 8_192, 16_384),     # gossip
+    ([128], (4, 128), (4, 128), None, 512, 3_584),               # urgent
+    ([1] * 7, (8, 1), (8, 1), None, 8, 0),                       # m = 1
+    ([3, 4, 4, 3], (4, 4), (4, 4), None, 16, 12),                # uniform
+    # the 3/4 rule, both sides of its edge in the (4, 4) bucket of 16
+    ([2, 2, 2, 4], (4, 4), (1, 4), (4, 2), 12, 7),               # 12 of 16
+    ([1, 4, 4, 4], (4, 4), (4, 4), None, 16, 12),                # 17 > 12
+    ([1, 1, 4, 3], (4, 4), (2, 4), (2, 1), 10, 6),
+    # the cheapest t of several that split: t = 4 (32), not t = 1 (34)
+    ([1, 1, 3, 16], (4, 16), (1, 16), (4, 4), 32, 76),
+], ids=["electra", "electra_thin", "deneb", "aggregates", "gossip", "urgent",
+        "one_key_sets", "uniform", "edge_at", "edge_over", "block_by_index",
+        "cheapest"])
+def test_key_grid_plan(widths, bucket, wide, narrow, slots, lane_adds):
+    from lighthouse_tpu.crypto.jaxbls import backend as be
+
+    n, m = bucket
+    assert be.padding_bucket(len(widths), max(widths), single_chip=True) == bucket
+    grids, where = be.key_grid_plan(widths, n, m)
+    assert grids == ((wide,) if narrow is None else (wide, narrow))
+    assert sum(rows * width for rows, width in grids) == slots
+    assert sum(be.co.tree_sum_plan(width, rows)[3]
+               for rows, width in grids) == lane_adds
+    if narrow is None:
+        assert where is None and wide == bucket
+        assert be.key_grid_plan(widths, n, m) == be.one_key_grid(n, m)
+        assert be._grid_rows(be.one_key_grid(n, m), len(widths)) == [
+            (0, i) for i in range(len(widths))]
+        return
+    # every set has one row of a grid wide enough for it, rows are used
+    # once and in the sets' order; padded set slots read the identity,
+    # the entry behind both grids' sums (n_w + n_n may exceed n: Deneb)
+    assert where.dtype == np.int32 and where.shape == (n,)
+    placed = be._grid_rows((grids, where), len(widths))
+    assert len(set(placed)) == len(widths)
+    for g in (0, 1):
+        rows = [row for grid, row in placed if grid == g]
+        assert rows == list(range(len(rows))) and len(rows) <= grids[g][0]
+    assert all(w <= grids[g][1] for w, (g, _) in zip(widths, placed))
+    assert narrow[1] < wide[1] == m
+    assert (where[len(widths):] == wide[0] + narrow[0]).all()
+    assert 4 * slots <= 3 * n * m
 
 
 def test_rows_equal_the_reference_after_build_append_and_growth():
@@ -131,13 +196,13 @@ def test_an_index_outside_the_table_is_refused_and_counted(bad):
     table.append(KEYS[:8])
     sig = bls.Signature(bls_api.hash_to_g2_point(b"\x01" * 32))
     ok = bls.SignatureSet(sig, KEYS[:3], b"\x01" * 32, signing_indices=[0, 1, 7])
-    idx, mask = table.index_grid([ok], 4, 4, 8)
+    idx, mask = table.index_grid([ok], ONE_GRID, 8)
     assert idx.dtype == np.int32 and idx.tolist()[0] == [0, 1, 7, 0]
     assert mask.tolist()[0] == [1, 1, 1, 0] and not mask[1:].any()
     before = _value("jaxbls_registry_refused_total")
     named = bls.SignatureSet(sig, KEYS[:2], b"\x01" * 32,
                              signing_indices=[2, bad])
-    assert table.index_grid([ok, named], 4, 4, 8) is None
+    assert table.index_grid([ok, named], ONE_GRID, 8) is None
     assert _value("jaxbls_registry_refused_total") == before + 1
     # the capacity is not the limit: a spare row is refused like any other
     assert table.capacity == 16
@@ -175,7 +240,7 @@ def test_the_path_is_chosen_by_the_data(one_chip_backend):
 
     elsewhere = ValidatorPubkeyCache(table=reg.PubkeyTable())
     elsewhere.import_new_pubkeys(_state(8))
-    assert backend._marshal_indices([named(elsewhere)], 4, 4, 3) is None
+    assert backend._marshal_indices([named(elsewhere)], ONE_GRID, 3) is None
     table = backend.install_registry()
     assert backend.registry is table and len(table) == 0
     cache = ValidatorPubkeyCache(table=table)
@@ -188,11 +253,11 @@ def test_the_path_is_chosen_by_the_data(one_chip_backend):
     unowned = bls.SignatureSet(sig, mine.signing_keys, b"\x03" * 32,
                                signing_indices=[1, 5, 2])
     for other in (bare, theirs, unowned):
-        assert backend._marshal_indices([mine, other], 4, 4, 6) is None
+        assert backend._marshal_indices([mine, other], ONE_GRID, 6) is None
     tr = obstrace.Trace("gossip_block", 1)
     obstrace.set_current_trace(tr)
     try:
-        tx, ty, idx, mask = backend._marshal_indices([mine, mine], 4, 4, 6)
+        tx, ty, idx, mask = backend._marshal_indices([mine, mine], ONE_GRID, 6)
     finally:
         obstrace.set_current_trace(None)
     assert tx is table.x and ty is table.y
@@ -498,11 +563,21 @@ def test_the_benchmarks_reference_is_its_own_and_agrees(what):
 _TABLE_ROWS = 24      # 12 validators, headroom 8: the table's capacity
 
 
+#: the key grids the batches below lay (backend.key_grid_plan) in the
+#: (4, 4) bucket: the block by index, widths (1, 1, 4, 3), and a batch whose
+#: narrow grid is wide enough for a set to sum to the identity, (1, 2, 2, 4)
+_BLOCK_GRIDS = ((2, 4), (2, 1))
+_PAIR_GRIDS = ((1, 4), (4, 2))
+
+
 @pytest.fixture(scope="module")
 def _one_device_programs():
-    """The five programs the tests below dispatch, compiled side by side:
-    the unsharded four stages at 4 sets with the packed prepare at m = 4,
-    and the indexed prepare at (4, 4) over the 24-row table."""
+    """The eight programs the tests below dispatch, compiled side by side
+    in three threads: the unsharded four stages at 4 sets with the packed
+    prepare at m = 4; the indexed prepares at (4, 4) over the 24-row
+    table, over one grid and over the block's two; the packed two-grid
+    prepares, over the block's grids and over `_PAIR_GRIDS`. The first is
+    the longest."""
     import functools
 
     import jax
@@ -510,19 +585,39 @@ def _one_device_programs():
 
     import lighthouse_tpu.crypto.jaxbls.backend as be
     from lighthouse_tpu.crypto.jaxbls import limbs as lb
+    from lighthouse_tpu.parallel import put_single
 
     def limbs(*shape):
         return np.zeros(shape + (lb.NL,), np.uint32)
 
-    def warm_indexed():
-        jax.block_until_ready(be._get_prepare_indexed()(
-            limbs(_TABLE_ROWS), limbs(_TABLE_ROWS),
-            np.zeros((4, 4), np.int32), np.ones((4, 4), np.uint32),
-            limbs(4, 2), limbs(4, 2), np.ones((4, be.Z_DIGITS), np.uint32),
-            np.ones((4,), np.uint32)))
+    def ones(*shape):
+        return put_single(np.ones(shape, np.uint32))
 
-    run_in_threads(functools.partial(warm_build, 4, (4,), None), warm_indexed)
+    def warm_prepares(*programs):
+        # placed as the marshal places them (the table as `append` does)
+        table = (jax.device_put(limbs(_TABLE_ROWS)),
+                 jax.device_put(limbs(_TABLE_ROWS)))
+        for stage, grids in programs:
+            if "indexed" in stage:
+                keys = table + tuple(
+                    a for g in grids
+                    for a in (put_single(np.zeros(g, np.int32)), ones(*g)))
+            else:
+                keys = tuple(
+                    put_single(a) for g in grids
+                    for a in (limbs(*g), limbs(*g), np.ones(g, np.uint32)))
+            if len(grids) == 2:
+                keys += (put_single(np.zeros((4,), np.int32)),)
+            jax.block_until_ready(be._get_prepare_variant(stage)(
+                *keys, put_single(limbs(4, 2)), put_single(limbs(4, 2)),
+                ones(4, be.Z_DIGITS), ones(4)))
 
+    run_in_threads(
+        functools.partial(warm_build, 4, (4,), None),
+        functools.partial(warm_prepares, ("prepare_indexed", ((4, 4),)),
+                          ("prepare_indexed_grids", _BLOCK_GRIDS)),
+        functools.partial(warm_prepares, ("prepare_grids", _BLOCK_GRIDS),
+                          ("prepare_grids", _PAIR_GRIDS)))
 
 
 @pytest.fixture()
@@ -592,6 +687,26 @@ def _keys_taken():
     return {s: be._REGISTRY_KEYS.labels(s).value for s in ("table", "packed")}
 
 
+_ZS = [3, 0xDEADBEEF12345677, 0x42, 2**63 + 9]
+
+
+def _prepare_rest(sets, n_real=4):
+    """Stage 1's arguments behind the keys for up to four sets at n = 4:
+    (sig_x, sig_y, z_digits, set_mask), the coefficients `_ZS`."""
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+    from lighthouse_tpu.crypto.jaxbls import curve_ops as co
+
+    sig_x = np.zeros((4, 2, 24), np.uint32)
+    sig_y = np.zeros((4, 2, 24), np.uint32)
+    for i, s in enumerate(sets):
+        (x0, x1), (y0, y1) = s.signature.point
+        sig_x[i] = be.pack_ints_vec([x0, x1])
+        sig_y[i] = be.pack_ints_vec([y0, y1])
+    z = co.scalars_to_digits(_ZS, 64, be.Z_WINDOW)[:, :be.Z_DIGITS]
+    set_mask = np.array([1] * n_real + [0] * (4 - n_real), np.uint32)
+    return sig_x, sig_y, np.asarray(z, np.uint32), set_mask
+
+
 def test_indexed_prepare_is_bit_equal_to_the_packed_prepare(registry_chain):
     """`_stage_prepare_indexed` over the table and `_stage_prepare` over
     the packed grid of the same keys: z_pk, sig_acc and bad_aggpk equal
@@ -600,25 +715,16 @@ def test_indexed_prepare_is_bit_equal_to_the_packed_prepare(registry_chain):
     import numpy as np
 
     import lighthouse_tpu.crypto.jaxbls.backend as be
-    from lighthouse_tpu.crypto.jaxbls import curve_ops as co
 
     backend, cache, sks = registry_chain
     sets = _block_by_index(cache, sks)
-    idx, mask = backend.registry.index_grid(sets, 4, 4, 12)
-    pk_x, pk_y, pk_mask = backend._marshal_pubkeys(sets, 4, 4,
+    idx, mask = backend.registry.index_grid(sets, ONE_GRID, 12)
+    pk_x, pk_y, pk_mask = backend._marshal_pubkeys(sets, ONE_GRID,
                                                    single_chip=True)
     assert np.array_equal(np.asarray(pk_mask), mask)
-    sig_x = np.zeros((4, 2, 24), np.uint32)
-    sig_y = np.zeros((4, 2, 24), np.uint32)
-    for i, s in enumerate(sets):
-        (x0, x1), (y0, y1) = s.signature.point
-        sig_x[i] = be.pack_ints_vec([x0, x1])
-        sig_y[i] = be.pack_ints_vec([y0, y1])
-    z = co.scalars_to_digits([3, 0xDEADBEEF12345677, 0x42, 2**63 + 9],
-                             64, be.Z_WINDOW)[:, :be.Z_DIGITS]
-    rest = (sig_x, sig_y, np.asarray(z, np.uint32), np.ones((4,), np.uint32))
+    rest = _prepare_rest(sets)
     table = backend.registry
-    got = be._get_prepare_indexed()(table.x, table.y, idx, mask, *rest)
+    got = be._get_prepare_variant("prepare_indexed")(table.x, table.y, idx, mask, *rest)
     want = be._get_stages(mesh=None)[0](pk_x, pk_y, pk_mask, *rest)
     import jax
 
@@ -640,7 +746,10 @@ def test_block_by_index_through_signature_batch_parity(registry_chain,
     lane takes the indexed path, no other entry: every key of the dispatch
     comes from the table, the marshal's key part is `jaxbls:marshal.indices`
     (no grid is packed), the bucket's slots are counted as the packed path
-    counts them, and the verdict is the pure-Python backend's."""
+    counts them — the slots the dispatch LAYS: this block's widths (1, 1,
+    4, 3) lie as a wide grid of 2 x 4 and a narrow one of 2 x 1, 10 slots
+    where the (4, 4) bucket that still names the dispatch has 16 — and the
+    verdict is the pure-Python backend's."""
     import lighthouse_tpu.crypto.jaxbls.backend as be
     from lighthouse_tpu.observability import trace as obstrace
     from lighthouse_tpu.state_transition.block import SignatureBatch
@@ -648,10 +757,15 @@ def test_block_by_index_through_signature_batch_parity(registry_chain,
     backend, cache, sks = registry_chain
     batch = SignatureBatch()
     batch.add(_block_by_index(cache, sks, damage))
-    # the start-up warm-up compiles the very program the dispatch runs
-    program = be._get_prepare_indexed()
+    # the start-up warm-up compiles the one-grid program, which a batch of
+    # near-equal widths runs; this block runs the two-grid one, compiled at
+    # its first dispatch (here: by the module's fixture)
+    one_grid = be._get_prepare_variant("prepare_indexed")
     be.warm_prepare_indexed(3, 4, backend.registry)       # rounds to (4, 4)
-    compiled = program._cache_size()
+    program = be._get_prepare_variant("prepare_indexed_grids")
+    compiled = program._cache_size(), one_grid._cache_size()
+    adds = be._TREE_SUM_LANE_ADDS.labels("done")
+    adds0 = adds.value
     slots = {k: be._BUCKET_SLOTS.labels("keys", k) for k in ("real", "padded")}
     before = {k: c.value for k, c in slots.items()}
     taken = _keys_taken()
@@ -662,19 +776,26 @@ def test_block_by_index_through_signature_batch_parity(registry_chain,
     finally:
         obstrace.set_current_trace(None)
     n_keys = sum(len(s.signing_keys) for s in batch.sets)
-    assert program._cache_size() == compiled
+    assert (program._cache_size(), one_grid._cache_size()) == compiled
     assert _keys_taken() == {"table": taken["table"] + n_keys,
                              "packed": taken["packed"]}
+    assert be.key_grid_plan([len(s.signing_keys) for s in batch.sets],
+                            4, 4)[0] == _BLOCK_GRIDS
     assert {k: c.value - before[k] for k, c in slots.items()} == {
-        "real": n_keys, "padded": 16}
+        "real": n_keys, "padded": 2 * 4 + 2 * 1}
+    # tree_sum_plan(4, 2) + tree_sum_plan(1, 2): 3 adds on 2 lanes, none
+    assert adds.value - adds0 == 6
     spans = {s[0]: s for s in tr.spans}
     assert "jaxbls:marshal.pubkeys" not in spans
     assert "jaxbls:marshal.pubkeys_upload" not in spans
     assert spans["jaxbls:marshal.indices"][4] == "jaxbls:marshal"
     assert spans["jaxbls:marshal.indices"][3] == {
-        "keys": n_keys, "bytes": 2 * 4 * 4 * 4}
+        "keys": n_keys, "bytes": 2 * (2 * 4 + 2 * 1) * 4}
     assert spans["jaxbls:prepare"][4] == "jaxbls:enqueue"   # stage 1's name
+    # the bucket is still the dispatch's name, the grids beside it
     assert tr.meta["bucket"] == "4x4" and tr.meta["real_keys"] == n_keys
+    assert tr.meta["key_grids"] == "2x4+2x1"
+    assert (4, 4) in be._seen_exec_buckets
     bls_api.set_backend("python")
     on_python = batch.verify()
     assert on_python is (damage is None)
@@ -754,11 +875,185 @@ def test_another_chains_sets_pack_their_keys_whatever_their_indices(
             for i, ix in enumerate(((13,), (14, 0), (2, 9, 4), (1, 12, 3)))]
     assert all(s.signing_registry is None for s in sets)
     # beside the first chain's own sets: one foreign set and the batch packs
-    mixed = _block_by_index(cache, first_sks)[:2] + [sets[0], sets[2]]
+    # (widths 1, 1, 3, 3: the block's two grids, packed)
+    mixed = _block_by_index(cache, first_sks)[:2] + [sets[2], sets[3]]
     assert [s.signing_registry for s in mixed] == [cache.table] * 2 + [None] * 2
     refused, taken = reg.REFUSED.value, _keys_taken()
     assert bls.verify_signature_sets(mixed) is True
     assert reg.REFUSED.value == refused
     assert _keys_taken() == {"table": taken["table"],
-                             "packed": taken["packed"] + 6}
-    assert backend._marshal_indices(sets, 4, 4, 9) is None
+                             "packed": taken["packed"] + 8}
+    assert backend._marshal_indices(sets, ONE_GRID, 9) is None
+
+
+# ------------------------------------------------------- two key grids
+# The batch lane of one device lays a batch of unequal widths as a wide and
+# a narrow key grid (backend.key_grid_plan) and stage 1 sums each by the
+# one tree_sum. Below: widths (1, 2, 2, 4) in the (4, 4) bucket, wide 1 x 4
+# and narrow 4 x 2 (`_PAIR_GRIDS`: 12 slots of 16, the edge of the 3/4
+# rule), packed; the block by index above is the same on 2 x 4 + 2 x 1.
+# The reference is the pure-Python backend, and for the aggregate keys the
+# pure-Python curve.
+
+
+def _set_of(sks, msg, valid=True):
+    """A set of the keys of `sks` signed by all of them; where they sum to
+    zero the signature is some point that is not the identity (no
+    signature verifies against the identity key)."""
+    agg = (sum(sks) + (0 if valid else 1)) % R or 7
+    return bls.SignatureSet(
+        bls.Signature(cv.g2_mul(bls_api.hash_to_g2_point(msg), agg)),
+        [bls.PublicKey(cv.g1_mul(cv.G1_GEN, sk)) for sk in sks], msg)
+
+
+def _pair_batch(case):
+    """Four sets of 1, 2, 2 and 4 keys; `case` damages one."""
+    rng = random.Random(0x261D)
+    sks = [[rng.randrange(1, R) for _ in range(w)] for w in (1, 2, 2, 4)]
+    if case in ("identity_narrow", "identities"):
+        sks[1][1] = R - sks[1][0]
+    if case in ("identity_wide", "identities"):
+        sks[3][1], sks[3][3] = R - sks[3][0], R - sks[3][2]
+    return [_set_of(ks, bytes([0xC0 + i]) * 32, valid=(case, i) != ("tampered", 2))
+            for i, ks in enumerate(sks)]
+
+
+@pytest.mark.parametrize("case", [
+    "valid", "tampered", "identity_narrow", "identity_wide"])
+def test_a_mixed_batch_on_two_grids_gives_the_reference_verdict(
+        registry_chain, case):
+    """`bls.verify_signature_sets` on a packed batch of unequal widths:
+    the dispatch lays two grids (its trace says which, its bucket is
+    still (4, 4)), counts the slots it lays and the lane-additions of
+    both sums, and its verdict is the pure-Python backend's — True when
+    sound, False with one bad signature, False with a set whose keys sum
+    to the identity in the narrow grid or in the wide one."""
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+
+    sets = _pair_batch(case)
+    assert be.key_grid_plan([1, 2, 2, 4], 4, 4)[0] == _PAIR_GRIDS
+    program = be._get_prepare_variant("prepare_grids")
+    compiled = program._cache_size()
+    padded = be._BUCKET_SLOTS.labels("keys", "padded")
+    adds = be._TREE_SUM_LANE_ADDS.labels("done")
+    padded0, adds0, taken = padded.value, adds.value, _keys_taken()
+    tr = obstrace.Trace("gossip_block", 1)
+    obstrace.set_current_trace(tr)
+    try:
+        on_jax = bls.verify_signature_sets(sets)
+    finally:
+        obstrace.set_current_trace(None)
+    assert program._cache_size() == compiled
+    assert tr.meta["bucket"] == "4x4" and tr.meta["key_grids"] == "1x4+4x2"
+    assert padded.value - padded0 == 1 * 4 + 4 * 2
+    # tree_sum_plan(4, 1) + tree_sum_plan(2, 4): 3 adds, and 1 on 4 lanes
+    assert adds.value - adds0 == 3 + 4
+    assert _keys_taken() == {"table": taken["table"],
+                             "packed": taken["packed"] + 9}
+    bls_api.set_backend("python")
+    assert bls.verify_signature_sets(sets) is (case == "valid")
+    assert on_jax is (case == "valid")
+
+
+def _affine_points(jac):
+    """[(x, y) or None] of a batch of Jacobian G1 points in Montgomery
+    limbs, by Python integers."""
+    from lighthouse_tpu.crypto.bls381.constants import P
+    from lighthouse_tpu.crypto.jaxbls import tower as tw
+
+    out = []
+    for x, y, z in zip(*(tw.fq_batch_from_device(c) for c in jac)):
+        zi = pow(z, -1, P) if z else 0
+        out.append((x * zi * zi % P, y * zi * zi * zi % P) if z else None)
+    return out
+
+
+@pytest.mark.parametrize("case,n_real", [("identities", 4), ("valid", 3)],
+                         ids=["identities", "padded_slot"])
+def test_two_grids_sum_to_the_one_grids_aggregate_keys(registry_chain, case,
+                                                       n_real):
+    """The two-grid prepare against the one-grid prepare on the same sets:
+    every z_i * aggpk_i the same AFFINE point (the sums associate
+    differently, so the Jacobian limbs differ) and the pure-Python
+    curve's, the signatures' sum limb for limb, `bad_aggpk` alike — set
+    where a real set's keys sum to the identity, in the narrow grid and in
+    the wide one. A padded set slot reads the identity entry behind the
+    grids' sums, as the one grid's all-masked row sums to it (three sets,
+    the wide grid left empty by a hand-laid `where`)."""
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+
+    backend, _, _ = registry_chain
+    sets = _pair_batch(case)[:n_real]
+    plan = be.key_grid_plan([1, 2, 2, 4], 4, 4)
+    if n_real == 3:
+        plan = (plan[0], np.array([1, 2, 3, 5], np.int32))
+    assert plan[0] == _PAIR_GRIDS and plan[1].tolist()[:3] == [1, 2, 3]
+    rest = _prepare_rest(sets, n_real)
+    grids = backend._marshal_pubkeys(sets, plan, single_chip=True)
+    one = backend._marshal_pubkeys(sets, ONE_GRID, single_chip=True)
+    assert [g.shape for g in grids] == [
+        (1, 4, 24), (1, 4, 24), (1, 4), (4, 2, 24), (4, 2, 24), (4, 2), (4,)]
+    assert sum(int(np.asarray(m).sum()) for m in (grids[2], grids[5])) == (
+        int(np.asarray(one[2]).sum())) == sum(len(s.signing_keys) for s in sets)
+    got = be._get_prepare_variant("prepare_grids")(*grids, *rest)
+    want = be._get_stages(mesh=None)[0](*one, *rest)
+
+    def aggregate(s, k):
+        total = None
+        for pk in s.signing_keys:
+            total = cv.g1_add(total, pk.point)
+        return cv.g1_mul(total, k)
+
+    reference = [aggregate(s, k) for s, k in zip(sets, _ZS)] + [None] * (4 - n_real)
+    assert _affine_points(got[0]) == _affine_points(want[0]) == reference
+    assert reference.count(None) == {"valid": 1, "identities": 2}[case]
+    for a, b in zip(got[1], want[1]):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert bool(np.asarray(got[2])) is bool(np.asarray(want[2])) is (
+        case == "identities")
+
+
+def test_indexed_grids_prepare_is_bit_equal_to_the_packed_grids_prepare(
+        registry_chain):
+    """Over the block's two grids as over the one: the rows gathered from
+    the table and the keys packed on the host give stage 1's outputs limb
+    for limb, and `index_grid` lays indices where `_marshal_pubkeys` lays
+    keys."""
+    import jax
+
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+
+    backend, cache, sks = registry_chain
+    sets = _block_by_index(cache, sks)
+    plan = be.key_grid_plan([len(s.signing_keys) for s in sets], 4, 4)
+    assert plan[0] == _BLOCK_GRIDS and plan[1].tolist() == [2, 3, 0, 1]
+    table = backend.registry
+    wide_idx, wide_mask, narrow_idx, narrow_mask = table.index_grid(
+        sets, plan, 12)
+    assert wide_idx.tolist() == [[0, 3, 7, 11], [2, 9, 4, 0]]
+    assert narrow_idx.tolist() == [[5], [5]]
+    packed = backend._marshal_pubkeys(sets, plan, single_chip=True)
+    assert np.array_equal(np.asarray(packed[2]), wide_mask)
+    assert np.array_equal(np.asarray(packed[5]), narrow_mask)
+    assert np.asarray(packed[6]).tolist() == plan[1].tolist()
+    rest = _prepare_rest(sets)
+    got = be._get_prepare_variant("prepare_indexed_grids")(
+        table.x, table.y, wide_idx, wide_mask, narrow_idx, narrow_mask,
+        plan[1], *rest)
+    want = be._get_prepare_variant("prepare_grids")(*packed, *rest)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert not bool(np.asarray(got[2]))
+
+
+def test_module_stays_under_the_mapping_mark(registry_chain):
+    """Last on purpose, as in the two other modules that drive the staged
+    backend: with this module's eight programs compiled and kept (one
+    unsharded build and four more prepares), the process must be under
+    conftest's mark — past it conftest drops the executables between tests
+    and each later test recompiles for minutes."""
+    from conftest import _MAP_COUNT_HIGH_MARK, _n_memory_mappings
+
+    assert _n_memory_mappings() < _MAP_COUNT_HIGH_MARK
