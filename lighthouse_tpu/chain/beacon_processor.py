@@ -41,7 +41,11 @@ from ..observability import slo as obs_slo
 from ..observability import trace as obs
 from ..qos.admission import count_shed
 from ..utils.logging import get_logger
-from ..utils.metrics import REGISTRY
+from ..utils.metrics import (
+    REGISTRY,
+    SIGNATURE_BATCH_SIZE,
+    SIGNATURE_VERIFY_TIME,
+)
 
 log = get_logger("beacon_processor")
 
@@ -486,7 +490,7 @@ class BeaconProcessor:
             self._exec_lock.acquire()
         _EXEC_LOCK_WAIT.observe(waited.t1 - waited.t0)
         try:
-            with obs.span("marshal", trace):
+            with obs.span("marshal", trace) as marshalled:
                 if batch is not None:
                     kind = batch[0].kind
                     runner = batch[0].run_batch
@@ -509,14 +513,17 @@ class BeaconProcessor:
         self.processed[kind] += n
         self._m_processed[kind].inc(n)
         self.slo.record_processed(kind.name, n)
-        self._handle_result(result, trace, kind, n)
+        self._handle_result(result, trace, kind, n, marshalled.t0)
 
-    def _handle_result(self, result, trace=None, kind=None, n=1) -> None:
+    def _handle_result(self, result, trace=None, kind=None, n=1,
+                       t_run=None) -> None:
         """A runner may return (handle, continuation): the device batch is
         in flight and the continuation runs when it resolves. The pump keeps
         pulling (and marshalling) new work while up to max_inflight device
         batches verify — the host/device overlap the reference gets from
-        its worker pool (beacon_processor/src/lib.rs:732-1100)."""
+        its worker pool (beacon_processor/src/lib.rs:732-1100). `t_run`
+        is when the runner was entered: the batch's verify time counts
+        from there to the verdict (bls_batch_verify_seconds)."""
         if (
             isinstance(result, tuple)
             and len(result) == 2
@@ -524,7 +531,8 @@ class BeaconProcessor:
             and callable(result[1])
         ):
             with self._lock:
-                self._inflight.append((result[0], result[1], trace, kind, n))
+                self._inflight.append(
+                    (result[0], result[1], trace, kind, n, t_run))
                 self.pipelined_batches += 1
                 _INFLIGHT.set(len(self._inflight))
                 over = len(self._inflight) > self.config.max_inflight
@@ -538,7 +546,7 @@ class BeaconProcessor:
         with self._lock:
             if not self._inflight:
                 return False
-            handle, cont, trace, kind, n = self._inflight.popleft()
+            handle, cont, trace, kind, n, t_run = self._inflight.popleft()
             _INFLIGHT.set(len(self._inflight))
         # the unit's trace is current again while its handle resolves and
         # its continuation runs, on whichever worker got here: what they
@@ -546,11 +554,11 @@ class BeaconProcessor:
         outer = obs.current_trace()
         obs.set_current_trace(trace)
         try:
-            return self._resolve(handle, cont, trace, kind, n)
+            return self._resolve(handle, cont, trace, kind, n, t_run)
         finally:
             obs.set_current_trace(outer)
 
-    def _resolve(self, handle, cont, trace, kind, n) -> bool:
+    def _resolve(self, handle, cont, trace, kind, n, t_run=None) -> bool:
         # a device failure mid-batch (device lost) must never kill the pump
         # worker: the batch is lost (its deferred gossip validations expire
         # as ignores) but the node keeps verifying
@@ -567,6 +575,11 @@ class BeaconProcessor:
             return True
         dev_secs = waited.t1 - waited.t0
         self.slo.record_verify_latency(dev_secs)
+        if t_run is not None and kind is not WorkKind.gossip_blob_sidecar:
+            # runner entered (marshal, dispatch, the wait behind batches in
+            # flight) -> the verdict read: what one signature batch took
+            SIGNATURE_VERIFY_TIME.observe(waited.t1 - t_run)
+            SIGNATURE_BATCH_SIZE.observe(n)
         if kind is not None and kind in self.BATCHABLE:
             # the scheduler's batch cost model learns from DEVICE resolves
             # only (host-path wall time must not steer device batch sizing)

@@ -107,7 +107,9 @@ _MILLER_PLAN = REGISTRY.counter_vec(
     "sees: dispatches, "
     "accumulators = W carried through the loop, in_step_levels = dense "
     "Fq12 tree levels left inside each of its steps; in_step_levels over "
-    "dispatches is 0 where the lines are narrowed only after the loop",
+    "dispatches is 0 where the lines are narrowed only after the loop; "
+    "lines_per_accumulator = g, the lines one accumulator takes a step (1 "
+    "a sparse line, 2 a line pair, 8 at a 1,024-set bucket's 1,025 pairs)",
     ("kind",),
 )
 _KZG_LANES = REGISTRY.counter_vec(
@@ -145,6 +147,8 @@ def _count_miller_plan(miller_pairs: int) -> None:
     _MILLER_PLAN.labels("dispatches").inc()
     _MILLER_PLAN.labels("accumulators").inc(w)
     _MILLER_PLAN.labels("in_step_levels").inc(in_step_levels)
+    _MILLER_PLAN.labels("lines_per_accumulator").inc(
+        po._lines_per_accumulator(miller_pairs, w))
 
 
 def _next_pow2(n: int) -> int:

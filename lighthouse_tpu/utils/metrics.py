@@ -301,11 +301,14 @@ BLOCK_PROCESSING_TIME = REGISTRY.histogram(
     "beacon_block_processing_seconds", "Full block import latency"
 )
 SIGNATURE_BATCH_SIZE = REGISTRY.histogram(
-    "bls_batch_verify_sets", "Signature sets per device batch",
+    "bls_batch_verify_sets",
+    "Signature sets per pipelined device batch of the beacon processor",
     buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024),
 )
 SIGNATURE_VERIFY_TIME = REGISTRY.histogram(
-    "bls_batch_verify_seconds", "Device batch verification latency"
+    "bls_batch_verify_seconds",
+    "Runner entered (marshal, dispatch) to verdict read, per pipelined "
+    "signature batch of the beacon processor",
 )
 ATTESTATION_BATCHES = REGISTRY.counter(
     "gossip_attestation_batches_total", "Coalesced attestation batches"

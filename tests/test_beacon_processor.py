@@ -230,3 +230,70 @@ def test_a_lone_blob_sidecar_is_a_batch_of_one_and_pipelines():
     assert log == [("batch", ["sc"]), "resolved", ("cont", "verdicts")]
     assert bp.pipelined_batches == 1
     assert bp.scheduler.model()["samples"] == 0
+
+
+def test_an_explicit_batch_of_1024_coalesces_to_1024_and_not_beyond():
+    """The cell subnet_flood_1key's width: `max_attestation_batch=1024`
+    given explicitly is pinned - a plan installed later (here one that says
+    64, the reference's default) re-bases nothing - and 2,500 queued
+    `gossip_attestation` items leave as 1,024 + 1,024 + 452, in order."""
+    import dataclasses
+
+    from lighthouse_tpu.autotune import planner, runtime
+
+    bp = BeaconProcessor(BeaconProcessorConfig(max_attestation_batch=1024,
+                                               num_workers=1))
+    assert bp.config.max_attestation_batch_explicit
+    assert bp.scheduler.pinned["gossip_attestation"]
+    try:
+        runtime.install_runtime_plan(dataclasses.replace(
+            planner.DEFAULT_PLAN, max_attestation_batch=64, source="test"))
+        assert bp.scheduler.caps["gossip_attestation"] == 1024
+        got = []
+        for i in range(2500):
+            assert bp.submit(WorkItem(WorkKind.gossip_attestation, payload=i,
+                                      run_batch=got.append))
+        bp.run_until_idle()
+    finally:
+        runtime.clear()
+    assert [len(b) for b in got] == [1024, 1024, 452]
+    assert [x for b in got for x in b] == list(range(2500))
+    assert bp.dropped[WorkKind.gossip_attestation] == 0
+
+
+def test_a_pipelined_signature_batch_records_its_verify_time_and_width():
+    """`bls_batch_verify_seconds` / `bls_batch_verify_sets`: one observation
+    a pipelined batch, runner entered -> verdict read (the benchmark's
+    `sn_batch_verify_ms`); a runner that returns no handle records none,
+    nor does a blob-sidecar batch (not a signature batch)."""
+    from lighthouse_tpu.utils.metrics import (
+        SIGNATURE_BATCH_SIZE,
+        SIGNATURE_VERIFY_TIME,
+    )
+
+    class Handle:
+        def result(self):
+            time.sleep(0.02)
+            return True
+
+    def pipelined(payloads):
+        time.sleep(0.01)
+        return Handle(), lambda ok: None
+
+    bp = BeaconProcessor(BeaconProcessorConfig(max_attestation_batch=8,
+                                               num_workers=1))
+    n0, t0 = SIGNATURE_VERIFY_TIME.n, SIGNATURE_VERIFY_TIME.total
+    w0, s0 = SIGNATURE_BATCH_SIZE.n, SIGNATURE_BATCH_SIZE.total
+    for i in range(8):
+        bp.submit(WorkItem(WorkKind.gossip_attestation, payload=i,
+                           run_batch=pipelined))
+    bp.submit(WorkItem(WorkKind.gossip_attestation, payload=9,
+                       run_batch=lambda xs: None))
+    for i in range(2):
+        bp.submit(WorkItem(WorkKind.gossip_blob_sidecar, payload=i,
+                           run_batch=pipelined))
+    bp.run_until_idle()
+    assert SIGNATURE_VERIFY_TIME.n - n0 == 1
+    assert SIGNATURE_VERIFY_TIME.total - t0 >= 0.03     # marshal + the wait
+    assert SIGNATURE_BATCH_SIZE.n - w0 == 1
+    assert SIGNATURE_BATCH_SIZE.total - s0 == 8

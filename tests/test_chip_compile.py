@@ -23,7 +23,10 @@ prepare of the Electra block's 16x32768 bucket over the 1,114,112-row
 registry table (`_stage_prepare_indexed`, minutes), and the two-grid
 prepares (`backend.key_grid_plan`) that the three cells of unequal widths
 serve since PR 42: the Electra block's 8x32768 + 4x512 by index, the Deneb
-block's 1x512 + 256x128 and the aggregates' 64x512 + 128x1 packed.
+block's 1x512 + 256x128 and the aggregates' 64x512 + 128x1 packed; and
+since PR 43 the 1024x1 bucket of `subnet_flood_1key` (`pairs` unmarked,
+~45 s; `slow`: the indexed prepare ~75 s, hash-to-G2 at 1,024 lanes ~3.5
+min and 1.9 GB of temporaries, the Miller loop at 1,025 pairs ~3.5 min).
 """
 
 import numpy as np
@@ -40,9 +43,9 @@ from lighthouse_tpu.crypto.jaxbls import pairing_ops as po
 
 V5E_HBM_BYTES = 16 * 1024**3
 N_SETS, N_PKS = 64, 128   # the served gossip bucket (chip_smoke.py)
-#: the served buckets: urgent, gossip, block, Electra block (BENCHMARK.json's
-#: BLS cells)
-SERVED_BUCKETS = ((4, 128), (64, 128), (256, 512), (16, 32768))
+#: the served buckets: urgent, gossip, block, Electra block, a dispatch of
+#: single-key subnet attestations (BENCHMARK.json's BLS cells)
+SERVED_BUCKETS = ((4, 128), (64, 128), (256, 512), (16, 32768), (1024, 1))
 #: the registry table of 1,048,576 validators with its room for deposits
 TABLE_ROWS = 1_114_112
 
@@ -181,6 +184,12 @@ def _shapes(tree):
     pytest.param("final_exp", (N_SETS, N_PKS), marks=pytest.mark.slow),
     pytest.param("prepare_indexed", SERVED_BUCKETS[3],
                  marks=pytest.mark.slow),
+    # subnet_flood_1key's bucket: no key axis, 1,024 lanes in stages 1-2,
+    # 1,025 pairs = eight lines an accumulator in the Miller loop
+    pytest.param("prepare_indexed", SERVED_BUCKETS[4],
+                 marks=pytest.mark.slow),
+    pytest.param("h2c", SERVED_BUCKETS[4], marks=pytest.mark.slow),
+    pytest.param("miller", SERVED_BUCKETS[4], marks=pytest.mark.slow),
 ], ids=lambda v: v if isinstance(v, str) else "%dx%d" % v)
 def test_stage_compiles_for_v5e(stage, bucket, one_chip,
                                 no_persistent_cache):

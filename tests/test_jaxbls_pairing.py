@@ -126,6 +126,9 @@ _CPU_PLANS = [
     (1, (1, 0, 0)), (2, (1, 0, 0)), (3, (1, 0, 0)), (5, (1, 1, 0)),
     (9, (1, 2, 0)), (17, (1, 3, 0)), (33, (128, 0, 7)), (65, (128, 0, 7)),
     (129, (128, 0, 7)), (257, (128, 0, 7)), (258, (128, 1, 7)),
+    # a 1,024-set bucket's pairs: eight lines an accumulator, the line
+    # pairs and two dense levels in the step (subnet_flood_1key)
+    (1025, (128, 2, 7)),
 ]
 _PLAN_ROWS = [("cpu", n, plan) for n, plan in _CPU_PLANS] + [
     ("tpu", n, plan if n >= 33 else (128, 0, 7)) for n, plan in _CPU_PLANS]
@@ -162,21 +165,25 @@ def miller_loops():
     each + the pair over (the block bucket's form), "padded" sixteen
     accumulators of one sparse line, seven of them padding (the form of
     the gossip bucket and, on a TPU, of the urgent bucket and the KZG
-    check). The module's three Miller-loop compiles."""
+    check); and at seventeen pair lanes "eights": two accumulators of
+    EIGHT lines each + the pair over, so line pairs and two dense levels
+    inside the step, the form of a 1,024-set bucket's 1,025 pairs on the
+    chip's row of 128. The module's four Miller-loop compiles."""
     shipped = po.MILLER_LANES, po.MILLER_WIDE_FROM
-    plans = {"w1": (128, 1 << 30, (1, 2, 0), 8),
-             "pairs": (4, 1, (4, 0, 2), 2),
-             "padded": (16, 1, (16, 0, 4), 1)}
+    plans = {"w1": (128, 1 << 30, (1, 2, 0), 8, 9),
+             "pairs": (4, 1, (4, 0, 2), 2, 9),
+             "padded": (16, 1, (16, 0, 4), 1, 9),
+             "eights": (2, 1, (2, 2, 1), 8, 17)}
     fns = {}
     try:
-        for name, (lanes, wide_from, plan, g) in plans.items():
+        for name, (lanes, wide_from, plan, g, n_pairs) in plans.items():
             # the one entry every platform falls back to: whatever this
             # process runs on takes it
             po.MILLER_LANES, po.MILLER_WIDE_FROM = lanes, {"cpu": wide_from}
-            assert po.miller_lane_plan(9) == plan
-            assert po._lines_per_accumulator(9, plan[0]) == g
+            assert po.miller_lane_plan(n_pairs) == plan
+            assert po._lines_per_accumulator(n_pairs, plan[0]) == g
             # trace and compile now, while the patched plan is in force
-            dp, dq, mask = _device_pairs([], 9)
+            dp, dq, mask = _device_pairs([], n_pairs)
             fns[name] = jax.jit(be._stage_miller).lower(
                 *dp, *dq, mask).compile()
     finally:
@@ -244,6 +251,35 @@ def test_stage_four_as_two_programs_gives_the_product_checks_verdict(
     ok = _stage_final_exp(f)
     assert ok.shape == () and ok.dtype == jnp.bool_
     assert bool(ok) is bool(tw.fq12_eq_one(_final_exp(f))) is (not tamper)
+
+
+@pytest.mark.parametrize("tamper", [False, True], ids=["valid", "tampered"])
+def test_eight_lines_an_accumulator_give_the_product_of_the_loops(
+        miller_loops, tamper):
+    """Seventeen pair lanes on two accumulators: each takes EIGHT lines a
+    step (four line pairs, two dense in-step levels: miller_lane_plan's
+    (W, 2, .), what 1,025 pairs are on a row of 128), the pair over folded
+    into lane 0. Its Miller value equals, limb for limb, the product of
+    one-accumulator loops over the same pairs (lanes 0-8 and lanes 9-16 as
+    two calls of the nine-lane loop, multiplied), and the verdict after
+    final exponentiation is the product's truth."""
+    ab, pairs = _eight_pairs()
+    lanes = pairs + [(pc.g1_mul(pc.G1_GEN, b), pc.g2_mul(pc.G2_GEN, a))
+                     for a, b in ab]                  # sixteen pairs
+    total = 2 * sum(a * b for a, b in ab)
+    lanes.append((pc.g1_neg(pc.g1_mul(pc.G1_GEN, (total + tamper) % R)),
+                  pc.G2_GEN))
+    dp, dq, _ = _device_pairs(lanes, 17)
+    wide = miller_loops["eights"](*dp, *dq, jnp.ones(17, bool))
+
+    def nine(chunk):
+        p, q, _ = _device_pairs(chunk + [(None, None)] * (9 - len(chunk)), 9)
+        mask = np.arange(9) < len(chunk)
+        return miller_loops["w1"](*p, *q, jnp.asarray(mask))
+
+    narrow = tw.fq12_mul(nine(lanes[:9]), nine(lanes[9:]))
+    assert np.array_equal(np.asarray(wide), np.asarray(narrow))
+    assert bool(tw.fq12_eq_one(_final_exp(wide))) is (not tamper)
 
 
 def test_all_masked_accumulator_lane_leaves_the_product_unchanged(

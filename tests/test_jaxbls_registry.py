@@ -104,6 +104,10 @@ _AGGREGATES = [1] * 64 + [1] * 64 + [448 + i for i in range(64)]
     ([128] * 64, (64, 128), (64, 128), None, 8_192, 16_384),     # gossip
     ([128], (4, 128), (4, 128), None, 512, 3_584),               # urgent
     ([1] * 7, (8, 1), (8, 1), None, 8, 0),                       # m = 1
+    # a dispatch of single-key attestations at the program's own width:
+    # all-ones widths keep the one grid (n, 1), and tree_sum_plan takes
+    # m = 1 with nothing to add (subnet_flood_1key)
+    ([1] * 1024, (1024, 1), (1024, 1), None, 1024, 0),
     ([3, 4, 4, 3], (4, 4), (4, 4), None, 16, 12),                # uniform
     # the 3/4 rule, both sides of its edge in the (4, 4) bucket of 16
     ([2, 2, 2, 4], (4, 4), (1, 4), (4, 2), 12, 7),               # 12 of 16
@@ -112,7 +116,7 @@ _AGGREGATES = [1] * 64 + [1] * 64 + [448 + i for i in range(64)]
     # the cheapest t of several that split: t = 4 (32), not t = 1 (34)
     ([1, 1, 3, 16], (4, 16), (1, 16), (4, 4), 32, 76),
 ], ids=["electra", "electra_thin", "deneb", "aggregates", "gossip", "urgent",
-        "one_key_sets", "uniform", "edge_at", "edge_over", "block_by_index",
+        "one_key_sets", "subnet_1024", "uniform", "edge_at", "edge_over", "block_by_index",
         "cheapest"])
 def test_key_grid_plan(widths, bucket, wide, narrow, slots, lane_adds):
     from lighthouse_tpu.crypto.jaxbls import backend as be
@@ -572,12 +576,13 @@ _PAIR_GRIDS = ((1, 4), (4, 2))
 
 @pytest.fixture(scope="module")
 def _one_device_programs():
-    """The eight programs the tests below dispatch, compiled side by side
+    """The nine programs the tests below dispatch, compiled side by side
     in three threads: the unsharded four stages at 4 sets with the packed
     prepare at m = 4; the indexed prepares at (4, 4) over the 24-row
     table, over one grid and over the block's two; the packed two-grid
-    prepares, over the block's grids and over `_PAIR_GRIDS`. The first is
-    the longest."""
+    prepares, over the block's grids and over `_PAIR_GRIDS`, and the
+    indexed prepare at (4, 1), single-key sets (no key axis to sum: the
+    smallest of them). The first is the longest."""
     import functools
 
     import jax
@@ -617,7 +622,8 @@ def _one_device_programs():
         functools.partial(warm_prepares, ("prepare_indexed", ((4, 4),)),
                           ("prepare_indexed_grids", _BLOCK_GRIDS)),
         functools.partial(warm_prepares, ("prepare_grids", _BLOCK_GRIDS),
-                          ("prepare_grids", _PAIR_GRIDS)))
+                          ("prepare_grids", _PAIR_GRIDS),
+                          ("prepare_indexed", ((4, 1),))))
 
 
 @pytest.fixture()
@@ -800,6 +806,75 @@ def test_block_by_index_through_signature_batch_parity(registry_chain,
     on_python = batch.verify()
     assert on_python is (damage is None)
     assert on_jax is on_python
+
+
+_SUBNET = ((3, b"\xA1" * 32), (9, b"\xA1" * 32), (0, b"\xA1" * 32),
+           (7, b"\xB2" * 32))
+
+
+@pytest.mark.parametrize("damage", [
+    None, "swapped_signature", "flipped_message", "replaced_signer"],
+    ids=lambda d: d or "valid")
+def test_single_key_sets_with_shared_messages_by_index(registry_chain,
+                                                       damage):
+    """Four unaggregated attestations as `prepare_unaggregated_attestations`
+    builds them - ONE attesting index a set, three of them on one shared
+    message - through `bls.verify_signature_sets` on the batch lane: the
+    bucket is (4, 1), its keys ONE grid (all-ones widths never split), every
+    key gathered from the table, the Miller plan counted with its lines an
+    accumulator, and the verdict the pure-Python backend's - valid, with
+    one set's signature swapped for another's, with one byte of one set's
+    (shared) message flipped, with one set's signer exchanged for another
+    validator."""
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+    from lighthouse_tpu.crypto.jaxbls import pairing_ops as po
+
+    backend, cache, sks = registry_chain
+    sets = [_set_by_index(cache, sks, [i], msg) for i, msg in _SUBNET]
+    victim = sets[1]
+    if damage == "swapped_signature":
+        sets[1] = bls.SignatureSet(sets[0].signature, victim.signing_keys,
+                                   victim.message, signing_indices=[9],
+                                   signing_registry=cache.table)
+    elif damage == "flipped_message":
+        msg = bytes([victim.message[0] ^ 1]) + victim.message[1:]
+        sets[1] = bls.SignatureSet(victim.signature, victim.signing_keys,
+                                   msg, signing_indices=[9],
+                                   signing_registry=cache.table)
+    elif damage == "replaced_signer":
+        sets[1] = _set_by_index(cache, sks, [4], victim.message, signers=[9])
+    assert be.key_grid_plan([1] * 4, 4, 1) == be.one_key_grid(4, 1)
+    bls_api.set_backend("python")
+    want = bls.verify_signature_sets(sets)
+    assert want is (damage is None)
+    bls_api.set_backend("jax")
+    before = _keys_taken()
+    plan0 = {k: be._MILLER_PLAN.labels(k).value
+             for k in ("dispatches", "lines_per_accumulator")}
+    tr = obstrace.Trace("test", 1)
+    obstrace.set_current_trace(tr)
+    try:
+        got = bls.verify_signature_sets(sets)
+    finally:
+        obstrace.set_current_trace(None)
+    assert got is want
+    after = _keys_taken()
+    assert after["table"] - before["table"] == 4
+    assert after["packed"] == before["packed"]
+    assert tr.meta["bucket"] == "4x1" and tr.meta["key_grids"] == "4x1"
+    assert tr.meta["real_keys"] == 4
+    assert tr.meta["distinct_messages"] == (3 if damage == "flipped_message"
+                                            else 2)
+    names = [s[0] for s in tr.spans]
+    assert "jaxbls:marshal.indices" in names
+    assert "jaxbls:marshal.pubkeys" not in names
+    assert (4, 1) in be._seen_exec_buckets
+    w = po.miller_lane_plan(5)[0]
+    assert be._MILLER_PLAN.labels("dispatches").value == plan0[
+        "dispatches"] + 1
+    assert (be._MILLER_PLAN.labels("lines_per_accumulator").value
+            - plan0["lines_per_accumulator"]
+            == po._lines_per_accumulator(5, w))
 
 
 def test_a_set_without_indices_sends_the_batch_down_the_packed_path(

@@ -678,6 +678,13 @@ def test_new_layer_metrics_read_spans_the_program_emits():
         "el_marshal_h2f_ms": "jaxbls:marshal.h2f",
         "el_marshal_upload_ms": "jaxbls:marshal.upload",
         "el_exec_lock_wait_ms": "beacon_processor_exec_lock_wait_seconds",
+        # the twins that name the cell subnet_flood_1key (a dispatch of
+        # single-key sets by index: test_jaxbls_registry's
+        # test_single_key_sets_with_shared_messages_by_index)
+        "sn_marshal_indices_ms": "jaxbls:marshal.indices",
+        "sn_marshal_sigs_ms": "jaxbls:marshal.sigs",
+        "sn_marshal_h2f_ms": "jaxbls:marshal.h2f",
+        "sn_marshal_upload_ms": "jaxbls:marshal.upload",
     }
     from lighthouse_tpu.crypto.jaxbls import registry  # noqa: F401
 
@@ -687,7 +694,9 @@ def test_new_layer_metrics_read_spans_the_program_emits():
             # what el_registry_key_share and el_prepare_key_bytes_share
             # read, and the table's three other families
             "jaxbls_registry_keys_total", "jaxbls_registry_refused_total",
-            "jaxbls_registry_rows", "jaxbls_registry_bytes"} <= families
+            "jaxbls_registry_rows", "jaxbls_registry_bytes",
+            # what sn_batch_verify_ms reads (the processor observes it)
+            "bls_batch_verify_seconds"} <= families
     assert not {"jaxbls_dispatch_enqueue_seconds",
                 "jaxbls_device_wait_seconds"} & families
 
