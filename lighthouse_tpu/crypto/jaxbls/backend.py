@@ -86,9 +86,11 @@ _BUCKET_SLOTS = REGISTRY.counter_vec(
 _DISPATCH_MESSAGES = REGISTRY.counter_vec(
     "jaxbls_dispatch_messages_total",
     "messages of the real sets per dispatch: sent = one a set, distinct = "
-    "different byte strings among them; each set is hashed to G2 and "
-    "paired on its own today, so distinct over sent is the share of that "
-    "work a dispatch with shared messages would still need",
+    "different byte strings among them, lanes = what is laid for stages 2-4 "
+    "(one hash-to-G2 map and one Miller pair a lane: message_lanes' k where "
+    "the dispatch folds its sets by message, the set bucket's n where it "
+    "does not), folded_sets = the real sets of the dispatches that folded; "
+    "folded_sets over sent is the share of sets verified on the message axis",
     ("kind",),
 )
 _TREE_SUM_LANE_ADDS = REGISTRY.counter_vec(
@@ -230,6 +232,41 @@ def key_grid_plan(widths, n: int, m: int) -> tuple:
 def one_key_grid(n: int, m: int) -> tuple:
     """The key_grid_plan of ONE (n, m) grid, set i in row i."""
     return ((n, m),), None
+
+
+def message_lanes(distinct: int, n: int) -> int:
+    """How the messages of a one-chip batch-lane dispatch lie, from the
+    count of distinct message byte strings among its sets and its set
+    bucket `n` (`padding_bucket`'s, which stays the dispatch's NAME): the
+    lanes k laid for hash-to-G2, the batched inversion and the Miller loop
+    (k + 1 pairs). Pure and single owner, as key_grid_plan is for the keys:
+    the marshal hashes and indexes by it, stage 3 folds to it, the lane and
+    Miller-plan counters read it.
+
+    k = max(one row of Miller accumulators, distinct rounded up to a power
+    of two), and the dispatch FOLDS — sums its sets' z * pk by message
+    before stage 3, e(a, H) e(b, H) = e(a + b, H) — iff k < n; else k = n,
+    a lane a set, the programs every dispatch ran before the rule. Never
+    narrower than the row: on the chip no hash-to-G2 or Miller program is
+    cheaper below it (PERF.md S6, PR 30 and PR 44), and k then takes few
+    values (n = 256: 128; n = 1,024: 128, 256, 512), so a dispatch whose
+    count wobbles by a few meets no shape that has not compiled. The urgent
+    lane and a mesh do not ask: they lay a lane a set, as they lay the one
+    key grid."""
+    k = max(po.MILLER_LANES, _next_pow2(distinct))
+    return k if k < n else n
+
+
+def message_fold_index(lanes, n: int, k: int) -> np.ndarray:
+    """What a folding dispatch sends stage 3 beside its k lanes of `us`,
+    from the message lane of each real set (the caller's order): int32[2, n],
+    row 0 the set slots ordered by lane (a stable sort: the sets of a
+    message keep their order), row 1 the lane of the slot that stands
+    there, ascending; a padded slot's lane is k, behind every message."""
+    lane = np.full((n,), k, np.int32)
+    lane[:len(lanes)] = lanes
+    order = np.argsort(lane, kind="stable").astype(np.int32)
+    return np.stack([order, lane[order]])
 
 
 def _grid_rows(plan, n_sets: int) -> list:
@@ -433,6 +470,53 @@ def _stage_pairs(z_pk, h_jac, sig_acc, set_mask):
     return px, py, qxx, qyy, pair_mask
 
 
+def _fold_by_message(z_pk, fold, k: int):
+    """The sets' z * pk (n Jacobian G1 lanes, the caller's order) summed
+    by message: (the k lanes' sums, the k lanes' mask). `fold` is the
+    marshal's `message_fold_index`, int32[2, n]: the set slots ordered by
+    message lane, and their lanes, k for a padded slot (whose z * pk is
+    the identity: its coefficient is zero). The shape is (n, k)'s alone,
+    whatever the multiplicities: log2(n) rounds of ONE jac_add instance on
+    n lanes, round r adding to every lane the lane 2^r further on where
+    that still holds the same message — after them the first lane of a
+    message holds its sum. jac_add is complete: two sets of one key, message
+    and coefficient double, a group that cancels is the identity (its pair
+    then contributes 1, as an identity aggregate's does)."""
+    import jax
+    import jax.numpy as jnp
+
+    order, lane = fold[0], fold[1]
+    n = order.shape[0]
+    at = jnp.arange(n, dtype=jnp.int32)
+    none = tuple(jnp.broadcast_to(c, x.shape)
+                 for c, x in zip(co.identity(co.FQ_OPS), z_pk))
+
+    def round_(r, acc):
+        ahead = jnp.int32(1) << r
+        same = jnp.logical_and(jnp.roll(lane, -ahead) == lane, at + ahead < n)
+        partner = tuple(jnp.roll(x, -ahead, axis=0) for x in acc)
+        return co.jac_add(
+            acc, co.pt_select(co.FQ_OPS, same, partner, none), co.FQ_OPS)
+
+    acc = jax.lax.fori_loop(0, n.bit_length() - 1, round_,
+                            tuple(x[order] for x in z_pk))
+    lanes = jnp.arange(k, dtype=jnp.int32)
+    # a message's first lane: as many stand before it as hold a lower one
+    first = jnp.minimum(
+        jnp.sum(lane[None, :] < lanes[:, None], axis=1, dtype=jnp.int32),
+        n - 1)
+    return tuple(x[first] for x in acc), lane[first] == lanes
+
+
+def _stage_pairs_folded(z_pk, h_jac, sig_acc, fold):
+    """Stage 3 of a dispatch that folds (message_lanes' k < n): the sets'
+    z * pk summed by message into the k lanes hash-to-G2 ran on, then
+    `_stage_pairs` itself at k + 1 pairs, a lane without a message masked
+    as a padded set is."""
+    folded, lane_mask = _fold_by_message(z_pk, fold, h_jac[2].shape[0])
+    return _stage_pairs(folded, h_jac, sig_acc, lane_mask)
+
+
 def _stage_pairing(px, py, qxx, qyy, pair_mask):
     """Stage 4 as ONE program: shared-accumulator multi-Miller loop + final
     exponentiation (_verify_kernel, the meshed jit build, and the
@@ -493,6 +577,8 @@ STAGE_DONATE_ARGNUMS = dict(
     prepare_indexed=(4, 5, 6),
     # and behind two grids and `where`: never a key grid nor the table
     prepare_grids=(7, 8, 9), prepare_indexed_grids=(7, 8, 9),
+    # stage 3 of a dispatch that folds: the intermediates and the index
+    pairs_folded=(0, 1, 2, 3),
 )
 
 
@@ -770,22 +856,25 @@ def _get_stages(mesh=None):
     return _kernel_cache[key]
 
 
-#: stage 1 as one chip serves it beside `_get_stages()[0]`, by the name its
-#: donation goes under in STAGE_DONATE_ARGNUMS
-_PREPARE_VARIANTS = dict(
+#: what one chip's batch lane serves beside `_get_stages()`, by the name
+#: its donation goes under in STAGE_DONATE_ARGNUMS: stage 1 three ways, and
+#: stage 3 of a dispatch that folds its sets by message
+_ONE_CHIP_VARIANTS = dict(
     prepare_indexed=_stage_prepare_indexed,
     prepare_grids=_stage_prepare_grids,
     prepare_indexed_grids=_stage_prepare_indexed_grids,
+    pairs_folded=_stage_pairs_folded,
 )
 
 
-def _get_prepare_variant(stage: str):
-    """One of `_PREPARE_VARIANTS`, jitted for one chip (the batch lane of a
-    process without a mesh), under the donation mode of `_get_stages`:
-    the keys by index from the registry table, the keys as the two grids
-    of key_grid_plan, or both. With the packed one-grid prepare of
+def _get_one_chip_variant(stage: str):
+    """One of `_ONE_CHIP_VARIANTS`, jitted for one chip (the batch lane of
+    a process without a mesh), under the donation mode of `_get_stages`.
+    Stage 1: the keys by index from the registry table, the keys as the two
+    grids of key_grid_plan, or both — with the packed one-grid prepare of
     `_get_stages` the four stage-1 programs a node may serve at a bucket;
-    the dispatch's own keys say which."""
+    the dispatch's own keys say which. Stage 3: `_stage_pairs_folded`,
+    where the dispatch's own messages say so (message_lanes)."""
     import jax
 
     from . import pipeline as pl
@@ -798,7 +887,7 @@ def _get_prepare_variant(stage: str):
 
         setup_compilation_cache()
         _kernel_cache[key] = jax.jit(
-            _PREPARE_VARIANTS[stage],
+            _ONE_CHIP_VARIANTS[stage],
             **(dict(donate_argnums=STAGE_DONATE_ARGNUMS[stage])
                if donate else {}),
         )
@@ -819,7 +908,13 @@ def warm_stages(n_sets: int, n_pks: int, single_chip: bool = False) -> None:
     ONE (n, m) key grid, which a batch of near-equal widths, the urgent
     lane and a mesh run; a one-chip batch of unequal widths (a block, a
     dispatch of aggregates) runs the two-grid prepare of its own
-    key_grid_plan and compiles it at its first dispatch.
+    key_grid_plan and compiles it at its first dispatch. On one chip's
+    batch lane a bucket wider than one row of message lanes also warms
+    what a dispatch that folds to that row runs (message_lanes' k = 128,
+    what a mainnet slot's attestation messages give): hash-to-G2 at k
+    lanes, `_stage_pairs_folded` and stage 4 at k + 1 pairs, the last two
+    on zero inputs shaped as stage outputs are; another k compiles at its
+    first dispatch.
 
     Callers: the node's startup warmup thread walks the autotune plan's
     bucket list through here (autotune/runtime.start_warmup — which also
@@ -835,7 +930,7 @@ def warm_stages(n_sets: int, n_pks: int, single_chip: bool = False) -> None:
     from ...parallel import get_mesh, put_pk_grid, put_single, put_sets
 
     mesh = None if single_chip else get_mesh()
-    prepare, h2c_stage, _, _ = _get_stages(mesh=mesh)
+    prepare, h2c_stage, _, pairing_stage = _get_stages(mesh=mesh)
     n, m = padding_bucket(n_sets, n_pks, mesh=mesh, single_chip=single_chip)
     t0 = time.time()
 
@@ -853,6 +948,17 @@ def warm_stages(n_sets: int, n_pks: int, single_chip: bool = False) -> None:
     def _warm(fn, *args):
         jax.block_until_ready(fn(*args))
 
+    def _warm_folded(k):
+        def zeros(*shape):
+            return put_single(np.zeros(shape + (lb.NL,), np.uint32))
+
+        _warm(pairing_stage, *_get_one_chip_variant("pairs_folded")(
+            tuple(zeros(n) for _ in range(3)),
+            tuple(zeros(k, 2) for _ in range(3)),
+            tuple(zeros(2) for _ in range(3)),
+            put_single(np.zeros((2, n), np.int32)),
+        ))
+
     threads = [
         threading.Thread(
             target=_warm,
@@ -860,6 +966,13 @@ def warm_stages(n_sets: int, n_pks: int, single_chip: bool = False) -> None:
         ),
         threading.Thread(target=_warm, args=(h2c_stage, us)),
     ]
+    k = message_lanes(po.MILLER_LANES, n)      # a slot's 128 messages
+    if mesh is None and not single_chip and k < n:
+        threads += [
+            threading.Thread(target=_warm, args=(
+                h2c_stage, put_single(np.zeros((k, 2, 2, lb.NL), np.uint32)))),
+            threading.Thread(target=_warm_folded, args=(k,)),
+        ]
     for t in threads:
         t.start()
     for t in threads:
@@ -904,7 +1017,7 @@ def warm_prepare_indexed(n_sets: int, n_pks: int, table) -> None:
 
     n, m = padding_bucket(n_sets, n_pks, single_chip=True)
     table_x, table_y, _ = table.snapshot()
-    jax.block_until_ready(_get_prepare_variant("prepare_indexed")(
+    jax.block_until_ready(_get_one_chip_variant("prepare_indexed")(
         table_x, table_y,
         put_single(np.zeros((n, m), np.int32)),
         put_single(np.ones((n, m), np.uint32)),
@@ -1188,13 +1301,28 @@ class JaxBackend:
             _BUCKET_SLOTS.labels("keys", "real").inc(real_keys)
             _BUCKET_SLOTS.labels("keys", "padded").inc(
                 sum(rows * width for rows, width in grids))
-            distinct_messages = len({s.message for s in sets})
+            # the messages: each distinct byte string once, in the order it
+            # first comes (a set whose message differs in one byte has a
+            # lane of its own), and how they lie — a lane a set, or on one
+            # chip's batch lane message_lanes' k < n lanes, the sets folded
+            # onto them before stage 3
+            lane_of: dict = {}
+            for s in sets:
+                lane_of.setdefault(s.message, len(lane_of))
+            distinct_messages = len(lane_of)
+            k = (n if urgent or mesh is not None
+                 else message_lanes(distinct_messages, n))
+            folds = k < n
             _DISPATCH_MESSAGES.labels("sent").inc(n_real)
             _DISPATCH_MESSAGES.labels("distinct").inc(distinct_messages)
+            _DISPATCH_MESSAGES.labels("lanes").inc(k)
+            _DISPATCH_MESSAGES.labels("folded_sets").inc(n_real if folds else 0)
+            if folds:
+                pairs_stage = _get_one_chip_variant("pairs_folded")
             _TREE_SUM_LANE_ADDS.labels("done").inc(
                 sum(co.tree_sum_plan(width, rows)[3] for rows, width in grids))
             _TREE_SUM_LANE_ADDS.labels("needed").inc(real_keys - n_real)
-            miller_pairs = n + 1
+            miller_pairs = k + 1
             if isinstance(pairing_stage, _PairingDispatch):
                 miller_pairs = pairing_stage.miller_pairs(miller_pairs)
             _count_miller_plan(miller_pairs)
@@ -1219,7 +1347,7 @@ class JaxBackend:
             variant = ("prepare" + ("" if indexed is None else "_indexed")
                        + ("_grids" if len(grids) == 2 else ""))
             if variant != "prepare":
-                prepare = _get_prepare_variant(variant)
+                prepare = _get_one_chip_variant(variant)
 
             with _obs.span("jaxbls:marshal.sigs"):
                 sig_x = np.zeros((n, 2, lb.NL), np.uint32)
@@ -1244,13 +1372,22 @@ class JaxBackend:
                 )[:, :Z_DIGITS]
                 set_mask[:n_real] = 1
 
-            with _obs.span("jaxbls:marshal.h2f"):
-                us = np.zeros((n, 2, 2, lb.NL), np.uint32)
-                us[:n_real] = h2.hash_to_field_batch(
-                    [s.message for s in sets], self.dst)
+            with _obs.span("jaxbls:marshal.h2f", messages=distinct_messages):
+                # each distinct message hashed once, whatever is laid; the
+                # sets are never permuted: a folding dispatch sends, beside
+                # its k lanes of `us`, where each set's z * pk goes
+                us = np.zeros((k, 2, 2, lb.NL), np.uint32)
+                hashed = h2.hash_to_field_batch(list(lane_of), self.dst)
+                lanes = [lane_of[s.message] for s in sets]
+                if folds:
+                    us[:distinct_messages] = hashed
+                    fold = message_fold_index(lanes, n, k)
+                else:
+                    us[:n_real] = hashed[lanes]
 
             nbytes = (sig_x.nbytes + sig_y.nbytes + z_digits.nbytes
-                      + set_mask.nbytes + us.nbytes)
+                      + set_mask.nbytes + us.nbytes
+                      + (fold.nbytes if folds else 0))
             # staged dispatch: intermediates stay on device between jit calls,
             # inputs placed with the set axis sharded over the mesh (urgent:
             # whole on one chip; also the no-mesh single-device case)
@@ -1259,6 +1396,8 @@ class JaxBackend:
                 sig_x, sig_y, z_digits, set_mask, us = (
                     put(sig_x), put(sig_y), put(z_digits), put(set_mask), put(us),
                 )
+                # stage 3's last argument: the fold's index, or the set mask
+                pairs_by = put(fold) if folds else set_mask
         _MARSHAL_SECONDS.observe(marshalled.t1 - marshalled.t0)
         tr = _obs.current_trace()
         if tr is not None:
@@ -1266,7 +1405,8 @@ class JaxBackend:
                         key_grids="+".join(f"{r}x{w}" for r, w in grids),
                         real_sets=n_real,
                         real_keys=real_keys,
-                        distinct_messages=distinct_messages)
+                        distinct_messages=distinct_messages,
+                        message_lanes=k)
 
         def dispatch():
             # the dispatcher's `jaxbls:enqueue` span is open around this
@@ -1284,7 +1424,7 @@ class JaxBackend:
             )
             h_jac = _obs_dev.run_stage(attr, "h2c", h2c_stage, us)
             px, py, qxx, qyy, pair_mask = _obs_dev.run_stage(
-                attr, "pairs", pairs_stage, z_pk, h_jac, sig_acc, set_mask
+                attr, "pairs", pairs_stage, z_pk, h_jac, sig_acc, pairs_by
             )
             ok = _obs_dev.run_stage(
                 attr, "pairing", pairing_stage, px, py, qxx, qyy, pair_mask

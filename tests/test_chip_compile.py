@@ -26,7 +26,12 @@ serve since PR 42: the Electra block's 8x32768 + 4x512 by index, the Deneb
 block's 1x512 + 256x128 and the aggregates' 64x512 + 128x1 packed; and
 since PR 43 the 1024x1 bucket of `subnet_flood_1key` (`pairs` unmarked,
 ~45 s; `slow`: the indexed prepare ~75 s, hash-to-G2 at 1,024 lanes ~3.5
-min and 1.9 GB of temporaries, the Miller loop at 1,025 pairs ~3.5 min).
+min and 1.9 GB of temporaries, the Miller loop at 1,025 pairs ~3.5 min);
+since PR 44 `_stage_pairs_folded`, stage 3 of a dispatch that folds its
+sets by message onto a row of 128 lanes (`backend.message_lanes`): from
+256 sets unmarked (~45 s), from 1,024 `slow` (~1 min). The programs beside
+it at 128 lanes and 129 pairs are hash-to-G2 and the Miller loop as the
+64x128 cases compile them, at twice the lanes.
 """
 
 import numpy as np
@@ -219,7 +224,7 @@ def test_two_grid_prepare_compiles_for_v5e(dispatch, one_chip,
     chip, and hands stage 3 what the one-grid prepare hands it."""
     (n, m), widths, stage = MIXED_DISPATCHES[dispatch]
     be._init_consts()
-    fn = be._PREPARE_VARIANTS[stage]
+    fn = be._ONE_CHIP_VARIANTS[stage]
     args = _grids_args(stage, n, m, widths, one_chip)
     compiled = jax.jit(
         fn, donate_argnums=be.STAGE_DONATE_ARGNUMS[stage]
@@ -228,6 +233,28 @@ def test_two_grid_prepare_compiles_for_v5e(dispatch, one_chip,
     pairs = _stage_args(n, m, one_chip)["pairs"]
     assert _shapes(jax.eval_shape(fn, *args))[:2] == _shapes(
         (pairs[0], pairs[2]))
+
+
+@pytest.mark.parametrize("n", [
+    256, pytest.param(1024, marks=pytest.mark.slow)])
+def test_folded_stage_3_compiles_for_v5e(n, one_chip, no_persistent_cache):
+    """`_stage_pairs_folded` as the TPU node jits it, from the n sets of a
+    served bucket onto one row of message lanes: it compiles, fits the
+    chip, and hands the Miller loop k + 1 = 129 pairs, whatever n is."""
+    k = be.message_lanes(82, n)
+    assert k == po.MILLER_LANES < n
+    be._init_consts()
+    z_pk, _, sig_acc, _ = _stage_args(n, 1, one_chip)["pairs"]
+    _, h_jac, _, _ = _stage_args(k, 1, one_chip)["pairs"]
+    args = (z_pk, h_jac, sig_acc,
+            jax.ShapeDtypeStruct((2, n), jnp.int32, sharding=one_chip))
+    fn = be._ONE_CHIP_VARIANTS["pairs_folded"]
+    compiled = jax.jit(
+        fn, donate_argnums=be.STAGE_DONATE_ARGNUMS["pairs_folded"]
+    ).lower(*args).compile()
+    _assert_fits_hbm(compiled)
+    assert _shapes(jax.eval_shape(fn, *args)) == _shapes(
+        _stage_args(k, 1, one_chip)["miller"])
 
 
 def test_tree_hash_ladder_compiles_for_v5e(one_chip, no_persistent_cache):

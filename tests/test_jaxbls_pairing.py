@@ -103,6 +103,96 @@ def test_padded_lanes_contribute_one():
     assert bool(_product_check(dp, dq, mask))
 
 
+# Stage 3 of a dispatch that folds its sets by message (backend.message_lanes,
+# PR 44) in front of this module's four-lane product check: six single-key
+# sets in a bucket of eight on up to three message lanes, k + 1 = 4 pairs.
+# Stage 1's outputs are
+# the host's (z * pk a set, the sum of z * sig; 64-bit coefficients), the
+# messages' points the pure-Python hash-to-G2's; `_stage_pairs_folded` from 8
+# sets onto 3 lanes is the one program compiled here (small). The verdict is
+# the pure-Python backend's on the same sets.
+_FOLDED = ((3, b"\xA1" * 32), (9, b"\xA1" * 32), (0, b"\xB2" * 32),
+           (7, b"\xA1" * 32), (3, b"\xB2" * 32), (2, b"\xA1" * 32))
+_pairs_folded = jax.jit(be._stage_pairs_folded)
+
+
+@functools.lru_cache(maxsize=None)
+def _folded_signature(sk: int, msg: bytes):
+    """sk H(msg) by the pure-Python curve code, once a (key, message)."""
+    from lighthouse_tpu.crypto.bls import api as bls_api
+
+    return pc.g2_mul(bls_api.hash_to_g2_point(msg), sk)
+
+
+@pytest.mark.parametrize("damage", [
+    None, "swapped_among_one_message", "flipped_message", "replaced_signer",
+    "one_message"], ids=lambda d: d or "valid")
+def test_folded_stage_3_then_the_product_check_give_the_reference_verdict(
+        damage):
+    """Sound (two messages, four and two sets, one validator under both;
+    the third lane holds no message and is masked), with the signatures of
+    two sets of ONE message exchanged, with one byte of one set's shared
+    message flipped (a lane of its own, the third), with a signer replaced,
+    and with every set on one message."""
+    from lighthouse_tpu.crypto import bls
+    from lighthouse_tpu.crypto.bls import api as bls_api
+
+    be._init_consts()
+    r = random.Random(0xF01D)
+    sks = [r.randrange(1, R) for _ in range(12)]
+    pks = [bls.PublicKey(pc.g1_mul(pc.G1_GEN, sk)) for sk in sks]
+
+    def signed(i, msg, signer=None):
+        sk = sks[i if signer is None else signer]
+        return bls.SignatureSet(
+            bls.Signature(_folded_signature(sk, msg)), [pks[i]], msg)
+
+    folded = _FOLDED
+    if damage == "one_message":
+        folded = tuple((i, b"\xA1" * 32) for i, _ in _FOLDED[:4]) + (
+            (10, b"\xA1" * 32), (2, b"\xA1" * 32))
+    sets = [signed(i, msg) for i, msg in folded]
+    if damage == "swapped_among_one_message":
+        a, b = sets[0], sets[3]                   # both sign \xA1...
+        assert a.message == b.message
+        sets[0] = bls.SignatureSet(b.signature, a.signing_keys, a.message)
+        sets[3] = bls.SignatureSet(a.signature, b.signing_keys, b.message)
+    elif damage == "flipped_message":
+        victim = sets[1]
+        sets[1] = bls.SignatureSet(victim.signature, victim.signing_keys,
+                                   b"\xA0" + victim.message[1:])
+    elif damage == "replaced_signer":
+        sets[5] = signed(4, sets[5].message, signer=2)
+    bls_api.set_backend("python")
+    want = bls.verify_signature_sets(sets)
+    assert want is (damage in (None, "one_message"))
+
+    n, k = 8, 3
+    lane_of: dict = {}
+    for s in sets:
+        lane_of.setdefault(s.message, len(lane_of))
+    assert len(lane_of) == {"flipped_message": 3, "one_message": 1}.get(
+        damage, 2)
+    zs = [r.randrange(1, 1 << 64) for _ in sets]
+    z_pk = co.g1_batch_to_device(
+        [pc.g1_mul(s.signing_keys[0].point, z) for s, z in zip(sets, zs)]
+        + [None] * (n - len(sets)))                   # padded set slots
+    sig_sum = None
+    for s, z in zip(sets, zs):
+        sig_sum = pc.g2_add(sig_sum, pc.g2_mul(s.signature.point, z))
+    h_jac = co.g2_batch_to_device(
+        [bls_api.hash_to_g2_point(m) for m in lane_of]
+        + [None] * (k - len(lane_of)))
+    px, py, qxx, qyy, pair_mask = _pairs_folded(
+        z_pk, h_jac, co.g2_to_device(sig_sum),
+        be.message_fold_index([lane_of[s.message] for s in sets], n, k))
+    assert px.shape[0] == k + 1
+    assert [bool(b) for b in np.asarray(pair_mask)] == [
+        j < len(lane_of) for j in range(k)] + [True]
+    assert bool(_product_check((px, py), (qxx, qyy), pair_mask)) is want
+    assert _pairs_folded._cache_size() == 1   # one shape, every distribution
+
+
 def test_final_exp_matches_python_on_random_miller_output():
     # Feed the same Miller value through both final exps.
     p = pc.g1_mul(pc.G1_GEN, rng.randrange(1, R))

@@ -613,7 +613,7 @@ def _one_device_programs():
                     for a in (limbs(*g), limbs(*g), np.ones(g, np.uint32)))
             if len(grids) == 2:
                 keys += (put_single(np.zeros((4,), np.int32)),)
-            jax.block_until_ready(be._get_prepare_variant(stage)(
+            jax.block_until_ready(be._get_one_chip_variant(stage)(
                 *keys, put_single(limbs(4, 2)), put_single(limbs(4, 2)),
                 ones(4, be.Z_DIGITS), ones(4)))
 
@@ -730,7 +730,7 @@ def test_indexed_prepare_is_bit_equal_to_the_packed_prepare(registry_chain):
     assert np.array_equal(np.asarray(pk_mask), mask)
     rest = _prepare_rest(sets)
     table = backend.registry
-    got = be._get_prepare_variant("prepare_indexed")(table.x, table.y, idx, mask, *rest)
+    got = be._get_one_chip_variant("prepare_indexed")(table.x, table.y, idx, mask, *rest)
     want = be._get_stages(mesh=None)[0](pk_x, pk_y, pk_mask, *rest)
     import jax
 
@@ -766,9 +766,9 @@ def test_block_by_index_through_signature_batch_parity(registry_chain,
     # the start-up warm-up compiles the one-grid program, which a batch of
     # near-equal widths runs; this block runs the two-grid one, compiled at
     # its first dispatch (here: by the module's fixture)
-    one_grid = be._get_prepare_variant("prepare_indexed")
+    one_grid = be._get_one_chip_variant("prepare_indexed")
     be.warm_prepare_indexed(3, 4, backend.registry)       # rounds to (4, 4)
-    program = be._get_prepare_variant("prepare_indexed_grids")
+    program = be._get_one_chip_variant("prepare_indexed_grids")
     compiled = program._cache_size(), one_grid._cache_size()
     adds = be._TREE_SUM_LANE_ADDS.labels("done")
     adds0 = adds.value
@@ -1007,7 +1007,7 @@ def test_a_mixed_batch_on_two_grids_gives_the_reference_verdict(
 
     sets = _pair_batch(case)
     assert be.key_grid_plan([1, 2, 2, 4], 4, 4)[0] == _PAIR_GRIDS
-    program = be._get_prepare_variant("prepare_grids")
+    program = be._get_one_chip_variant("prepare_grids")
     compiled = program._cache_size()
     padded = be._BUCKET_SLOTS.labels("keys", "padded")
     adds = be._TREE_SUM_LANE_ADDS.labels("done")
@@ -1070,7 +1070,7 @@ def test_two_grids_sum_to_the_one_grids_aggregate_keys(registry_chain, case,
         (1, 4, 24), (1, 4, 24), (1, 4), (4, 2, 24), (4, 2, 24), (4, 2), (4,)]
     assert sum(int(np.asarray(m).sum()) for m in (grids[2], grids[5])) == (
         int(np.asarray(one[2]).sum())) == sum(len(s.signing_keys) for s in sets)
-    got = be._get_prepare_variant("prepare_grids")(*grids, *rest)
+    got = be._get_one_chip_variant("prepare_grids")(*grids, *rest)
     want = be._get_stages(mesh=None)[0](*one, *rest)
 
     def aggregate(s, k):
@@ -1112,10 +1112,10 @@ def test_indexed_grids_prepare_is_bit_equal_to_the_packed_grids_prepare(
     assert np.array_equal(np.asarray(packed[5]), narrow_mask)
     assert np.asarray(packed[6]).tolist() == plan[1].tolist()
     rest = _prepare_rest(sets)
-    got = be._get_prepare_variant("prepare_indexed_grids")(
+    got = be._get_one_chip_variant("prepare_indexed_grids")(
         table.x, table.y, wide_idx, wide_mask, narrow_idx, narrow_mask,
         plan[1], *rest)
-    want = be._get_prepare_variant("prepare_grids")(*packed, *rest)
+    want = be._get_one_chip_variant("prepare_grids")(*packed, *rest)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         assert a.shape == b.shape and a.dtype == b.dtype
