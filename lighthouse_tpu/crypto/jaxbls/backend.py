@@ -130,14 +130,22 @@ _REGISTRY_KEYS = REGISTRY.counter_vec(
     "host into the (n, m) limb grid",
     ("source",),
 )
+_PREPARE_REFUSED = REGISTRY.counter_vec(
+    "jaxbls_prepare_refused_total",
+    "dispatches whose verdict is False by stage 1's `bad` code, read with "
+    "the verdict: identity_aggpk = a real set's keys sum to the identity, "
+    "chain_exception = a coefficient chain (curve_ops.scalar_mul_z) of a "
+    "real set met accumulator = +-base, which no point of order r gives: a "
+    "key or signature outside the subgroup; 0 on honest traffic",
+    ("why",),
+)
 # buckets that have resolved at least once: the benchmark's drivers and
 # chip_smoke.py check that a run compiled the one bucket it meant to
 _seen_exec_buckets: set = set()
 
 MIN_SETS = 4          # smallest bucket (pairs axis = sets + 1 rounded up)
 MIN_PKS = 1
-Z_WINDOW = 1          # z-scaling digit width: 1 = plain double-and-add bits
-Z_DIGITS = 64 // Z_WINDOW
+Z_BITS = 64           # a coefficient as the marshal uploads it: its bits, MSB first
 
 _LIVE_MESH = object()  # sentinel: "resolve parallel.get_mesh() lazily"
 
@@ -257,6 +265,24 @@ def message_lanes(distinct: int, n: int) -> int:
     return k if k < n else n
 
 
+# The digit width of stage 1's two coefficient chains (co.scalar_mul_z:
+# z_i * aggpk_i and z_i * sig_i), one constant for every lane count, named on
+# the dispatch's trace (`z_window`). From scripts/measure_z_chain.py's table on
+# a v5e (PERF.md S6, PR 45; each chain alone, ms, median of 5, at 4 / 16 / 64 /
+# 128 / 256 / 512 / 1,024 lanes; parent = scalar_mul_bits): G2 parent 75.0 /
+# 60.7 / 68.6 / 28.6 / 55.8 / 118.4 / 236.2, one bit a step 34.8 / 35.3 / 45.7
+# / 15.0 / 28.3 / 58.3 / 114.6, two bits 27.6 / - / 48.3 / - / 25.6 / - /
+# 100.2, four 25.9 / 24.1 / 38.2 / 11.6 / 22.0 / 44.1 / 87.0; G1 parent 17.1 /
+# 15.8 / 22.2 / 10.8 / 18.8 / 36.6 / 72.7, one bit 16.0 / 11.9 / 16.8 / 8.6 /
+# 14.3 / 27.9 / 54.7, four 9.1 / 8.3 / 12.2 / 6.3 / 9.8 / 18.5 / 36.0: four
+# bits a digit is the cheapest form at EVERY width on both groups, and a chain
+# costs in proportion to its lanes from 128 on, so the lanes are not walked in
+# chunks. A FULL row of 128 lanes is the cheapest width of all, under half of
+# 4-64 lanes: padding narrow dispatches to it is the next lever, not taken
+# here.
+Z_WINDOW = 4
+
+
 def message_fold_index(lanes, n: int, k: int) -> np.ndarray:
     """What a folding dispatch sends stage 3 beside its k lanes of `us`,
     from the message lane of each real set (the caller's order): int32[2, n],
@@ -353,29 +379,37 @@ def _sum_key_grid(pk_x, pk_y, pk_mask):
     return co.tree_sum(pk_jac_t, co.FQ_OPS)                # (rows,) jacobian G1
 
 
+#: the `bad` output of stage 1 is a code, one bit a reason (VerifyHandle
+#: refuses on any, and counts each in jaxbls_prepare_refused_total{why})
+PREPARE_REFUSED = {1: "identity_aggpk", 2: "chain_exception"}
+
+
 def _prepare_from_sums(aggpk, sig_x, sig_y, z_digits, set_mask):
     """Stage 1 after the key side: the sets' aggregate keys (n,) and the
-    signatures in Montgomery form to (z_pk, sig_acc, bad_aggpk)."""
+    signatures in Montgomery form to (z_pk, sig_acc, bad). `bad` is a
+    uint32 code, zero for a dispatch stage 1 has nothing against:
+    PREPARE_REFUSED's 1 where a real set's keys sum to the identity, 2 where
+    a coefficient chain of a real set met the case its additions leave out
+    (co.scalar_mul_z: the accumulator +-the base, which no point of order r
+    gives — a signature or key outside the subgroup steered it there, and
+    the spec refuses such a point)."""
     import jax.numpy as jnp
 
+    real = jnp.asarray(set_mask, bool)
     aggpk_inf = co.FQ_OPS.is_zero(aggpk[2])
-    bad_aggpk = jnp.any(jnp.logical_and(aggpk_inf, set_mask))
+    bad_aggpk = jnp.any(jnp.logical_and(aggpk_inf, real))
 
-    # z_i * aggpk_i (double-and-add: the windowed form's runtime table
-    # build added ~25k HLO ops per instance and dominated kernel compiles)
-    z_pk = co.scalar_mul_bits(aggpk, z_digits, co.FQ_OPS)
-
-    # sum_i z_i * sig_i  (mask padded sets to identity first)
-    sig_jac = co.affine_to_jac(co.FQ2_OPS, (sig_x, sig_y), inf_mask=jnp.logical_not(set_mask))
-    z_sig = co.scalar_mul_bits(sig_jac, z_digits, co.FQ2_OPS)
-    z_sig = co.pt_select(
-        co.FQ2_OPS,
-        jnp.asarray(set_mask, bool),
-        z_sig,
-        tuple(jnp.broadcast_to(c, x.shape) for c, x in zip(co.identity(co.FQ2_OPS), z_sig)),
-    )
+    # z_i * aggpk_i, and z_i * sig_i with a padded set's signature the
+    # identity by its mask: its product then stays the identity
+    z_pk, met_pk = co.scalar_mul_z(aggpk, z_digits, co.FQ_OPS, window=Z_WINDOW)
+    z_sig, met_sig = co.scalar_mul_z(
+        (sig_x, sig_y), z_digits, co.FQ2_OPS,
+        p_inf=jnp.logical_not(real), window=Z_WINDOW)
+    met = jnp.logical_or(met_pk, met_sig)
     sig_acc = co.tree_sum(z_sig, co.FQ2_OPS)               # single jacobian G2
-    return z_pk, sig_acc, bad_aggpk
+    bad = (bad_aggpk.astype(jnp.uint32)
+           | (jnp.any(jnp.logical_and(met, real)).astype(jnp.uint32) << 1))
+    return z_pk, sig_acc, bad
 
 
 def _stage_prepare(pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits, set_mask):
@@ -555,14 +589,14 @@ def _verify_kernel(pk_x, pk_y, pk_mask, sig_x, sig_y, us, z_digits, set_mask):
       us:        (n, 2, 2, NL) hash_to_field outputs per message (standard)
       z_digits:  (n, 64)     coefficient bits, MSB first
       set_mask:  (n,)        1 = real set
-    Returns (ok, any_bad_aggpk)."""
-    z_pk, sig_acc, bad_aggpk = _stage_prepare(
+    Returns (ok, bad): stage 1's code, zero for nothing against."""
+    z_pk, sig_acc, bad = _stage_prepare(
         pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits, set_mask
     )
     h_jac = h2.hash_to_g2_jacobian(us)
     px, py, qxx, qyy, pair_mask = _stage_pairs(z_pk, h_jac, sig_acc, set_mask)
     ok = _stage_pairing(px, py, qxx, qyy, pair_mask)
-    return ok, bad_aggpk
+    return ok, bad
 
 
 _NEG_G1_GEN = None
@@ -941,7 +975,7 @@ def warm_stages(n_sets: int, n_pks: int, single_chip: bool = False) -> None:
     pk_mask = put_pk_grid(np.ones((n, m), np.uint32))
     sig_x = put_sets(np.zeros((n, 2, lb.NL), np.uint32))
     sig_y = put_sets(np.zeros((n, 2, lb.NL), np.uint32))
-    z_digits = put_sets(np.ones((n, Z_DIGITS), np.uint32))
+    z_digits = put_sets(np.ones((n, Z_BITS), np.uint32))
     set_mask = put_sets(np.ones((n,), np.uint32))
     us = put_sets(np.zeros((n, 2, 2, lb.NL), np.uint32))
 
@@ -991,7 +1025,7 @@ def warm_stages(n_sets: int, n_pks: int, single_chip: bool = False) -> None:
         if _pl.donation_enabled()[0]:
             sig_x = put_sets(np.zeros((n, 2, lb.NL), np.uint32))
             sig_y = put_sets(np.zeros((n, 2, lb.NL), np.uint32))
-            z_digits = put_sets(np.ones((n, Z_DIGITS), np.uint32))
+            z_digits = put_sets(np.ones((n, Z_BITS), np.uint32))
             us = put_sets(np.zeros((n, 2, 2, lb.NL), np.uint32))
         _obs_perf.maybe_capture_program(
             "prepare", prepare,
@@ -1023,7 +1057,7 @@ def warm_prepare_indexed(n_sets: int, n_pks: int, table) -> None:
         put_single(np.ones((n, m), np.uint32)),
         put_single(np.zeros((n, 2, lb.NL), np.uint32)),
         put_single(np.zeros((n, 2, lb.NL), np.uint32)),
-        put_single(np.ones((n, Z_DIGITS), np.uint32)),
+        put_single(np.ones((n, Z_BITS), np.uint32)),
         put_single(np.ones((n,), np.uint32)),
     ))
 
@@ -1033,7 +1067,8 @@ class VerifyHandle:
 
     Keeps references to the dispatched device values so the work proceeds
     asynchronously; result() blocks on the device and applies the host-side
-    semantic (bad aggregate pubkey => False). Dispatch-timed handles carry
+    semantic (stage 1's `bad` code nonzero => False, each reason counted in
+    jaxbls_prepare_refused_total at the first resolve). Dispatch-timed handles carry
     their padding bucket and submit time so resolving feeds the autotune
     profiler (first resolve only — result() is idempotent). The handle
     keeps the pipeline Trace current at its dispatch: `jaxbls:device_wait`
@@ -1059,7 +1094,7 @@ class VerifyHandle:
         if self._hostfail:
             return False
         with _obs.span("jaxbls:device_wait", self._trace) as waited:
-            r = bool(np.asarray(self._ok)) and not bool(np.asarray(self._bad))
+            ok, bad = bool(np.asarray(self._ok)), int(np.asarray(self._bad))
         self.t_ready = waited.t1
         if self._t0 is not None and self._bucket is not None:
             from ...autotune import profiler
@@ -1067,7 +1102,10 @@ class VerifyHandle:
             dt, self._t0 = self.t_ready - self._t0, None
             profiler.observe_dispatch(*self._bucket, dt, self._n_real)
             _seen_exec_buckets.add(self._bucket)
-        return r
+            for bit, why in PREPARE_REFUSED.items():
+                if bad & bit:
+                    _PREPARE_REFUSED.labels(why).inc()
+        return ok and not bad
 
 
 class JaxBackend:
@@ -1352,7 +1390,7 @@ class JaxBackend:
             with _obs.span("jaxbls:marshal.sigs"):
                 sig_x = np.zeros((n, 2, lb.NL), np.uint32)
                 sig_y = np.zeros((n, 2, lb.NL), np.uint32)
-                z_digits = np.zeros((n, Z_DIGITS), np.uint32)
+                z_digits = np.zeros((n, Z_BITS), np.uint32)
                 set_mask = np.zeros((n,), np.uint32)
 
                 sig_ints = []
@@ -1367,9 +1405,8 @@ class JaxBackend:
                 sig_y[:n_real, 1] = pack_ints_vec([sp[1][1] for sp in sig_ints])
 
                 zmask = (1 << 64) - 1
-                z_digits[:n_real] = co.scalars_to_digits(
-                    [z & zmask for z in rands], 64, Z_WINDOW
-                )[:, :Z_DIGITS]
+                z_digits[:n_real] = co.scalars_to_bits(
+                    [z & zmask for z in rands], Z_BITS)
                 set_mask[:n_real] = 1
 
             with _obs.span("jaxbls:marshal.h2f", messages=distinct_messages):
@@ -1406,7 +1443,7 @@ class JaxBackend:
                         real_sets=n_real,
                         real_keys=real_keys,
                         distinct_messages=distinct_messages,
-                        message_lanes=k)
+                        message_lanes=k, z_window=Z_WINDOW)
 
         def dispatch():
             # the dispatcher's `jaxbls:enqueue` span is open around this

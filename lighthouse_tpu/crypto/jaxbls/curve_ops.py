@@ -228,6 +228,183 @@ def scalar_mul_bits(p_jac, bits, ops):
     return acc
 
 
+# ---- the batch-verification coefficient's chain ---------------------------
+#
+# z * P for a 64-bit z and a P of prime order r ~ 2^255: every accumulator is
+# k * P with 0 <= k < 2^64, so the chain never adds a point to itself or to
+# its negative, and the complete jac_add's embedded doubling (7 of its 23
+# products) is dead work. The formulas below leave it out and REPORT the one
+# comparison that says they were wrong (H == 0 with both points finite), so a
+# caller whose input is not of order r refuses it instead of trusting the sum.
+
+
+def _products(ops, muls=(), sqrs=()):
+    """One stacked multiply round: the products a * b of `muls` and the
+    squares of `sqrs`, all in ONE lb.mont_mul call. Over Fq a square is a
+    product; over Fq2 a product is three Fq lanes (Karatsuba, as
+    tower.fq2_mul) and a square two ((a0 + a1)(a0 - a1), a0 a1, as
+    tower.fq2_sqr). Returns (products, squares) as tuples."""
+    n_m, n_s = len(muls), len(sqrs)
+    if ops is FQ_OPS:
+        t = lb.mont_mul(
+            jnp.stack([a for a, _ in muls] + list(sqrs), axis=-2),
+            jnp.stack([b for _, b in muls] + list(sqrs), axis=-2),
+        )
+        out = tuple(t[..., i, :] for i in range(n_m + n_s))
+        return out[:n_m], out[n_m:]
+
+    def parts(els):
+        s = jnp.stack(els, axis=-3)                    # (..., k, 2, NL)
+        return s[..., 0, :], s[..., 1, :]
+
+    lhs, rhs = [], []        # the Fq lanes of the one mont_mul, by group
+    if n_m:
+        a0, a1 = parts([a for a, _ in muls])
+        b0, b1 = parts([b for _, b in muls])
+    if n_s:
+        s0, s1 = parts(list(sqrs))
+    # every operand sum in one add: a0 + a1, b0 + b1, s0 + s1
+    sums = lb.add_mod(
+        jnp.concatenate(([a0, b0] if n_m else []) + ([s0] if n_s else []), axis=-2),
+        jnp.concatenate(([a1, b1] if n_m else []) + ([s1] if n_s else []), axis=-2),
+    )
+    if n_m:
+        lhs += [a0, a1, sums[..., :n_m, :]]
+        rhs += [b0, b1, sums[..., n_m:2 * n_m, :]]
+    if n_s:
+        lhs += [sums[..., 2 * n_m:, :], s0]
+        rhs += [lb.sub_mod(s0, s1), s1]
+    t = lb.mont_mul(jnp.concatenate(lhs, axis=-2), jnp.concatenate(rhs, axis=-2))
+    t0, t1, t2 = (t[..., i * n_m:(i + 1) * n_m, :] for i in range(3))
+    c0, h = t[..., 3 * n_m:3 * n_m + n_s, :], t[..., 3 * n_m + n_s:, :]
+    # t0 + t1 (a product's cross term needs it) and 2 a0 a1 in one add
+    adds = lb.add_mod(jnp.concatenate([t0, h], axis=-2),
+                      jnp.concatenate([t1, h], axis=-2))
+    prods = sqs = ()
+    if n_m:
+        res = lb.sub_mod(jnp.concatenate([t0, t2], axis=-2),
+                         jnp.concatenate([t1, adds[..., :n_m, :]], axis=-2))
+        prods = tuple(jnp.stack([res[..., i, :], res[..., n_m + i, :]], axis=-2)
+                      for i in range(n_m))
+    if n_s:
+        sqs = tuple(jnp.stack([c0[..., i, :], adds[..., n_m + i, :]], axis=-2)
+                    for i in range(n_s))
+    return prods, sqs
+
+
+def _z_double(p, ops):
+    """jac_double with its five squares as squares: 2 products + 5 squares
+    in the same three rounds."""
+    X, Y, Z = p
+    (YZ,), (A, B) = _products(ops, [(Y, Z)], [X, Y])
+    E = ops.small(A, 3)
+    _, (C, t, F) = _products(ops, sqrs=[B, ops.add(X, B), E])
+    D = ops.small(ops.sub(ops.sub(t, A), C), 2)
+    X3 = ops.sub(F, ops.small(D, 2))
+    (EDX,), _ = _products(ops, [(E, ops.sub(D, X3))])
+    return (X3, ops.sub(EDX, ops.small(C, 8)), ops.small(YZ, 2))
+
+
+def _z_add(p1, p2, ops):
+    """p1 + p2 for Jacobian p1 and p2 either Jacobian (X2, Y2, Z2) or affine
+    (x2, y2) — a mixed addition, Z2 known to be one: 11 products where the
+    general sum takes 16, in the same five rounds. No doubling inside and no
+    identity handling: right wherever both points are finite and H != 0.
+    Returns (sum, H == 0); the caller selects around the identities and owns
+    what an H == 0 between finite points means."""
+    X1, Y1, Z1 = p1
+    if len(p2) == 2:
+        (X2, Y2), Z2 = p2, None
+        (Y2Z1,), (Z1Z1,) = _products(ops, [(Y2, Z1)], [Z1])
+        (U2, S2), _ = _products(ops, [(X2, Z1Z1), (Y2Z1, Z1Z1)])
+        U1, S1 = X1, Y1
+    else:
+        X2, Y2, Z2 = p2
+        (Y1Z2, Y2Z1), (Z1Z1, Z2Z2) = _products(
+            ops, [(Y1, Z2), (Y2, Z1)], [Z1, Z2])
+        (U1, U2, S1, S2), _ = _products(
+            ops, [(X1, Z2Z2), (X2, Z1Z1), (Y1Z2, Z2Z2), (Y2Z1, Z1Z1)])
+    H = ops.sub(U2, U1)
+    r = ops.sub(S2, S1)
+    if Z2 is None:
+        _, (HH, rr) = _products(ops, sqrs=[H, r])
+        (HHH, V, Z3), _ = _products(ops, [(H, HH), (U1, HH), (Z1, H)])
+    else:
+        (Z1Z2,), (HH, rr) = _products(ops, [(Z1, Z2)], [H, r])
+        (HHH, V, Z3), _ = _products(ops, [(H, HH), (U1, HH), (Z1Z2, H)])
+    X3 = ops.sub(ops.sub(rr, HHH), ops.small(V, 2))
+    (rVX3, S1HHH), _ = _products(ops, [(r, ops.sub(V, X3)), (S1, HHH)])
+    return (X3, ops.sub(rVX3, S1HHH), Z3), ops.is_zero(H)
+
+
+def _z_add_finite(acc, p, p_jac, p_inf, ops):
+    """acc + p by `_z_add`, selected around the identities: (the sum — p
+    where acc is the identity, acc where p is, by the mask `p_inf` —, the
+    lanes where two finite points met H == 0: acc = +-p, the case `_z_add`
+    gets wrong). `p` is `p_jac` itself or its affine (x, y)."""
+    added, h_zero = _z_add(acc, p, ops)
+    acc_inf = ops.is_zero(acc[2])
+    out = pt_select(ops, p_inf, acc, pt_select(ops, acc_inf, p_jac, added))
+    met = jnp.logical_and(
+        h_zero, jnp.logical_not(jnp.logical_or(acc_inf, p_inf)))
+    return out, met
+
+
+def scalar_mul_z(p, bits, ops, p_inf=None, window: int = 4):
+    """bits * p, the chain of the batch-verification coefficients: `bits`
+    (..., 64) uint32, MSB first; p Jacobian (X, Y, Z), or affine (x, y) with
+    `p_inf` (...,) bool marking the identity lanes (then every addition of p
+    is a mixed one). Returns (product, met): `met` (...,) bool is where some
+    step added two finite points with H == 0 — the accumulator was +-p,
+    which a 64-bit coefficient times a point of order r never is; a lane
+    that reports it holds no trustworthy product and its caller refuses it
+    (scalar_mul_bits, whose every step carries a doubling for that case,
+    stays for the scalars that can meet it: msm.py).
+
+    The table [0 .. 2^window - 1] * p is built once (one doubling, then a
+    scan of `previous + p`); then per `window` bits as many doublings and
+    ONE addition of the table entry the digit names (a one-hot masked sum,
+    as scalar_mul_windowed reads its table). `window` divides 64 and is at
+    least 2."""
+    if len(p) == 2:
+        p_jac = affine_to_jac(ops, p, inf_mask=p_inf)
+    else:
+        p_jac, p_inf = p, ops.is_zero(p[2])
+    none = jax.tree_util.tree_map(
+        lambda c, x: jnp.broadcast_to(c, x.shape), identity(ops), p_jac)
+    met0 = jnp.zeros(p_inf.shape, bool)
+    moved = jnp.moveaxis(bits, -1, 0)
+
+    nt = 1 << window
+    two = _z_double(p_jac, ops)
+
+    def next_entry(carry, _):
+        prev, met = carry
+        nxt, now = _z_add_finite(prev, p, p_jac, p_inf, ops)
+        return (nxt, jnp.logical_or(met, now)), nxt
+
+    (_, met), rest = jax.lax.scan(next_entry, (two, met0), None, length=nt - 3)
+    table = tuple(
+        jnp.concatenate([jnp.stack([z, x, d]), more])
+        for z, x, d, more in zip(none, p_jac, two, rest))
+    weights = jnp.asarray(1 << np.arange(window - 1, -1, -1), jnp.uint32)
+    digits = jnp.sum(
+        moved.reshape((-1, window) + moved.shape[1:])
+        * weights.reshape((1, window) + (1,) * (moved.ndim - 1)), axis=1)
+    nt_range = jnp.arange(nt, dtype=jnp.uint32)
+
+    def digit_step(carry, digit):
+        acc, met = carry
+        acc = jax.lax.fori_loop(
+            0, window, lambda _, a: _z_double(a, ops), acc)
+        q = _table_entry(table, digit, nt_range)
+        acc, now = _z_add_finite(acc, q, q, ops.is_zero(q[2]), ops)
+        return (acc, jnp.logical_or(met, now)), None
+
+    (acc, met), _ = jax.lax.scan(digit_step, (none, met), digits)
+    return acc, met
+
+
 def scalar_mul_static(p_jac, k: int, ops):
     """p * k for a static Python int k (e.g. cofactors, subgroup order)."""
     if k < 0:
@@ -245,6 +422,21 @@ def scalar_mul_static(p_jac, k: int, ops):
     init = jax.tree_util.tree_map(lambda c, x: jnp.broadcast_to(c, x.shape), identity(ops), p_jac)
     acc, _ = jax.lax.scan(body, init, bits)
     return acc
+
+
+def _table_entry(table_arr, digit, nt_range):
+    """Entry `digit` (...,) of a per-lane table — a tuple of coordinates,
+    each (nt,) + batch + element dims, `nt_range` = arange(nt) made outside
+    the caller's loop — by a one-hot masked sum (nt elementwise mult-adds).
+    A take_along_axis gather here made XLA:TPU compile times explode with
+    batch size; the mask-select form lowers to plain VPU ops."""
+    def g(coord):
+        # coord: (nt, ...batch, *elem)
+        oh = digit[None, ...] == nt_range[(slice(None),) + (None,) * digit.ndim]
+        oh = oh[(...,) + (None,) * (coord.ndim - 1 - digit.ndim)]
+        return jnp.sum(coord * jnp.asarray(oh, coord.dtype), axis=0)
+
+    return tuple(g(c) for c in table_arr)
 
 
 def scalar_mul_windowed(p_jac, digits, ops, window: int = 4):
@@ -278,16 +470,7 @@ def scalar_mul_windowed(p_jac, digits, ops, window: int = 4):
     nt_range = jnp.arange(nt, dtype=jnp.uint32)
 
     def gather(digit):
-        # digit: (...,) -> select table entries per lane via a one-hot
-        # masked sum (16 elementwise mult-adds). A take_along_axis gather
-        # here made XLA:TPU compile times explode with batch size; the
-        # mask-select form lowers to plain VPU ops.
-        def g(coord):
-            # coord: (nt, ...batch, *elem)
-            oh = digit[None, ...] == nt_range[(slice(None),) + (None,) * digit.ndim]
-            oh = oh[(...,) + (None,) * (coord.ndim - 1 - digit.ndim)]
-            return jnp.sum(coord * jnp.asarray(oh, coord.dtype), axis=0)
-        return tuple(g(c) for c in table_arr)
+        return _table_entry(table_arr, digit, nt_range)
 
     moved = jnp.moveaxis(digits, -1, 0)
 

@@ -274,7 +274,7 @@ def profile_stages(
     # serving path's device-resident pubkey cache).
     h_pk_x, h_pk_y = rl((n, m)), rl((n, m))
     h_sig_x, h_sig_y = rl((n, 2)), rl((n, 2))
-    h_z = np.ones((n, be.Z_DIGITS), np.uint32)
+    h_z = np.ones((n, be.Z_BITS), np.uint32)
     h_mask = np.ones((n,), np.uint32)
     h_us = rl((n, 2, 2))
     pk_x, pk_y = put_pk_grid(h_pk_x), put_pk_grid(h_pk_y)

@@ -75,14 +75,14 @@ def main():
     pk_x, pk_y, pk_mask = backend._marshal_pubkeys([s], be.one_key_grid(n, m))
     sig_x = np.zeros((n, 2, lb.NL), np.uint32)
     sig_y = np.zeros((n, 2, lb.NL), np.uint32)
-    z_digits = np.zeros((n, be.Z_DIGITS), np.uint32)
+    z_digits = np.zeros((n, be.Z_BITS), np.uint32)
     set_mask = np.zeros((n,), np.uint32)
     sp = s.signature.point
     sig_x[0, 0] = lb.pack(sp[0][0])
     sig_x[0, 1] = lb.pack(sp[0][1])
     sig_y[0, 0] = lb.pack(sp[1][0])
     sig_y[0, 1] = lb.pack(sp[1][1])
-    z_digits[0, be.Z_DIGITS - 1] = 1          # z = 1, MSB-first bits
+    z_digits[0, be.Z_BITS - 1] = 1          # z = 1, MSB-first bits
     set_mask[0] = 1
     us = np.zeros((n, 2, 2, lb.NL), np.uint32)
     us[:1] = h2.hash_to_field_batch([s.message], backend.dst)

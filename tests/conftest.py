@@ -64,11 +64,12 @@ _LONGEST_FIRST = (
     "test_ef_vectors.py",               # 738
     "test_jaxbls_backend.py",           # 622
     "test_multichip.py",                # 568
-    "test_jaxbls_registry.py",          # ~500 alone (300 at PR 41: five one-device programs; eight since PR 42)
+    "test_jaxbls_registry.py",          # ~500 alone at PR 44 (nine one-device programs); 362 at PR 45 (eight)
     "test_multichip_2d.py",             # 380 (1 test: takes the 7th along)
     "test_jaxbls_pairing.py",           # 369
     "test_fleet.py",                    # 183 (seventh: the short one)
     "test_kzg.py",                      # 297 alone (PR 33: the blob batches)
+    "test_jaxbls_key_grids.py",         # 239 alone (PR 45: five one-device programs, out of the registry file)
     "test_beacon_chain.py",             # 250
     "test_jaxbls_h2c.py",               # 167
     "test_jaxbls_msm.py",               # 123

@@ -98,12 +98,12 @@ def _stage_args(n: int, m: int, sharding) -> dict:
     mask = jax.ShapeDtypeStruct((n + 1,), jnp.bool_, sharding=sharding)
     return {
         "prepare": (u32(n, m, NL), u32(n, m, NL), u32(n, m),
-                    u32(n, 2, NL), u32(n, 2, NL), u32(n, be.Z_DIGITS), u32(n)),
+                    u32(n, 2, NL), u32(n, 2, NL), u32(n, be.Z_BITS), u32(n)),
         # the table's two coordinates, the index grid, then as `prepare`
         "prepare_indexed": (
             u32(TABLE_ROWS, NL), u32(TABLE_ROWS, NL),
             jax.ShapeDtypeStruct((n, m), jnp.int32, sharding=sharding),
-            u32(n, m), u32(n, 2, NL), u32(n, 2, NL), u32(n, be.Z_DIGITS),
+            u32(n, m), u32(n, 2, NL), u32(n, 2, NL), u32(n, be.Z_BITS),
             u32(n)),
         "h2c": (u32(n, 2, 2, NL),),
         "pairs": (g1, g2, acc, u32(n)),
@@ -140,7 +140,7 @@ def _grids_args(stage: str, n: int, m: int, widths, sharding) -> tuple:
         keys = tuple(a for g in grids
                      for a in (arr(g + (NL,)), arr(g + (NL,)), arr(g)))
     return keys + (arr(where.shape, jnp.int32), arr((n, 2, NL)),
-                   arr((n, 2, NL)), arr((n, be.Z_DIGITS)), arr((n,)))
+                   arr((n, 2, NL)), arr((n, be.Z_BITS)), arr((n,)))
 
 
 def _stage_miller_for_a_tpu(px, py, qxx, qyy, pair_mask):

@@ -149,6 +149,57 @@ def test_pairing_stage_is_one_observation_a_dispatch():
         assert {key[0] for key, _ in family.children()} <= set(obsdev.STAGES)
 
 
+@pytest.mark.parametrize("signature", ["of_order_r", "of_order_13"])
+def test_a_signature_outside_the_subgroup_is_refused_by_the_chain(signature):
+    """Four one-key sets on the urgent lane (the warmed 4 x 1 program). With
+    every signature in G2 stage 1's code is zero and the verdict True. With
+    one replaced by a point of order 13 on the twist (`Signature` holds what
+    `deserialize(subgroup_check=False)` would hand it) under a coefficient
+    that brings the chain to T + T, the addition it leaves out, the code
+    says chain_exception, the counter takes it and the verdict is False:
+    the pure-Python backend's."""
+    from jaxbls_warm import order_13_twist_point
+
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+
+    backend = bls_api.set_backend("jax")
+    sets = [_mk_set(1, bytes([0xD0 + i]) * 32) for i in range(4)]
+    rands = [0xDEADBEEF12345677, 0b1111, 3, (1 << 64) - 1]
+    if signature == "of_order_13":
+        sets[1] = bls.SignatureSet(bls.Signature(order_13_twist_point()),
+                                   sets[1].signing_keys, sets[1].message)
+    refused = be._PREPARE_REFUSED.labels("chain_exception")
+    before = refused.value
+    want = bls_api._BACKENDS["python"].verify_signature_sets(sets, rands)
+    assert want is (signature == "of_order_r")
+    assert backend.verify_signature_sets_urgent(sets, rands) is want
+    assert refused.value - before == (0 if want else 1)
+
+
+def test_the_handle_refuses_on_any_code_and_counts_each_reason_once():
+    """`VerifyHandle.result()`: stage 1's `bad` code, one read with the
+    verdict — any bit refuses, each bit is one count of its reason at the
+    first resolve and none at a second."""
+    import time
+
+    import numpy as np
+
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+
+    counts = {why: be._PREPARE_REFUSED.labels(why)
+              for why in be.PREPARE_REFUSED.values()}
+    before = {why: c.value for why, c in counts.items()}
+    verdicts = []
+    for code in (0, 1, 2, 3):
+        handle = be.VerifyHandle(np.bool_(True), np.uint32(code),
+                                 bucket=(4, 1), t0=time.perf_counter(),
+                                 n_real=1)
+        verdicts.append((handle.result(), handle.result()))
+    assert verdicts == [(True, True)] + [(False, False)] * 3
+    assert {why: c.value - before[why] for why, c in counts.items()} == {
+        "identity_aggpk": 2, "chain_exception": 2}
+
+
 def test_single_verify_parity():
     bls_api.set_backend("jax")
     sk = bls.SecretKey(rng.randrange(1, R))

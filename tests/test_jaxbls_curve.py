@@ -8,6 +8,7 @@ import pytest
 
 from lighthouse_tpu.crypto.bls381 import curve as pc
 from lighthouse_tpu.crypto.bls381.constants import P, R
+from lighthouse_tpu.crypto.jaxbls import backend as be
 from lighthouse_tpu.crypto.jaxbls import curve_ops as co
 from lighthouse_tpu.crypto.jaxbls import tower as tw
 
@@ -199,3 +200,131 @@ def test_batch_affine_roundtrip():
         else:
             assert not infs[i]
             assert (xs[i], ys[i]) == pt
+
+
+# ---- the batch-verification coefficient's chain (co.scalar_mul_z) ---------
+
+_Z_CASES = {
+    "one": 1,
+    "two": 2,
+    "top_bit_alone": 1 << 63,
+    "all_ones": (1 << 64) - 1,
+    "twenty_leading_zeros": (1 << 43) + 0x5A5A5A5A5,
+    "random": 0xC0FFEE0DDBA11AD5,
+    "identity_base": 0x1234567,          # the lane whose base is the identity
+    "masked_slot": 0,                    # a padded set slot: zero bits too
+}
+@pytest.fixture(scope="module")
+def z_chains():
+    """ONE compiled program a group, eight lanes, the window stage 1 runs
+    (backend.Z_WINDOW), shared by every case below: fn(group)(base lanes...,
+    bits) -> (product, met)."""
+    import functools
+
+    @functools.lru_cache(maxsize=None)
+    def fn(group):
+        if group == "g1":
+            return jax.jit(lambda p, b: co.scalar_mul_z(
+                p, b, co.FQ_OPS, window=be.Z_WINDOW))
+        return jax.jit(lambda x, y, inf, b: co.scalar_mul_z(
+            (x, y), b, co.FQ2_OPS, p_inf=inf, window=be.Z_WINDOW))
+
+    return fn
+
+
+def _z_chain_lanes(group, points, zs):
+    bits = co.scalars_to_bits(zs, 64)
+    if group == "g1":
+        # a Z of its own a lane, as tree_sum leaves an aggregate key
+        jac = []
+        for i, pt in enumerate(points):
+            s = 3 + i
+            jac.append(None if pt is None else
+                       (pt[0] * s * s % P, pt[1] * s ** 3 % P))
+        x, y, z = (np.asarray(c) for c in co.g1_batch_to_device(jac))
+        scale = np.asarray(tw.fq_batch_to_device(
+            [3 + i for i in range(len(points))]))
+        z = np.where((z != 0).any(-1, keepdims=True), scale, z)
+        return ((x, y, z), bits)
+    x, y, _ = co.g2_batch_to_device(points)
+    return (x, y, np.array([pt is None for pt in points]), bits)
+
+
+def _lane_is(group, prod, i, affine):
+    """Lane i of a Jacobian product against the host's affine point (None
+    the identity): X = x Z^2, Y = y Z^3 in host integers — no inversion on
+    the device, which op by op would compile for longer than the chains."""
+    if group == "g1":
+        return _same_point(
+            tuple(tw.fq_batch_from_device(c[i:i + 1])[0] for c in prod), affine)
+    from lighthouse_tpu.crypto.bls381 import fields as f
+
+    X, Y, Z = (tuple(tw.fq_batch_from_device(c[i])) for c in prod)
+    if affine is None or Z == (0, 0):
+        return affine is None and Z == (0, 0)
+    zz = f.fq2_sqr(Z)
+    return (X == f.fq2_mul(affine[0], zz)
+            and Y == f.fq2_mul(affine[1], f.fq2_mul(zz, Z)))
+
+
+@pytest.fixture(scope="module")
+def z_chain_products(z_chains):
+    """{group: (bases, products, met)} over the lanes of `_Z_CASES`, each
+    program run once."""
+    r = random.Random(0x45)
+    out = {}
+    for group, gen, mul in (("g1", pc.G1_GEN, pc.g1_mul),
+                            ("g2", pc.G2_GEN, pc.g2_mul)):
+        bases = [mul(gen, r.randrange(1, R)) for _ in _Z_CASES]
+        bases[list(_Z_CASES).index("identity_base")] = None
+        bases[list(_Z_CASES).index("masked_slot")] = None
+        prod, met = z_chains(group)(
+            *_z_chain_lanes(group, bases, list(_Z_CASES.values())))
+        out[group] = (bases, prod, np.asarray(met))
+    return out
+
+
+@pytest.mark.parametrize("group", ["g1", "g2"])
+@pytest.mark.parametrize("case", list(_Z_CASES))
+def test_z_chain_matches_the_host_product(z_chain_products, case, group):
+    """z * P by the coefficient chain against the pure-Python curve's, as a
+    group element (another formula gives another Z): the corner
+    coefficients, an identity base, a masked slot; `met` stays down."""
+    bases, prod, met = z_chain_products[group]
+    i = list(_Z_CASES).index(case)
+    mul = pc.g1_mul if group == "g1" else pc.g2_mul
+    z = _Z_CASES[case]
+    want = mul(bases[i], z) if bases[i] is not None and z else None
+    assert _lane_is(group, prod, i, want)
+    assert not met[i]
+
+
+# coefficients under which the bit chain would meet T + T and -T + T for a
+# point T of order 13 (14 T = T, 12 T = -T); building the window's table
+# meets 14 T + T on every lane of such a point, whatever its digits
+_STEERED = (0b1111, 0b1101)
+
+
+def test_z_chain_reports_the_addition_it_leaves_out(z_chains):
+    """A point of order 13 on the twist: the chain meets T + T (the case the
+    shorter addition gets wrong) and `met` rises on its lanes — at the least
+    on the first — while the same coefficients on points of order r leave
+    it down and give the host's products."""
+    from jaxbls_warm import order_13_twist_point
+
+    t = order_13_twist_point()
+    r = random.Random(0x13)
+    good = [pc.g2_mul(pc.G2_GEN, r.randrange(1, R)) for _ in range(2)]
+    zs = list(_STEERED) * 2 + [5, 5, 1, 1]
+    points = [t, t] + good + [t, good[0], t, None]
+    prod, met = z_chains("g2")(*_z_chain_lanes("g2", points, zs))
+    met = np.asarray(met)
+    assert met[0], "T + T went unreported"
+    assert met[1] or _lane_is("g2", prod, 1, pc.mul_raw(t, zs[1], pc.FQ2_OPS))
+    assert not met[2] and not met[3] and not met[5] and not met[7]
+    for i in (2, 3, 5):
+        assert _lane_is("g2", prod, i, pc.g2_mul(points[i], zs[i]))
+    # a lane the chain did not steer wrong is either exact or reported
+    for i in (4, 6):
+        assert met[i] or _lane_is("g2", prod, i,
+                                  pc.mul_raw(t, zs[i], pc.FQ2_OPS))

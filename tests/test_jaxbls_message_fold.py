@@ -300,9 +300,10 @@ def test_the_marshal_hashes_each_distinct_message_once_and_folds(one_chip):
     # the sets are not permuted: prepare's arrays are per set, in order
     set_mask = np.asarray(one_chip.calls["prepare"][-1])
     assert set_mask.tolist() == [1] * n_real + [0] * 64
-    assert np.asarray(one_chip.calls["prepare"][-2]).shape == (256, be.Z_DIGITS)
+    assert np.asarray(one_chip.calls["prepare"][-2]).shape == (256, be.Z_BITS)
     # the bucket is still the dispatch's name, the lanes beside it
     assert tr.meta["bucket"] == "256x1" and tr.meta["message_lanes"] == 128
+    assert tr.meta["z_window"] == be.Z_WINDOW == 4
     assert tr.meta["distinct_messages"] == distinct
     assert (256, 1) in be._seen_exec_buckets
     h2f = next(s for s in tr.spans if s[0] == "jaxbls:marshal.h2f")

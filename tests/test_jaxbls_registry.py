@@ -1,14 +1,15 @@
 """The registry table on the device (crypto/jaxbls/registry.py), the indices
 a SignatureSet carries, what feeds both, and the indexed path of the jax
 backend's batch lane against the packed one and against the pure-Python
-backend; and the key grids that lane lays (backend.key_grid_plan): the
-plan as a pure function, and a wide and a narrow grid against the one grid
-and the pure-Python backend. The first half compiles no stage program; the
-second (from "keys by validator index" on) drives the real staged backend
-on ONE device at the (4, 4) bucket, the unsharded programs compiled once by
-a module fixture (eight programs: test_jaxbls_backend.py is at its
-memory-mapping mark with its eight-device builds, whose mesh keeps the one
-grid, so these tests have a file of their own). The reference is the
+backend; and the key grids that lane lays (backend.key_grid_plan) as a pure
+function and by index (packed: test_jaxbls_key_grids.py). The first half
+compiles no stage program; the second (from "keys by validator index" on)
+drives the real staged backend on ONE device at the (4, 4) bucket, the
+unsharded programs compiled once by a module fixture (eight programs:
+test_jaxbls_backend.py is at its memory-mapping mark with its eight-device
+builds, whose mesh keeps the one grid, so these tests have a file of their
+own, and the packed two-grid batch a third since PR 45: nine programs with
+the 4-bit coefficient chains pass conftest's mark). The reference is the
 pure-Python curve code on keys decompressed from their 48-byte form."""
 
 import hashlib
@@ -28,6 +29,8 @@ from lighthouse_tpu.crypto.jaxbls import registry as reg
 from lighthouse_tpu.crypto.jaxbls.backend import one_key_grid
 from lighthouse_tpu.observability import trace as obstrace
 from lighthouse_tpu.utils.metrics import REGISTRY
+
+from jaxbls_warm import prepare_rest
 
 rng = random.Random(0x7AB1E)
 KEYS = [bls.SecretKey(rng.randrange(1, R)).public_key() for _ in range(16)]
@@ -567,62 +570,30 @@ def test_the_benchmarks_reference_is_its_own_and_agrees(what):
 _TABLE_ROWS = 24      # 12 validators, headroom 8: the table's capacity
 
 
-#: the key grids the batches below lay (backend.key_grid_plan) in the
-#: (4, 4) bucket: the block by index, widths (1, 1, 4, 3), and a batch whose
-#: narrow grid is wide enough for a set to sum to the identity, (1, 2, 2, 4)
+#: the key grids the block by index, widths (1, 1, 4, 3), lays in the
+#: (4, 4) bucket (backend.key_grid_plan)
 _BLOCK_GRIDS = ((2, 4), (2, 1))
-_PAIR_GRIDS = ((1, 4), (4, 2))
 
 
 @pytest.fixture(scope="module")
 def _one_device_programs():
-    """The nine programs the tests below dispatch, compiled side by side
+    """The eight programs the tests below dispatch, compiled side by side
     in three threads: the unsharded four stages at 4 sets with the packed
     prepare at m = 4; the indexed prepares at (4, 4) over the 24-row
     table, over one grid and over the block's two; the packed two-grid
-    prepares, over the block's grids and over `_PAIR_GRIDS`, and the
-    indexed prepare at (4, 1), single-key sets (no key axis to sum: the
-    smallest of them). The first is the longest."""
+    prepare over the block's grids, and the indexed prepare at (4, 1),
+    single-key sets (no key axis to sum: the smallest of them). The first
+    is the longest."""
     import functools
 
-    import jax
-    from jaxbls_warm import run_in_threads, warm_build
+    from jaxbls_warm import run_in_threads, warm_build, warm_one_chip_prepares
 
-    import lighthouse_tpu.crypto.jaxbls.backend as be
-    from lighthouse_tpu.crypto.jaxbls import limbs as lb
-    from lighthouse_tpu.parallel import put_single
-
-    def limbs(*shape):
-        return np.zeros(shape + (lb.NL,), np.uint32)
-
-    def ones(*shape):
-        return put_single(np.ones(shape, np.uint32))
-
-    def warm_prepares(*programs):
-        # placed as the marshal places them (the table as `append` does)
-        table = (jax.device_put(limbs(_TABLE_ROWS)),
-                 jax.device_put(limbs(_TABLE_ROWS)))
-        for stage, grids in programs:
-            if "indexed" in stage:
-                keys = table + tuple(
-                    a for g in grids
-                    for a in (put_single(np.zeros(g, np.int32)), ones(*g)))
-            else:
-                keys = tuple(
-                    put_single(a) for g in grids
-                    for a in (limbs(*g), limbs(*g), np.ones(g, np.uint32)))
-            if len(grids) == 2:
-                keys += (put_single(np.zeros((4,), np.int32)),)
-            jax.block_until_ready(be._get_one_chip_variant(stage)(
-                *keys, put_single(limbs(4, 2)), put_single(limbs(4, 2)),
-                ones(4, be.Z_DIGITS), ones(4)))
-
+    warm = functools.partial(warm_one_chip_prepares, table_rows=_TABLE_ROWS)
     run_in_threads(
         functools.partial(warm_build, 4, (4,), None),
-        functools.partial(warm_prepares, ("prepare_indexed", ((4, 4),)),
+        functools.partial(warm, ("prepare_indexed", ((4, 4),)),
                           ("prepare_indexed_grids", _BLOCK_GRIDS)),
-        functools.partial(warm_prepares, ("prepare_grids", _BLOCK_GRIDS),
-                          ("prepare_grids", _PAIR_GRIDS),
+        functools.partial(warm, ("prepare_grids", _BLOCK_GRIDS),
                           ("prepare_indexed", ((4, 1),))))
 
 
@@ -693,26 +664,6 @@ def _keys_taken():
     return {s: be._REGISTRY_KEYS.labels(s).value for s in ("table", "packed")}
 
 
-_ZS = [3, 0xDEADBEEF12345677, 0x42, 2**63 + 9]
-
-
-def _prepare_rest(sets, n_real=4):
-    """Stage 1's arguments behind the keys for up to four sets at n = 4:
-    (sig_x, sig_y, z_digits, set_mask), the coefficients `_ZS`."""
-    import lighthouse_tpu.crypto.jaxbls.backend as be
-    from lighthouse_tpu.crypto.jaxbls import curve_ops as co
-
-    sig_x = np.zeros((4, 2, 24), np.uint32)
-    sig_y = np.zeros((4, 2, 24), np.uint32)
-    for i, s in enumerate(sets):
-        (x0, x1), (y0, y1) = s.signature.point
-        sig_x[i] = be.pack_ints_vec([x0, x1])
-        sig_y[i] = be.pack_ints_vec([y0, y1])
-    z = co.scalars_to_digits(_ZS, 64, be.Z_WINDOW)[:, :be.Z_DIGITS]
-    set_mask = np.array([1] * n_real + [0] * (4 - n_real), np.uint32)
-    return sig_x, sig_y, np.asarray(z, np.uint32), set_mask
-
-
 def test_indexed_prepare_is_bit_equal_to_the_packed_prepare(registry_chain):
     """`_stage_prepare_indexed` over the table and `_stage_prepare` over
     the packed grid of the same keys: z_pk, sig_acc and bad_aggpk equal
@@ -728,7 +679,7 @@ def test_indexed_prepare_is_bit_equal_to_the_packed_prepare(registry_chain):
     pk_x, pk_y, pk_mask = backend._marshal_pubkeys(sets, ONE_GRID,
                                                    single_chip=True)
     assert np.array_equal(np.asarray(pk_mask), mask)
-    rest = _prepare_rest(sets)
+    rest = prepare_rest(sets)
     table = backend.registry
     got = be._get_one_chip_variant("prepare_indexed")(table.x, table.y, idx, mask, *rest)
     want = be._get_stages(mesh=None)[0](pk_x, pk_y, pk_mask, *rest)
@@ -961,133 +912,6 @@ def test_another_chains_sets_pack_their_keys_whatever_their_indices(
     assert backend._marshal_indices(sets, ONE_GRID, 9) is None
 
 
-# ------------------------------------------------------- two key grids
-# The batch lane of one device lays a batch of unequal widths as a wide and
-# a narrow key grid (backend.key_grid_plan) and stage 1 sums each by the
-# one tree_sum. Below: widths (1, 2, 2, 4) in the (4, 4) bucket, wide 1 x 4
-# and narrow 4 x 2 (`_PAIR_GRIDS`: 12 slots of 16, the edge of the 3/4
-# rule), packed; the block by index above is the same on 2 x 4 + 2 x 1.
-# The reference is the pure-Python backend, and for the aggregate keys the
-# pure-Python curve.
-
-
-def _set_of(sks, msg, valid=True):
-    """A set of the keys of `sks` signed by all of them; where they sum to
-    zero the signature is some point that is not the identity (no
-    signature verifies against the identity key)."""
-    agg = (sum(sks) + (0 if valid else 1)) % R or 7
-    return bls.SignatureSet(
-        bls.Signature(cv.g2_mul(bls_api.hash_to_g2_point(msg), agg)),
-        [bls.PublicKey(cv.g1_mul(cv.G1_GEN, sk)) for sk in sks], msg)
-
-
-def _pair_batch(case):
-    """Four sets of 1, 2, 2 and 4 keys; `case` damages one."""
-    rng = random.Random(0x261D)
-    sks = [[rng.randrange(1, R) for _ in range(w)] for w in (1, 2, 2, 4)]
-    if case in ("identity_narrow", "identities"):
-        sks[1][1] = R - sks[1][0]
-    if case in ("identity_wide", "identities"):
-        sks[3][1], sks[3][3] = R - sks[3][0], R - sks[3][2]
-    return [_set_of(ks, bytes([0xC0 + i]) * 32, valid=(case, i) != ("tampered", 2))
-            for i, ks in enumerate(sks)]
-
-
-@pytest.mark.parametrize("case", [
-    "valid", "tampered", "identity_narrow", "identity_wide"])
-def test_a_mixed_batch_on_two_grids_gives_the_reference_verdict(
-        registry_chain, case):
-    """`bls.verify_signature_sets` on a packed batch of unequal widths:
-    the dispatch lays two grids (its trace says which, its bucket is
-    still (4, 4)), counts the slots it lays and the lane-additions of
-    both sums, and its verdict is the pure-Python backend's — True when
-    sound, False with one bad signature, False with a set whose keys sum
-    to the identity in the narrow grid or in the wide one."""
-    import lighthouse_tpu.crypto.jaxbls.backend as be
-
-    sets = _pair_batch(case)
-    assert be.key_grid_plan([1, 2, 2, 4], 4, 4)[0] == _PAIR_GRIDS
-    program = be._get_one_chip_variant("prepare_grids")
-    compiled = program._cache_size()
-    padded = be._BUCKET_SLOTS.labels("keys", "padded")
-    adds = be._TREE_SUM_LANE_ADDS.labels("done")
-    padded0, adds0, taken = padded.value, adds.value, _keys_taken()
-    tr = obstrace.Trace("gossip_block", 1)
-    obstrace.set_current_trace(tr)
-    try:
-        on_jax = bls.verify_signature_sets(sets)
-    finally:
-        obstrace.set_current_trace(None)
-    assert program._cache_size() == compiled
-    assert tr.meta["bucket"] == "4x4" and tr.meta["key_grids"] == "1x4+4x2"
-    assert padded.value - padded0 == 1 * 4 + 4 * 2
-    # tree_sum_plan(4, 1) + tree_sum_plan(2, 4): 3 adds, and 1 on 4 lanes
-    assert adds.value - adds0 == 3 + 4
-    assert _keys_taken() == {"table": taken["table"],
-                             "packed": taken["packed"] + 9}
-    bls_api.set_backend("python")
-    assert bls.verify_signature_sets(sets) is (case == "valid")
-    assert on_jax is (case == "valid")
-
-
-def _affine_points(jac):
-    """[(x, y) or None] of a batch of Jacobian G1 points in Montgomery
-    limbs, by Python integers."""
-    from lighthouse_tpu.crypto.bls381.constants import P
-    from lighthouse_tpu.crypto.jaxbls import tower as tw
-
-    out = []
-    for x, y, z in zip(*(tw.fq_batch_from_device(c) for c in jac)):
-        zi = pow(z, -1, P) if z else 0
-        out.append((x * zi * zi % P, y * zi * zi * zi % P) if z else None)
-    return out
-
-
-@pytest.mark.parametrize("case,n_real", [("identities", 4), ("valid", 3)],
-                         ids=["identities", "padded_slot"])
-def test_two_grids_sum_to_the_one_grids_aggregate_keys(registry_chain, case,
-                                                       n_real):
-    """The two-grid prepare against the one-grid prepare on the same sets:
-    every z_i * aggpk_i the same AFFINE point (the sums associate
-    differently, so the Jacobian limbs differ) and the pure-Python
-    curve's, the signatures' sum limb for limb, `bad_aggpk` alike — set
-    where a real set's keys sum to the identity, in the narrow grid and in
-    the wide one. A padded set slot reads the identity entry behind the
-    grids' sums, as the one grid's all-masked row sums to it (three sets,
-    the wide grid left empty by a hand-laid `where`)."""
-    import lighthouse_tpu.crypto.jaxbls.backend as be
-
-    backend, _, _ = registry_chain
-    sets = _pair_batch(case)[:n_real]
-    plan = be.key_grid_plan([1, 2, 2, 4], 4, 4)
-    if n_real == 3:
-        plan = (plan[0], np.array([1, 2, 3, 5], np.int32))
-    assert plan[0] == _PAIR_GRIDS and plan[1].tolist()[:3] == [1, 2, 3]
-    rest = _prepare_rest(sets, n_real)
-    grids = backend._marshal_pubkeys(sets, plan, single_chip=True)
-    one = backend._marshal_pubkeys(sets, ONE_GRID, single_chip=True)
-    assert [g.shape for g in grids] == [
-        (1, 4, 24), (1, 4, 24), (1, 4), (4, 2, 24), (4, 2, 24), (4, 2), (4,)]
-    assert sum(int(np.asarray(m).sum()) for m in (grids[2], grids[5])) == (
-        int(np.asarray(one[2]).sum())) == sum(len(s.signing_keys) for s in sets)
-    got = be._get_one_chip_variant("prepare_grids")(*grids, *rest)
-    want = be._get_stages(mesh=None)[0](*one, *rest)
-
-    def aggregate(s, k):
-        total = None
-        for pk in s.signing_keys:
-            total = cv.g1_add(total, pk.point)
-        return cv.g1_mul(total, k)
-
-    reference = [aggregate(s, k) for s, k in zip(sets, _ZS)] + [None] * (4 - n_real)
-    assert _affine_points(got[0]) == _affine_points(want[0]) == reference
-    assert reference.count(None) == {"valid": 1, "identities": 2}[case]
-    for a, b in zip(got[1], want[1]):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-    assert bool(np.asarray(got[2])) is bool(np.asarray(want[2])) is (
-        case == "identities")
-
-
 def test_indexed_grids_prepare_is_bit_equal_to_the_packed_grids_prepare(
         registry_chain):
     """Over the block's two grids as over the one: the rows gathered from
@@ -1111,7 +935,7 @@ def test_indexed_grids_prepare_is_bit_equal_to_the_packed_grids_prepare(
     assert np.array_equal(np.asarray(packed[2]), wide_mask)
     assert np.array_equal(np.asarray(packed[5]), narrow_mask)
     assert np.asarray(packed[6]).tolist() == plan[1].tolist()
-    rest = _prepare_rest(sets)
+    rest = prepare_rest(sets)
     got = be._get_one_chip_variant("prepare_indexed_grids")(
         table.x, table.y, wide_idx, wide_mask, narrow_idx, narrow_mask,
         plan[1], *rest)
@@ -1124,7 +948,7 @@ def test_indexed_grids_prepare_is_bit_equal_to_the_packed_grids_prepare(
 
 
 def test_module_stays_under_the_mapping_mark(registry_chain):
-    """Last on purpose, as in the two other modules that drive the staged
+    """Last on purpose, as in the other modules that drive the staged
     backend: with this module's eight programs compiled and kept (one
     unsharded build and four more prepares), the process must be under
     conftest's mark — past it conftest drops the executables between tests

@@ -257,13 +257,17 @@ def test_mesh_backend_collective_cost_model():
     eight = MeshShardedBackend(8, base_ms=0.0, per_set_ms=0.05)
     import time as _t
 
-    t0 = _t.perf_counter()
-    assert one.verify_signature_sets([None] * 64, [1] * 64) is True
-    t_one = _t.perf_counter() - t0
-    t0 = _t.perf_counter()
-    assert eight.verify_signature_sets([None] * 64, [1] * 64) is True
-    t_eight = _t.perf_counter() - t0
-    assert t_eight < t_one  # 64*0.05ms vs 8*0.05ms + overhead
+    def best_of_three(backend):
+        # the quickest of three: beside five other xdist workers a thread
+        # can wait longer for a core than the 3.2 ms being compared
+        times = []
+        for _ in range(3):
+            t0 = _t.perf_counter()
+            assert backend.verify_signature_sets([None] * 64, [1] * 64) is True
+            times.append(_t.perf_counter() - t0)
+        return min(times)
+
+    assert best_of_three(eight) < best_of_three(one)  # 8*0.05ms + overhead vs 64*0.05ms
     # occupancy ledger: every chip busy, balanced
     occ = eight.occupancy()
     assert occ["devices"] == 8 and len(occ["chip_busy_secs"]) == 8

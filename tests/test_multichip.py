@@ -72,7 +72,7 @@ def _marshal(backend, sets, rands):
     pk_mask = np.zeros((n, m), np.uint32)
     sig_x = np.zeros((n, 2, lb.NL), np.uint32)
     sig_y = np.zeros((n, 2, lb.NL), np.uint32)
-    z_digits = np.zeros((n, be.Z_DIGITS), np.uint32)
+    z_digits = np.zeros((n, be.Z_BITS), np.uint32)
     set_mask = np.zeros((n,), np.uint32)
     us = np.zeros((n, 2, 2, lb.NL), np.uint32)
 
@@ -87,9 +87,8 @@ def _marshal(backend, sets, rands):
         sig_y[i, 0] = be.pack_ints_vec([sp[1][0]])[0]
         sig_y[i, 1] = be.pack_ints_vec([sp[1][1]])[0]
     zmask = (1 << 64) - 1
-    z_digits[:n_real] = co.scalars_to_digits(
-        [z & zmask for z in rands], 64, be.Z_WINDOW
-    )[:, : be.Z_DIGITS]
+    z_digits[:n_real] = co.scalars_to_bits(
+        [z & zmask for z in rands], be.Z_BITS)
     set_mask[:n_real] = 1
     us[:n_real] = h2.hash_to_field_batch([s.message for s in sets], backend.dst)
     return (pk_x, pk_y, pk_mask, sig_x, sig_y, us, z_digits, set_mask)
