@@ -167,7 +167,7 @@ class DeviceProfile:
     @property
     def mesh_shape(self) -> str | None:
         """Canonical topology string the profile was measured on
-        (parallel.mesh_shape_key format: "single", "sets8", "sets4-pks2");
+        (parallel.mesh_shape_key format: "single", "sets8");
         None on pre-r8 profiles that never recorded one."""
         v = self.key.get("mesh_shape")
         return None if v is None else str(v)

@@ -176,21 +176,21 @@ def padding_bucket(n_sets: int, n_pks: int, mesh=_LIVE_MESH,
     calibrator classify by calling this, so their keys can never desync
     from what actually compiles.
 
-    Mesh-shape-keyed: the set (and on a 2-D mesh, pubkey) axis rounds up
-    to a multiple of the mesh axis so every dispatched batch shards
-    evenly; pass an explicit `mesh` to bucket for a topology other than
-    the live one (the --mesh-devices sweep), or `single_chip=True` for
-    the urgent bypass lane's plain pow2 buckets (urgent verifies are
-    pinned to one chip and never pay mesh padding)."""
+    Mesh-shape-keyed: the set axis rounds up to a multiple of the mesh's
+    one axis so every dispatched batch shards evenly (the key width is a
+    power of two on every topology); pass an explicit `mesh` to bucket
+    for a topology other than the live one (the --mesh-devices sweep), or
+    `single_chip=True` for the urgent bypass lane's plain pow2 buckets
+    (urgent verifies are pinned to one chip and never pay mesh padding)."""
     n = max(MIN_SETS, _next_pow2(n_sets))
     m = max(MIN_PKS, _next_pow2(n_pks))
     if single_chip:
         return n, m
-    from ...parallel import pad_pks, pad_sets
+    from ...parallel import pad_sets
 
     if mesh is _LIVE_MESH:
-        return pad_sets(n), pad_pks(m)
-    return pad_sets(n, mesh=mesh), pad_pks(m, mesh=mesh)
+        return pad_sets(n), m
+    return pad_sets(n, mesh=mesh), m
 
 
 def key_grid_plan(widths, n: int, m: int) -> tuple:
@@ -416,7 +416,18 @@ def _stage_prepare(pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits, set_mask):
     """Stage 1: mont conversion, pubkey tree-aggregation, z-scaling of
     aggregate pubkeys and signatures, signature tree-sum. The keys as ONE
     (n, m) grid, set i in row i: what a batch of near-equal widths, the
-    urgent lane and a mesh run (key_grid_plan)."""
+    urgent lane and a mesh run (key_grid_plan).
+
+    Shapes, here and for hash-to-G2's `us` beside it:
+      pk_x/pk_y: (n, m, NL)  padded pubkey affine coords, STANDARD form
+      pk_mask:   (n, m)      1 = real pubkey
+      sig_x/sig_y: (n, 2, NL) signature affine G2 coords, standard form
+                   (infinity rejected host-side per blst semantics)
+      us:        (n, 2, 2, NL) hash_to_field outputs per message (standard)
+      z_digits:  (n, 64)     coefficient bits, MSB first
+      set_mask:  (n,)        1 = real set
+    Returns (z_pk, sig_acc, bad): `bad` the stage's code, zero for nothing
+    against (PREPARE_REFUSED)."""
     pk_x = _to_mont_dev(pk_x)
     pk_y = _to_mont_dev(pk_y)
     sig_x = _to_mont_dev(sig_x)
@@ -553,50 +564,24 @@ def _stage_pairs_folded(z_pk, h_jac, sig_acc, fold):
 
 def _stage_pairing(px, py, qxx, qyy, pair_mask):
     """Stage 4 as ONE program: shared-accumulator multi-Miller loop + final
-    exponentiation (_verify_kernel, the meshed jit build, and the
-    one-accumulator buckets of a platform without the row; where the loop
-    carries the row, one chip runs the two programs below)."""
+    exponentiation. What the meshed jit build compiles (_PairingDispatch,
+    until ROADMAP M4 decides the mesh's stage 4) and nothing else: one
+    chip runs the two programs below on every platform."""
     return po.pairing_product_is_one((px, py), (qxx, qyy), pair_mask)
 
 
 def _stage_miller(px, py, qxx, qyy, pair_mask):
-    """Stage 4 on a row of accumulators, first program: the
-    shared-accumulator multi-Miller loop, one Fq12 out. Compiled per bucket
-    (n + 1 pairs)."""
+    """Stage 4 on one chip, first program: the shared-accumulator
+    multi-Miller loop over miller_lane_plan's W accumulators (a row of
+    them, or the one), one Fq12 out. Compiled per bucket (n + 1 pairs)."""
     return po.miller_loop_product((px, py), (qxx, qyy), pair_mask)
 
 
 def _stage_final_exp(f):
-    """Stage 4 on a row of accumulators, second program: final
-    exponentiation of the Miller value and the comparison with one. No pair
-    axis: one program for every bucket, the KZG check included."""
+    """Stage 4 on one chip, second program: final exponentiation of the
+    Miller value and the comparison with one. No pair axis: one program
+    for every bucket, the KZG check included."""
     return tw.fq12_eq_one(po.final_exponentiation(f))
-
-
-def _verify_kernel(pk_x, pk_y, pk_mask, sig_x, sig_y, us, z_digits, set_mask):
-    """The full device program as ONE composition (kept for the sharding
-    tests and the multichip dryrun; the hot path runs the four stages as
-    SEPARATE jit calls, five on one chip where the row is — smaller
-    programs compile minutes faster and cache independently,
-    intermediates stay device-resident between calls, and stage 4's wide
-    Miller loop keeps the layout it has compiled alone: _PairingPrograms).
-
-    Shapes:
-      pk_x/pk_y: (n, m, NL)  padded pubkey affine coords, STANDARD form
-      pk_mask:   (n, m)      1 = real pubkey
-      sig_x/sig_y: (n, 2, NL) signature affine G2 coords, standard form
-                   (infinity rejected host-side per blst semantics)
-      us:        (n, 2, 2, NL) hash_to_field outputs per message (standard)
-      z_digits:  (n, 64)     coefficient bits, MSB first
-      set_mask:  (n,)        1 = real set
-    Returns (ok, bad): stage 1's code, zero for nothing against."""
-    z_pk, sig_acc, bad = _stage_prepare(
-        pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits, set_mask
-    )
-    h_jac = h2.hash_to_g2_jacobian(us)
-    px, py, qxx, qyy, pair_mask = _stage_pairs(z_pk, h_jac, sig_acc, set_mask)
-    ok = _stage_pairing(px, py, qxx, qyy, pair_mask)
-    return ok, bad
 
 
 _NEG_G1_GEN = None
@@ -679,43 +664,34 @@ def _build_shard_map_pairing(mesh):
 
 
 class _PairingPrograms:
-    """Stage 4 on one chip, under the one stage name. Where the Miller
-    loop carries a row of accumulators (miller_lane_plan's W > 1: on a TPU
-    every bucket, the urgent one's 5 pairs and KZG's 4 padded to the row
-    inside the program; elsewhere from 33 pairs on) it is TWO jitted
-    programs, `_stage_miller` then `_stage_final_exp`, enqueued back to
-    back — the Miller value stays on the device, the host waits for
-    neither. They share no compilation on purpose: beside final
-    exponentiation's one-lane scans the compiler lays the wide scan's
-    point carry limb-minor and the stage takes 226 ms where the two take
-    127 at 257 pairs, 116 where they take 89 at 65, and the same 116.4
-    against 89.1 / 89.2 at 5 and 4 pairs on the row (a v5e,
-    scripts/measure_miller_lanes.py --stage, PERF.md S6, PR 32 and PR 35).
-    With one accumulator (few pairs on a platform whose lanes cost a lane
-    each: the CPU tests) the ONE program `_stage_pairing` stays. Callable
-    like a jitted stage; `.lower` gives the lowerings of what serves the
-    shape, so program capture sees the whole stage."""
+    """Stage 4 on one chip, under the one stage name: TWO jitted programs
+    on every platform, `_stage_miller` then `_stage_final_exp`, enqueued
+    back to back — the Miller value stays on the device, the host waits
+    for neither. How many accumulators the loop carries is
+    miller_lane_plan's choice and the Miller program's alone (a row on a
+    TPU at every pair count, the urgent bucket's 5 pairs and KZG's 4
+    padded to it; elsewhere the one below 33 pairs); final exponentiation
+    has no pair axis, so a process compiles it once. They share no
+    compilation on purpose: beside final exponentiation's one-lane scans
+    the compiler lays the wide scan's point carry limb-minor and the stage
+    takes 226 ms where the two take 127 at 257 pairs, 116 where they take
+    89 at 65, and the same 116.4 against 89.1 / 89.2 at 5 and 4 pairs on
+    the row (a v5e, scripts/measure_miller_lanes.py --stage, PERF.md S6,
+    PR 32 and PR 35). The one program `_stage_pairing` is the mesh's
+    (_PairingDispatch). Callable like a jitted stage; `.lower` gives the
+    two lowerings, so program capture sees the whole stage."""
 
-    def __init__(self, one, miller, final_exp):
-        self.one = one
+    def __init__(self, miller, final_exp):
         self.miller = miller
         self.final_exp = final_exp
 
-    @staticmethod
-    def _split(px) -> bool:
-        return po.miller_lane_plan(px.shape[0])[0] > 1
-
     def __call__(self, px, py, qxx, qyy, pair_mask):
-        if not self._split(px):
-            return self.one(px, py, qxx, qyy, pair_mask)
         with _obs.annotation_scope("jaxbls:pairing.miller"):
             f = self.miller(px, py, qxx, qyy, pair_mask)
         with _obs.annotation_scope("jaxbls:pairing.final_exp"):
             return self.final_exp(f)
 
     def lower(self, *args):
-        if not self._split(args[0]):
-            return self.one.lower(*args)
         miller = self.miller.lower(*args)
         return miller, self.final_exp.lower(miller.out_info)
 
@@ -789,25 +765,25 @@ class _PairingDispatch:
 def _get_stages(mesh=None):
     """The four stage callables (each program cached separately on disk).
 
-    Four stages, five programs a bucket on a TPU: with `mesh=None` (the
+    Four stages, five programs a bucket on one chip: with `mesh=None` (the
     urgent single-chip lane, host-side callers like aggregate_verify, and
     single-device processes) stages 1-3 are plain jits — input placement
-    decides the executable — and stage 4 is a _PairingPrograms: where the
-    Miller loop carries a row of accumulators (every pair count on a TPU,
-    from 33 pairs on elsewhere) the Miller loop and the final
-    exponentiation as two jits under the one stage name, because compiled
-    together the wide Miller scan runs at half its speed; with one
-    accumulator, the one program. With a mesh, the stages compile under
-    that mesh's contract: explicit `in_shardings`
-    over the 1-D `sets` (2-D `(sets, pks)`) axes for every host-marshalled
-    input — exactly the NamedShardings `put_sets`/`put_pk_grid` commit, so
-    the lowered programs (and their persistent-cache keys) are identical
-    to what propagation produced, but a mis-placed input now fails loudly
-    instead of silently resharding. Stage-OUTPUT inputs (z_pk/h_jac/
-    sig_acc) keep `None` entries — their shardings are XLA's choice — and
-    output shardings stay XLA's too (pinning them forks the compile cache
-    for zero layout change; docs/PERF_NOTES.md "Multichip serving"). The
-    stage-4 pair product gets a shard_map fallback via _PairingDispatch.
+    decides the executable — and stage 4 is a _PairingPrograms: the Miller
+    loop and the final exponentiation as two jits under the one stage
+    name, on every platform (compiled together a wide Miller scan runs at
+    half its speed, and alone the final exponentiation is one program for
+    every bucket). With a mesh, the stages compile under that mesh's
+    contract: explicit `in_shardings` over the `sets` axis for every
+    host-marshalled input — exactly the NamedShardings `put_sets`/
+    `put_pk_grid` commit, so the lowered programs (and their
+    persistent-cache keys) are identical to what propagation produced,
+    but a mis-placed input now fails loudly instead of silently
+    resharding. Stage-OUTPUT inputs (z_pk/h_jac/sig_acc) keep `None`
+    entries — their shardings are XLA's choice — and output shardings
+    stay XLA's too (pinning them forks the compile cache for zero layout
+    change; docs/PERF_NOTES.md "Multichip serving"). The mesh's stage 4
+    is the one program `_stage_pairing`, with a shard_map fallback, via
+    _PairingDispatch.
 
     With buffer donation on (pipeline.donation_enabled — default on
     accelerators, env/flag overridable) the per-batch inputs are marked
@@ -854,7 +830,6 @@ def _get_stages(mesh=None):
                 jax.jit(h2.hash_to_g2_jacobian, **donate_kw["h2c"]),
                 jax.jit(_stage_pairs, **donate_kw["pairs"]),
                 _PairingPrograms(
-                    jax.jit(_stage_pairing, **donate_kw["pairing"]),
                     jax.jit(_stage_miller, **donate_kw["miller"]),
                     jax.jit(_stage_final_exp, **donate_kw["final_exp"]),
                 ),
@@ -865,12 +840,8 @@ def _get_stages(mesh=None):
             def sets_s(ndim):
                 return pm.sets_sharding(mesh, ndim)
 
-            pk_s = (
-                pm.pks_sharding if pm.PK_AXIS in mesh.axis_names
-                else pm.sets_sharding
-            )
             prepare_in = (
-                pk_s(mesh, 3), pk_s(mesh, 3), pk_s(mesh, 2),  # pk_x/y/mask
+                sets_s(3), sets_s(3), sets_s(2),               # pk_x/y/mask
                 sets_s(3), sets_s(3),                          # sig_x/sig_y
                 sets_s(2), sets_s(1),                          # z_digits/mask
             )
@@ -1266,10 +1237,9 @@ class JaxBackend:
                 arrays.append(plan[1])
         from ...parallel import put_pk_grid, put_single
 
-        # (rows, width, ...) pubkey arrays: set axis sharded; on a 2-D mesh
-        # the pubkey axis is sharded too (within-set aggregation
-        # parallelism). Urgent single-chip batches place whole on one
-        # device instead, and so do the two grids (never laid over a mesh).
+        # (rows, width, ...) pubkey arrays: set axis sharded, key axis
+        # whole. Urgent single-chip batches place whole on one device
+        # instead, and so do the two grids (never laid over a mesh).
         put = put_single if single_chip else put_pk_grid
         with _obs.span("jaxbls:marshal.pubkeys_upload", bytes=nbytes):
             placed = tuple(put(a) for a in arrays)

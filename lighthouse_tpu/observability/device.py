@@ -3,17 +3,16 @@
 The dispatch path runs four stages (prepare, hash-to-G2, pairs, pairing —
 `crypto/jaxbls/backend.py`) asynchronously: the host enqueues all four and
 blocks once, on the final result. Four stages, five jitted programs a
-bucket on one chip: where the Miller loop carries a row of accumulators
-(`pairing_ops.miller_lane_plan`: every pair count on a TPU, from 33 pairs on
-elsewhere) stage 4 is the Miller loop (`_stage_miller`, a program a bucket)
-and the final exponentiation (`_stage_final_exp`, one program for every
-bucket), enqueued back to back under the one stage name `pairing` — compiled
-as one program the wide Miller scan takes twice its time; with one
-accumulator the one program `_stage_pairing` stays (PERF.md S6, PR 32 and
-PR 35). A profiler capture shows the two apart (`jaxbls:pairing.miller`,
-`jaxbls:pairing.final_exp`, the programs' own names); everything timed or
-counted here keeps the four stage names. That is the right shape
-for throughput, and it leaves the device's own time as one number a
+bucket on one chip, on every platform: stage 4 is the Miller loop
+(`_stage_miller`, a program a bucket, over the accumulators
+`pairing_ops.miller_lane_plan` gives it) and the final exponentiation
+(`_stage_final_exp`, one program for every bucket), enqueued back to back
+under the one stage name `pairing` — compiled as one program the wide Miller
+scan takes twice its time (PERF.md S6, PR 32 and PR 35); the one program
+`_stage_pairing` is what a mesh compiles. A profiler capture shows the
+two apart (`jaxbls:pairing.miller`, `jaxbls:pairing.final_exp`, the
+programs' own names); everything timed or counted here keeps the four
+stage names. That is the right shape for throughput, and it leaves the device's own time as one number a
 dispatch: the pipeline tracer (observability/trace.py) shows the host's
 phases around it — `jaxbls:enqueue` with the stage scopes as children,
 `jaxbls:device_wait` — and `jaxbls_dispatch_device_seconds{lane}` the time
@@ -65,8 +64,8 @@ from ..utils.metrics import REGISTRY
 from . import perf as _perf
 from . import trace as _trace
 
-#: canonical jit-stage order of the multi-set verify kernel
-#: (`_verify_kernel` in crypto/jaxbls/backend.py)
+#: canonical jit-stage order of the multi-set verify pipeline
+#: (`_get_stages` in crypto/jaxbls/backend.py)
 STAGES = ("prepare", "h2c", "pairs", "pairing")
 
 #: the KZG blob batch's first stage (validation + linear combinations,

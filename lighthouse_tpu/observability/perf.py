@@ -113,12 +113,12 @@ def maybe_capture_program(stage: str, jitted_fn, args, bucket: tuple):
 def capture_program(stage: str, jitted_fn, args, bucket: tuple) -> dict | None:
     """Lower+compile one jit stage at concrete args and record its cost/
     memory analysis. A stage served as several programs run one after the
-    other (stage 4 on one chip where the Miller loop carries a row: `.lower` gives a tuple)
-    records their sum of flops, bytes accessed and generated code, and of
-    each memory region the largest — the programs never hold the device
-    together. Best-effort: any failure returns None and records nothing (a
-    node on an exotic backend must not lose the verify path to a
-    diagnostics call)."""
+    other (stage 4 on one chip, `_PairingPrograms`: `.lower` gives a tuple;
+    every other stage gives one lowering) records their sum of flops, bytes
+    accessed and generated code, and of each memory region the largest —
+    the programs never hold the device together. Best-effort: any failure
+    returns None and records nothing (a node on an exotic backend must
+    not lose the verify path to a diagnostics call)."""
     n, m = int(bucket[0]), int(bucket[1])
     try:
         lowered = jitted_fn.lower(*args)

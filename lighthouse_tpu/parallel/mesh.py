@@ -19,9 +19,9 @@ Resolution seams (all consumed by the forced-host-device harness,
                                  the `bn loadtest --mesh-devices` sweep's
                                  way of comparing 1-vs-8-chip serving in
                                  one process (k=1 means no mesh)
-  LIGHTHOUSE_TPU_PK_SHARDS=k     fold the devices into a 2-D (sets, pks)
-                                 mesh; must be a power of two dividing the
-                                 device count, rejected LOUDLY otherwise
+
+The mesh has the one axis. A wide key axis is spread on the chip, by the
+key grids a dispatch lays (`backend.key_grid_plan`), not across chips.
 
 `reset_mesh_cache()` re-runs discovery after any of these change — the
 test seam the harness flips between sweep points.
@@ -34,7 +34,6 @@ import os
 from ..utils.metrics import REGISTRY
 
 SET_AXIS = "sets"
-PK_AXIS = "pks"
 
 # ------------------------------------------------------------------ metrics
 # mesh_* series are labeled families (scripts/lint_metrics.py enforces it):
@@ -43,8 +42,8 @@ PK_AXIS = "pks"
 
 _MESH_AXIS_SIZE = REGISTRY.gauge_vec(
     "mesh_axis_size",
-    "devices along each mesh axis of the resolved device mesh (1-D sets "
-    "or 2-D sets x pks); absent until a mesh resolves",
+    "devices along the one axis (`sets`) of the resolved device mesh; "
+    "absent until a mesh resolves",
     ("axis",),
 )
 MESH_DISPATCH = REGISTRY.counter_vec(
@@ -61,14 +60,13 @@ _cached: list = []  # [mesh_or_None] once resolved
 def _record_bringup(mesh) -> None:
     """Flight-recorder + metrics + one structured log line for a resolved
     mesh: topology changes are exactly the bring-up facts an incident dump
-    should carry next to breaker/route events. Every known axis gauge is
-    (re)written — a re-resolution from 2-D to 1-D (or to no mesh at all)
-    must not leave a stale pks/sets size on /metrics."""
+    should carry next to breaker/route events. The axis gauge is
+    (re)written every time — a re-resolution to no mesh at all must not
+    leave a stale size on /metrics."""
     from ..utils.logging import get_logger
 
     shape = dict(mesh.shape) if mesh is not None else {}
-    for axis in (SET_AXIS, PK_AXIS):
-        _MESH_AXIS_SIZE.labels(axis).set(shape.get(axis, 0))
+    _MESH_AXIS_SIZE.labels(SET_AXIS).set(shape.get(SET_AXIS, 0))
     if mesh is None:
         return
     get_logger("mesh").info(
@@ -86,39 +84,14 @@ def _record_bringup(mesh) -> None:
         pass  # diagnostics must never break mesh discovery
 
 
-def _reject_pk_shards(raw: str, devices: int, why: str) -> None:
-    """ONE structured warn naming the rejected LIGHTHOUSE_TPU_PK_SHARDS
-    value — the docstring's "loudly". Every rejection path (unparseable
-    included) funnels through here so none can fall back silently."""
-    from ..utils.logging import get_logger
-
-    get_logger("mesh").warn(
-        "ignoring LIGHTHOUSE_TPU_PK_SHARDS (must be a power of two "
-        "dividing the device count); falling back to the 1-D sets mesh",
-        value=raw, devices=devices, reason=why,
-    )
-    try:
-        from ..observability.flight_recorder import RECORDER
-
-        RECORDER.record("mesh_config_rejected", severity="warn",
-                        pk_shards=raw, devices=devices, reason=why)
-    except Exception:
-        pass
-
-
 def get_mesh():
     """The process-wide device mesh, or None when only one device is
     attached (or LIGHTHOUSE_TPU_MESH=0, or LIGHTHOUSE_TPU_MESH_DEVICES=1).
     Resolved once — device topology does not change within a process;
     harnesses that flip the env seams call `reset_mesh_cache` after.
 
-    Default shape: 1-D over the `sets` axis (signature sets are
-    data-parallel). LIGHTHOUSE_TPU_PK_SHARDS=k > 1 folds the devices into a
-    2-D (sets, pks) mesh: the PUBKEY axis of each set is also sharded, so a
-    single huge aggregation (the 512-pubkey sync-committee case — the
-    within-set Pippenger-style parallelism SURVEY §5 calls for) spreads its
-    point tree across chips, with the tree reduction lowering to
-    collectives over the pks axis."""
+    The shape is 1-D over the `sets` axis: signature sets are
+    data-parallel, and every key of a set stays on its set's chip."""
     if _cached:
         return _cached[0]
     mesh = None
@@ -163,36 +136,7 @@ def get_mesh():
             import numpy as np
             from jax.sharding import Mesh
 
-            raw = os.environ.get("LIGHTHOUSE_TPU_PK_SHARDS", "1")
-            try:
-                pk_shards = int(raw)
-            except ValueError:
-                pk_shards = 1
-                # the pre-r10 silent branch: an unparseable value fell
-                # back to the 1-D mesh with no trace of the typo'd knob
-                _reject_pk_shards(raw, len(devices), "unparseable")
-            # the kernels' tree reductions are pow2-structured: only accept
-            # a pow2 shard count that divides the device count. EVERY
-            # other value — zero/negative included — falls back to the
-            # 1-D mesh loudly; only an explicit 1 (the documented
-            # "no pk sharding") is a quiet no-op.
-            valid = (
-                pk_shards > 1
-                and pk_shards & (pk_shards - 1) == 0
-                and len(devices) % pk_shards == 0
-            )
-            if pk_shards < 1:
-                _reject_pk_shards(raw, len(devices), "non_positive")
-            elif pk_shards > 1 and not valid:
-                _reject_pk_shards(
-                    raw, len(devices),
-                    "not_pow2" if pk_shards & (pk_shards - 1) else "not_dividing",
-                )
-            if valid:
-                grid = np.array(devices).reshape(-1, pk_shards)
-                mesh = Mesh(grid, (SET_AXIS, PK_AXIS))
-            else:
-                mesh = Mesh(np.array(devices), (SET_AXIS,))
+            mesh = Mesh(np.array(devices), (SET_AXIS,))
     _record_bringup(mesh)  # also clears stale gauges when mesh is None
     _cached.append(mesh)
     return mesh
@@ -201,16 +145,16 @@ def get_mesh():
 def reset_mesh_cache() -> None:
     """Test/harness seam: force re-discovery. The forced-host-device
     harness (and the `--mesh-devices` sweep) flips LIGHTHOUSE_TPU_MESH /
-    LIGHTHOUSE_TPU_MESH_DEVICES / LIGHTHOUSE_TPU_PK_SHARDS and calls this
-    so the next `get_mesh()` re-reads them; the jaxbls stage cache is
-    keyed by the mesh signature, so a re-resolved mesh picks up fresh
-    compiled variants without clearing anything else."""
+    LIGHTHOUSE_TPU_MESH_DEVICES and calls this so the next `get_mesh()`
+    re-reads them; the jaxbls stage cache is keyed by the mesh signature,
+    so a re-resolved mesh picks up fresh compiled variants without
+    clearing anything else."""
     _cached.clear()
 
 
 def mesh_shape_key(mesh=_cached) -> str:
     """Canonical topology string for autotune profile keys: "single" for
-    no mesh, else axis-size segments like "sets8" / "sets4-pks2". Pass an
+    no mesh, else the axis and its size, "sets8". Pass an
     explicit mesh (or None) to stringify a known topology without
     resolving the live one."""
     if mesh is _cached:
@@ -221,7 +165,8 @@ def mesh_shape_key(mesh=_cached) -> str:
 
 
 def parse_mesh_shape(key: str | None) -> dict:
-    """Inverse of mesh_shape_key: {"sets": 8, "pks": 2}; {} for
+    """Inverse of mesh_shape_key: {"sets": 8}, an axis a "-"-joined
+    segment (a profile on disk may name axes this build has not); {} for
     None/"single"/unparseable (treated as single-chip)."""
     import re
 
@@ -242,16 +187,6 @@ def sets_sharding(mesh, ndim: int):
     from jax.sharding import NamedSharding, PartitionSpec
 
     return NamedSharding(mesh, PartitionSpec(SET_AXIS, *([None] * (ndim - 1))))
-
-
-def pks_sharding(mesh, ndim: int):
-    """NamedSharding partitioning (set, pubkey) leading axes — for the
-    (n, m, ...) pubkey coordinate arrays on a 2-D mesh."""
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    return NamedSharding(
-        mesh, PartitionSpec(SET_AXIS, PK_AXIS, *([None] * (ndim - 2)))
-    )
 
 
 def replicated_sharding(mesh):
@@ -278,19 +213,9 @@ def put_sets(a, mesh=None):
 
 
 def put_pk_grid(a, mesh=None):
-    """Place an (n_sets, n_pks, ...) pubkey array: set axis sharded always;
-    pubkey axis additionally sharded on a 2-D mesh."""
-    import jax
-
-    if mesh is None:
-        mesh = get_mesh()
-    if mesh is None:
-        return jax.device_put(a)
-    import numpy as np
-
-    if PK_AXIS in mesh.axis_names:
-        return jax.device_put(a, pks_sharding(mesh, np.ndim(a)))
-    return jax.device_put(a, sets_sharding(mesh, np.ndim(a)))
+    """Place an (n_sets, n_pks, ...) key grid: set axis sharded, key axis
+    whole, so a set's key sum never leaves its chip."""
+    return put_sets(a, mesh)
 
 
 def put_single(a):
@@ -304,10 +229,6 @@ def put_single(a):
     import jax
 
     return jax.device_put(a)
-
-
-def _axis_size(mesh, axis: str) -> int:
-    return mesh.shape[axis] if mesh is not None and axis in mesh.axis_names else 1
 
 
 def _pad_pow2_multiple(n: int, size: int) -> int:
@@ -338,14 +259,4 @@ def pad_sets(n: int, mesh=None) -> int:
         mesh = get_mesh()
     if mesh is None:
         return n
-    return _pad_pow2_multiple(n, _axis_size(mesh, SET_AXIS))
-
-
-def pad_pks(m: int, mesh=None) -> int:
-    """Round a per-set pubkey count up to a pow2 multiple of the pks axis
-    (the pubkey aggregation is a pow2 halving tree)."""
-    if mesh is None:
-        mesh = get_mesh()
-    if mesh is None:
-        return m
-    return _pad_pow2_multiple(m, _axis_size(mesh, PK_AXIS))
+    return _pad_pow2_multiple(n, mesh.shape[SET_AXIS])

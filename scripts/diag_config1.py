@@ -63,14 +63,11 @@ def main():
         return 0
 
     # ---- stage bisect at the same bucket the real path uses ----
-    # pad_sets/pad_pks make this match verify_signature_sets' bucket math;
+    # padding_bucket is verify_signature_sets' own bucket math;
     # NOTE on a multi-device VM the real path additionally mesh-shards its
     # inputs (parallel.put_sets) — this bisect runs unsharded, so a
     # mesh-layout-specific divergence can reproduce verbatim but not here.
-    from lighthouse_tpu.parallel import pad_pks, pad_sets
-
-    n = pad_sets(max(be.MIN_SETS, be._next_pow2(1)))
-    m = pad_pks(max(be.MIN_PKS, be._next_pow2(len(s.signing_keys))))
+    n, m = be.padding_bucket(1, len(s.signing_keys))
     print(f"bisecting at bucket n={n} m={m}", flush=True)
     pk_x, pk_y, pk_mask = backend._marshal_pubkeys([s], be.one_key_grid(n, m))
     sig_x = np.zeros((n, 2, lb.NL), np.uint32)

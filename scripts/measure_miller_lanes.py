@@ -22,8 +22,7 @@ Measures, on the device JAX gives (a TPU, or it says so), what
      every count): ms a call of `_stage_pairing`, of `_stage_miller`, of
      `_stage_final_exp`, and of the two enqueued back to back with one
      `block_until_ready`, the verdicts checked equal. The table behind
-     `backend._PairingPrograms`, which serves the two wherever the plan's
-     W > 1.
+     `backend._PairingPrograms`, which serves the two on one chip.
 
     chiprun --chips 1 -- python3 scripts/measure_miller_lanes.py \
         [--budget-s N] [--jobs 65:1,65:128,...] [--stage [--pairs 5,4]]
@@ -223,11 +222,17 @@ def _traced_stage(out, n_pairs: int):
 
 
 def _stage_split(out, pair_counts) -> bool:
-    """Part 4: stage 4 as one program against two, the jits the backend
-    holds (donation as the platform has it): on the chip they are the
-    served executables and share their cache entries."""
+    """Part 4: stage 4 as one program against two. The two are the jits
+    the backend holds (donation as the platform has it): on the chip they
+    are the served executables and share their cache entries. The one
+    program is the mesh's; it is jitted here, under the same donation, for
+    its column."""
+    from lighthouse_tpu.crypto.jaxbls import pipeline as pl
+
     served = be._get_stages()[3]
-    one = served.one
+    one = jax.jit(be._stage_pairing, **(
+        dict(donate_argnums=be.STAGE_DONATE_ARGNUMS["pairing"])
+        if pl.donation_enabled()[0] else {}))
 
     def back_to_back(*args):                  # no sync between the two
         return served.final_exp(served.miller(*args))

@@ -51,28 +51,29 @@ def pytest_configure(config):
 
 
 # Files that need the most seconds on their worker, longest first, with the
-# seconds of a six-worker tier-1 run on the 8-core sandbox (PR 25: 941 s in
-# all) beside each. Their items move to the front of the collection so
+# seconds each took on its worker in a six-worker tier-1 run on the 8-core
+# sandbox beside it (PR 46's final tree: 1,381 s in all, on a night when the
+# sandbox ran PR 45's tree in 1,453 s and the driver's machine had run it in
+# 1,132). Their items move to the front of the collection so
 # `--dist loadfile` hands them out at t = 0 and the ~1000 light tests fill
 # in behind: the run is then bounded by about max(longest file, total /
 # workers). A file that needs more than 300 s on its worker belongs here.
 # xdist hands a worker its next file while the last two tests of its current
-# one are still pending, so the worker of a one-test file among the first
-# six takes the seventh file on at t = 0 and runs it after minutes of
+# one are still pending, so the worker of a one- or two-test file among the
+# first six takes the seventh file on at t = 0 and runs it after minutes of
 # compiling: keep a short file in seventh place.
 _LONGEST_FIRST = (
-    "test_ef_vectors.py",               # 738
-    "test_jaxbls_backend.py",           # 622
-    "test_multichip.py",                # 568
-    "test_jaxbls_registry.py",          # ~500 alone at PR 44 (nine one-device programs); 362 at PR 45 (eight)
-    "test_multichip_2d.py",             # 380 (1 test: takes the 7th along)
-    "test_jaxbls_pairing.py",           # 369
-    "test_fleet.py",                    # 183 (seventh: the short one)
-    "test_kzg.py",                      # 297 alone (PR 33: the blob batches)
-    "test_jaxbls_key_grids.py",         # 239 alone (PR 45: five one-device programs, out of the registry file)
-    "test_beacon_chain.py",             # 250
-    "test_jaxbls_h2c.py",               # 167
-    "test_jaxbls_msm.py",               # 123
+    "test_jaxbls_backend.py",           # 1091 (574 alone)
+    "test_jaxbls_pairing.py",           # 932
+    "test_ef_vectors.py",               # 882
+    "test_multichip.py",                # 856
+    "test_jaxbls_registry.py",          # 826 (486 alone: eight one-device programs)
+    "test_kzg.py",                      # 644 beside the five above from t = 0 (394 alone)
+    "test_fleet.py",                    # 208 (seventh: the short one)
+    "test_jaxbls_key_grids.py",         # 455 (293 alone: five one-device programs)
+    "test_beacon_chain.py",             # 325
+    "test_jaxbls_h2c.py",               # 248
+    "test_jaxbls_msm.py",               # 171
 )
 
 

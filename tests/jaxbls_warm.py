@@ -5,8 +5,7 @@ pairs, pairing) are large at any shape: on XLA:CPU one build of them costs
 minutes, and `tests/conftest.py` drops compiled executables at every file
 boundary, so each module that drives the real `JaxBackend` pays for its
 builds itself. The two modules that do (`test_jaxbls_backend.py`,
-`test_multichip.py`; the 2-D mesh's `test_multichip_2d.py` is a third,
-with a build nothing shares) call `warm_builds` from one module-scoped
+`test_multichip.py`) call `warm_builds` from one module-scoped
 fixture with exactly the builds their tests dispatch, a thread a build: XLA
 releases the GIL while it compiles, so the wall cost is about one build's,
 not the sum. No more threads than that: in the six-worker tier-1 run every

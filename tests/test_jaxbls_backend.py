@@ -113,12 +113,13 @@ def test_stage_attribution_on_real_dispatch():
 
 def test_pairing_stage_is_one_observation_a_dispatch():
     """One chip's stage 4 is a _PairingPrograms under the ONE stage name:
-    the one program here (on the CPU the urgent bucket's 5 pairs keep one
-    accumulator), two enqueued back to back where the loop carries a row,
-    from 33 pairs on here and at every count on a TPU (test_mesh drives
-    that branch with stand-ins; no toy bucket reaches it). A
-    dispatch with attribution on records one `pairing` resolve, no stage
-    label is new, and the verdicts are right."""
+    the Miller loop and the final exponentiation enqueued back to back,
+    here as on a TPU (on the CPU the urgent bucket's 5 pairs keep one
+    accumulator inside the Miller program; a row of them from 33 pairs on
+    and at every count on a TPU — the plan's business, which test_mesh
+    drives with stand-ins). A dispatch with attribution on records one
+    `pairing` resolve, no stage label is new, and the verdicts are
+    right."""
     from lighthouse_tpu.observability import device as obsdev
     from lighthouse_tpu.observability import trace as obstrace
 

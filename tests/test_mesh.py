@@ -2,10 +2,10 @@
 
 Runs on the forced-host-device harness (tests/conftest.py pins
 XLA_FLAGS=--xla_force_host_platform_device_count=8): mesh resolution
-seams, the 2-D acceptance/rejection matrix, placement, mesh-keyed
-padding, the stage-cache keying and the shard_map fallback's flip
-mechanism (with a stub). Nothing here compiles a staged program: the
-end-to-end 8-virtual-device dispatch through the REAL
+seams, the one axis, placement, mesh-keyed padding, the stage-cache
+keying, one chip's two stage-4 programs (with stand-ins) and the shard_map
+fallback's flip mechanism (with a stub). Nothing here compiles a staged
+program: the end-to-end 8-virtual-device dispatch through the REAL
 `PipelinedDispatcher`, and the `slow`-marked real shard_map collective,
 live in test_jaxbls_backend.py, beside the programs they need.
 """
@@ -22,7 +22,6 @@ def _fresh_mesh(monkeypatch):
     """Every test re-resolves the mesh from a clean seam state and leaves
     the process-wide cache re-resolved for the next test file."""
     monkeypatch.delenv("LIGHTHOUSE_TPU_MESH_DEVICES", raising=False)
-    monkeypatch.delenv("LIGHTHOUSE_TPU_PK_SHARDS", raising=False)
     monkeypatch.delenv("LIGHTHOUSE_TPU_MESH", raising=False)
     parallel.reset_mesh_cache()
     yield
@@ -69,72 +68,39 @@ def test_mesh_devices_env_seam(monkeypatch):
 
 def test_mesh_shape_key_parse_round_trip():
     assert parallel.parse_mesh_shape("sets8") == {"sets": 8}
+    # an axis a segment, whatever a profile on disk names
     assert parallel.parse_mesh_shape("sets4-pks2") == {"sets": 4, "pks": 2}
     assert parallel.parse_mesh_shape("single") == {}
     assert parallel.parse_mesh_shape(None) == {}
     assert parallel.parse_mesh_shape("garbage!!") == {}
 
 
-# ------------------------------------------ 2-D acceptance/rejection matrix
+# ------------------------------------------------------------ the one axis
+
+#: the switch that folded the devices into a second mesh axis until PR 46,
+#: spelled in halves: no code reads it, and a search for the name says so
+_RETIRED_SECOND_AXIS_SWITCH = "LIGHTHOUSE_TPU_PK" + "_SHARDS"
 
 
-@pytest.mark.parametrize("raw,expected_shape", [
-    ("2", {"sets": 4, "pks": 2}),
-    ("4", {"sets": 2, "pks": 4}),
-    ("8", {"sets": 1, "pks": 8}),
-])
-def test_pk_shards_accepted(monkeypatch, raw, expected_shape):
-    monkeypatch.setenv("LIGHTHOUSE_TPU_PK_SHARDS", raw)
+@pytest.mark.parametrize("devices", [1, 2, 4, 8])
+def test_mesh_has_the_one_axis(monkeypatch, devices):
+    """However many devices serve, and whatever a process left over from
+    an older deployment still sets, the mesh is 1-D over `sets`."""
+    monkeypatch.setenv(_RETIRED_SECOND_AXIS_SWITCH, "2")
+    monkeypatch.setenv("LIGHTHOUSE_TPU_MESH_DEVICES", str(devices))
     parallel.reset_mesh_cache()
     mesh = parallel.get_mesh()
-    assert dict(mesh.shape) == expected_shape
-    assert pm.PK_AXIS in mesh.axis_names
-
-
-@pytest.mark.parametrize("raw,reason", [
-    ("3", "not_pow2"),          # not a power of two
-    ("6", "not_pow2"),
-    ("16", "not_dividing"),     # pow2 but exceeds/doesn't divide 8
-    ("abc", "unparseable"),     # the pre-r10 SILENT branch: must warn now
-    ("", None),                 # empty string parses to... rejected loudly
-    ("0", "non_positive"),      # zero/negative: also previously silent
-    ("-4", "non_positive"),
-])
-def test_pk_shards_rejected_loudly(monkeypatch, raw, reason):
-    from lighthouse_tpu.observability.flight_recorder import RECORDER
-
-    monkeypatch.setenv("LIGHTHOUSE_TPU_PK_SHARDS", raw)
-    parallel.reset_mesh_cache()
-    before = RECORDER.events_recorded
-    mesh = parallel.get_mesh()
-    # every invalid value falls back to the 1-D sets mesh...
-    assert dict(mesh.shape) == {"sets": 8}
-    # ...and leaves a structured trace naming the rejected value
-    events = [e for e in RECORDER.events(16)
-              if e["kind"] == "mesh_config_rejected"]
-    assert events, f"no rejection event for {raw!r}"
-    assert events[-1]["pk_shards"] == raw
-    if reason is not None:
-        assert events[-1]["reason"] == reason
-    assert RECORDER.events_recorded > before
-
-
-def test_pk_shards_one_means_1d_quietly(monkeypatch):
-    from lighthouse_tpu.observability.flight_recorder import RECORDER
-
-    monkeypatch.setenv("LIGHTHOUSE_TPU_PK_SHARDS", "1")
-    parallel.reset_mesh_cache()
-    n_rejections = len([
-        e for e in RECORDER.events(64)
-        if e["kind"] == "mesh_config_rejected"
-    ])
-    mesh = parallel.get_mesh()
-    assert dict(mesh.shape) == {"sets": 8}
-    after = len([
-        e for e in RECORDER.events(64)
-        if e["kind"] == "mesh_config_rejected"
-    ])
-    assert after == n_rejections  # an explicit 1 is not a config error
+    if devices == 1:
+        assert mesh is None
+        assert parallel.mesh_shape_key() == "single"
+    else:
+        assert mesh.axis_names == ("sets",)
+        assert dict(mesh.shape) == {"sets": devices}
+        assert parallel.mesh_shape_key() == f"sets{devices}"
+    assert {labels for labels, _ in pm._MESH_AXIS_SIZE.children()} == {
+        ("sets",)}
+    assert pm._MESH_AXIS_SIZE.labels("sets").value == (
+        devices if devices > 1 else 0)
 
 
 def test_mesh_devices_zero_rejected_loudly(monkeypatch, capsys):
@@ -193,13 +159,13 @@ def test_put_sets_shards_leading_axis():
     assert mesh is not None
 
 
-def test_put_pk_grid_2d_mesh_shards_pk_axis(monkeypatch):
-    monkeypatch.setenv("LIGHTHOUSE_TPU_PK_SHARDS", "2")
-    parallel.reset_mesh_cache()
-    a = parallel.put_pk_grid(np.zeros((4, 2, 5), np.uint32))
-    assert tuple(a.sharding.spec) == ("sets", "pks", None)
-    b = parallel.put_sets(np.zeros((4, 5), np.uint32))
-    assert tuple(b.sharding.spec) == ("sets", None)
+def test_put_pk_grid_shards_the_sets_axis_only():
+    """A key grid over the mesh: a set's row on its set's chip, the key
+    axis whole there (the key sum is a chip's own work)."""
+    a = parallel.put_pk_grid(np.zeros((8, 4, 5), np.uint32))
+    assert tuple(a.sharding.spec) == ("sets", None, None)
+    assert len(a.sharding.device_set) == 8
+    assert all(s.data.shape == (1, 4, 5) for s in a.addressable_shards)
 
 
 def test_put_single_keeps_array_whole():
@@ -224,11 +190,21 @@ def test_pad_sets_mesh_keyed():
     assert parallel.pad_sets(5, mesh=mesh2) == 8
 
 
-def test_pad_pks_follows_pks_axis(monkeypatch):
-    assert parallel.pad_pks(3) == 4          # 1-D mesh: pow2 only
-    monkeypatch.setenv("LIGHTHOUSE_TPU_PK_SHARDS", "2")
+def test_key_width_is_pow2_on_every_topology(monkeypatch):
+    """No topology pads the key axis beyond its power of two: the live
+    mesh, a named one, one chip, and a process without a mesh."""
+    import jax
+    from jax.sharding import Mesh
+
+    from lighthouse_tpu.crypto.jaxbls.backend import padding_bucket
+
+    mesh2 = Mesh(np.array(jax.devices()[:2]), ("sets",))
+    assert padding_bucket(1, 3)[1] == 4
+    assert padding_bucket(1, 1, mesh=mesh2)[1] == 1
+    assert padding_bucket(1, 3, single_chip=True)[1] == 4
+    monkeypatch.setenv("LIGHTHOUSE_TPU_MESH", "0")
     parallel.reset_mesh_cache()
-    assert parallel.pad_pks(1) == 2          # must cover the pks axis
+    assert padding_bucket(1, 3) == (4, 4)
 
 
 def test_padding_bucket_mesh_vs_single_chip():
@@ -271,35 +247,35 @@ def test_stage_cache_keyed_by_mesh_and_donation(monkeypatch):
     assert isinstance(
         be._kernel_cache["stages_d0_sets8"][3], be._PairingDispatch
     )
-    # one chip's holds the one program and the two that serve wide
-    # buckets, each jitted under its own name
+    # one chip's holds the two programs, each jitted under its own name,
+    # and nothing else; the one program is the mesh's
     pairing = be._kernel_cache["stages_d0"][3]
     assert isinstance(pairing, be._PairingPrograms)
-    assert pairing.one.__wrapped__ is be._stage_pairing
+    assert vars(pairing).keys() == {"miller", "final_exp"}
     assert pairing.miller.__wrapped__ is be._stage_miller
     assert pairing.final_exp.__wrapped__ is be._stage_final_exp
+    assert (be._kernel_cache["stages_d0_sets8"][3]._jit.__wrapped__
+            is be._stage_pairing)
 
 
-@pytest.mark.parametrize("lanes,platform,programs", [
-    (5, "cpu", ["@jit_one"]), (32, "cpu", ["@jit_one"]),
-    (33, "cpu", ["@jit_miller", "@jit_final_exp"]),
-    (257, "cpu", ["@jit_miller", "@jit_final_exp"]),
-    (5, "tpu", ["@jit_miller", "@jit_final_exp"]),
-    (4, "tpu", ["@jit_miller", "@jit_final_exp"]),
-], ids=["5-one", "32-one", "33-two", "257-two", "5-two-on-a-tpu",
-        "4-two-on-a-tpu"])
-def test_pairing_programs_by_pair_lanes(lanes, platform, programs,
+@pytest.mark.parametrize("lanes,platform,accumulators", [
+    (5, "cpu", 1), (32, "cpu", 1), (33, "cpu", 128), (257, "cpu", 128),
+    (5, "tpu", 128), (4, "tpu", 128),
+], ids=["5-one", "32-one", "33-row", "257-row", "5-row-on-a-tpu",
+        "4-row-on-a-tpu"])
+def test_pairing_programs_by_pair_lanes(lanes, platform, accumulators,
                                         monkeypatch):
-    """The one-chip stage-4 callable with stand-in programs (nothing here
-    compiles a Miller loop). What serves follows the Miller loop's plan:
-    one accumulator -> the one program, a row of them (33 pairs on here,
-    every pair count — the urgent bucket's 5, KZG's 4 — where the process
-    runs on a TPU, which the plan reads from jax.default_backend()) -> two
-    programs chained on the device. `.lower` gives the lowerings of what
-    serves — the second at the first's output shape — program capture
-    records their sum under the one stage, an attributed dispatch is ONE
-    `pairing` resolve, and with donation on each dispatch consumes its own
-    inputs and holds nothing over to the next."""
+    """The one-chip stage-4 callable with stand-in programs. Whatever the
+    Miller loop's plan gives — one accumulator below 33 pairs here, a row
+    of them from there on and at every pair count (the urgent bucket's 5,
+    KZG's 4) where the process runs on a TPU, which the plan reads from
+    jax.default_backend() — stage 4 is the same two programs chained on
+    the device: the plan is the Miller program's business, not the
+    holder's. `.lower` gives the two lowerings — the second at the first's
+    output shape — program capture records their sum under the one stage,
+    an attributed dispatch is ONE `pairing` resolve, and with donation on
+    each dispatch consumes its own inputs and holds nothing over to the
+    next."""
     import jax
     import jax.numpy as jnp
 
@@ -310,7 +286,7 @@ def test_pairing_programs_by_pair_lanes(lanes, platform, programs,
 
     assert jax.default_backend() == "cpu"
     monkeypatch.setattr(jax, "default_backend", lambda: platform)
-    assert (po.miller_lane_plan(lanes)[0] > 1) == (len(programs) == 2)
+    assert po.miller_lane_plan(lanes)[0] == accumulators
 
     def miller(px, py, qxx, qyy, pair_mask):
         return jnp.where(pair_mask, px + py + qxx + qyy, 0)
@@ -318,12 +294,8 @@ def test_pairing_programs_by_pair_lanes(lanes, platform, programs,
     def final_exp(f):
         return jnp.sum(f * f) == 100.0 * (lanes - 1)
 
-    def one(px, py, qxx, qyy, pair_mask):
-        return final_exp(miller(px, py, qxx, qyy, pair_mask))
-
     donate = be.STAGE_DONATE_ARGNUMS
     pairing = be._PairingPrograms(
-        jax.jit(one, donate_argnums=donate["pairing"]),
         jax.jit(miller, donate_argnums=donate["miller"]),
         jax.jit(final_exp, donate_argnums=donate["final_exp"]),
     )
@@ -333,20 +305,17 @@ def test_pairing_programs_by_pair_lanes(lanes, platform, programs,
                      for v in (1, 2, 3, 4)) + (jnp.arange(lanes) < lanes - 1,)
 
     lowered = pairing.lower(*inputs())
-    as_tuple = lowered if isinstance(lowered, tuple) else (lowered,)
-    assert [low.as_text().split()[1] for low in as_tuple] == programs
-    if len(programs) == 2:
-        assert lowered[0].out_info.shape == (lanes,)
-        assert lowered[1].in_avals[0][0].shape == (lanes,)
+    assert [low.as_text().split()[1] for low in lowered] == [
+        "@jit_miller", "@jit_final_exp"]
+    assert lowered[0].out_info.shape == (lanes,)
+    assert lowered[1].in_avals[0][0].shape == (lanes,)
 
     first = inputs()
     assert bool(pairing(*first)) is True
-    if len(programs) == 2:
-        # the stand-in Miller value can live in a donated input (the one
-        # program's scalar cannot, and XLA:CPU then keeps the inputs)
-        assert first[0].is_deleted()
-        with pytest.raises((RuntimeError, ValueError), match="deleted"):
-            pairing(*first)
+    # the stand-in Miller value can live in a donated input
+    assert first[0].is_deleted()
+    with pytest.raises((RuntimeError, ValueError), match="deleted"):
+        pairing(*first)
     resolves = obsdev.STAGE_DEVICE_SECONDS.labels("pairing", lanes - 1, 1)
     with obsdev.attributed():
         attr = obsdev.begin((lanes - 1, 1))
@@ -360,18 +329,38 @@ def test_pairing_programs_by_pair_lanes(lanes, platform, programs,
         served = perf.capture_program("pairing", pairing, inputs(), (4, 1))
         alone = [perf.capture_program("pairing", fn, a, (4, 1)) for fn, a in (
             (pairing.miller, inputs()),
-            (pairing.final_exp, (jnp.ones(lanes, jnp.float32),)),
-            (pairing.one, inputs()))]
+            (pairing.final_exp, (jnp.ones(lanes, jnp.float32),)))]
     finally:
         perf.reset_programs()
-    if len(programs) == 1:
-        assert served == alone[2]
-        return
     for summed in ("flops", "bytes_accessed", "generated_code_bytes"):
         assert served[summed] == alone[0][summed] + alone[1][summed]
     assert alone[0]["flops"] > 0 and alone[1]["flops"] > 0
     for largest in ("argument_bytes", "output_bytes", "temp_bytes"):
         assert served[largest] == max(alone[0][largest], alone[1][largest])
+
+
+def test_final_exp_is_one_program_for_every_pair_count():
+    """A process that serves several one-chip buckets compiles the Miller
+    loop once a pair count and final exponentiation ONCE: the Miller value
+    has no pair axis, on any platform. Stand-in programs with that
+    contract; nothing here compiles a Miller loop."""
+    import jax
+    import jax.numpy as jnp
+
+    from lighthouse_tpu.crypto.jaxbls import backend as be
+
+    def miller(px, py, qxx, qyy, pair_mask):
+        return jnp.sum(jnp.where(pair_mask, px + py + qxx + qyy, 0)) / (
+            jnp.sum(pair_mask))
+
+    pairing = be._PairingPrograms(
+        jax.jit(miller), jax.jit(lambda f: f == 10.0))
+    for lanes in (5, 9, 5):
+        assert bool(pairing(
+            *(jnp.full((lanes,), v, jnp.float32) for v in (1, 2, 3, 4)),
+            jnp.arange(lanes) < lanes - 1)) is True
+    assert pairing.miller._cache_size() == 2
+    assert pairing.final_exp._cache_size() == 1
 
 
 def test_pairing_dispatch_flips_to_fallback_once(monkeypatch):

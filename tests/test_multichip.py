@@ -11,9 +11,7 @@ This is one of the two modules that drive the real JaxBackend through its
 four staged programs (the other is test_jaxbls_backend.py). Each compiles
 its programs once, in one module-scoped warm-up (tests/jaxbls_warm.py): a
 new test of the staged backend joins one of the two instead of opening a
-file, and keeps to the builds and key-count buckets its module warms. The
-2-D (sets, pks) mesh shares no program with the 1-D one and has
-test_multichip_2d.py to itself.
+file, and keeps to the builds and key-count buckets its module warms.
 """
 
 import random
