@@ -50,30 +50,31 @@ def pytest_configure(config):
         config.option.loadscopereorder = False
 
 
-# Files that need the most seconds on their worker, longest first, with the
-# seconds each took on its worker in a six-worker tier-1 run on the 8-core
-# sandbox beside it (PR 46's final tree: 1,381 s in all, on a night when the
-# sandbox ran PR 45's tree in 1,453 s and the driver's machine had run it in
-# 1,132). Their items move to the front of the collection so
-# `--dist loadfile` hands them out at t = 0 and the ~1000 light tests fill
-# in behind: the run is then bounded by about max(longest file, total /
-# workers). A file that needs more than 300 s on its worker belongs here.
-# xdist hands a worker its next file while the last two tests of its current
-# one are still pending, so the worker of a one- or two-test file among the
-# first six takes the seventh file on at t = 0 and runs it after minutes of
-# compiling: keep a short file in seventh place.
+# The order in which `--dist loadfile` hands the heavy files to the six
+# workers, with the seconds each took on its worker (and its process's CPU
+# seconds) in a whole tier-1 run on the 8-core sandbox (PR 47's tree: 973 s in
+# all; tests/README.md has the runs). Their items move to the front of the
+# collection, the ~1000 light tests fill in behind, and the run is bounded by
+# about max(longest file, total / workers). First the file the run waits
+# for, then five files of ONE busy thread each: the backend module's warm-up
+# traces in Python (the GIL) and compiles in two threads, and beside two more
+# warm-ups of four and six threads on these eight cores it took 703 s where
+# it takes 387 alone. The other compile modules start when the first of the
+# five ends, early enough to end before the backend module does. A file that
+# needs more than 300 s on its worker belongs here; none but the first, whose
+# seconds are the run's, may need more than 750 s. xdist hands a worker its
+# next file two tests before the end: no one- or two-test file in the first six.
 _LONGEST_FIRST = (
-    "test_jaxbls_backend.py",           # 1091 (574 alone)
-    "test_jaxbls_pairing.py",           # 932
-    "test_ef_vectors.py",               # 882
-    "test_multichip.py",                # 856
-    "test_jaxbls_registry.py",          # 826 (486 alone: eight one-device programs)
-    "test_kzg.py",                      # 644 beside the five above from t = 0 (394 alone)
-    "test_fleet.py",                    # 208 (seventh: the short one)
-    "test_jaxbls_key_grids.py",         # 455 (293 alone: five one-device programs)
-    "test_beacon_chain.py",             # 325
-    "test_jaxbls_h2c.py",               # 248
-    "test_jaxbls_msm.py",               # 171
+    "test_jaxbls_backend.py",           # 959 (816 alone; CPU 2008: two builds)
+    "test_ef_vectors.py",               # 608 (434 alone; CPU 336: pure Python)
+    "test_kzg.py",                      # 442 (CPU 456)
+    "test_beacon_chain.py",             # 276 (CPU 195)
+    "test_fleet.py",                    # 193 (CPU 90)
+    "test_jaxbls_curve.py",             # 196 (CPU 298)
+    "test_jaxbls_registry.py",          # 619 (559 alone; CPU 1172: nine programs)
+    "test_jaxbls_pairing.py",           # 517 (385 alone; CPU 669: six programs)
+    "test_chip_compile.py",             # 379 (CPU 722: the TPU compiler's threads)
+    "test_jaxbls_msm.py",               # 194
 )
 
 

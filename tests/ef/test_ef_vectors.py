@@ -23,7 +23,22 @@ _va = VectorAccess(VECTOR_ROOT)
 
 @pytest.fixture(scope="module", autouse=True)
 def _backend():
+    """The pure-Python backend, with `hash_to_g2` memoised by (message, DST)
+    while this module runs: the cases sign the same chains over and over
+    (854 calls on 507 distinct messages at PR 47, a third of a second each,
+    most of it the cofactor's 636-bit multiplication), and a point is a
+    tuple of integers, safe to share. Keys and signatures already keep
+    their decompressed points by their bytes (crypto/bls)."""
+    import functools
+
+    from lighthouse_tpu.crypto.bls381 import hash_to_curve as h2c
+
     bls.set_backend("python")
+    real = h2c.hash_to_g2
+    memo = functools.lru_cache(maxsize=None)(real)
+    h2c.hash_to_g2 = lambda msg, dst: memo(bytes(msg), dst)
+    yield
+    h2c.hash_to_g2 = real
 
 
 @pytest.mark.skipif(not CASES, reason="no vectors generated")

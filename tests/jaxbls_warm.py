@@ -4,23 +4,22 @@ Not a test file. The programs of the four stages (prepare, hash-to-G2,
 pairs, pairing) are large at any shape: on XLA:CPU one build of them costs
 minutes, and `tests/conftest.py` drops compiled executables at every file
 boundary, so each module that drives the real `JaxBackend` pays for its
-builds itself. The two modules that do (`test_jaxbls_backend.py`,
-`test_multichip.py`) call `warm_builds` from one module-scoped
-fixture with exactly the builds their tests dispatch, a thread a build: XLA
-releases the GIL while it compiles, so the wall cost is about one build's,
-not the sum. No more threads than that: in the six-worker tier-1 run every
-core is taken already, and PR 25 measured three threads a build (prepare,
-hash-to-G2 and pairing side by side) at the same seconds for the module
-(589-650 against 604-608) and no fewer for the files beside it.
-A new test that drives the staged backend joins one of those modules
-(and, if it needs a new bucket, adds it to that module's warm-up) instead
-of opening a file of its own.
+builds itself. The module that does over the mesh (`test_jaxbls_backend.py`)
+calls `warm_builds` from one module-scoped fixture with exactly the builds
+its tests dispatch, a thread a build: XLA releases the GIL while it compiles,
+so the wall cost is about one build's, not the sum. No more threads than
+that: in the six-worker tier-1 run every core is taken already, and PR 25
+measured three threads a build (prepare, hash-to-G2 and pairing side by
+side) at the same seconds for the module (589-650 against 604-608) and no
+fewer for the files beside it. A new test that drives the staged backend
+joins that module (and, if it needs a new bucket, adds it to the module's
+warm-up) instead of opening a file of its own.
 
-Also here, what more than one of those files needs: `warm_one_chip_prepares`
-and `prepare_rest` for the files that drive stage 1 of the batch lane without
-a mesh (test_jaxbls_registry.py, test_jaxbls_key_grids.py), and for the tests
-of the coefficient chain's refusal in both the curve's and the backend's file
-`order_13_twist_point`, a point of E'(Fq2) outside G2.
+Also here: `warm_one_chip_prepares` and `prepare_rest` for the file that
+drives stage 1 of the batch lane without a mesh (test_jaxbls_registry.py),
+`run_in_threads` for any module fixture that compiles several programs, and
+for the tests of the coefficient chain's refusal in both the curve's and the
+backend's file `order_13_twist_point`, a point of E'(Fq2) outside G2.
 """
 
 import functools

@@ -2,15 +2,18 @@
 generic BLS API — the same dual-backend strategy the reference uses for
 blst vs fake_crypto (/root/reference/crypto/bls/tests/tests.rs).
 
-This is one of the two modules that drive the real JaxBackend through its
-four stages (the other is test_multichip.py). Each compiles its
-programs once, in one module-scoped warm-up (tests/jaxbls_warm.py): a new
-test of the staged backend joins one of the two instead of opening a
-file, and keeps to the builds and key-count buckets its module warms."""
+This is the module that drives the real JaxBackend through its four
+stages over the mesh and on the urgent lane's one chip (test_multichip.py's
+sharded runs joined it at PR 47; test_jaxbls_registry.py drives the batch
+lane without a mesh). It compiles its programs once, in one module-scoped
+warm-up (tests/jaxbls_warm.py): a new test of the staged backend joins it
+instead of opening a file, and keeps to the builds and key-count buckets
+the module warms."""
 
 import json
 import random
 
+import numpy as np
 import pytest
 
 from lighthouse_tpu.crypto import bls
@@ -600,13 +603,16 @@ def test_shard_map_pairing_fallback_real_collective():
         stages = be._get_stages(mesh=mesh)
         pd = stages[3]
         assert isinstance(pd, be._PairingDispatch)
-        old = (pd._jit, pd._use_fallback, pd._fallback)
+        old = (pd._jit, pd._use_fallback, pd._fallback, pd._jit_served)
 
         class _Boom:
             def __call__(self, *a):
                 raise RuntimeError("forced propagation failure")
 
-        pd._jit, pd._use_fallback, pd._fallback = _Boom(), False, None
+        # a stage that has served nothing yet: behind this module's other
+        # tests the jit build has, and a failure would be a runtime one
+        pd._jit, pd._use_fallback, pd._fallback, pd._jit_served = (
+            _Boom(), False, None, False)
         try:
             rng = random.Random(0x5AFE)
             sets = [_mk_set_from(rng, 1, bytes([i]) * 32) for i in range(8)]
@@ -615,9 +621,350 @@ def test_shard_map_pairing_fallback_real_collective():
             bad = sets[:-1] + [_mk_set_from(rng, 1, b"\x99" * 32, valid=False)]
             assert backend.verify_signature_sets(bad, [1] * 8) is False
         finally:
-            pd._jit, pd._use_fallback, pd._fallback = old
+            pd._jit, pd._use_fallback, pd._fallback, pd._jit_served = old
     finally:
         bls_api.set_backend("python")
+
+
+# ------------------------------------------- the staged programs, sharded
+# (test_multichip.py until PR 47: it compiled the mesh's build a second
+# time, at a key count of its own, and an unsharded build of 8 sets beside
+# it. Here its sharded runs ride this module's (8, 4) programs; the two
+# tests that need the 8-set build on one device compile it themselves and
+# are `slow`, each made up for by a case below that compiles nothing.)
+#
+# The framework's scaling story (SURVEY.md S5): signature sets are
+# data-parallel over a `sets` mesh axis; the cross-set pair-product and
+# signature tree-sum become XLA collectives. These tests prove the sharded
+# programs (a) compile and run over 8 devices, (b) give the pure-Python
+# curve's points stage by stage and the one-device programs' verdict, and
+# (c) agree with the pure-Python backend on valid AND invalid batches.
+
+N_DEV = 8
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    devices = jax.devices()
+    if len(devices) < N_DEV:
+        pytest.skip(f"needs {N_DEV} virtual devices, got {len(devices)}")
+    return Mesh(np.array(devices[:N_DEV]), ("sets",))
+
+
+def _build_sets(n_sets: int, n_pks: int, seed: int, tamper: int | None = None):
+    """n_sets aggregate sets; if tamper is an index, that set's signature is
+    signed over a different message (invalid)."""
+    rng = random.Random(seed)
+    sets = []
+    for i in range(n_sets):
+        sks = [rng.randrange(1, R) for _ in range(n_pks)]
+        pks = [bls.PublicKey(cv.g1_mul(cv.G1_GEN, sk)) for sk in sks]
+        msg = i.to_bytes(32, "big")
+        signed = (i + 1).to_bytes(32, "big") if tamper == i else msg
+        h = bls_api.hash_to_g2_point(signed)
+        sig = bls.Signature(cv.g2_mul(h, sum(sks) % R))
+        sets.append(bls.SignatureSet(sig, pks, msg))
+    rands = [1] + [rng.getrandbits(64) | 1 for _ in range(n_sets - 1)]
+    return sets, rands
+
+
+def _marshal(backend, sets, rands):
+    """Reuse the backend's own wire-format marshalling, returning host arrays."""
+    from lighthouse_tpu.crypto.jaxbls import backend as be
+    from lighthouse_tpu.crypto.jaxbls import limbs as lb, curve_ops as co, h2c_ops as h2
+
+    n_real = len(sets)
+    n = max(be.MIN_SETS, 1 << (n_real - 1).bit_length())
+    m = max(len(s.signing_keys) for s in sets)
+    m = max(be.MIN_PKS, 1 << (m - 1).bit_length())
+
+    pk_x = np.zeros((n, m, lb.NL), np.uint32)
+    pk_y = np.zeros((n, m, lb.NL), np.uint32)
+    pk_mask = np.zeros((n, m), np.uint32)
+    sig_x = np.zeros((n, 2, lb.NL), np.uint32)
+    sig_y = np.zeros((n, 2, lb.NL), np.uint32)
+    z_digits = np.zeros((n, be.Z_BITS), np.uint32)
+    set_mask = np.zeros((n,), np.uint32)
+    us = np.zeros((n, 2, 2, lb.NL), np.uint32)
+
+    for i, s in enumerate(sets):
+        keys = s.signing_keys
+        pk_x[i, : len(keys)] = be.pack_ints_vec([pk.point[0] for pk in keys])
+        pk_y[i, : len(keys)] = be.pack_ints_vec([pk.point[1] for pk in keys])
+        pk_mask[i, : len(keys)] = 1
+        sp = s.signature.point
+        sig_x[i, 0] = be.pack_ints_vec([sp[0][0]])[0]
+        sig_x[i, 1] = be.pack_ints_vec([sp[0][1]])[0]
+        sig_y[i, 0] = be.pack_ints_vec([sp[1][0]])[0]
+        sig_y[i, 1] = be.pack_ints_vec([sp[1][1]])[0]
+    zmask = (1 << 64) - 1
+    z_digits[:n_real] = co.scalars_to_bits(
+        [z & zmask for z in rands], be.Z_BITS)
+    set_mask[:n_real] = 1
+    us[:n_real] = h2.hash_to_field_batch([s.message for s in sets], backend.dst)
+    return (pk_x, pk_y, pk_mask, sig_x, sig_y, us, z_digits, set_mask)
+
+@pytest.fixture(scope="module")
+def jax_backend():
+    return bls_api.set_backend("jax")
+
+
+def _staged(args, mesh=None):
+    """The production staged pipeline, every stage's outputs; with a mesh,
+    every input is sharded along the sets axis (collectives cross shards in
+    the reductions)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as Pspec
+
+    from lighthouse_tpu.crypto.jaxbls import backend as be
+
+    be._init_consts()
+    pk_x, pk_y, pk_mask, sig_x, sig_y, us, z_digits, set_mask = args
+    if mesh is not None:
+        def shard(a):
+            return jax.device_put(
+                a, NamedSharding(mesh, Pspec("sets", *([None] * (a.ndim - 1))))
+            )
+        pk_x, pk_y, pk_mask, sig_x, sig_y, us, z_digits, set_mask = (
+            shard(a) for a in (pk_x, pk_y, pk_mask, sig_x, sig_y, us, z_digits, set_mask)
+        )
+    # the stages a dispatch over this mesh runs (`in_shardings` over the
+    # `sets` axis, stage 4 the one program), not plain jits that would
+    # compile a third stage 4 by propagation from the inputs' shardings
+    prepare, h2c_stage, pairs_stage, pairing_stage = be._get_stages(mesh=mesh)
+    z_pk, sig_acc, bad = prepare(
+        pk_x, pk_y, pk_mask, sig_x, sig_y, z_digits, set_mask
+    )
+    h_jac = h2c_stage(us)
+    px, py, qxx, qyy, pair_mask = pairs_stage(z_pk, h_jac, sig_acc, set_mask)
+    ok = pairing_stage(px, py, qxx, qyy, pair_mask)
+    return z_pk, sig_acc, bad, h_jac, ok
+
+
+def _run_staged(args, mesh=None):
+    *_, bad, _h_jac, ok = _staged(args, mesh)
+    return bool(np.asarray(ok)) and not bool(np.asarray(bad))
+
+
+def _run_sharded(mesh, args):
+    return _run_staged(args, mesh=mesh)
+
+
+def _warm_eight_sets_on_one_device():
+    """The unsharded build at 8 sets of 4 keys, for the two `slow` tests
+    below: minutes, and a third build in this process (past conftest's
+    mapping mark: run them by name)."""
+    from jaxbls_warm import warm_builds
+
+    warm_builds((8, (4,), None))
+
+
+def test_sharded_valid_batch_verifies(mesh, jax_backend):
+    sets, rands = _build_sets(8, 4, seed=0x51)
+    args = _marshal(jax_backend, sets, rands)
+    assert _run_sharded(mesh, args) is True
+    # python ground truth agrees
+    py = bls_api._BACKENDS["python"]
+    assert py.verify_signature_sets(sets, rands) is True
+
+
+def test_sharded_invalid_batch_rejects(mesh, jax_backend):
+    sets, rands = _build_sets(8, 4, seed=0x52, tamper=5)
+    args = _marshal(jax_backend, sets, rands)
+    assert _run_sharded(mesh, args) is False
+    py = bls_api._BACKENDS["python"]
+    assert py.verify_signature_sets(sets, rands) is False
+
+
+@pytest.mark.slow
+def test_sharded_matches_unsharded_bit_identical(mesh, jax_backend):
+    _warm_eight_sets_on_one_device()
+    sets, rands = _build_sets(8, 4, seed=0x53)
+    args = _marshal(jax_backend, sets, rands)
+
+    unsharded = _run_staged(args, mesh=None)
+    sharded = _run_sharded(mesh, args)
+    assert sharded == unsharded == True  # noqa: E712
+
+
+def _affine_g1(jac):
+    """[(x, y) or None] of a batch of Jacobian G1 points in Montgomery
+    limbs, by Python integers."""
+    from lighthouse_tpu.crypto.bls381.constants import P
+    from lighthouse_tpu.crypto.jaxbls import tower as tw
+
+    out = []
+    for x, y, z in zip(*(tw.fq_batch_from_device(c) for c in jac)):
+        zi = pow(z, -1, P) if z else 0
+        out.append((x * zi * zi % P, y * zi * zi * zi % P) if z else None)
+    return out
+
+
+def _affine_g2(jac):
+    """The same for G2: a batch of lanes, or one point."""
+    from lighthouse_tpu.crypto.bls381 import fields as pyf
+    from lighthouse_tpu.crypto.jaxbls import tower as tw
+
+    coords = [np.asarray(c) for c in jac]
+    if coords[0].ndim == 2:
+        coords = [c[None] for c in coords]
+    out = []
+    for lane in zip(*coords):
+        x, y, z = (tw.fq2_from_device(c) for c in lane)
+        if z == (0, 0):
+            out.append(None)
+            continue
+        zi = pyf.fq2_inv(z)
+        zi2 = pyf.fq2_sqr(zi)
+        out.append((pyf.fq2_mul(x, zi2), pyf.fq2_mul(y, pyf.fq2_mul(zi2, zi))))
+    return out
+
+
+@pytest.mark.parametrize("tamper", [None, 5], ids=["valid", "tampered"])
+def test_sharded_stages_give_the_reference_curves_points(mesh, jax_backend,
+                                                         tamper):
+    """Made up for test_sharded_matches_unsharded_bit_identical (`slow`
+    since PR 47, with the 8-set build on one device it alone needed): what
+    that test held the sharded programs to by a verdict, these hold them to
+    point by point against the pure-Python curve — z_i * aggpk_i of every
+    set, the sum of z_i * sig_i across the shards, H(m_i) of every lane —
+    on a sound batch and on one whose set 5 signed another message, and
+    the verdict beside them. A sharded program that computes anything but
+    what one device computes fails here, with no build beside the mesh's."""
+    sets, rands = _build_sets(8, 4, seed=0x57, tamper=tamper)
+    z_pk, sig_acc, bad, h_jac, ok = _staged(
+        _marshal(jax_backend, sets, rands), mesh=mesh)
+    sig_sum, z_aggpk = None, []
+    for s, z in zip(sets, rands):
+        aggpk = None
+        for pk in s.signing_keys:
+            aggpk = cv.g1_add(aggpk, pk.point)
+        z_aggpk.append(cv.g1_mul(aggpk, z))
+        sig_sum = cv.g2_add(sig_sum, cv.g2_mul(s.signature.point, z))
+    assert _affine_g1(z_pk) == z_aggpk
+    assert _affine_g2(sig_acc) == [sig_sum]
+    assert _affine_g2(h_jac) == [
+        bls_api.hash_to_g2_point(s.message) for s in sets]
+    assert not bool(np.asarray(bad))
+    assert bool(np.asarray(ok)) is (tamper is None)
+
+
+# --------------------------------------------------------- backend path
+# The production JaxBackend discovers the mesh itself (parallel/mesh.py):
+# verify_signature_sets(_async) is the SAME call sites the chain uses.
+
+
+def test_backend_dispatch_uses_mesh(jax_backend):
+    from lighthouse_tpu import parallel
+
+    parallel.reset_mesh_cache()
+    m = parallel.get_mesh()
+    assert m is not None and m.devices.size == N_DEV
+
+    sets, rands = _build_sets(8, 4, seed=0x54)
+    assert jax_backend.verify_signature_sets(sets, rands) is True
+    bad, bad_rands = _build_sets(8, 4, seed=0x55, tamper=3)
+    assert jax_backend.verify_signature_sets(bad, bad_rands) is False
+    # async path too (what the beacon processor drives)
+    h = jax_backend.verify_signature_sets_async(sets, rands)
+    assert h.result() is True
+
+
+@pytest.mark.slow
+def test_backend_mesh_agrees_with_single_device(jax_backend, monkeypatch):
+    from lighthouse_tpu import parallel
+
+    _warm_eight_sets_on_one_device()
+    sets, rands = _build_sets(8, 4, seed=0x56)
+    monkeypatch.setenv("LIGHTHOUSE_TPU_MESH", "0")
+    parallel.reset_mesh_cache()
+    assert parallel.get_mesh() is None
+    single = jax_backend.verify_signature_sets(sets, rands)
+    monkeypatch.setenv("LIGHTHOUSE_TPU_MESH", "1")
+    parallel.reset_mesh_cache()
+    assert parallel.get_mesh() is not None
+    meshed = jax_backend.verify_signature_sets(sets, rands)
+    parallel.reset_mesh_cache()
+    assert single == meshed == True  # noqa: E712
+
+
+def test_the_mesh_switch_moves_a_dispatch_between_the_lanes(jax_backend,
+                                                           monkeypatch):
+    """Made up for test_backend_mesh_agrees_with_single_device (`slow`
+    since PR 47): the same batch dispatched with LIGHTHOUSE_TPU_MESH=0 and
+    =1, the stages recording stand-ins. Without a mesh the backend asks for
+    the one-device stages, places every argument whole on one device and
+    counts a `single_device` dispatch; with it, the mesh's stages, every
+    argument spread over the eight devices, a `sharded` dispatch. (That the
+    one-device programs then give the verdict the mesh's give is the slow
+    test's; at 4 sets the urgent lane's tests hold them to the reference.)"""
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+    from lighthouse_tpu import parallel
+    from lighthouse_tpu.parallel.mesh import MESH_DISPATCH
+
+    asked = []
+
+    def stand_ins(mesh=None):
+        def prepare(*args):
+            asked.append((mesh, [len(a.sharding.device_set) for a in args]))
+            return "z_pk", "sig_acc", np.uint32(0)
+
+        return (prepare, lambda us: "h_jac",
+                lambda *a: ("px", "py", "qxx", "qyy", "pair_mask"),
+                lambda *a: np.bool_(True))
+
+    monkeypatch.setattr(be, "_get_stages", stand_ins)
+    sets, rands = _build_sets(8, 4, seed=0x56)
+    lanes = {k: MESH_DISPATCH.labels(k) for k in ("single_device", "sharded")}
+    before = {k: c.value for k, c in lanes.items()}
+    try:
+        monkeypatch.setenv("LIGHTHOUSE_TPU_MESH", "0")
+        parallel.reset_mesh_cache()
+        assert parallel.get_mesh() is None
+        assert jax_backend.verify_signature_sets(sets, rands) is True
+        monkeypatch.setenv("LIGHTHOUSE_TPU_MESH", "1")
+        parallel.reset_mesh_cache()
+        live = parallel.get_mesh()
+        assert live is not None and live.devices.size == N_DEV
+        assert jax_backend.verify_signature_sets(sets, rands) is True
+    finally:
+        monkeypatch.undo()
+        parallel.reset_mesh_cache()
+    assert asked == [(None, [1] * 7), (live, [N_DEV] * 7)]
+    assert {k: c.value - before[k] for k, c in lanes.items()} == {
+        "single_device": 1, "sharded": 1}
+
+
+def test_hash_to_g2_matches_python():
+    """(test_jaxbls_h2c.py until PR 47, where it compiled hash-to-G2 at
+    three lanes for itself.) The urgent lane's program, four lanes: each
+    message's point is the pure-Python `hash_to_g2`'s."""
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+    from lighthouse_tpu.crypto.bls381 import hash_to_curve as ph2c
+    from lighthouse_tpu.crypto.bls381.constants import DST_POP
+    from lighthouse_tpu.crypto.jaxbls import h2c_ops as h2, limbs as lb
+
+    msgs = [b"lighthouse-tpu %d" % i for i in range(3)]
+    us = np.zeros((4, 2, 2, lb.NL), np.uint32)
+    us[:3] = h2.hash_to_field_batch(msgs, DST_POP)
+    h2c_stage = be._get_stages()[1]
+    compiled = h2c_stage._cache_size()
+    pts = _affine_g2(h2c_stage(us))
+    assert h2c_stage._cache_size() == compiled      # the module's program
+    for i, msg in enumerate(msgs):
+        assert pts[i] == ph2c.hash_to_g2(msg, DST_POP)
+
+
+def test_sharded_runs_leave_the_module_under_the_mapping_mark():
+    """(test_multichip.py's own mark test until PR 47.) The sharded runs
+    above added no program to this module's two builds."""
+    from conftest import _MAP_COUNT_HIGH_MARK, _n_memory_mappings
+
+    assert _n_memory_mappings() < _MAP_COUNT_HIGH_MARK
 
 
 def test_module_stays_under_the_mapping_mark():

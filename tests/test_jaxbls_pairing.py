@@ -5,6 +5,7 @@ the ground truth by Fq2 units — equality is checked after final
 exponentiation (the only form consensus code ever uses)."""
 
 import functools
+import hashlib
 import random
 
 import jax
@@ -38,12 +39,51 @@ def _device_pairs(pairs, pad_to):
     return (xp, yp), (xq, yq), jnp.asarray(mask)
 
 
-_full_pairing = jax.jit(
-    lambda p, q, m: po.final_exponentiation(po.fq12_product(po.miller_loop_batch(p, q, m)))
-)
-_product_check = jax.jit(po.pairing_product_is_one)
-_final_exp = jax.jit(po.final_exponentiation)
-_stage_final_exp = jax.jit(be._stage_final_exp)
+# Final exponentiation is compiled ONCE in this module (it was five times: fused
+# behind a Miller loop in `_full_pairing` and, at two and at four lanes, in
+# `pairing_product_is_one`, and alone twice): every test takes its Miller value
+# from the module's one-accumulator `_stage_miller` at nine lanes ("w1" of
+# `miller_loops`; fewer pairs are padded with masked lanes, which
+# test_padded_lanes_contribute_one shows neutral) and hands it to the one
+# program below, as one chip's stage 4 hands it from program to program.
+_compiled: dict = {}          # name -> executable, filled by `_programs`
+_digests: dict = {}           # name -> sha256 of the program's lowered text
+_final_exp_eqns: list = []    # the traced equations of `_final_exp_and_verdict`
+
+
+def _final_exp_and_verdict(f):
+    """The element after final exponentiation and, from the same program,
+    `be._stage_final_exp`'s verdict on it (that stage's body:
+    test_the_modules_final_exponentiation_is_the_stages_program)."""
+    e = po.final_exponentiation(f)
+    return e, tw.fq12_eq_one(e)
+
+
+def _final_exp(f):
+    return _compiled["final_exp"](f)[0]
+
+
+def _stage_final_exp(f):
+    return _compiled["final_exp"](f)[1]
+
+
+def _nine(p, q, mask):
+    """(xp, yp), (xq, yq) and the mask of up to nine pair lanes as
+    `_stage_miller`'s five arguments at nine, the lanes added masked and
+    zero, as the backend pads."""
+    def pad(a):
+        a = np.asarray(a)
+        return np.pad(a, [(0, 9 - a.shape[0])] + [(0, 0)] * (a.ndim - 1))
+
+    return (*map(pad, p), *map(pad, q), pad(mask).astype(bool))
+
+
+def _full_pairing(p, q, mask):
+    return _final_exp(_compiled["w1"](*_nine(p, q, mask)))
+
+
+def _product_check(p, q, mask):
+    return _stage_final_exp(_compiled["w1"](*_nine(p, q, mask)))
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -193,6 +233,18 @@ def test_folded_stage_3_then_the_product_check_give_the_reference_verdict(
     assert _pairs_folded._cache_size() == 1   # one shape, every distribution
 
 
+def test_the_modules_final_exponentiation_is_the_stages_program():
+    """This module's one final exponentiation traced to
+    `be._stage_final_exp`'s own equations, one after the other: the stage's
+    program, with the element kept beside the verdict. (The stage itself is
+    compiled and held to the pure-Python verdicts in
+    test_jaxbls_backend.py's urgent lane.)"""
+    f = jax.ShapeDtypeStruct(tw.FQ12_ONE.shape, tw.FQ12_ONE.dtype)
+    stage = jax.make_jaxpr(be._stage_final_exp)(f)
+    assert [str(e) for e in stage.eqns] == _final_exp_eqns
+    assert len(stage.eqns) > 10
+
+
 def test_final_exp_matches_python_on_random_miller_output():
     # Feed the same Miller value through both final exps.
     p = pc.g1_mul(pc.G1_GEN, rng.randrange(1, R))
@@ -247,9 +299,12 @@ def test_miller_lane_plan(platform, n_pairs, plan):
     assert after == w.bit_length() - 1
 
 
-@pytest.fixture(scope="module")
-def miller_loops():
-    """The backend's `_stage_miller` (miller_loop_product over the stage's
+@pytest.fixture(scope="module", autouse=True)
+def _programs(_no_cache_writes_for_this_module):
+    """Every program of the module, compiled side by side (one thread a
+    program: XLA releases the GIL) into `_compiled`.
+
+    The backend's `_stage_miller` (miller_loop_product over the stage's
     five flat arguments) at nine pair lanes under three plans: "w1" one
     accumulator (the CPU's loop), "pairs" four accumulators of a line pair
     each + the pair over (the block bucket's form), "padded" sixteen
@@ -258,13 +313,21 @@ def miller_loops():
     check); and at seventeen pair lanes "eights": two accumulators of
     EIGHT lines each + the pair over, so line pairs and two dense levels
     inside the step, the form of a 1,024-set bucket's 1,025 pairs on the
-    chip's row of 128. The module's four Miller-loop compiles."""
+    chip's row of 128. The module's four Miller-loop compiles; beside them
+    the one final exponentiation and `_stage_pairs_folded` from eight sets
+    onto three lanes, at the operands of its test."""
+    from jaxbls_warm import run_in_threads
+
+    be._init_consts()
     shipped = po.MILLER_LANES, po.MILLER_WIDE_FROM
     plans = {"w1": (128, 1 << 30, (1, 2, 0), 8, 9),
              "pairs": (4, 1, (4, 0, 2), 2, 9),
              "padded": (16, 1, (16, 0, 4), 1, 9),
              "eights": (2, 1, (2, 2, 1), 8, 17)}
-    fns = {}
+    traced = jax.jit(_final_exp_and_verdict).trace(
+        jax.ShapeDtypeStruct(tw.FQ12_ONE.shape, tw.FQ12_ONE.dtype))
+    _final_exp_eqns[:] = [str(e) for e in traced.jaxpr.eqns]
+    lowered = {"final_exp": traced.lower()}
     try:
         for name, (lanes, wide_from, plan, g, n_pairs) in plans.items():
             # the one entry every platform falls back to: whatever this
@@ -272,13 +335,49 @@ def miller_loops():
             po.MILLER_LANES, po.MILLER_WIDE_FROM = lanes, {"cpu": wide_from}
             assert po.miller_lane_plan(n_pairs) == plan
             assert po._lines_per_accumulator(n_pairs, plan[0]) == g
-            # trace and compile now, while the patched plan is in force
+            # traced now, while the patched plan is in force, and as a
+            # function of its own: jit keeps a function's trace by its
+            # argument shapes, whatever plan is in force, and handed the
+            # three nine-lane plans the first one's program from PR 32 on
+            def stage(*args):
+                return be._stage_miller(*args)
+
+            stage.__name__ = "_stage_miller_" + name
             dp, dq, mask = _device_pairs([], n_pairs)
-            fns[name] = jax.jit(be._stage_miller).lower(
-                *dp, *dq, mask).compile()
+            lowered[name] = jax.jit(stage).lower(*dp, *dq, mask)
     finally:
         po.MILLER_LANES, po.MILLER_WIDE_FROM = shipped
-    return fns
+
+    def compile_(name):
+        _digests[name] = hashlib.sha256(
+            lowered[name].as_text().encode()).hexdigest()
+        _compiled[name] = lowered[name].compile()
+
+    def folded():
+        jax.block_until_ready(_pairs_folded(
+            co.g1_batch_to_device([pc.G1_GEN] * 7 + [None]),
+            co.g2_batch_to_device([pc.G2_GEN] * 2 + [None]),
+            co.g2_to_device(pc.G2_GEN), be.message_fold_index([0], 8, 3)))
+
+    run_in_threads(folded, *(functools.partial(compile_, n) for n in lowered))
+    yield
+    _compiled.clear()
+    _digests.clear()
+    _final_exp_eqns.clear()
+
+
+@pytest.fixture(scope="module")
+def miller_loops():
+    """The four `_stage_miller` plans of `_programs`, by name."""
+    return _compiled
+
+
+def test_every_plan_is_a_program_of_its_own(miller_loops):
+    """The four plans lowered to four different programs: what the tests
+    below compare are different Miller loops, not one loop with itself."""
+    plans = ("w1", "pairs", "padded", "eights")
+    assert set(plans) < set(miller_loops)
+    assert len({_digests[name] for name in plans}) == 4
 
 
 @functools.cache

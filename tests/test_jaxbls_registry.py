@@ -2,16 +2,17 @@
 a SignatureSet carries, what feeds both, and the indexed path of the jax
 backend's batch lane against the packed one and against the pure-Python
 backend; and the key grids that lane lays (backend.key_grid_plan) as a pure
-function and by index (packed: test_jaxbls_key_grids.py). The first half
-compiles no stage program; the second (from "keys by validator index" on)
-drives the real staged backend on ONE device at the (4, 4) bucket, the
-unsharded programs compiled once by a module fixture (eight programs:
-test_jaxbls_backend.py is at its memory-mapping mark with its eight-device
-builds, whose mesh keeps the one grid, so these tests have a file of their
-own, and the packed two-grid batch a third since PR 45: nine programs with
-the 4-bit coefficient chains pass conftest's mark). The reference is the
-pure-Python curve code on keys decompressed from their 48-byte form."""
+function, by index and packed. The first half compiles no stage program; the
+second (from "keys by validator index" on) drives the staged backend on ONE
+device at the (4, 4) bucket, the unsharded programs compiled once by a module
+fixture: nine programs, stage 1's four forms among them, which are the
+module's subject, with stage 2 served from the host (test_jaxbls_backend.py
+is at its memory-mapping mark with its eight-device builds, whose mesh keeps
+the one grid, so these tests have a file of their own; the packed two-grid
+batch had a third from PR 45 to PR 47). The reference is the pure-Python
+curve code on keys decompressed from their 48-byte form."""
 
+import functools
 import hashlib
 import random
 from types import SimpleNamespace
@@ -30,7 +31,7 @@ from lighthouse_tpu.crypto.jaxbls.backend import one_key_grid
 from lighthouse_tpu.observability import trace as obstrace
 from lighthouse_tpu.utils.metrics import REGISTRY
 
-from jaxbls_warm import prepare_rest
+from jaxbls_warm import PREPARE_ZS, prepare_rest
 
 rng = random.Random(0x7AB1E)
 KEYS = [bls.SecretKey(rng.randrange(1, R)).public_key() for _ in range(16)]
@@ -575,26 +576,79 @@ _TABLE_ROWS = 24      # 12 validators, headroom 8: the table's capacity
 _BLOCK_GRIDS = ((2, 4), (2, 1))
 
 
+@functools.lru_cache(maxsize=None)
+def _host_map_to_g2(lane: bytes):
+    """What `hash_to_g2_jacobian` does to one lane, by the pure-Python curve
+    code: the lane's two standard-form u-values (the bytes of its (2, 2, NL)
+    limbs) through SSWU, the isogeny and the cofactor, an affine point."""
+    from lighthouse_tpu.crypto.bls381 import hash_to_curve as ph2c
+    from lighthouse_tpu.crypto.jaxbls import limbs as lb
+
+    u0, u1 = (tuple(lb.unpack(c) for c in u)
+              for u in np.frombuffer(lane, np.uint32).reshape(2, 2, lb.NL))
+    return cv.g2_clear_cofactor(cv.g2_add(
+        ph2c.iso_map(ph2c.sswu(u0)), ph2c.iso_map(ph2c.sswu(u1))))
+
+
+def _host_hash_to_g2(us):
+    """Stage 2 from the host, a stand-in of the kind test_mesh.py and
+    test_graft_entry.py use for stage 4: this module's subject is stage 1's
+    four forms, and hash-to-G2 at four lanes, the dearest program of a
+    build, is compiled and held to this same reference in
+    test_jaxbls_backend.py's urgent lane and test_jaxbls_h2c.py."""
+    from lighthouse_tpu.crypto.jaxbls import curve_ops as co
+
+    return co.g2_batch_to_device(
+        [_host_map_to_g2(lane.tobytes()) for lane in np.asarray(us)])
+
+
 @pytest.fixture(scope="module")
 def _one_device_programs():
-    """The eight programs the tests below dispatch, compiled side by side
-    in three threads: the unsharded four stages at 4 sets with the packed
-    prepare at m = 4; the indexed prepares at (4, 4) over the 24-row
-    table, over one grid and over the block's two; the packed two-grid
-    prepare over the block's grids, and the indexed prepare at (4, 1),
-    single-key sets (no key axis to sum: the smallest of them). The first
-    is the longest."""
-    import functools
-
+    """The nine programs the tests below dispatch, compiled side by side in
+    four threads: the unsharded stages at 4 sets with the packed prepare at
+    m = 4 — stages 1, 3 and the two of 4; stage 2 is `_host_hash_to_g2`
+    from here to the end of the module —; the indexed prepares at (4, 4)
+    over the 24-row table, over one grid and over the block's two; the
+    packed two-grid prepare over the block's grids and over `_PAIR_GRIDS`;
+    and the indexed prepare at (4, 1), single-key sets (no key axis to
+    sum: the smallest of them)."""
+    import lighthouse_tpu.crypto.jaxbls.backend as be
     from jaxbls_warm import run_in_threads, warm_build, warm_one_chip_prepares
 
+    stages = be._get_stages(mesh=None)
+    key = next(k for k, v in be._kernel_cache.items() if v is stages)
+    be._kernel_cache[key] = (
+        stages[0], _host_hash_to_g2, stages[2], stages[3])
     warm = functools.partial(warm_one_chip_prepares, table_rows=_TABLE_ROWS)
-    run_in_threads(
-        functools.partial(warm_build, 4, (4,), None),
-        functools.partial(warm, ("prepare_indexed", ((4, 4),)),
-                          ("prepare_indexed_grids", _BLOCK_GRIDS)),
-        functools.partial(warm, ("prepare_grids", _BLOCK_GRIDS),
-                          ("prepare_indexed", ((4, 1),))))
+    try:
+        run_in_threads(
+            functools.partial(warm_build, 4, (4,), None),
+            functools.partial(warm, ("prepare_indexed", ((4, 4),)),
+                              ("prepare_indexed_grids", _BLOCK_GRIDS)),
+            functools.partial(warm, ("prepare_grids", _BLOCK_GRIDS),
+                              ("prepare_indexed", ((4, 1),))),
+            functools.partial(warm, ("prepare_grids", _PAIR_GRIDS)))
+        yield
+    finally:
+        be._kernel_cache[key] = stages
+
+
+@pytest.mark.parametrize("msg", [b"\xE0" * 32, b"", b"lighthouse-tpu"],
+                         ids=["block_root", "empty", "text"])
+def test_the_host_stand_in_of_stage_2_is_the_reference_hash_to_g2(msg):
+    """`_host_hash_to_g2` on the marshal's u-values of a message is
+    `hash_to_g2` of that message (RFC 9380's J.10.1 vector pins it in
+    test_bls381_core.py), in the limbs stage 3 reads, beside a padded
+    lane's zeros."""
+    from lighthouse_tpu.crypto.bls381.constants import DST_POP
+    from lighthouse_tpu.crypto.jaxbls import h2c_ops as h2, tower as tw
+
+    us = np.zeros((2, 2, 2, 24), np.uint32)
+    us[0] = h2.hash_to_field_batch([msg], DST_POP)[0]
+    jac = _host_hash_to_g2(us)
+    assert [a.shape for a in jac] == [(2, 2, 24)] * 3
+    x, y, z = (tw.fq2_from_device(c[0]) for c in jac)
+    assert (x, y) == bls_api.hash_to_g2_point(msg) and z == (1, 0)
 
 
 @pytest.fixture()
@@ -945,6 +999,150 @@ def test_indexed_grids_prepare_is_bit_equal_to_the_packed_grids_prepare(
         assert a.shape == b.shape and a.dtype == b.dtype
         assert np.array_equal(np.asarray(a), np.asarray(b))
     assert not bool(np.asarray(got[2]))
+
+
+# ----------------------------------------------- two key grids, packed
+# (test_jaxbls_key_grids.py from PR 45 to PR 47: back here since the module's
+# stage 2 is the host's, which left the mapping mark room for the ninth
+# program.) A batch of unequal widths lies as a wide and a narrow grid and
+# stage 1 sums each by the one tree_sum. Widths (1, 2, 2, 4) in the (4, 4)
+# bucket, wide 1 x 4 and narrow 4 x 2 (`_PAIR_GRIDS`: 12 slots of 16, the
+# edge of the 3/4 rule), against the one grid, the pure-Python backend and,
+# for the aggregate keys, the pure-Python curve.
+
+_PAIR_GRIDS = ((1, 4), (4, 2))
+
+
+@pytest.fixture()
+def two_grid_backend(_one_device_programs, one_chip_backend):
+    """`one_chip_backend` behind the module's compiled programs."""
+    return one_chip_backend
+
+
+def _set_of(sks, msg, valid=True):
+    """A set of the keys of `sks` signed by all of them; where they sum to
+    zero the signature is some point that is not the identity (no
+    signature verifies against the identity key)."""
+    agg = (sum(sks) + (0 if valid else 1)) % R or 7
+    return bls.SignatureSet(
+        bls.Signature(cv.g2_mul(bls_api.hash_to_g2_point(msg), agg)),
+        [bls.PublicKey(cv.g1_mul(cv.G1_GEN, sk)) for sk in sks], msg)
+
+
+def _pair_batch(case):
+    """Four sets of 1, 2, 2 and 4 keys; `case` damages one."""
+    rng = random.Random(0x261D)
+    sks = [[rng.randrange(1, R) for _ in range(w)] for w in (1, 2, 2, 4)]
+    if case in ("identity_narrow", "identities"):
+        sks[1][1] = R - sks[1][0]
+    if case in ("identity_wide", "identities"):
+        sks[3][1], sks[3][3] = R - sks[3][0], R - sks[3][2]
+    return [_set_of(ks, bytes([0xC0 + i]) * 32, valid=(case, i) != ("tampered", 2))
+            for i, ks in enumerate(sks)]
+
+
+@pytest.mark.parametrize("case", [
+    "valid", "tampered", "identity_narrow", "identity_wide"])
+def test_a_mixed_batch_on_two_grids_gives_the_reference_verdict(
+        two_grid_backend, case):
+    """`bls.verify_signature_sets` on a packed batch of unequal widths:
+    the dispatch lays two grids (its trace says which, its bucket is
+    still (4, 4)), counts the slots it lays and the lane-additions of
+    both sums, and its verdict is the pure-Python backend's — True when
+    sound, False with one bad signature, False with a set whose keys sum
+    to the identity in the narrow grid or in the wide one."""
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+
+    sets = _pair_batch(case)
+    assert be.key_grid_plan([1, 2, 2, 4], 4, 4)[0] == _PAIR_GRIDS
+    program = be._get_one_chip_variant("prepare_grids")
+    compiled = program._cache_size()
+    padded = be._BUCKET_SLOTS.labels("keys", "padded")
+    adds = be._TREE_SUM_LANE_ADDS.labels("done")
+    padded0, adds0, taken = padded.value, adds.value, _keys_taken()
+    tr = obstrace.Trace("gossip_block", 1)
+    obstrace.set_current_trace(tr)
+    try:
+        on_jax = bls.verify_signature_sets(sets)
+    finally:
+        obstrace.set_current_trace(None)
+    assert program._cache_size() == compiled
+    assert tr.meta["bucket"] == "4x4" and tr.meta["key_grids"] == "1x4+4x2"
+    assert padded.value - padded0 == 1 * 4 + 4 * 2
+    # tree_sum_plan(4, 1) + tree_sum_plan(2, 4): 3 adds, and 1 on 4 lanes
+    assert adds.value - adds0 == 3 + 4
+    assert _keys_taken() == {"table": taken["table"],
+                             "packed": taken["packed"] + 9}
+    bls_api.set_backend("python")
+    assert bls.verify_signature_sets(sets) is (case == "valid")
+    assert on_jax is (case == "valid")
+
+
+def _affine_points(jac):
+    """[(x, y) or None] of a batch of Jacobian G1 points in Montgomery
+    limbs, by Python integers."""
+    from lighthouse_tpu.crypto.bls381.constants import P
+    from lighthouse_tpu.crypto.jaxbls import tower as tw
+
+    out = []
+    for x, y, z in zip(*(tw.fq_batch_from_device(c) for c in jac)):
+        zi = pow(z, -1, P) if z else 0
+        out.append((x * zi * zi % P, y * zi * zi * zi % P) if z else None)
+    return out
+
+
+@pytest.mark.parametrize("case,n_real", [("identities", 4), ("valid", 3)],
+                         ids=["identities", "padded_slot"])
+def test_two_grids_sum_to_the_one_grids_aggregate_keys(two_grid_backend,
+                                                       case, n_real):
+    """The two-grid prepare against the one-grid prepare on the same sets:
+    every z_i * aggpk_i the same AFFINE point (the sums associate
+    differently, so the Jacobian limbs differ) and the pure-Python
+    curve's, the signatures' sum limb for limb, `bad_aggpk` alike — set
+    where a real set's keys sum to the identity, in the narrow grid and in
+    the wide one. A padded set slot reads the identity entry behind the
+    grids' sums, as the one grid's all-masked row sums to it (three sets,
+    the wide grid left empty by a hand-laid `where`)."""
+    import lighthouse_tpu.crypto.jaxbls.backend as be
+
+    backend = two_grid_backend
+    sets = _pair_batch(case)[:n_real]
+    plan = be.key_grid_plan([1, 2, 2, 4], 4, 4)
+    if n_real == 3:
+        plan = (plan[0], np.array([1, 2, 3, 5], np.int32))
+    assert plan[0] == _PAIR_GRIDS and plan[1].tolist()[:3] == [1, 2, 3]
+    rest = prepare_rest(sets, n_real)
+    grids = backend._marshal_pubkeys(sets, plan, single_chip=True)
+    one = backend._marshal_pubkeys(sets, ONE_GRID, single_chip=True)
+    assert [g.shape for g in grids] == [
+        (1, 4, 24), (1, 4, 24), (1, 4), (4, 2, 24), (4, 2, 24), (4, 2), (4,)]
+    assert sum(int(np.asarray(m).sum()) for m in (grids[2], grids[5])) == (
+        int(np.asarray(one[2]).sum())) == sum(len(s.signing_keys) for s in sets)
+    got = be._get_one_chip_variant("prepare_grids")(*grids, *rest)
+    want = be._get_stages(mesh=None)[0](*one, *rest)
+
+    def aggregate(s, k):
+        total = None
+        for pk in s.signing_keys:
+            total = cv.g1_add(total, pk.point)
+        return cv.g1_mul(total, k)
+
+    reference = [aggregate(s, k) for s, k in zip(sets, PREPARE_ZS)] + [None] * (4 - n_real)
+    assert _affine_points(got[0]) == _affine_points(want[0]) == reference
+    assert reference.count(None) == {"valid": 1, "identities": 2}[case]
+    for a, b in zip(got[1], want[1]):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert bool(np.asarray(got[2])) is bool(np.asarray(want[2])) is (
+        case == "identities")
+
+
+def test_two_grid_programs_stay_under_the_mapping_mark(two_grid_backend):
+    """As in the other modules that drive the staged backend: with this
+    module's nine programs compiled and kept, the process must be under
+    conftest's mark."""
+    from conftest import _MAP_COUNT_HIGH_MARK, _n_memory_mappings
+
+    assert _n_memory_mappings() < _MAP_COUNT_HIGH_MARK
 
 
 def test_module_stays_under_the_mapping_mark(registry_chain):
